@@ -105,8 +105,7 @@ stream-check:
 # Compressed-plan-stream gate (tools/compress_check.py): lossless/f32
 # codec round trip, the measured-error gate (lossless <= 1e-12 vs fused,
 # measured 0.0; f32 <= 1e-6), off-tier bit-identity with bitpacked rok,
-# the Pallas decode kernel (interpret) vs the XLA decode path, encoded
-# plan bytes >= 2.5x smaller gated via `obs_report diff --phases`
+# encoded plan bytes >= 2.5x smaller gated via `obs_report diff --phases`
 # (phase_plan_h2d_bytes down, compute flat), and the PROGRESS.jsonl
 # trend gate guarding compress_ratio.  Deterministic, ~40 s on CPU.
 compress-check:

@@ -33,7 +33,12 @@ from distributed_matvec_tpu.utils.cache import enable_compilation_cache
 enable_compilation_cache()
 
 
-def main(argv=None):
+def main(argv=None, inspect=None):
+    """Run the app.  ``inspect``, when given, is called once after the solve
+    with a namespace (``engine``, ``config``, ``eigenvalues``, ``residuals``,
+    ``eigenvectors``, ``iterations``, ``solve_seconds``, ``timer``) while the
+    engine and the solver's vectors are still alive — ``chip_smoke.py`` checks
+    where they live and applies the engine once more through it."""
     # root run span: every event of the run (engine builds, solver
     # iterations, applies, the save epilogue) becomes a descendant of one
     # `diagonalize` span, and the trace-id stamp resolves lazily AFTER
@@ -42,10 +47,10 @@ def main(argv=None):
     from distributed_matvec_tpu.obs import trace as _trace
 
     with _trace.span("diagonalize", kind="run"):
-        return _main(argv)
+        return _main(argv, inspect)
 
 
-def _main(argv=None):
+def _main(argv=None, inspect=None):
     ap = argparse.ArgumentParser(
         description=__doc__.split("\n")[0],
         epilog="exit codes: 0 solved, 2 bad config/arguments, "
@@ -315,7 +320,7 @@ def _main(argv=None):
 
     from distributed_matvec_tpu.utils.profiling import maybe_profile
 
-    resumed_from = 0
+    resumed_from = restarts = 0
     try:
         with timer.scope("solve"), maybe_profile():
             t0 = time.perf_counter()
@@ -389,7 +394,7 @@ def _main(argv=None):
                                            res.residual_norms,
                                            res.num_iters)
                 evecs = res.eigenvectors
-                resumed_from = res.resumed_from
+                resumed_from, restarts = res.resumed_from, res.restarts
                 if not res.converged:
                     print("warning: solver did not converge",
                           file=sys.stderr)
@@ -413,11 +418,18 @@ def _main(argv=None):
         print(f"solver: resumed from {resumed_from} checkpointed "
               "iterations")
     print(f"solver: {niter} iterations in {dt:.2f}s "
-          f"({niter / max(dt, 1e-9):.2f} iters/s)")
+          f"({niter / max(dt, 1e-9):.2f} iters/s)"
+          + (f", {restarts} thick restarts" if restarts else ""))
     obs.emit("diagonalize_result",
              eigenvalues=[float(w) for w in np.atleast_1d(evals)],
              residuals=[float(r) for r in np.atleast_1d(residuals)],
              iters=int(niter), solve_s=round(dt, 3))
+    if inspect is not None:
+        from types import SimpleNamespace
+        inspect(SimpleNamespace(
+            engine=eng, config=cfg, eigenvalues=np.atleast_1d(evals),
+            residuals=np.atleast_1d(residuals), eigenvectors=evecs,
+            iterations=int(niter), solve_seconds=dt, timer=timer))
 
     evec_rows = None
     evecs_hashed = None

@@ -2,9 +2,11 @@
 
 Dispatch (the ``_enumerateStates`` analog, StatesEnumeration.chpl:257-265):
 the streaming C++ kernel handles projected sectors (compiled on first use,
-``native.py``); the NumPy path covers trivial/spin-inversion-only sectors and
-acts as the portable fallback.  ``enumeration_backend`` config: ``auto`` |
-``native`` | ``numpy``.
+``native.py``); the NumPy path covers trivial/spin-inversion-only sectors.
+The choice is made by sector, never by a failed build: a kernel that cannot
+be built raises ``native.NativeBuildError``.  ``enumeration_backend`` config:
+``auto`` | ``native`` (same dispatch) | ``numpy`` (NumPy for every sector —
+the tests' reference, and the explicit way out on a host with no compiler).
 """
 
 from typing import Optional, Tuple
@@ -20,6 +22,7 @@ __all__ = ["host", "enumerate_representatives"]
 def enumerate_representatives(
     n_sites: int, hamming_weight: Optional[int], group
 ) -> Tuple[np.ndarray, np.ndarray]:
+    from ..obs.metrics import counter
     from ..utils.timers import timed
 
     backend = get_config().enumeration_backend
@@ -31,13 +34,11 @@ def enumerate_representatives(
     if backend != "numpy" and projected and not spin_inv_only:
         from . import native
 
+        counter("enumeration", backend="native").inc()
         with timed(f"enumerate[native] n={n_sites} hw={hamming_weight} "
                    f"G={len(group)}"):
-            out = native.enumerate_representatives_native(
+            return native.enumerate_representatives_native(
                 n_sites, hamming_weight, group)
-        if out is not None:
-            return out
-        if backend == "native":
-            raise RuntimeError("native enumeration requested but unavailable")
+    counter("enumeration", backend="numpy").inc()
     with timed(f"enumerate[numpy] n={n_sites} hw={hamming_weight}"):
         return host.enumerate_representatives(n_sites, hamming_weight, group)

@@ -4,9 +4,11 @@ The reference's enumeration is native (Haskell/C kernels called in 10240-state
 batches, StatesEnumeration.chpl:158-200) and parallel (dynamic chunking over
 tasks, :321-334).  This wrapper:
 
-  * compiles ``_native.cpp`` on first use with g++ (-O3 -march=native) and
-    caches the .so next to the source (falls back to the pure-NumPy path in
-    ``host.py`` if no compiler is available),
+  * compiles ``_native.cpp`` on first use with g++ for one fixed, named
+    target (``_build_command``) and keeps the .so next to the source under
+    a name keyed on the source and the command, so a binary that something
+    else produced is never loaded; a failed build raises
+    :class:`NativeBuildError` with the compiler's stderr,
   * splits the search range into equal-*index*-work chunks via the
     fixed-hamming rank/unrank (``determineEnumerationRanges``,
     StatesEnumeration.chpl:94-113),
@@ -20,26 +22,41 @@ tasks, :321-334).  This wrapper:
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
+import platform
+import shutil
 import subprocess
-import sys
 import threading
-from typing import Optional, Tuple
+from typing import List, Optional, Tuple
 
 import numpy as np
 
 from . import host as _host
-from ..utils.logging import log_debug
 
-__all__ = ["native_available", "enumerate_representatives_native",
+__all__ = ["NativeBuildError", "native_available", "build_info",
+           "enumerate_representatives_native",
            "lookup_owners", "full_state_range", "rank_state_ranges"]
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "_native.cpp")
-_SO = os.path.join(_HERE, f"_native_{sys.platform}.so")
+_CXX = "g++"
+# One named target per architecture instead of -march=native: the binary is
+# built on whatever host first needs it (a sandbox, a TPU VM) and must mean
+# the same thing on each.  x86-64-v3 (AVX2/BMI2/POPCNT, Haswell 2013 and
+# later) covers every host a TPU is attached to; /proc/cpuinfo is checked
+# against _TARGET_CPU_FLAGS before the build so a lesser CPU is an error
+# here, not a SIGILL in the scan.
+_TARGETS = {"x86_64": "x86-64-v3"}
+_TARGET_CPU_FLAGS = ("avx2", "bmi1", "bmi2", "fma", "movbe", "popcnt", "abm")
 _lock = threading.Lock()
 _lib = None
-_lib_failed = False
+_lib_error: Optional["NativeBuildError"] = None
+
+
+class NativeBuildError(RuntimeError):
+    """The C++ enumeration kernel could not be built or loaded."""
 
 
 class _Group(ctypes.Structure):
@@ -54,38 +71,100 @@ class _Group(ctypes.Structure):
     ]
 
 
-def _build() -> Optional[str]:
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
-        return _SO
+def _build_flags() -> List[str]:
+    flags = ["-O3", "-std=c++17", "-shared", "-fPIC"]
+    march = _TARGETS.get(platform.machine())
+    if march:
+        flags.append(f"-march={march}")
+    return flags
+
+
+def _build_command(out: str) -> List[str]:
+    return [_CXX, *_build_flags(), "-o", out, _SRC, "-lpthread"]
+
+
+def _so_path() -> str:
+    """The one file name the current source + compiler flags may produce
+    (paths stay out of the key: every checkout of one commit agrees)."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join([_CXX, *_build_flags()]).encode())
+    return os.path.join(_HERE, f"_native_{h.hexdigest()[:16]}.so")
+
+
+def _check_cpu() -> None:
+    if platform.machine() not in _TARGETS:
+        return
+    try:
+        with open("/proc/cpuinfo") as f:
+            line = next((ln for ln in f if ln.startswith("flags")), "")
+    except OSError:
+        return
+    have = set(line.split(":", 1)[-1].split())
+    missing = [fl for fl in _TARGET_CPU_FLAGS if fl not in have]
+    if have and missing:
+        raise NativeBuildError(
+            f"this CPU lacks {missing}, which the native enumerator's "
+            f"build target -march={_TARGETS[platform.machine()]} assumes")
+
+
+def _build() -> str:
+    so = _so_path()
+    if os.path.exists(so):
+        return so
+    _check_cpu()
     # compile to a temp name and rename: writing the .so in place would
     # clobber the text mapping of any process that already dlopened it
     # (a long-running enumeration would SIGBUS mid-flight)
-    tmp = _SO + f".build{os.getpid()}"
-    cmd = ["g++", "-O3", "-march=native", "-std=c++17", "-shared", "-fPIC",
-           "-o", tmp, _SRC, "-lpthread"]
+    tmp = so + f".build{os.getpid()}"
+    cmd = _build_command(tmp)
     try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, _SO)
-        return _SO
-    except Exception as e:  # no compiler / sandboxed FS → NumPy fallback
-        log_debug(f"native enumeration unavailable ({e}); using NumPy path")
+        subprocess.run(cmd, check=True, capture_output=True, text=True,
+                       timeout=300)
+        os.replace(tmp, so)
+    except (OSError, subprocess.SubprocessError) as e:
         try:
             os.unlink(tmp)
         except OSError:
             pass
-        return None
+        stderr = (getattr(e, "stderr", None) or "").strip()
+        raise NativeBuildError(
+            f"building the native enumerator failed: {e!r}\n"
+            f"command: {' '.join(cmd)}\n"
+            f"compiler stderr:\n{stderr or '(none)'}\n"
+            "The NumPy enumerator is not substituted for it; pick it "
+            "explicitly with DMT_ENUMERATION_BACKEND=numpy (small sectors "
+            "only).") from e
+    # binaries of earlier sources or flags are never loaded again; unlinking
+    # one that a running process has mapped is harmless
+    for old in glob.glob(os.path.join(_HERE, "_native_*.so")):
+        if old != so:
+            try:
+                os.unlink(old)
+            except OSError:
+                pass
+    return so
 
 
 def _load():
-    global _lib, _lib_failed
+    """The loaded kernel library; raises :class:`NativeBuildError` (every
+    time, without recompiling) once a build or load has failed."""
+    global _lib, _lib_error
     with _lock:
-        if _lib is not None or _lib_failed:
+        if _lib is not None:
             return _lib
-        so = _build()
-        if so is None:
-            _lib_failed = True
-            return None
-        lib = ctypes.CDLL(so)
+        if _lib_error is not None:
+            raise _lib_error
+        try:
+            lib = ctypes.CDLL(_build())
+        except NativeBuildError as e:
+            _lib_error = e
+            raise
+        except OSError as e:
+            _lib_error = NativeBuildError(
+                f"loading the native enumerator failed: {e!r}")
+            raise _lib_error from e
         lib.dmt_enumerate_ranges.restype = ctypes.c_int64
         lib.dmt_enumerate_ranges.argtypes = [
             ctypes.POINTER(ctypes.c_uint64), ctypes.POINTER(ctypes.c_uint64),
@@ -113,7 +192,22 @@ def _load():
 
 
 def native_available() -> bool:
-    return _load() is not None
+    """Whether this host has the compiler the kernel is built with.  A
+    compiler that is present and fails is an error (:func:`_load`), not
+    'unavailable'."""
+    return shutil.which(_CXX) is not None
+
+
+def build_info() -> dict:
+    """What the environment line of ``chip_smoke.py`` prints: compiler
+    path, build command, and whether the kernel for the current source is
+    built (building it if need be)."""
+    so = _so_path()
+    prebuilt = os.path.exists(so)
+    _load()
+    return {"compiler": shutil.which(_CXX),
+            "command": " ".join(_build_command(os.path.basename(so))),
+            "result": "loaded existing" if prebuilt else "built"}
 
 
 def _group_tables_cheap_first(group):
@@ -293,15 +387,14 @@ def enumerate_representatives_native(
     n_chunks: Optional[int] = None,
     n_threads: Optional[int] = None,
     norm_tol: float = 1e-12,
-) -> Optional[Tuple[np.ndarray, np.ndarray]]:
-    """Streaming native enumeration; None if the kernel is unavailable.
+) -> Tuple[np.ndarray, np.ndarray]:
+    """Streaming native enumeration; raises :class:`NativeBuildError` if
+    the kernel cannot be built.
 
     Matches :func:`host.enumerate_representatives` exactly (same order,
     same norms) — property-tested in tests/test_enumeration.py.
     """
     lib = _load()
-    if lib is None:
-        return None
     parts_s, parts_n = [], []
     for s, n in _stream_native(lib, n_sites, hamming_weight, group,
                                n_chunks, n_threads, norm_tol):
@@ -317,11 +410,8 @@ def lookup_owners(betas: np.ndarray, alphas: np.ndarray,
                   n_threads: Optional[int] = None):
     """(owner, idx, found) for each state in ``betas`` against the per-shard
     sorted representative prefixes ``alphas[d][:counts[d]]`` — the routing
-    plan's hot host loop in one threaded native pass.  Returns None when
-    the kernel is unavailable (callers fall back to NumPy)."""
+    plan's hot host loop in one threaded native pass."""
     lib = _load()
-    if lib is None:
-        return None
     betas = np.ascontiguousarray(betas, np.uint64)
     alphas = np.ascontiguousarray(alphas, np.uint64)
     counts = np.ascontiguousarray(counts, np.int64)

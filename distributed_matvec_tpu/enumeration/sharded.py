@@ -113,11 +113,6 @@ def enumerate_to_shards(
         # mid-run preserves the previous (still self-consistent) file
 
     lib = _native._load()
-    if lib is None:
-        raise RuntimeError(
-            "sharded enumeration needs the native kernel (g++); "
-            "it is not available on this host"
-        )
 
     D = n_shards
     counts = np.zeros(D, dtype=np.int64)

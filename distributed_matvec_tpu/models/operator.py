@@ -305,6 +305,40 @@ class Operator:
                 np.add.at(y, idx, a)
         return y
 
+    def matvec_host_rows(self, x: np.ndarray, rows) -> np.ndarray:
+        """Rows ``rows`` of y = H·x on the host, in row (gather) form — the
+        reference for bases too large for a full :meth:`matvec_host` (the
+        whole chain_32_symm apply takes about half an hour in NumPy; 65,536
+        rows take seconds).  For Hermitian H::
+
+            y[i] = d(α_i)·x[i] + Σ_t conj(amp_t·χ_t)·(n_β/n_α_i)·x[index(rep β_t)]
+
+        where ``(β_t, amp_t)`` are the column entries ``apply_off_diag``
+        generates from ``α_i``.  ``rows`` is an index array or a slice.
+        """
+        basis = self.basis
+        reps, norms = basis.representatives, basis.norms
+        x = np.asarray(x)
+        alphas = reps[rows]
+        betas, amps = self.apply_off_diag(alphas)           # [R, T]
+        coeff = np.conj(amps.reshape(-1))
+        flat = betas.reshape(-1)
+        if basis.requires_projection:
+            flat, chars, norm_b = basis.group.state_info(flat)
+            # out-of-sector betas come back with norm 0, so coeff == 0
+            coeff = coeff * np.conj(chars) \
+                * (norm_b / np.repeat(norms[rows], betas.shape[1]))
+        idx = basis.state_index(flat)
+        if ((idx < 0) & (coeff != 0)).any():
+            raise RuntimeError(
+                f"generated state not in basis: {flat[idx < 0][:5]}")
+        # a clipped index can only pick up a zero coefficient
+        vals = coeff * x[np.clip(idx, 0, x.shape[0] - 1)]
+        y = self.apply_diag(alphas) * x[rows] \
+            + vals.reshape(betas.shape).sum(axis=1)
+        real = self.effective_is_real and not np.iscomplexobj(x)
+        return y.real if real else y
+
     def to_sparse(self):
         """Sparse CSR matrix of the (symmetry-adapted) operator — host only."""
         import scipy.sparse as sp

@@ -8,6 +8,7 @@ terms), and optional ``observables``.
 
 from __future__ import annotations
 
+import os
 from dataclasses import dataclass, field
 from typing import List, Optional
 
@@ -16,7 +17,13 @@ import yaml
 from .basis import SpinBasis, SpinfulFermionBasis, SpinlessFermionBasis
 from .operator import Operator
 
-__all__ = ["Config", "load_config_from_yaml", "basis_from_dict", "operator_from_dict"]
+__all__ = ["Config", "DATA_DIR", "load_config_from_yaml", "basis_from_dict",
+           "operator_from_dict"]
+
+#: the model files that ship with the checkout (upstream-schema YAMLs, each
+#: naming its upstream source) — what the tools' ``--config`` names resolve in
+DATA_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__)))), "data")
 
 
 @dataclass

@@ -66,12 +66,14 @@ __all__ = [
 RATE_FIELDS = ("gather_rows_per_s", "h2d_bytes_per_s",
                "exchange_bytes_per_s", "flops_per_s")
 
-#: Documented defaults per backend family.  TPU numbers are the DESIGN.md §2
-#: v5e measurements (gather 160–185 M rows/s at large tables — the flat,
-#: locality-independent per-row rate); h2d/ICI are nominal catalog numbers.
-#: CPU numbers are conservative single-core-rig figures for the virtual-
-#: device test mesh; a `tools/gather_bound.py` run replaces them with
-#: measured rates.
+#: Documented defaults per backend family.  The "tpu" row is a round-2
+#: builder's guess for ONE device_kind (TPU v5e: the gather rate is the
+#: DESIGN.md §2 figure, 160–185 M rows/s; h2d/ICI/flops are catalog-style
+#: round numbers) — no run on an attached chip has calibrated it, and doing
+#: so is the benchmark PR's work (ROADMAP D7).  CPU numbers are conservative
+#: single-core-rig figures for the virtual-device test mesh; a
+#: `tools/gather_bound.py` run replaces either row with measured rates.
+#: A backend that is not in the table is an error, never a default.
 DEFAULT_CALIBRATIONS: Dict[str, Dict[str, float]] = {
     "tpu": {"gather_rows_per_s": 185e6, "h2d_bytes_per_s": 8e9,
             "exchange_bytes_per_s": 45e9, "flops_per_s": 2e11},
@@ -88,13 +90,14 @@ def default_calibration(backend: Optional[str] = None) -> dict:
     """The analytic default rates for ``backend`` (``jax.default_backend()``
     when None), tagged ``source="default"`` so reports say so."""
     if backend is None:
-        try:
-            import jax
-            backend = jax.default_backend()
-        except Exception:
-            backend = "cpu"
-    base = DEFAULT_CALIBRATIONS.get(
-        str(backend).lower(), DEFAULT_CALIBRATIONS["cpu"])
+        import jax
+        backend = jax.default_backend()
+    base = DEFAULT_CALIBRATIONS.get(str(backend).lower())
+    if base is None:
+        raise ValueError(
+            f"no default calibration for backend {backend!r} (known: "
+            f"{sorted(DEFAULT_CALIBRATIONS)}); measure one with "
+            "tools/gather_bound.py or pass explicit rates")
     return dict(base, backend=str(backend), source="default")
 
 
@@ -120,9 +123,13 @@ def calibration_path(backend: Optional[str] = None,
             device_kind = device_kind or jax.devices()[0].device_kind
         except Exception:
             return None
-    return artifact_path(
-        "calibration", _calibration_fingerprint(backend, device_kind),
-        ".json")
+    try:
+        return artifact_path(
+            "calibration", _calibration_fingerprint(backend, device_kind),
+            ".json")
+    except OSError as e:
+        log_debug(f"calibration artifact cache unavailable: {e!r}")
+        return None
 
 
 def save_calibration(cal: dict, path: Optional[str] = None) -> Optional[str]:

@@ -70,14 +70,8 @@ Tiers (``stream_compress`` knob / ``DMT_STREAM_COMPRESS``):
 Versioned: ``spec["version"]`` rides the sidecar (and the engine
 fingerprint), so a format change misses and rebuilds — never misreads.
 
-The decode runs either as plain XLA ops traced into the chunk program
-(the default — XLA fuses unpack+gather+multiply+segment-add into the one
-compiled chunk executable) or through the explicit Pallas kernel
-:func:`fused_decode_gather_scatter` (``stream_kernel=pallas``, interpret
-mode on non-TPU backends — the CPU rig's path), which fuses
-decode + x-gather + multiply + the send-side scatter in one kernel; the
-``all_to_all`` necessarily splits the region, so the receive-side
-``segment_sum`` stays in the XLA epilogue either way.
+The decode runs as plain XLA ops traced into the chunk program: XLA fuses
+unpack+gather+multiply+segment-add into the one compiled chunk executable.
 """
 
 from __future__ import annotations
@@ -98,7 +92,6 @@ __all__ = [
     "unpack_bits",
     "PlanCodec",
     "decode_plan_shard",
-    "fused_decode_gather_scatter",
 ]
 
 PLAN_CODEC_VERSION = 1
@@ -175,10 +168,7 @@ def unpack_bits(packed, n: int, width: int):
     """Device (jax) unpack: one gather + shifts per value, branch-free
     (both words of a potentially-straddling value are always read; the
     second index is clamped so the read is in-bounds even without the
-    spare word — a masked ``where`` discards it when unused).  The ONE
-    implementation — also the Pallas kernel's body helper (``jnp.take``
-    works on loaded values and Refs-read-as-arrays alike), so the
-    XLA-vs-Pallas bit-identity gate covers a single decode.  Bit offsets
+    spare word — a masked ``where`` discards it when unused).  Bit offsets
     are computed in i64: ``n·width`` routinely exceeds 2³² at
     chain_32-class shard sizes, and u32 offset wrap would decode silently
     wrong destinations."""
@@ -611,8 +601,7 @@ def decode_plan_shard(spec: Dict, dest, coeff, ridx, rok, cdict):
     ridx i32 [D·cap_eff], rok bool)``.  Pure jax ops — traced into the
     (shard_mapped) chunk program, where XLA fuses the unpack/gather
     chain with the multiply + scatter + ``segment_sum`` that follows
-    (the default "fused decode" path; ``stream_kernel=pallas`` swaps the
-    send side for the explicit kernel below)."""
+    (the "fused decode" path)."""
     import jax.numpy as jnp
 
     n_recv = spec["n_recv"]
@@ -646,54 +635,3 @@ def _decode_coeff_vals(spec: Dict, coeff, cdict):
     if ckind == "complex":
         return (v[..., 0] + 1j * v[..., 1]).astype(jnp.complex128)
     return v
-
-
-def fused_decode_gather_scatter(spec: Dict, edest, ecodes, cdict, x_c,
-                                interpret: bool):
-    """The explicit fused decode+gather+multiply+scatter kernel (Pallas):
-    unpack the bitpacked destination and row streams, decode the
-    coefficient codes through the dictionary, gather each live entry's x
-    row, multiply, and scatter the amplitudes into the send buffer — one
-    kernel, nothing materialized in HBM between steps.  Returns the
-    ``[D·cap_eff + 1]`` f64 send buffer (the trailing slot collects the
-    padding entries; the caller slices it off before the ``all_to_all``).
-    The receive-side ``segment_sum`` stays in the XLA epilogue — the
-    collective necessarily splits the fused region.
-
-    Scope (enforced by the caller's eligibility check in
-    ``_make_streamed_matvec``): real sector, single column, dict-coded
-    coefficients.  ``interpret=True`` on non-TPU backends (the CPU rig);
-    opt-in via ``stream_kernel=pallas`` — the XLA-ops path in
-    :func:`decode_plan_shard` is the default and the fallback.
-    """
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    nl, wd, wr = spec["n_live"], spec["w_dest"], spec["w_row"]
-    n_recv = spec["n_recv"]
-    nwd = packed_words(nl, wd)
-
-    def kernel(edest_ref, codes_ref, cdict_ref, x_ref, out_ref):
-        out_ref[...] = jnp.zeros_like(out_ref)
-        packed = edest_ref[...]
-        dest = unpack_bits(packed[:nwd], nl, wd).astype(jnp.int32)
-        rows = unpack_bits(packed[nwd:], nl, wr).astype(jnp.int32)
-        cf = jnp.take(cdict_ref[...], codes_ref[...].astype(jnp.int32))
-        amps = cf * jnp.take(x_ref[...], rows)
-        # dest slots are unique by construction (in-bucket rank), so the
-        # scatter is collision-free; padding entries land in the
-        # trailing drop slot
-        dest = jnp.minimum(dest, n_recv)
-
-        def body(i, _):
-            out_ref[dest[i]] = amps[i]
-            return 0
-
-        jax.lax.fori_loop(0, nl, body, 0)
-
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((n_recv + 1,), jnp.float64),
-        interpret=interpret,
-    )(edest, ecodes, cdict, x_c)

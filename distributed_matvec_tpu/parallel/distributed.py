@@ -122,8 +122,7 @@ from .engine import (SENTINEL_STATE, analyze_bound_apply, apply_diag_jit,
                      precompile, raise_deferred_failure,
                      record_structure_cache, register_engine_memory,
                      compact_magnitude, unroll_terms_ok, use_pair_complex)
-from .mesh import (SHARD_AXIS, make_mesh, pcast_varying,
-                   shard_map_compat, shard_spec)
+from .mesh import SHARD_AXIS, make_mesh, shard_spec
 from .shuffle import HashedLayout
 
 __all__ = ["DistributedEngine"]
@@ -739,12 +738,6 @@ class DistributedEngine:
                     # value-exact); a hand-pinned DMT_STREAM_COMPRESS or
                     # non-default config value was never overridden above
                     self._compress = self._tune_compress
-                sk = str(cfg.stream_kernel).strip().lower() or "auto"
-                if sk not in ("auto", "xla", "pallas"):
-                    raise ValueError(
-                        f"unknown stream_kernel {cfg.stream_kernel!r}; "
-                        "pick auto|xla|pallas")
-                self._stream_kernel = "xla" if sk == "auto" else sk
                 #: hybrid mode's resolved [T] stream mask (True = the
                 #: term's entries travel in the plan stream, False = the
                 #: term recomputes on device inside the chunk program);
@@ -1706,7 +1699,7 @@ class DistributedEngine:
         cf_ndim = 4 if is_pair else 3
 
         def build_fn(a_c, n_c, tables, lk_pair, lk_dir):
-            f = shard_map_compat(
+            f = jax.shard_map(
                 shard_body, mesh=mesh,
                 in_specs=(_pspec(2), _pspec(2), P(), _pspec(3), _pspec(2)),
                 out_specs=(_pspec(2), _pspec(cf_ndim), _pspec(2), _pspec(2),
@@ -2846,7 +2839,6 @@ class DistributedEngine:
         # the true max fill (cap_eff == cap_build for the off tier)
         cap_apply = int(spec["cap_eff"])
         n_recv = D * cap_apply
-        pallas_interp = self.mesh.devices.flat[0].platform != "tpu"
 
         def make_recompute(tail):
             """HYBRID's recompute side for one chunk: re-derive the
@@ -2927,32 +2919,12 @@ class DistributedEngine:
             ``decode_recv``), so the two schedules compute identical
             amplitudes by construction."""
             nbt = len(tail) - len(ptail)   # number of batch axes (0 or 1)
-            # the explicit Pallas kernel covers the dict-coded real-sector
-            # single-column stream (the bench/gate shape); every other
-            # shape — hybrid chunks included (their recompute side merges
-            # after the decode, the documented fallback) — decodes through
-            # the XLA-ops path, which the compiler fuses into the chunk
-            # program anyway
-            use_pallas = (self._stream_kernel == "pallas"
-                          and not tier_off and not hyb
-                          and spec["coeff"] == "dict"
-                          and self.real and tail == ())
             add_recompute = make_recompute(tail) if (hyb and n_rec) \
                 else None
 
             def decode_send(x_c, dest, coeff, ridx, rok, cdict,
                             a_c=None, n_c=None, ht=None):
-                if use_pallas:
-                    # fused decode+gather+multiply+scatter in one kernel;
-                    # same arithmetic, so the result is bit-identical to
-                    # the XLA decode path
-                    ridx_ = PC.unpack_bits(
-                        ridx, n_recv, spec["w_ridx"]).astype(jnp.int32)
-                    rok_ = PC.unpack_bits(rok, n_recv, 1).astype(bool)
-                    send_a = PC.fused_decode_gather_scatter(
-                        spec, dest, coeff, cdict, x_c,
-                        interpret=pallas_interp)[:n_recv]
-                elif tier_off:
+                if tier_off:
                     # raw plan layout: identical arithmetic to the fused
                     # chunk — amplitudes are conj-coefficient × x,
                     # dead/overflowed entries dropped by dest == D·Cap
@@ -3073,7 +3045,7 @@ class DistributedEngine:
 
             def chunk_fn(xp, y, start, dest, coeff, ridx, rok, cdict,
                          *hargs):
-                f = shard_map_compat(
+                f = jax.shard_map(
                     shard_body, mesh=mesh,
                     in_specs=(_pspec(nd), _pspec(nd), P(),
                               _pspec(dest.ndim), _pspec(coeff.ndim),
@@ -3111,7 +3083,7 @@ class DistributedEngine:
                 return send_a[None]
 
             def send_fn(xp, start, dest, coeff, ridx, rok, cdict, *hargs):
-                f = shard_map_compat(
+                f = jax.shard_map(
                     send_body, mesh=mesh,
                     in_specs=(_pspec(nd), P(),
                               _pspec(dest.ndim), _pspec(coeff.ndim),
@@ -3130,7 +3102,7 @@ class DistributedEngine:
                 return accumulate(y_, recv_a, ridx_, rok_, tail)[None]
 
             def exch_fn(y, send, ridx, rok):
-                f = shard_map_compat(
+                f = jax.shard_map(
                     exch_body, mesh=mesh,
                     in_specs=(_pspec(nd), _pspec(2 + len(tail)),
                               _pspec(ridx.ndim), _pspec(rok.ndim)),
@@ -3411,7 +3383,7 @@ class DistributedEngine:
             # scan branch of `terms` hits this, i.e. the LARGE-T0 regime
             # small-config tests never reach)
             def zvar(a):
-                return pcast_varying(a, SHARD_AXIS)
+                return jax.lax.pcast(a, SHARD_AXIS, to="varying")
             acc = terms(zvar(jnp.zeros(x.shape, jnp.float64)), tags, T0)
             d = diag.reshape(diag.shape + (1,) * (x.ndim - 1))
             sc = (W * inv_n).reshape(inv_n.shape + (1,) * (x.ndim - 1))
@@ -3431,7 +3403,7 @@ class DistributedEngine:
             qin, tags, diag, inv_n, n_parts, norms_all, tail = operands
             tail_specs = tuple(_pspec(a.ndim) for a in tail) if has_tail \
                 else P()
-            f = shard_map_compat(
+            f = jax.shard_map(
                 shard_body, mesh=mesh,
                 in_specs=(_pspec(x.ndim), _pspec(qin.ndim),
                           _pspec(tags.ndim), _pspec(diag.ndim),
@@ -3493,8 +3465,8 @@ class DistributedEngine:
             if has_tail:
                 rows, idx_t, cf_t = (a[0] for a in tail)
                 zshape = rows.shape + x.shape[1:]
-                acc = terms(pcast_varying(jnp.zeros(zshape, dtype),
-                                           SHARD_AXIS),
+                acc = terms(jax.lax.pcast(jnp.zeros(zshape, dtype),
+                                          SHARD_AXIS, to="varying"),
                             idx_t, cf_t, idx_t.shape[0])
                 y = y.at[rows].add(acc, mode="drop")
             return y[None]
@@ -3505,7 +3477,7 @@ class DistributedEngine:
             qin, gidx, coeff, diag, tail = operands
             tail_specs = tuple(_pspec(a.ndim) for a in tail) if has_tail \
                 else P()
-            f = shard_map_compat(
+            f = jax.shard_map(
                 shard_body, mesh=mesh,
                 in_specs=(_pspec(x.ndim), _pspec(qin.ndim), _pspec(gidx.ndim),
                           _pspec(coeff.ndim), _pspec(diag.ndim), tail_specs),
@@ -3708,26 +3680,24 @@ class DistributedEngine:
                 xs = (ap.reshape(nchunks, B), np_.reshape(nchunks, B),
                       xp.reshape((nchunks, B) + tail).astype(dtype))
                 if not pipe:
-                    init = pcast_varying(
+                    init = jax.lax.pcast(
                         (jnp.zeros((M,) + tail, dtype),
                          jnp.zeros((), jnp.int64),
                          jnp.zeros((), jnp.int64)),
-                        SHARD_AXIS,
-                    )
+                        SHARD_AXIS, to="varying")
                     (y, overflow, invalid), _ = jax.lax.scan(chunk, init, xs)
                 else:
                     # prologue slot: an all-SENTINEL/zero in-flight chunk —
                     # its receive side is fully masked, so consuming it
                     # adds exact zeros to the all-+0.0 initial y (no bit
                     # can change) and counts nothing
-                    init = pcast_varying(
+                    init = jax.lax.pcast(
                         (jnp.zeros((M,) + tail, dtype),
                          jnp.zeros((), jnp.int64),
                          jnp.zeros((), jnp.int64),
                          jnp.full(D * Cap, SENTINEL_STATE),
                          jnp.zeros((D * Cap,) + tail, dtype)),
-                        SHARD_AXIS,
-                    )
+                        SHARD_AXIS, to="varying")
                     (y, overflow, invalid, last_b, last_a), _ = \
                         jax.lax.scan(chunk_pipe, init, xs)
                     # epilogue: the last chunk's exchange drains here
@@ -3740,7 +3710,7 @@ class DistributedEngine:
 
             def apply_fn(x, operands):
                 alphas, norms, diag, tables, lk_pair, lk_dir = operands
-                f = shard_map_compat(
+                f = jax.shard_map(
                     shard_body, mesh=mesh,
                     in_specs=(_pspec(x.ndim), _pspec(2), _pspec(2), P(),
                               _pspec(3), _pspec(2)),

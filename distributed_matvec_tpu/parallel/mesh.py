@@ -16,40 +16,9 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-__all__ = ["SHARD_AXIS", "make_mesh", "shard_spec", "init_distributed",
-           "shard_map_compat", "pcast_varying"]
+__all__ = ["SHARD_AXIS", "make_mesh", "shard_spec", "init_distributed"]
 
 SHARD_AXIS = "shards"
-
-
-def shard_map_compat(f, *, mesh: Mesh, in_specs, out_specs):
-    """``jax.shard_map`` across the jax versions this repo meets.
-
-    Newer jax exports ``jax.shard_map`` directly; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map``, whose replication checker
-    predates rules for some of the collectives the engine bodies use
-    (``all_to_all(tiled=True)``), so the fallback disables ``check_rep`` —
-    the specs still pin every input/output layout explicitly.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-def pcast_varying(a, axis_name: str):
-    """Mark ``a`` varying over ``axis_name`` inside a shard_map body.
-
-    New-jax ``lax.pcast`` makes an unvarying scan carry legal to combine
-    with shard-varying values; old jax has no varying-ness type system at
-    all, so the cast is simply the identity there.
-    """
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(a, axis_name, to="varying")
-    return a
 
 
 def init_distributed(coordinator_address: Optional[str] = None,

@@ -238,7 +238,7 @@ class Resharder:
         from jax.sharding import PartitionSpec as P
 
         from .distributed import _staged_all_to_all
-        from .mesh import SHARD_AXIS, shard_map_compat
+        from .mesh import SHARD_AXIS
 
         Dp, C, Mp = self.dst_d, self.capacity, self._mp
         tail = self.tail
@@ -258,7 +258,7 @@ class Resharder:
             return y[None]
 
         nil = [None] * len(tail)
-        sm = shard_map_compat(
+        sm = jax.shard_map(
             body, mesh=self.owner.mesh,
             in_specs=(P(SHARD_AXIS, None, None, *nil),
                       P(SHARD_AXIS, None, None),

@@ -209,7 +209,10 @@ def find_tuned(mode: Optional[str] = None,
 
     if not artifacts_enabled():
         return []
-    root = os.path.join(artifact_root(), "tuning")
+    try:
+        root = os.path.join(artifact_root(), "tuning")
+    except OSError:
+        return []
     if not os.path.isdir(root):
         return []
     recs = []

@@ -1,10 +1,8 @@
 """Content-addressed on-disk artifact cache — default ON.
 
-Every measured config spends orders of magnitude longer *constructing* an
-engine than applying it (the recorded TPU bench round: ``engine_init_s``
-59–207 s vs ``device_ms`` 6.5–663 ms), yet each of the three expensive
-construction
-products is a pure function of content that rarely changes:
+Constructing an engine costs far more than applying it once, yet each of
+the two expensive construction products is a pure function of content that
+rarely changes:
 
   basis/       representative + norm arrays, keyed by the basis JSON
                (sector, symmetries, particle content) — the
@@ -13,8 +11,9 @@ products is a pure function of content that rarely changes:
   structure/   ELL/compact structure sidecars, keyed by the engines'
                ``_structure_fingerprint()`` (basis content + operator term
                tables + mode/dtype/padding);
-  xla/         the persistent XLA compilation cache (see utils/cache.py),
-               shared by every program the engines compile.
+
+The persistent XLA compilation cache is NOT part of this tree: it follows
+``JAX_COMPILATION_CACHE_DIR`` or sits inside the checkout (utils/cache.py).
 
 Two cheaper-but-still-cacheable decision products ride in the same tree:
 
@@ -75,8 +74,7 @@ def record_cache_event(kind: str, event: str) -> None:
 
     counter("artifact_cache", kind=kind, event=event).inc()
 
-_DEFAULT_ROOT = os.path.join(os.path.expanduser("~"), ".cache",
-                             "distributed_matvec_tpu", "artifacts")
+_DEFAULT_SUBDIR = os.path.join(".cache", "distributed_matvec_tpu", "artifacts")
 
 # per-path corrupt-read tally for the retry/quarantine policy (DESIGN.md
 # §21): one failure is counted (transient disks happen), a second moves
@@ -187,10 +185,21 @@ def artifacts_enabled() -> bool:
 
 
 def artifact_root() -> str:
-    """Resolve the artifact root directory (no filesystem side effects)."""
-    return (os.environ.get("DMT_ARTIFACT_DIR")
-            or get_config().artifact_dir
-            or _DEFAULT_ROOT)
+    """Resolve the artifact root directory (no filesystem side effects).
+
+    Raises ``OSError`` when no root is configured and there is no home
+    directory to default to — every caller already treats an ``OSError``
+    from this layer as "cache unavailable" and builds instead."""
+    root = os.environ.get("DMT_ARTIFACT_DIR") or get_config().artifact_dir
+    if root:
+        return root
+    home = os.path.expanduser("~")
+    if not os.path.isabs(home):
+        # HOME unset and no passwd entry: "~" comes back unexpanded, and
+        # joining it would quietly create a "~" directory under the cwd
+        raise OSError("no home directory for the default artifact root; "
+                      "set DMT_ARTIFACT_DIR")
+    return os.path.join(home, _DEFAULT_SUBDIR)
 
 
 def artifact_path(kind: str, fingerprint: str, suffix: str = "") -> str:
@@ -348,28 +357,13 @@ def make_or_restore_basis(basis, path: Optional[str] = None,
 
 
 def ensure_compilation_cache() -> Optional[str]:
-    """Point JAX's persistent compilation cache under the artifact root.
-
-    No-op (returning the active directory) when a cache dir is already
-    configured — via ``JAX_COMPILATION_CACHE_DIR`` or an earlier explicit
-    :func:`~.cache.enable_compilation_cache` call — and ``None`` when the
-    artifact layer is off or the directory cannot be created.  Safe for
-    engines to call at construction time: the harness's explicit choice
-    always wins.
-    """
+    """Engines call this at construction: the persistent compilation cache
+    is on unless the artifact layer is off (``DMT_ARTIFACT_CACHE=off`` keeps
+    a run free of every cache).  Where it lives is
+    :func:`~.cache.enable_compilation_cache`'s rule, not the artifact
+    root's."""
     if not artifacts_enabled():
         return None
-    try:
-        import jax
+    from .cache import enable_compilation_cache
 
-        current = getattr(jax.config, "jax_compilation_cache_dir", None)
-        if current:
-            return current
-        from .cache import enable_compilation_cache
-
-        # no explicit directory: cache._default_dir resolves the artifact
-        # root's xla/ subtree — ONE place derives that path
-        return enable_compilation_cache()
-    except (OSError, ImportError) as e:
-        log_debug(f"compilation cache not enabled: {e!r}")
-        return None
+    return enable_compilation_cache()

@@ -174,12 +174,6 @@ class RuntimeConfig:
     #   (compress off|lossless, order-preserving pipeline depths), and
     #   explicitly passed constructor/config knobs always win over tuned
     #   ones.  DMT_TUNE_WINDOW overrides the live update window (8)
-    stream_kernel: str = "auto"            # compressed-chunk decode path
-    #   (DMT_STREAM_KERNEL): "auto" (currently = xla), "xla" (decode ops
-    #   traced into the chunk program — XLA fuses unpack+gather+multiply+
-    #   segment-add), "pallas" (the explicit fused decode+gather+multiply+
-    #   scatter kernel, interpret mode on non-TPU backends; real-sector
-    #   single-column dict-coded chunks only, others fall back to xla)
     split_gather: str = "auto"             # triple-f32 gathers: auto | on | off
     #   (auto = on for the TPU backend; see ops/split_gather.py)
     term_loop: str = "auto"                # ELL/compact per-term loop form:
@@ -190,14 +184,14 @@ class RuntimeConfig:
     #   the large-T0 code path the big bases take.
     complex_pair: str = "auto"             # (re,im)-f64 pair engines for
     #   complex sectors: auto | on | off.  auto = pair form on the TPU
-    #   backend (whose compiler cannot handle complex128 — see below),
+    #   backend (whose compiler refuses complex128 — see below),
     #   native c128 elsewhere.  "on" forces pair everywhere (useful for
     #   testing), "off" forces native c128 (subject to the TPU guard).
     allow_complex_on_tpu: bool = False     # override the c128-on-TPU guard
-    #   (measured here: ANY complex128 program hangs this platform's TPU
-    #    compiler indefinitely while f64 and c64 compile in <1 s; engines
-    #    refuse native-c128 sectors on the TPU backend unless this is set —
-    #    with complex_pair="auto" they run in pair form instead)
+    #   (the TPU compiler refuses any complex128 program with an internal
+    #    x64-rewriter error while f64 and c64 compile; engines refuse
+    #    native-c128 sectors on the TPU backend unless this is set — with
+    #    complex_pair="auto" they run in pair form instead)
 
     # -- solvers (solve/lanczos.py) -----------------------------------------
     lanczos_reorth: str = "selective"      # per-iteration reorthogonalization
@@ -296,74 +290,3 @@ def update_config(**kwargs) -> RuntimeConfig:
             raise AttributeError(f"unknown config field {k!r}")
         setattr(cfg, k, v)
     return cfg
-
-
-_xla_flag_support: dict = {}
-
-
-def xla_flag_supported(flag: str) -> bool:
-    """Whether this jaxlib's XLA knows ``flag`` (an ``XLA_FLAGS`` name).
-
-    XLA *hard-aborts the whole process* on unknown names in ``XLA_FLAGS``
-    ("Unknown flags in XLA_FLAGS", parse_flags_from_env.cc) at first
-    backend creation — long after the append, in whatever innocent code
-    happens to build the first client (observed: pytest collection dying
-    inside ``jax.devices()``).  There is no query API, but a supported
-    flag's name string is necessarily embedded in the extension binary
-    that parses it, so a byte scan of ``jaxlib.xla_extension`` decides
-    support without risking the fatal.  False when the binary cannot be
-    located — the safe direction (worst case we skip an optional flag).
-    """
-    if flag in _xla_flag_support:
-        return _xla_flag_support[flag]
-    found = False
-    try:
-        import mmap
-
-        import jaxlib.xla_extension as _xe
-
-        path = getattr(_xe, "__file__", None)
-        if path and os.path.isfile(path) and os.path.getsize(path):
-            with open(path, "rb") as f, \
-                    mmap.mmap(f.fileno(), 0, access=mmap.ACCESS_READ) as m:
-                found = m.find(flag.encode()) != -1
-    except Exception:
-        found = False
-    _xla_flag_support[flag] = found
-    return found
-
-
-def ensure_cpu_collective_timeout(seconds: int = 1200) -> bool:
-    """Raise XLA's CPU collective rendezvous termination timeout.
-
-    XLA's CPU runtime kills the whole process when collective participants
-    arrive more than 40 s apart ("Termination timeout ... exceeded").  On an
-    oversubscribed virtual-device CPU mesh — the multi-chip development
-    path of SURVEY.md §6, where N devices execute serially on few host
-    cores — a large apply (≥10⁷ states/shard) routinely has >40 s of
-    arrival skew, so the default kills runs that would finish fine.  The
-    flag must be in ``XLA_FLAGS`` before the CPU client is created, which
-    is why the package appends it at import time (harmless for TPU/GPU
-    backends: it only governs the CPU collective rendezvous).
-
-    Returns True when the flag is (now) present in ``XLA_FLAGS``; False
-    when a backend already initialised without it (the caller must re-exec
-    to benefit — this is an XLA runtime flag, not an engine parameter) or
-    when this jaxlib's XLA does not know the flag at all (appending it
-    would turn the first backend init into a process abort; such builds
-    predate the CPU rendezvous kill-switch, so there is nothing to raise).
-    """
-    flag = "xla_cpu_collective_call_terminate_timeout_seconds"
-    flags = os.environ.get("XLA_FLAGS", "")
-    if flag in flags:
-        return True
-    try:
-        from jax._src import xla_bridge
-        if xla_bridge._backends:        # too late: client already built
-            return False
-    except Exception:                   # private API moved: assume not yet
-        pass
-    if not xla_flag_supported(flag):
-        return False
-    os.environ["XLA_FLAGS"] = (flags + f" --{flag}={seconds}").strip()
-    return True

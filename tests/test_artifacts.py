@@ -200,3 +200,113 @@ def test_warm_cache_tool(artifact_root_dir, tmp_path):
     assert warm["chain_16"]["structure_restored"]
     assert os.path.isdir(os.path.join(root, "structure"))
     assert os.path.isdir(os.path.join(root, "basis"))
+
+
+# -- where the XLA compile cache lives (utils/cache.py) ----------------------
+
+_CACHE_PROBE = """
+import json, os, sys
+import apps.diagonalize                      # entry points enable the cache
+import jax, jax.numpy as jnp
+from distributed_matvec_tpu.utils.cache import enable_compilation_cache
+d = enable_compilation_cache()
+jax.jit(lambda a: a * 2 + 1)(jnp.arange(8.0)).block_until_ready()
+print(json.dumps({"returned": d,
+                  "config": jax.config.jax_compilation_cache_dir}))
+"""
+
+
+def _cache_probe(tmp_path, **env_extra):
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO,
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1", **env_extra)
+    if "JAX_COMPILATION_CACHE_DIR" not in env_extra:
+        env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    r = subprocess.run([sys.executable, "-c", _CACHE_PROBE],
+                       capture_output=True, text=True, timeout=300, env=env,
+                       cwd=str(tmp_path))
+    assert r.returncode == 0, r.stderr[-2000:]
+    import json
+    return json.loads(r.stdout.splitlines()[-1])
+
+
+def test_compile_cache_env_var_is_left_to_jax(tmp_path, monkeypatch):
+    """With JAX_COMPILATION_CACHE_DIR set the code sets no directory at all
+    (JAX reads the variable itself) and the cache is written there."""
+    from distributed_matvec_tpu.utils import cache
+
+    updates = []
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "x"))
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append(k))
+    assert cache.enable_compilation_cache() == str(tmp_path / "x")
+    assert "jax_compilation_cache_dir" not in updates
+    monkeypatch.undo()
+
+    placed = str(tmp_path / "placed")
+    got = _cache_probe(tmp_path, JAX_COMPILATION_CACHE_DIR=placed)
+    assert got == {"returned": placed, "config": placed}
+    assert os.listdir(placed)                # the process cached there
+
+
+def test_compile_cache_default_is_fixed_inside_checkout(tmp_path):
+    """Without the variable the directory is one fixed path inside the
+    checkout — no ``~``, pid or time in it — and two processes (with
+    different HOMEs and working directories) agree on it."""
+    from distributed_matvec_tpu.utils.cache import CHECKOUT_CACHE_DIR
+
+    assert CHECKOUT_CACHE_DIR == os.path.join(REPO, ".cache", "xla")
+    ro = tmp_path / "ro_home"
+    ro.mkdir()
+    ro.chmod(0o555)
+    a = _cache_probe(tmp_path, HOME=str(ro))
+    (tmp_path / "elsewhere").mkdir()
+    b = _cache_probe(tmp_path / "elsewhere", HOME=str(tmp_path / "missing"))
+    assert a == b == {"returned": CHECKOUT_CACHE_DIR,
+                      "config": CHECKOUT_CACHE_DIR}
+
+
+def test_entry_points_and_engine_work_without_a_usable_home(tmp_path):
+    """HOME read-only, then HOME missing: every entry point imports, and a
+    default (artifact layer ON) engine builds — cache writes fail soft."""
+    code = """
+import apps.diagonalize, apps.dynamics, apps.solve_service, bench
+import __graft_entry__
+from distributed_matvec_tpu.models.basis import SpinBasis
+from distributed_matvec_tpu.models.lattices import (chain_edges,
+                                                    heisenberg_from_edges)
+from distributed_matvec_tpu.parallel.engine import LocalEngine
+op = heisenberg_from_edges(SpinBasis(8, 4), chain_edges(8))
+eng = LocalEngine(op, mode="ell")
+print("built", eng.n_states, eng.structure_restored)
+"""
+    ro = tmp_path / "ro_home"
+    ro.mkdir()
+    ro.chmod(0o555)
+    base = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
+    for k in ("DMT_ARTIFACT_CACHE", "DMT_ARTIFACT_DIR", "HOME"):
+        base.pop(k, None)
+    for env in (dict(base, HOME=str(ro)),
+                dict(base, HOME=str(ro / "missing"))):
+        r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                           text=True, timeout=300, env=env,
+                           cwd=str(tmp_path))
+        assert r.returncode == 0, r.stderr[-2000:]
+        assert "built 70 False" in r.stdout
+    assert sorted(os.listdir(tmp_path)) == ["ro_home"]
+
+
+def test_artifact_root_without_any_home_fails_soft(monkeypatch):
+    """HOME unset and no passwd entry: ``~`` comes back unexpanded.  The
+    root is then an OSError (= cache unavailable), not a ``~`` directory
+    created under the working directory."""
+    from distributed_matvec_tpu.utils import artifacts
+
+    monkeypatch.setenv("DMT_ARTIFACT_CACHE", "on")
+    monkeypatch.delenv("DMT_ARTIFACT_DIR", raising=False)
+    monkeypatch.setattr(os.path, "expanduser", lambda p: p)
+    with pytest.raises(OSError, match="DMT_ARTIFACT_DIR"):
+        artifacts.artifact_root()
+    assert artifacts.default_structure_cache("ab" * 32) is None
+    b = build_heisenberg(8, 4, None, ()).basis
+    assert artifacts.make_or_restore_basis(b) is False and b.is_built

@@ -21,7 +21,7 @@ ATOL, RTOL = 1e-13, 1e-12
 def _ndev() -> int:
     """Device count, queried lazily: a module-import-time ``jax.devices()``
     initializes the backend during pytest collection, where an XLA-level
-    fatal (bad XLA_FLAGS, dead plugin) aborts the whole run instead of
+    fatal (bad XLA_FLAGS) aborts the whole run instead of
     failing one module."""
     return len(jax.devices())
 

@@ -191,8 +191,7 @@ def test_staged_exchange_equals_all_to_all(rng):
         pytest.skip("needs 4 devices")
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from distributed_matvec_tpu.parallel.mesh import (SHARD_AXIS,
-                                                      shard_map_compat)
+    from distributed_matvec_tpu.parallel.mesh import SHARD_AXIS
 
     D, cap = 4, 6
     mesh = Mesh(np.array(jax.devices()[:D]), (SHARD_AXIS,))
@@ -205,10 +204,10 @@ def test_staged_exchange_equals_all_to_all(rng):
         return _staged_all_to_all(a[0], SHARD_AXIS)[None]
 
     spec = P(SHARD_AXIS, None, None)
-    f_mono = shard_map_compat(mono, mesh=mesh, in_specs=(spec,),
-                              out_specs=spec)
-    f_staged = shard_map_compat(staged, mesh=mesh, in_specs=(spec,),
-                                out_specs=spec)
+    f_mono = jax.shard_map(mono, mesh=mesh, in_specs=(spec,),
+                           out_specs=spec)
+    f_staged = jax.shard_map(staged, mesh=mesh, in_specs=(spec,),
+                             out_specs=spec)
     np.testing.assert_array_equal(np.asarray(jax.jit(f_mono)(x)),
                                   np.asarray(jax.jit(f_staged)(x)))
 

@@ -1,6 +1,7 @@
 """Host enumeration: bit tricks, rank/unrank, hashing, representatives."""
 
 import math
+import os
 
 import numpy as np
 import pytest
@@ -221,3 +222,80 @@ def test_build_uses_backend_dispatch():
         update_config(enumeration_backend="auto")
     np.testing.assert_array_equal(b1.representatives, b2.representatives)
     np.testing.assert_allclose(b1.norms, b2.norms, atol=1e-14)
+
+
+# -- the native build is loud, and only its own binary is ever loaded --------
+
+
+@pytest.fixture
+def fresh_native(monkeypatch, tmp_path):
+    """The native module pointed at a private copy of the source, with no
+    library loaded — so a test can break the build without touching the
+    kernel the rest of the suite uses."""
+    import shutil
+
+    native = _native_or_skip()
+    src = tmp_path / "_native.cpp"
+    shutil.copy(native._SRC, src)
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_HERE", str(tmp_path))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_lib_error", None)
+    return native
+
+
+def test_failed_native_build_raises_with_compiler_stderr(fresh_native,
+                                                         tmp_path):
+    """A build failure is an exception carrying the compiler's words — never
+    a silent NumPy run of a sector the kernel was chosen for."""
+    from distributed_matvec_tpu.enumeration import enumerate_representatives
+    from distributed_matvec_tpu.models.symmetry import SymmetryGroup
+
+    native = fresh_native
+    with open(native._SRC, "a") as f:
+        f.write("\nthis is not C++;\n")
+    g = SymmetryGroup.build(8, [([*range(1, 8), 0], 0)])
+    with pytest.raises(native.NativeBuildError) as e:
+        enumerate_representatives(8, 4, g)
+    assert "compiler stderr" in str(e.value) and "error" in str(e.value)
+    assert "-march=native" not in str(e.value)
+    assert not list(tmp_path.glob("*.so*"))          # no half-built file
+    # the failure is remembered: no second compile, same error
+    with pytest.raises(native.NativeBuildError):
+        native._load()
+
+
+def test_missing_compiler_raises(fresh_native, monkeypatch):
+    native = fresh_native
+    monkeypatch.setattr(native, "_CXX", "no-such-compiler-xyz")
+    assert not native.native_available()
+    with pytest.raises(native.NativeBuildError, match="no-such-compiler"):
+        native._load()
+
+
+def test_stale_native_binary_is_not_loaded(fresh_native, tmp_path):
+    """The library's file name is keyed on the source and the flags: a
+    binary built from an older source (or by -march=native on another CPU)
+    sits under another name and is never opened."""
+    native = fresh_native
+    old = native._so_path()
+    native._load()
+    assert os.path.exists(old)
+    # corrupt the old binary in place: loading it now would fail loudly
+    with open(old, "wb") as f:
+        f.write(b"not an ELF file")
+    with open(native._SRC, "a") as f:
+        f.write("\n// a source change\n")
+    native._lib = None
+    new = native._so_path()
+    assert new != old
+    native._load()                                   # builds, loads `new`
+    assert os.path.exists(new)
+    assert not os.path.exists(old)                   # and sweeps the stale one
+    from distributed_matvec_tpu.enumeration import host
+    from distributed_matvec_tpu.models.symmetry import SymmetryGroup
+
+    g = SymmetryGroup.build(8, [([*range(1, 8), 0], 0)])
+    np.testing.assert_array_equal(
+        native.enumerate_representatives_native(8, 4, g)[0],
+        host.enumerate_representatives(8, 4, g)[0])

@@ -277,6 +277,15 @@ def test_roofline_reconciles_recorded_bench_stream_r05():
 # calibration sidecar
 
 
+def test_unknown_backend_has_no_default_calibration():
+    """A backend the table does not know is an error — it is never handed
+    the CPU's (or anyone's) rates."""
+    assert R.default_calibration("cpu")["source"] == "default"
+    assert R.default_calibration("tpu")["gather_rows_per_s"] > 0
+    with pytest.raises(ValueError, match="no default calibration"):
+        R.default_calibration("gpu")
+
+
 def test_calibration_roundtrip_content_addressed(tmp_path, monkeypatch):
     monkeypatch.setenv("DMT_ARTIFACT_CACHE", "on")
     monkeypatch.setenv("DMT_ARTIFACT_DIR", str(tmp_path))

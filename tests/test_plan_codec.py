@@ -229,26 +229,6 @@ def test_compressed_complex_sector(tier, rng):
         np.asarray(es.matvec(es.to_hashed(x))))
 
 
-@needs_4
-@pytest.mark.parametrize("tier", ["lossless"], indirect=True)
-def test_pallas_decode_kernel_matches_xla(tier, rng):
-    """The fused decode+gather+multiply+scatter Pallas kernel (interpret
-    mode on CPU) is bit-identical to the XLA decode path."""
-    op = build_heisenberg(12, 6, 1, [([*range(1, 12), 0], 0)])
-    op.basis.build()
-    x = rng.random(op.basis.number_states) - 0.5
-    e_x = DistributedEngine(op, n_devices=4, mode="streamed", batch_size=64)
-    y_x = np.asarray(e_x.matvec(e_x.to_hashed(x)))
-    update_config(stream_kernel="pallas")
-    try:
-        e_p = DistributedEngine(op, n_devices=4, mode="streamed",
-                                batch_size=64)
-        y_p = np.asarray(e_p.matvec(e_p.to_hashed(x)))
-    finally:
-        update_config(stream_kernel="auto")
-    np.testing.assert_array_equal(y_x, y_p)
-
-
 # -- sidecar: v3 fingerprint, compressed round trip, corrupt chunk ---------
 
 

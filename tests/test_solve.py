@@ -110,7 +110,7 @@ def test_lanczos_thick_restart(mcap):        # row-block — clamp regression
                   min_restart_size=8, tol=1e-10, max_iters=400,
                   compute_eigenvectors=True)
     want = np.linalg.eigvalsh(A)[:2]
-    assert res.converged
+    assert res.converged and res.restarts > 0
     np.testing.assert_allclose(res.eigenvalues, want, atol=1e-8)
     v = np.asarray(res.eigenvectors[0])
     assert np.linalg.norm(A @ v - res.eigenvalues[0] * v) < 1e-7
@@ -322,3 +322,20 @@ def test_lobpcg_private_api_present():
     from jax.experimental.sparse.linalg import _lobpcg_standard_callable
 
     assert callable(getattr(_lobpcg_standard_callable, "__wrapped__", None))
+
+
+@pytest.mark.parametrize("rows,keep", [(5, 2), (13, 3), (96, 24)],
+                         ids=["below_one_block", "ragged", "default_cap"])
+def test_combine_rows_matches_matmul(rows, keep):
+    """The blocked elementwise Sᵀ·V of the thick restart and the Ritz-vector
+    assembly, for row counts below, across and at multiples of the block."""
+    import importlib
+
+    import jax.numpy as jnp
+
+    lz = importlib.import_module("distributed_matvec_tpu.solve.lanczos")
+    rng = np.random.default_rng(rows)
+    S = rng.standard_normal((rows, keep))
+    V = rng.standard_normal((lz._buffer_rows(rows), 257))
+    got = np.asarray(lz._combine_rows(jnp.asarray(S), jnp.asarray(V)))
+    np.testing.assert_allclose(got, S.T @ V[:rows], atol=1e-13, rtol=0)

@@ -76,3 +76,20 @@ def test_scale_bench_end_to_end(tmp_path):
     assert by2["enumerate"]["restored"]
     assert by2["engine_build"]["structure_restored"]
     assert os.path.exists(str(tmp_path / "c16.h5") + ".structure.h5")
+
+
+def test_f64_probe_sections_on_the_cpu(tmp_path):
+    """The probe behind PERF.md's f64 findings runs every section, and on
+    the CPU's IEEE f64 both dot forms reach the 16-site anchor."""
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    import f64_probe
+
+    by = f64_probe.run(n=20_000, out_dir=str(tmp_path))
+    assert json.load(open(tmp_path / "f64_probe.json")) == by
+    assert by["device"]["platform"] == "cpu"
+    assert set(by["elementwise"]) >= {"mul", "sqrt", "div", "add", "mul_add"}
+    assert by["elementwise"]["add"]["max_err_rel_to_operand"] < 1e-15
+    assert by["combine"]["combine_rows_max_abs_err"] < 1e-12
+    for form in ("elementwise_vdot", "jnp_vdot_in_program"):
+        assert abs(by["in_solver"][form]["E0_minus_exact"]) < 2e-10
+    assert by["restart"]["finite"] and by["restart"]["median_s"] > 0

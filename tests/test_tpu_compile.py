@@ -1,0 +1,254 @@
+"""Ask the chip's compiler, without the chip.
+
+The TPU compiler is installed in the CPU sandbox and compiles for a device
+that is described, not attached (``/opt/skills/guides/on-chip-measurement``
+§2).  These tests lower the programs of the main path — the ones
+``chip_smoke.py`` runs on the chip — for a described ``v5e:2x2`` at the
+shapes of ``heisenberg_chain_32_symm`` (4,707,969 states, |G| = 128; shapes
+read off a CPU build of that engine) and check each program's memory against
+the 16 GB of one v5e chip.  Nothing runs, so they say nothing about results
+or times; they catch what the compiler refuses before chip time is spent.
+
+``jax.default_backend()`` is ``cpu`` here, so the knobs whose ``auto`` value
+follows the backend (``split_gather``, ``complex_pair``) are steered to
+their TPU values in the test, not through an option of the program.
+
+Rules this file keeps (the guide's): the topology is described inside a
+module-scoped fixture that skips when it cannot be, nothing TPU-related
+happens at import or collection time, every compile happens in the test's
+own process, the compile cache is off around the compiles (a described-
+device executable can be written to it but not read back), and all such
+tests live in this one file so one xdist worker holds the TPU library.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+from distributed_matvec_tpu.utils.config import update_config
+
+HBM_BYTES = 16 * 1024 ** 3          # one TPU v5e chip
+FULL_YAML = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "data", "heisenberg_chain_32_symm.yaml")
+
+# heisenberg_chain_32_symm as LocalEngine builds it (CPU build of the engine
+# from data/heisenberg_chain_32_symm.yaml): 32 off-diagonal terms, main ELL
+# table of width 20 over the padded rows, tail of 249,601 wide rows × 12,
+# bucketed lookup with a 2^24 directory.
+N = 4_707_969
+N_PAD = 4_718_592
+T, T0, T_TAIL, S_TAIL = 32, 20, 12, 249_601
+CHUNK = 1 << 16
+LK_DIR, LK_SHIFT, LK_PROBES = (1 << 24) + 1, 8, 6
+M_CAP = 96                          # apps/diagonalize.py's default Krylov cap
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def tpu_knobs():
+    """The values ``auto`` resolves to on a TPU backend."""
+    update_config(split_gather="on", complex_pair="on")
+    yield
+    update_config(split_gather="auto", complex_pair="auto")
+
+
+def _shapes(sharding):
+    """``S(shape, dtype=f64)`` → a ShapeDtypeStruct placed by ``sharding``."""
+    def S(shape, dtype=jnp.float64):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+    return S
+
+
+def _fits(compiled, what):
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             + m.temp_size_in_bytes - m.alias_size_in_bytes)
+    assert total < HBM_BYTES, f"{what}: {total / 2**30:.2f} GiB > 16 GiB"
+    return total
+
+
+def _local_ell_engine(sh, pair):
+    """A LocalEngine shell carrying chain_32_symm's ELL shapes — the apply
+    program depends on nothing else of the engine."""
+    from distributed_matvec_tpu.parallel.engine import LocalEngine
+
+    S = _shapes(sh)
+    ctail = (2,) if pair else ()
+    eng = object.__new__(LocalEngine)
+    eng.n_states, eng.pair, eng._dtype = N, pair, jnp.float64
+    eng._ell_T0 = T0
+    eng._ell_idx = S((T0, N_PAD), jnp.int32)
+    eng._ell_coeff = S((T0, N_PAD) + ctail, jnp.float64)
+    eng._diag = S((N_PAD,), jnp.float64)
+    eng._ell_tail = (S((S_TAIL,), jnp.int32),
+                     S((T_TAIL, S_TAIL), jnp.int32),
+                     S((T_TAIL, S_TAIL) + ctail, jnp.float64))
+    eng._make_ell_matvec()
+    return eng, S((N,) + ctail, jnp.float64)
+
+
+@pytest.mark.parametrize("pair", [False, True],
+                         ids=["real_sector", "re_im_pair_momentum_sector"])
+def test_ell_apply_compiles(one_chip, tpu_knobs, pair):
+    """The LocalEngine ELL apply with triple-f32 split gathers — and its
+    (re, im)-pair form, which complex momentum sectors take on a TPU."""
+    eng, x = _local_ell_engine(one_chip, pair)
+    apply_fn, operands = eng.bound_matvec()
+    compiled = jax.jit(apply_fn).lower(x, operands).compile()
+    _fits(compiled, "ell apply")
+
+
+def test_structure_build_chunk_compiles(one_chip):
+    """One ``ell_fill_chunk`` step: the |G| = 128 orbit scan over a 65,536-
+    row chunk, the u64 bucketed basis lookup, and the update of the donated
+    full-width tables."""
+    from functools import partial
+
+    from distributed_matvec_tpu.models.yaml_io import load_config_from_yaml
+    from distributed_matvec_tpu.ops import kernels as K
+    from distributed_matvec_tpu.parallel.engine import _ell_fill_chunk
+
+    S = _shapes(one_chip)
+    # the operator's kernel tables need the symmetry group, not the basis
+    op = load_config_from_yaml(FULL_YAML).hamiltonian
+    tables = jax.tree_util.tree_map(
+        lambda a: S(a.shape, a.dtype), K.device_tables(op, pair=False))
+    assert tables.off.x.shape[0] == T
+    assert tables.group.char_real.shape[0] == 128
+    fn = jax.jit(partial(_ell_fill_chunk, shift=LK_SHIFT, probes=LK_PROBES,
+                         is_pair=False), donate_argnums=(0, 1, 2))
+    compiled = fn.lower(
+        S((T, N_PAD), jnp.int32), S((T, N_PAD), jnp.float64),
+        S((), jnp.int64), tables, S((N, 2), jnp.uint32),
+        S((LK_DIR,), jnp.int32), S((CHUNK,), jnp.uint64),
+        S((CHUNK,), jnp.float64), S((), jnp.int32)).compile()
+    _fits(compiled, "ell_fill_chunk")
+
+
+@pytest.mark.parametrize("program",
+                         ["window", "full", "restart", "ritz_vectors"])
+def test_lanczos_programs_compile(one_chip, tpu_knobs, program):
+    """The Lanczos programs at n = 4.7M with the 96-row Krylov buffer: the
+    16-step selective window and the full-sweep block (ELL apply traced in,
+    buffer donated), the thick restart, and the Ritz-vector assembly.  The
+    last two were ``tensordot``s that XLA:TPU expanded into an
+    ``f32[8, 96, n]`` temporary — 23.85 GB, refused on the chip (PR 22)."""
+    from distributed_matvec_tpu.solve.lanczos import (
+        _buffer_rows, _combine_rows, _make_block_runner, _make_restart,
+        _make_window_runner)
+
+    eng, x = _local_ell_engine(one_chip, pair=False)
+    apply_fn, operands = eng.bound_matvec()
+
+    def mv(v, ops):
+        return apply_fn(v, ops)[0].astype(jnp.float64)
+
+    S = _shapes(one_chip)
+    V = S((_buffer_rows(M_CAP), N))
+    ab, i32 = S((M_CAP,)), S((), jnp.int32)
+    if program == "window":
+        fn = _make_window_runner(mv, M_CAP, (N,), jnp.float64, 2, 16)
+        compiled = fn.lower(V, ab, ab, i32, operands).compile()
+    elif program == "full":
+        fn = _make_block_runner(mv, M_CAP, (N,), jnp.float64, 2)
+        compiled = fn.lower(V, ab, ab, i32, i32, operands).compile()
+    elif program == "restart":
+        fn = _make_restart(M_CAP, (N,), jnp.float64, 24)
+        compiled = fn.lower(V, S((M_CAP, 24))).compile()
+    else:
+        compiled = _combine_rows.lower(
+            S((M_CAP, 1)), S((_buffer_rows(M_CAP), N))).compile()
+    # beside the program: the ELL tables it does not take as arguments
+    assert _fits(compiled, f"lanczos {program}") + 1.2e9 < HBM_BYTES
+
+
+def test_distributed_ell_apply_compiles_on_four_devices(topo, tpu_knobs):
+    """The hash-sharded ELL apply on a 4-device mesh built from the
+    described devices: the emulated-f64 ``all_to_all`` under ``shard_map``
+    must partition, and each device's share must fit its HBM."""
+    from distributed_matvec_tpu.parallel.distributed import DistributedEngine
+    from distributed_matvec_tpu.parallel.mesh import SHARD_AXIS
+
+    D = 4
+    mesh = Mesh(np.array(topo.devices[:D]), (SHARD_AXIS,))
+
+    def S(shape, dtype):
+        spec = P(SHARD_AXIS, *([None] * (len(shape) - 1)))
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    M = -(-N // D // 1024) * 1024 + 4096    # shard rows incl. hash imbalance
+    C = M // 2                              # per-peer query capacity
+    s_tail = S_TAIL // D + 1024
+    eng = object.__new__(DistributedEngine)
+    eng.n_devices, eng.query_capacity, eng.mesh = D, C, mesh
+    eng.pair, eng._dtype, eng._ell_T0 = False, jnp.float64, T0
+    eng._qin = S((D, D, C), jnp.int32)
+    eng._ell_idx = S((D, T0, M), jnp.int32)
+    eng._ell_coeff = S((D, T0, M), jnp.float64)
+    eng._diag = S((D, M), jnp.float64)
+    eng._ell_tail = (S((D, s_tail), jnp.int32),
+                     S((D, T_TAIL, s_tail), jnp.int32),
+                     S((D, T_TAIL, s_tail), jnp.float64))
+    eng._make_ell_matvec()
+    compiled = jax.jit(eng._apply_fn).lower(
+        S((D, M), jnp.float64), eng._operands).compile()
+    assert "all-to-all" in compiled.as_text()
+    # memory_analysis() of a partitioned program is per device
+    _fits(compiled, "distributed ell apply (per device)")
+
+    # ... and the Lanczos window over hashed [D, M] vectors that
+    # `apps/diagonalize.py --devices 4` runs, Krylov buffer sharded with them
+    from distributed_matvec_tpu.solve.lanczos import (_buffer_rows,
+                                                      _make_window_runner)
+
+    def mv(v, ops):
+        return eng._apply_fn(v, ops)[0].astype(jnp.float64)
+
+    rep = NamedSharding(mesh, P())
+    V = jax.ShapeDtypeStruct(
+        (_buffer_rows(M_CAP), D, M), jnp.float64,
+        sharding=NamedSharding(mesh, P(None, SHARD_AXIS, None)))
+    ab = jax.ShapeDtypeStruct((M_CAP,), jnp.float64, sharding=rep)
+    m0 = jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)
+    fn = _make_window_runner(mv, M_CAP, (D, M), jnp.float64, 2, 16)
+    _fits(fn.lower(V, ab, ab, m0, eng._operands).compile(),
+          "distributed lanczos window (per device)")
+
+
+def test_complex128_is_refused_not_hung(one_chip):
+    """What ``parallel/engine.py::check_complex_backend`` says: the TPU
+    compiler refuses a complex128 program with an error (it does not hang),
+    which is why complex sectors run in (re, im)-pair form there."""
+    x = jax.ShapeDtypeStruct((128,), jnp.complex128, sharding=one_chip)
+    with pytest.raises(Exception) as e:
+        jax.jit(lambda a: (a * a.conj()).real.sum()).lower(x).compile()
+    assert not isinstance(e.value, (AssertionError, TimeoutError))

@@ -11,7 +11,7 @@ speedup would pass every per-run gate.  This tool closes that loop:
   records carry ``"kind": "bench_trend"`` and readers here skip every
   other line, so the driver's own records are untouched)::
 
-      {"kind": "bench_trend", "ts": ..., "mode": "smoke|full|cpu_fallback",
+      {"kind": "bench_trend", "ts": ..., "mode": "smoke|full|serve",
        "backend": "cpu", "configs": {name: {metric: value, ...}}}
 
 * ``trend`` renders the per-(config, metric) trajectory across records;

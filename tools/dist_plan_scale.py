@@ -58,10 +58,11 @@ def main():
     args = ap.parse_args()
 
     from distributed_matvec_tpu.io import make_or_restore_representatives
-    from distributed_matvec_tpu.models.yaml_io import load_config_from_yaml
+    from distributed_matvec_tpu.models.yaml_io import (
+        DATA_DIR, load_config_from_yaml)
 
     cfg = load_config_from_yaml(
-        os.path.join("/root/reference/data", args.config + ".yaml"))
+        os.path.join(DATA_DIR, args.config + ".yaml"))
     if args.shards is None:
         t0 = time.time()
         restored = make_or_restore_representatives(cfg.basis, args.reps)

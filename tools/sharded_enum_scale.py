@@ -30,10 +30,11 @@ def _rank_worker(args):
     the group is rebuilt in-process from the YAML config)."""
     config, out, n_shards, rank, n_ranks, chunks, threads = args
     from distributed_matvec_tpu.enumeration.sharded import enumerate_to_shards
-    from distributed_matvec_tpu.models.yaml_io import load_config_from_yaml
+    from distributed_matvec_tpu.models.yaml_io import (
+        DATA_DIR, load_config_from_yaml)
 
     cfg = load_config_from_yaml(
-        os.path.join("/root/reference/data", config + ".yaml"))
+        os.path.join(DATA_DIR, config + ".yaml"))
     b = cfg.basis
     t0 = time.time()
     man = enumerate_to_shards(b.number_spins, b.hamming_weight, b.group,
@@ -62,11 +63,12 @@ def main():
     args = ap.parse_args()
 
     from distributed_matvec_tpu.enumeration.sharded import enumerate_to_shards
-    from distributed_matvec_tpu.models.yaml_io import load_config_from_yaml
+    from distributed_matvec_tpu.models.yaml_io import (
+        DATA_DIR, load_config_from_yaml)
     from math import comb
 
     cfg = load_config_from_yaml(
-        os.path.join("/root/reference/data", args.config + ".yaml"))
+        os.path.join(DATA_DIR, args.config + ".yaml"))
     basis = cfg.basis
     n, hw = basis.number_spins, basis.hamming_weight
     group = basis.group

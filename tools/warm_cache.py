@@ -7,7 +7,8 @@ checkpoints:
 
   * basis representatives  (``<root>/basis/``)
   * ELL structure sidecar  (``<root>/structure/``)
-  * XLA compiled programs  (``<root>/xla/``)
+  * XLA compiled programs  (``JAX_COMPILATION_CACHE_DIR``, else the
+    checkout's ``.cache/xla`` — ``utils/cache.py``)
 
 so the *next* process — ``bench.py``, the CLI, a driver inside a short
 accelerator window — constructs its engines in seconds instead of minutes
