@@ -1,5 +1,5 @@
 """obs — process-wide telemetry: metrics registry, structured event log,
-profiler annotations.
+spans that also lie on the profiler's host line.
 
 The reference answers "why was this run slow?" with tree timers and comm
 diagnostics behind ``--kDisplayTimings``/``--kVerboseComm``; after the
@@ -16,9 +16,7 @@ visibility GSPMD (arXiv:2105.04663) treats as a first-class signal):
   :func:`snapshot` turns the registry into plain data.
 * :mod:`~.events` — append-only JSONL per process
   (``<run_dir>/rank_<r>/events.jsonl``, rank-tagged envelope, monotonic
-  ``seq``, soft-fail writes), an in-memory ring buffer, and
-  :func:`annotate` spans that line the JSONL timeline up with
-  ``jax.profiler`` Perfetto traces.
+  ``seq``, soft-fail writes) and an in-memory ring buffer.
 * :mod:`~.health` — numerical-health probes (deferred-fetch NaN/Inf +
   norm reductions on engine applies, exchange overflow/invalid counters)
   and the solver watchdog (``solver_health`` events; ``DMT_HEALTH=strict``
@@ -33,7 +31,10 @@ visibility GSPMD (arXiv:2105.04663) treats as a first-class signal):
   ``trace_id`` per run (file-agreed across ranks through the shared run
   directory), a ``job_id`` namespacing knob (``DMT_JOB_ID``), and
   parent-linked spans (solve > iteration > apply > chunk) stamped into
-  every event's envelope; one ``span`` event per closed span.
+  every event's envelope; one ``span`` event per closed span, its
+  duration monotonic, its counts added while it was open, and (for the
+  leaf kinds) a ``jax.profiler.TraceAnnotation`` of the same name, so a
+  device trace shows the program's spans on the device's clock.
 * ``tools/obs_report.py`` — the reader: ``summarize`` one run, ``merge`` /
   ``report --ranks`` a multi-rank one (skew-corrected timeline, per-rank
   straggler attribution), ``diff`` two runs as a CI perf gate,
@@ -48,7 +49,7 @@ entirely, at which point every instrument is the shared no-op
 device-side work** (no syncs, no fetches — guard-tested).
 """
 
-from .events import (annotate, emit, event_path, events, flush, obs_enabled,
+from .events import (emit, event_path, events, flush, obs_enabled,
                      reset, run_dir)
 from .export import (merge_openmetrics, parse_openmetrics,
                      render_openmetrics, start_exporter, stop_exporter,
@@ -79,7 +80,6 @@ from .trace import (current_span_id, deepest_span, job_id, open_spans,
                     reset_trace, span, span_path, trace_enabled, trace_id)
 
 __all__ = [
-    "annotate",
     "emit",
     "event_path",
     "events",

@@ -1,4 +1,4 @@
-"""Structured event sink: append-only JSONL per process + profiler bridge.
+"""Structured event sink: append-only JSONL per process.
 
 One pillar of the telemetry subsystem (see ``obs/__init__``).  Every event is
 a flat JSON object with a fixed envelope::
@@ -28,10 +28,9 @@ Sink writes fail SOFT, mirroring the artifact layer's loud/quiet split
 ``log_warn`` and degrades to in-memory — telemetry must never turn a
 computation into an I/O error.
 
-:func:`annotate` bridges the host-side event timeline into device-side
-``jax.profiler`` traces: it returns a ``TraceAnnotation`` context so the
-phases instrumented here (engine init, chunk build, apply) show up as named
-spans in Perfetto/TensorBoard, lining up with the JSONL timestamps.
+The bridge into ``jax.profiler`` traces is ``obs/trace.py::span``: one
+call records the ``span`` event here and the same name on the profiler's
+host line.
 """
 
 from __future__ import annotations
@@ -41,7 +40,6 @@ import os
 import threading
 import time
 from collections import deque
-from contextlib import nullcontext
 from typing import List, Optional
 
 from ..utils.config import get_config
@@ -53,7 +51,6 @@ __all__ = [
     "event_path",
     "emit",
     "events",
-    "annotate",
     "flush",
     "reset",
     "set_trace_stamper",
@@ -204,20 +201,6 @@ def events(kind: Optional[str] = None) -> List[dict]:
     if kind is not None:
         evs = [e for e in evs if e.get("kind") == kind]
     return evs
-
-
-def annotate(name: str):
-    """Context manager marking a named span in the active ``jax.profiler``
-    trace (no-op when the layer is off or jax is unavailable).  Host-side
-    only — a ``TraceAnnotation`` never launches device work."""
-    if not obs_enabled():
-        return nullcontext()
-    try:
-        import jax
-
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return nullcontext()
 
 
 def flush() -> None:

@@ -12,7 +12,7 @@ per compile:
 * :func:`classify_op` buckets each instruction into the §22 phase
   taxonomy (``plan_h2d`` / ``compute`` / ``exchange`` / ``accumulate``
   / ``overhead``) keyed on opcode first and ``op_name`` substrings for
-  refinement — the same names the engines annotate via TraceAnnotation.
+  refinement (the traced jaxpr path, ``jax.named_scope`` names included).
 * :func:`attribute_costs` distributes the executable's whole-program
   ``cost_analysis()`` totals (flops / bytes accessed) over the parsed
   ops so per-op and per-phase costs *sum exactly* to the program

@@ -34,12 +34,38 @@ heartbeat watchdog only *reads* the stack, which is why it is global and
 locked rather than thread-local).  Closing a span emits ONE ``span`` event
 carrying ``name``/``cat``/``t0``/``dur_ms``/``parent_span_id`` — emitted
 *before* the pop, so the envelope's ``span_id`` stamp is the span's own id.
-The canonical taxonomy (DESIGN.md §24)::
+``dur_ms`` comes from ``perf_counter_ns`` (monotonic); ``t0`` stays on the
+wall clock, which is what the cross-rank merge aligns.  The handle the
+``with`` yields takes counts while the span is open (``sp.add(steps=16)``);
+they close with the span, on its event.  The canonical taxonomy
+(DESIGN.md §24)::
 
     run (diagonalize / bench)  >  solve (one solver call)
       >  iteration (one convergence block / block step / segment)
+        >  phase (a named stretch of host work: ``lanczos/dispatch``,
+           ``plan/pack``, ``device_wait``)
         >  apply (one eager matvec)
           >  chunk (one streamed plan chunk: H2D wait + dispatch)
+    build (one engine's structure or plan build)  >  phase (its passes)
+
+The profiler's clock
+--------------------
+This is the one place the program opens a ``jax.profiler.TraceAnnotation``:
+a span of a LEAF kind (phase, apply, chunk, ...) holds one of its own name
+for its lifetime, so under any profiler session (``DMT_PROFILE_DIR``, a
+harness's ``start_trace``) it lies on the host line of the same
+``.xplane.pb`` as the device's operations, on one clock.  The ENCLOSING
+kinds (:data:`ENCLOSING_KINDS`: run, solve, iteration, batch, config,
+build) are not mirrored: a reader that labels a device-idle gap by the host
+span covering most of it (``benchmark/trace_reduce.py``) takes the first of
+equal covers, and an enclosing span always covers at least what its
+children do, so mirroring one would relabel every gap of a solve
+``lanczos`` and every gap of a plan build ``engine_init/build_plan``.  A
+span whose children are the named stretches of its work takes an enclosing
+kind; a leaf may still hold a narrower leaf (a pass its ``device_wait``,
+``lanczos/start`` its ``apply``), and then the outer of the two labels the
+gap.  Outside a profiler session an annotation costs about a microsecond
+and records nothing.
 
 Contracts (the health-probe pattern applied to causality): spans are pure
 host bookkeeping — the apply HLO is **byte-identical** with tracing on or
@@ -62,6 +88,8 @@ from ..utils.logging import log_warn
 from .events import emit, obs_enabled, run_dir, set_trace_stamper
 
 __all__ = [
+    "ENCLOSING_KINDS",
+    "NULL_SPAN",
     "trace_enabled",
     "trace_id",
     "job_id",
@@ -72,6 +100,11 @@ __all__ = [
     "span_path",
     "reset_trace",
 ]
+
+#: span kinds that enclose other spans for most of their lifetime; they
+#: stay out of the profiler's host line (see the module docstring)
+ENCLOSING_KINDS = frozenset({"run", "solve", "iteration", "batch", "config",
+                             "build"})
 
 _lock = threading.Lock()
 _stack: List["_Span"] = []
@@ -183,6 +216,28 @@ class _Span:
         self.t0 = time.time()
         self.attrs = attrs
 
+    def add(self, **counts) -> None:
+        """Add to the open span's counts (absent ones start at 0); they
+        are fields of its ``span`` event when it closes."""
+        with _lock:
+            for key, n in counts.items():
+                self.attrs[key] = self.attrs.get(key, 0) + n
+
+
+class _NullSpan:
+    """What ``with span(...)`` yields with tracing off: takes counts and
+    keeps nothing."""
+    __slots__ = ()
+    sid = None
+
+    def add(self, **counts) -> None:
+        pass
+
+
+#: the handle a caller holds when no span is open for it
+NULL_SPAN = _NullSpan()
+_NULL_CM = nullcontext(NULL_SPAN)
+
 
 def _next_span_id() -> str:
     """Span ids are ``<rank-local ordinal>-<4 random hex>`` — unique
@@ -194,16 +249,28 @@ def _next_span_id() -> str:
     return f"{_id_counter:x}-{_rand_id(2)}"
 
 
+def _profiler_annotation(sp: "_Span"):
+    """The span on the profiler's host line (module docstring, "The
+    profiler's clock"); a null context for the enclosing kinds."""
+    if sp.kind in ENCLOSING_KINDS:
+        return nullcontext()
+    import jax
+
+    return jax.profiler.TraceAnnotation(sp.name)
+
+
 @contextmanager
 def _span_cm(name: str, kind: str, attrs: Dict):
     with _lock:
         parent = _stack[-1].sid if _stack else None
         sp = _Span(str(name), str(kind), _next_span_id(), parent, attrs)
         _stack.append(sp)
+    t0_ns = time.perf_counter_ns()
     try:
-        yield sp
+        with _profiler_annotation(sp):
+            yield sp
     finally:
-        dur_ms = (time.time() - sp.t0) * 1e3
+        dur_ms = (time.perf_counter_ns() - t0_ns) / 1e6
         # emit BEFORE the pop: the envelope stamper sees the closing span
         # on top of the stack, so the span event's own span_id is itself
         # and its children's events (already written) point at it
@@ -218,11 +285,12 @@ def _span_cm(name: str, kind: str, attrs: Dict):
 
 
 def span(name: str, kind: str = "span", **attrs):
-    """Context manager for one traced span.  With tracing disabled this is
-    a shared null context: no id, no lock, no event — the provable-no-op
-    contract of ``DMT_OBS=off``."""
+    """Context manager for one traced span; yields a handle whose
+    ``add(**counts)`` puts counts on the span's event.  With tracing
+    disabled this is a shared null context: no id, no lock, no event, no
+    profiler annotation — the provable-no-op contract of ``DMT_OBS=off``."""
     if not trace_enabled():
-        return nullcontext()
+        return _NULL_CM
     return _span_cm(name, kind, attrs)
 
 

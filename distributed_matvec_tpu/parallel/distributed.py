@@ -102,7 +102,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..models.operator import Operator
-from ..obs import annotate, counter, emit, histogram, obs_enabled
+from ..obs import counter, emit, histogram, obs_enabled
 from ..obs import trace as obs_trace
 from ..obs import health as obs_health
 from ..obs import profile as obs_profile
@@ -462,7 +462,8 @@ class DistributedEngine:
         self.counts = counts
         from ..utils.artifacts import ensure_compilation_cache
         ensure_compilation_cache()
-        with self.timer.scope("transfer"), annotate("engine_init/transfer"):
+        with self.timer.scope("transfer"), \
+                obs_trace.span("engine_init/transfer", kind="phase"):
             self.tables = K.device_tables(operator, pair=self.pair)
         counter("bytes_h2d", path="engine_tables").inc(sum(
             a.nbytes for a in jax.tree_util.tree_leaves(self.tables)))
@@ -597,7 +598,8 @@ class DistributedEngine:
             if not self.structure_restored:
                 _t_build = time.perf_counter()
                 with self.timer.scope("build_plan"), \
-                        annotate("engine_init/build_plan"):
+                        obs_trace.span("engine_init/build_plan",
+                                       kind="build"):
                     try:
                         self._plan_stream(row_provider, compact=False)
                     except Exception as e:
@@ -652,7 +654,8 @@ class DistributedEngine:
                 self._c_W = float(vals[0]) if vals.size else 0.0
                 _t_build = time.perf_counter()
                 with self.timer.scope("build_plan"), \
-                        annotate("engine_init/build_plan"):
+                        obs_trace.span("engine_init/build_plan",
+                                       kind="build"):
                     try:
                         self._plan_stream(row_provider, compact=True)
                     except Exception as e:
@@ -766,7 +769,8 @@ class DistributedEngine:
                 if not self.structure_restored:
                     _t_build = time.perf_counter()
                     with self.timer.scope("build_plan"), \
-                            annotate("engine_init/build_plan"):
+                            obs_trace.span("engine_init/build_plan",
+                                           kind="build"):
                         try:
                             self._build_stream_plan(row_provider)
                             if mode == "hybrid":
@@ -873,7 +877,8 @@ class DistributedEngine:
         devs = list(self.mesh.devices.flat)
         piece = np.ascontiguousarray(np.asarray(piece))
         counter("bytes_h2d", path="shard_put").inc(piece.nbytes)
-        return jax.device_put(piece[None], devs[d])
+        with obs_trace.span("device_wait", kind="phase", at="shard_put"):
+            return jax.device_put(piece[None], devs[d])
 
     def _assemble_sharded(self, shards):
         """[D, ...] device array from per-shard pieces via
@@ -1001,7 +1006,9 @@ class DistributedEngine:
                 # chunk → ~0 stall) or didn't — record the wait, it is the
                 # stream's whole performance story
                 _t_fetch = time.perf_counter()
-                with self.timer.scope("transfer"):
+                with self.timer.scope("transfer"), \
+                        obs_trace.span("device_wait", kind="phase",
+                                       at="plan_chunk_fetch"):
                     betas, cf = np.asarray(betas_d), np.asarray(cf_d)
                 histogram("double_buffer_stall_ms").observe(
                     (time.perf_counter() - _t_fetch) * 1e3)
@@ -1016,278 +1023,284 @@ class DistributedEngine:
 
         # -- pass 1: row-nnz counts, per-peer unique remote targets, local
         #    sector check — own shards only, chunk-streamed ----------------
-        nnz = {d: np.zeros(M, np.int32) for d in my_shards}
-        pend = {d: [[] for _ in range(D)] for d in my_shards}
-        bad = 0
+        with obs_trace.span("plan/count", kind="phase"):
+            nnz = {d: np.zeros(M, np.int32) for d in my_shards}
+            pend = {d: [[] for _ in range(D)] for d in my_shards}
+            bad = 0
 
-        def fold_unique(lst):
-            if len(lst) > 1:
-                lst[:] = [np.unique(np.concatenate(lst))]
+            def fold_unique(lst):
+                if len(lst) > 1:
+                    lst[:] = [np.unique(np.concatenate(lst))]
 
-        for d in my_shards:
-            a_d, _ = row_provider(d)
-            for s, e, n_c, betas, cf, nz in chunks(d):
-                nnz[d][s:e] = nz.sum(axis=1)[: e - s]
-                flat_b = betas[nz]
-                owner = shard_index_host(flat_b, D)
-                loc = owner == d
-                if loc.any():
-                    lb = flat_b[loc]
-                    ip = np.searchsorted(a_d, lb)
-                    np.clip(ip, 0, M - 1, out=ip)
-                    bad += int((a_d[ip] != lb).sum())
+            for d in my_shards:
+                a_d, _ = row_provider(d)
+                for s, e, n_c, betas, cf, nz in chunks(d):
+                    nnz[d][s:e] = nz.sum(axis=1)[: e - s]
+                    flat_b = betas[nz]
+                    owner = shard_index_host(flat_b, D)
+                    loc = owner == d
+                    if loc.any():
+                        lb = flat_b[loc]
+                        ip = np.searchsorted(a_d, lb)
+                        np.clip(ip, 0, M - 1, out=ip)
+                        bad += int((a_d[ip] != lb).sum())
+                    for p in range(D):
+                        if p == d:
+                            continue
+                        sel = owner == p
+                        if sel.any():
+                            acc = pend[d][p]
+                            acc.append(np.unique(flat_b[sel]))
+                            if sum(a.size for a in acc) > \
+                                    max(1 << 22, 4 * acc[0].size):
+                                fold_unique(acc)
+                    log_debug(f"plan pass1 shard {d}: rows {e}/{M}")
                 for p in range(D):
-                    if p == d:
-                        continue
-                    sel = owner == p
-                    if sel.any():
-                        acc = pend[d][p]
-                        acc.append(np.unique(flat_b[sel]))
-                        if sum(a.size for a in acc) > \
-                                max(1 << 22, 4 * acc[0].size):
-                            fold_unique(acc)
-                log_debug(f"plan pass1 shard {d}: rows {e}/{M}")
-            for p in range(D):
-                fold_unique(pend[d][p])
+                    fold_unique(pend[d][p])
 
         # -- pass 1b: resolve unique targets against each peer's rows (one
         #    peer resident at a time) ------------------------------------
-        queries = {d: [None] * D for d in my_shards}
-        qstate = {d: [None] * D for d in my_shards}
-        qnorm = {d: [None] * D for d in my_shards}
-        for p in range(D):
-            peer = None
-            for d in my_shards:
-                if p == d:
-                    continue
-                if not pend[d][p]:
-                    queries[d][p] = np.zeros(0, np.int32)
-                    qstate[d][p] = np.zeros(0, np.uint64)
-                    qnorm[d][p] = np.zeros(0)
-                    continue
-                if peer is None:
-                    peer = row_provider(p)
-                a_p, n_p = peer
-                ub = pend[d][p][0]
-                ip = np.searchsorted(a_p, ub)
-                np.clip(ip, 0, M - 1, out=ip)
-                ok = a_p[ip] == ub
-                bad += int((~ok).sum())
-                queries[d][p] = ip[ok].astype(np.int32)
-                qstate[d][p] = ub[ok]
-                qnorm[d][p] = n_p[ip[ok]]
-                pend[d][p] = []
-            del peer
-        del pend
-
-        if multi:
-            # agree on the sector check globally so a violation fails
-            # loudly on every rank instead of hanging the collectives
-            bad = int(np.sum(mhu.process_allgather(np.int64(bad))))
-        if bad:
-            raise RuntimeError(
-                f"{bad} generated matrix elements map outside the basis — "
-                "operator does not preserve the chosen sector"
-            )
-
-        hist = np.zeros(T + 1, np.int64)
-        for d in my_shards:
-            hist += np.bincount(nnz[d], minlength=T + 1)
-        cap = max((queries[d][p].size for d in my_shards for p in range(D)
-                   if queries[d][p] is not None), default=0)
-        if multi:
-            hist = np.sum(mhu.process_allgather(hist), axis=0)
-            cap = int(np.max(mhu.process_allgather(np.int64(cap))))
-        T0, S, Tmax = choose_ell_split(hist, D * M, T,
-                                       real_rows=self.n_states)
-        self._ell_T0 = T0
-        Tw = Tmax - T0 if S else 0
-        C = _round_up(cap, 8)
-        self.query_capacity = C
-        remote_unique = sum(queries[d][p].size for d in my_shards
-                            for p in range(D) if queries[d][p] is not None)
-        self._plan_remote_unique = remote_unique
-        log_debug(f"routing plan: D={D} M={M} T={T} T0={T0} tail={S} "
-                  f"capacity={C} remote_unique(local)={remote_unique}")
-
-        # qin[d][q] = the local indices peer q reads from this shard
-        # (0-padded); sorted-unique order fixed by pass 1b.  queries[q][d]
-        # lives on shard q's owner, so in a multi-controller run each
-        # source shard's query lists cross processes in ONE bounded
-        # [D, C] allgather round.
-        qin_rows = {d: np.zeros((D, C), np.int32) for d in my_shards}
-        if not multi:
-            for d in my_shards:
-                for q in range(D):
-                    if q != d:
-                        ql = queries[q][d]
-                        qin_rows[d][q, : ql.size] = ql
-        else:
-            for q in range(D):
-                buf = np.zeros((D, C), np.int32)
-                if q in queries:
-                    for dd in range(D):
-                        if dd != q:
-                            ql = queries[q][dd]
-                            buf[dd, : ql.size] = ql
-                buf = np.sum(mhu.process_allgather(buf), axis=0,
-                             dtype=np.int32)
+        with obs_trace.span("plan/resolve", kind="phase"):
+            queries = {d: [None] * D for d in my_shards}
+            qstate = {d: [None] * D for d in my_shards}
+            qnorm = {d: [None] * D for d in my_shards}
+            for p in range(D):
+                peer = None
                 for d in my_shards:
-                    if d != q:
-                        qin_rows[d][q] = buf[d]
-        qin_shards = [qin_rows.get(d) for d in range(D)]
-        self._qin = self._assemble_sharded(qin_shards)
-
-        W = self._c_W if compact else 0.0
-        cdtype = np.float64 if self.real else np.complex128
-        S_max = 0
-        if S:
-            S_max = max((int((nnz[d] > T0).sum()) for d in my_shards),
-                        default=0)
-            if multi:
-                # tail buffers assemble to a uniform [D, S_max]
-                S_max = int(np.max(mhu.process_allgather(np.int64(S_max))))
-
-        # -- pass 2: pack per-shard tables, one shard resident at a time ---
-        idx_shards, cf_shards = [], []
-        trow_shards, tidx_shards, tcf_shards = [], [], []
-        n_all_shards = []
-        badw = 0
-        for d in range(D):
-            if not self._shard_addressable(d):
-                # another process packs this shard; keep list positions
-                for lst in (idx_shards, cf_shards, trow_shards,
-                            tidx_shards, tcf_shards, n_all_shards):
-                    lst.append(None)
-                continue
-            a_d, n_d = row_provider(d)
-            g_main = None if compact else np.zeros((T0, M), np.int32)
-            v_main = (np.zeros((T0, M), np.int32) if compact
-                      else np.zeros((T0, M), cdtype))
-            rows_t = np.zeros(S_max, np.int32)
-            v_tail = (np.zeros((Tw, S_max), np.int32) if compact
-                      else np.zeros((Tw, S_max), cdtype))
-            i_tail = None if compact else np.zeros((Tw, S_max), np.int32)
-            t_cursor = 0
-            for s, e, n_c, betas, cf, nz in chunks(d):
-                # per-entry destination: local index, or M + p·C + slot
-                # where slot = position in the pass-1b unique-state list
-                # (binary search — the lists are sorted by construction)
-                flat_b = betas[nz]
-                owner = shard_index_host(flat_b, D)
-                gflat = np.zeros(flat_b.size, np.int64)
-                nflat = np.ones(flat_b.size)
-                loc = owner == d
-                if loc.any():
-                    ip = np.searchsorted(a_d, flat_b[loc])
-                    np.clip(ip, 0, M - 1, out=ip)
-                    gflat[loc] = ip
-                    if compact:
-                        nflat[loc] = n_d[ip]
-                for p in range(D):
                     if p == d:
                         continue
-                    sel = owner == p
-                    if not sel.any():
+                    if not pend[d][p]:
+                        queries[d][p] = np.zeros(0, np.int32)
+                        qstate[d][p] = np.zeros(0, np.uint64)
+                        qnorm[d][p] = np.zeros(0)
                         continue
-                    pos = np.searchsorted(qstate[d][p], flat_b[sel])
-                    np.clip(pos, 0, max(qstate[d][p].size - 1, 0), out=pos)
-                    gflat[sel] = M + p * C + pos
-                    if compact:
-                        nflat[sel] = qnorm[d][p][pos]
-                g = np.zeros(betas.shape, np.int64)
-                g[nz] = gflat
-                if compact:
-                    n_b = np.ones(betas.shape)
-                    n_b[nz] = nflat
-                cfz = np.where(nz, cf, 0)
-                if compact:
-                    ratio = np.abs(cfz) * n_c[:, None] / n_b
-                    badw += int((nz & (np.abs(ratio - W) > 1e-9 * W)).sum())
-                order = np.argsort(~nz, axis=1, kind="stable")
-                g_p = np.take_along_axis(np.where(nz, g, 0), order, axis=1)
-                c_p = np.take_along_axis(cfz, order, axis=1)
-                r = e - s
+                    if peer is None:
+                        peer = row_provider(p)
+                    a_p, n_p = peer
+                    ub = pend[d][p][0]
+                    ip = np.searchsorted(a_p, ub)
+                    np.clip(ip, 0, M - 1, out=ip)
+                    ok = a_p[ip] == ub
+                    bad += int((~ok).sum())
+                    queries[d][p] = ip[ok].astype(np.int32)
+                    qstate[d][p] = ub[ok]
+                    qnorm[d][p] = n_p[ip[ok]]
+                    pend[d][p] = []
+                del peer
+            del pend
 
-                def pack(gg, cc):
-                    if compact:
-                        return np.where(
-                            cc != 0,
-                            np.sign(cc.real).astype(np.int32)
-                            * (gg.astype(np.int32) + 1), 0)
-                    return cc
+        # -- query lists: the sector check agreed, the split chosen, what
+        #    each peer reads from each shard --------------------------------
+        with obs_trace.span("plan/queries", kind="phase"):
+            if multi:
+                # agree on the sector check globally so a violation fails
+                # loudly on every rank instead of hanging the collectives
+                bad = int(np.sum(mhu.process_allgather(np.int64(bad))))
+            if bad:
+                raise RuntimeError(
+                    f"{bad} generated matrix elements map outside the basis — "
+                    "operator does not preserve the chosen sector"
+                )
 
-                if not compact:
-                    g_main[:, s:e] = g_p[:r, :T0].T
-                v_main[:, s:e] = pack(g_p[:r, :T0], c_p[:r, :T0]).T
-                if S:
-                    rd = np.nonzero(nnz[d][s:e] > T0)[0]
-                    if rd.size:
-                        tsl = slice(t_cursor, t_cursor + rd.size)
-                        rows_t[tsl] = (s + rd).astype(np.int32)
-                        if not compact:
-                            i_tail[:, tsl] = g_p[rd, T0:Tmax].T
-                        v_tail[:, tsl] = pack(g_p[rd, T0:Tmax],
-                                              c_p[rd, T0:Tmax]).T
-                        t_cursor += rd.size
-                log_debug(f"plan pass2 shard {d}: rows {e}/{M}")
-            # ship this shard's tables to its device NOW so the host
-            # staging above is freed before the next shard packs
-            if compact:
-                idx_shards.append(self._put_shard(v_main, d))  # sign tags
+            hist = np.zeros(T + 1, np.int64)
+            for d in my_shards:
+                hist += np.bincount(nnz[d], minlength=T + 1)
+            cap = max((queries[d][p].size for d in my_shards for p in range(D)
+                       if queries[d][p] is not None), default=0)
+            if multi:
+                hist = np.sum(mhu.process_allgather(hist), axis=0)
+                cap = int(np.max(mhu.process_allgather(np.int64(cap))))
+            T0, S, Tmax = choose_ell_split(hist, D * M, T,
+                                           real_rows=self.n_states)
+            self._ell_T0 = T0
+            Tw = Tmax - T0 if S else 0
+            C = _round_up(cap, 8)
+            self.query_capacity = C
+            remote_unique = sum(queries[d][p].size for d in my_shards
+                                for p in range(D) if queries[d][p] is not None)
+            self._plan_remote_unique = remote_unique
+            log_debug(f"routing plan: D={D} M={M} T={T} T0={T0} tail={S} "
+                      f"capacity={C} remote_unique(local)={remote_unique}")
+
+            # qin[d][q] = the local indices peer q reads from this shard
+            # (0-padded); sorted-unique order fixed by pass 1b.  queries[q][d]
+            # lives on shard q's owner, so in a multi-controller run each
+            # source shard's query lists cross processes in ONE bounded
+            # [D, C] allgather round.
+            qin_rows = {d: np.zeros((D, C), np.int32) for d in my_shards}
+            if not multi:
+                for d in my_shards:
+                    for q in range(D):
+                        if q != d:
+                            ql = queries[q][d]
+                            qin_rows[d][q, : ql.size] = ql
             else:
-                idx_shards.append(self._put_shard(g_main, d))
-                cf_shards.append(self._put_shard(
-                    K.pair_from_complex(v_main) if self.pair else v_main, d))
-            if S:
-                trow_shards.append(self._put_shard(rows_t, d))
-                if compact:
-                    tidx_shards.append(self._put_shard(v_tail, d))
-                else:
-                    tidx_shards.append(self._put_shard(i_tail, d))
-                    tcf_shards.append(self._put_shard(
-                        K.pair_from_complex(v_tail) if self.pair else v_tail,
-                        d))
-            if compact:
-                n_all_d = np.ones(M + D * C if D > 1 else M)
-                n_all_d[:M] = n_d
-                for p in range(D):
-                    if p != d and qnorm[d][p].size:
-                        n_all_d[M + p * C: M + p * C + qnorm[d][p].size] = \
-                            qnorm[d][p]
-                n_all_shards.append(n_all_d)
-        if compact and self._multi:
-            # badw is accumulated over THIS process's addressable shards
-            # only; agree on the total before raising so a non-qualifying
-            # operator fails loudly on every rank instead of hanging the
-            # others in the next collective
-            from jax.experimental import multihost_utils
-            badw = int(np.sum(multihost_utils.process_allgather(
-                np.int64(badw))))
-        if badw:
-            raise RuntimeError(
-                f"{badw} matrix elements violate the ±W·n(j)/n(i) form "
-                f"(W={W}); the operator does not qualify for compact mode "
-                "— use mode='ell'"
-            )
+                for q in range(D):
+                    buf = np.zeros((D, C), np.int32)
+                    if q in queries:
+                        for dd in range(D):
+                            if dd != q:
+                                ql = queries[q][dd]
+                                buf[dd, : ql.size] = ql
+                    buf = np.sum(mhu.process_allgather(buf), axis=0,
+                                 dtype=np.int32)
+                    for d in my_shards:
+                        if d != q:
+                            qin_rows[d][q] = buf[d]
+            qin_shards = [qin_rows.get(d) for d in range(D)]
+            self._qin = self._assemble_sharded(qin_shards)
 
-        if compact:
-            self._c_idx = self._assemble_sharded(idx_shards)   # [D, T0, M]
-            self._c_tail = None
+        with obs_trace.span("plan/pack", kind="phase"):
+            W = self._c_W if compact else 0.0
+            cdtype = np.float64 if self.real else np.complex128
+            S_max = 0
             if S:
-                self._c_tail = (self._assemble_sharded(trow_shards),
-                                self._assemble_sharded(tidx_shards))
-            self._finish_compact_aux(self._assemble_sharded(n_all_shards))
-            # per-shard host copies kept only until _save_structure runs
-            self._c_n_all_shards = n_all_shards
-        else:
-            self._ell_idx = self._assemble_sharded(idx_shards)
-            self._ell_coeff = self._assemble_sharded(cf_shards)
-            self._ell_tail = None
-            if S:
-                self._ell_tail = (self._assemble_sharded(trow_shards),
-                                  self._assemble_sharded(tidx_shards),
-                                  self._assemble_sharded(tcf_shards))
+                S_max = max((int((nnz[d] > T0).sum()) for d in my_shards),
+                            default=0)
+                if multi:
+                    # tail buffers assemble to a uniform [D, S_max]
+                    S_max = int(np.max(mhu.process_allgather(np.int64(S_max))))
+
+            # -- pass 2: pack per-shard tables, one shard resident at a time
+            idx_shards, cf_shards = [], []
+            trow_shards, tidx_shards, tcf_shards = [], [], []
+            n_all_shards = []
+            badw = 0
+            for d in range(D):
+                if not self._shard_addressable(d):
+                    # another process packs this shard; keep list positions
+                    for lst in (idx_shards, cf_shards, trow_shards,
+                                tidx_shards, tcf_shards, n_all_shards):
+                        lst.append(None)
+                    continue
+                a_d, n_d = row_provider(d)
+                g_main = None if compact else np.zeros((T0, M), np.int32)
+                v_main = (np.zeros((T0, M), np.int32) if compact
+                          else np.zeros((T0, M), cdtype))
+                rows_t = np.zeros(S_max, np.int32)
+                v_tail = (np.zeros((Tw, S_max), np.int32) if compact
+                          else np.zeros((Tw, S_max), cdtype))
+                i_tail = None if compact else np.zeros((Tw, S_max), np.int32)
+                t_cursor = 0
+                for s, e, n_c, betas, cf, nz in chunks(d):
+                    # per-entry destination: local index, or M + p·C + slot
+                    # where slot = position in the pass-1b unique-state list
+                    # (binary search — the lists are sorted by construction)
+                    flat_b = betas[nz]
+                    owner = shard_index_host(flat_b, D)
+                    gflat = np.zeros(flat_b.size, np.int64)
+                    nflat = np.ones(flat_b.size)
+                    loc = owner == d
+                    if loc.any():
+                        ip = np.searchsorted(a_d, flat_b[loc])
+                        np.clip(ip, 0, M - 1, out=ip)
+                        gflat[loc] = ip
+                        if compact:
+                            nflat[loc] = n_d[ip]
+                    for p in range(D):
+                        if p == d:
+                            continue
+                        sel = owner == p
+                        if not sel.any():
+                            continue
+                        pos = np.searchsorted(qstate[d][p], flat_b[sel])
+                        np.clip(pos, 0, max(qstate[d][p].size - 1, 0), out=pos)
+                        gflat[sel] = M + p * C + pos
+                        if compact:
+                            nflat[sel] = qnorm[d][p][pos]
+                    g = np.zeros(betas.shape, np.int64)
+                    g[nz] = gflat
+                    if compact:
+                        n_b = np.ones(betas.shape)
+                        n_b[nz] = nflat
+                    cfz = np.where(nz, cf, 0)
+                    if compact:
+                        ratio = np.abs(cfz) * n_c[:, None] / n_b
+                        badw += int((nz & (np.abs(ratio - W) > 1e-9 * W)).sum())
+                    order = np.argsort(~nz, axis=1, kind="stable")
+                    g_p = np.take_along_axis(np.where(nz, g, 0), order, axis=1)
+                    c_p = np.take_along_axis(cfz, order, axis=1)
+                    r = e - s
+
+                    def pack(gg, cc):
+                        if compact:
+                            return np.where(
+                                cc != 0,
+                                np.sign(cc.real).astype(np.int32)
+                                * (gg.astype(np.int32) + 1), 0)
+                        return cc
+
+                    if not compact:
+                        g_main[:, s:e] = g_p[:r, :T0].T
+                    v_main[:, s:e] = pack(g_p[:r, :T0], c_p[:r, :T0]).T
+                    if S:
+                        rd = np.nonzero(nnz[d][s:e] > T0)[0]
+                        if rd.size:
+                            tsl = slice(t_cursor, t_cursor + rd.size)
+                            rows_t[tsl] = (s + rd).astype(np.int32)
+                            if not compact:
+                                i_tail[:, tsl] = g_p[rd, T0:Tmax].T
+                            v_tail[:, tsl] = pack(g_p[rd, T0:Tmax],
+                                                  c_p[rd, T0:Tmax]).T
+                            t_cursor += rd.size
+                    log_debug(f"plan pass2 shard {d}: rows {e}/{M}")
+                # ship this shard's tables to its device NOW so the host
+                # staging above is freed before the next shard packs
+                if compact:
+                    idx_shards.append(self._put_shard(v_main, d))  # sign tags
+                else:
+                    idx_shards.append(self._put_shard(g_main, d))
+                    cf_shards.append(self._put_shard(
+                        K.pair_from_complex(v_main) if self.pair else v_main, d))
+                if S:
+                    trow_shards.append(self._put_shard(rows_t, d))
+                    if compact:
+                        tidx_shards.append(self._put_shard(v_tail, d))
+                    else:
+                        tidx_shards.append(self._put_shard(i_tail, d))
+                        tcf_shards.append(self._put_shard(
+                            K.pair_from_complex(v_tail) if self.pair else v_tail,
+                            d))
+                if compact:
+                    n_all_d = np.ones(M + D * C if D > 1 else M)
+                    n_all_d[:M] = n_d
+                    for p in range(D):
+                        if p != d and qnorm[d][p].size:
+                            n_all_d[M + p * C: M + p * C + qnorm[d][p].size] = \
+                                qnorm[d][p]
+                    n_all_shards.append(n_all_d)
+            if compact and self._multi:
+                # badw is accumulated over THIS process's addressable shards
+                # only; agree on the total before raising so a non-qualifying
+                # operator fails loudly on every rank instead of hanging the
+                # others in the next collective
+                from jax.experimental import multihost_utils
+                badw = int(np.sum(multihost_utils.process_allgather(
+                    np.int64(badw))))
+            if badw:
+                raise RuntimeError(
+                    f"{badw} matrix elements violate the ±W·n(j)/n(i) form "
+                    f"(W={W}); the operator does not qualify for compact mode "
+                    "— use mode='ell'"
+                )
+
+            if compact:
+                self._c_idx = self._assemble_sharded(idx_shards)   # [D, T0, M]
+                self._c_tail = None
+                if S:
+                    self._c_tail = (self._assemble_sharded(trow_shards),
+                                    self._assemble_sharded(tidx_shards))
+                self._finish_compact_aux(self._assemble_sharded(n_all_shards))
+                # per-shard host copies kept only until _save_structure runs
+                self._c_n_all_shards = n_all_shards
+            else:
+                self._ell_idx = self._assemble_sharded(idx_shards)
+                self._ell_coeff = self._assemble_sharded(cf_shards)
+                self._ell_tail = None
+                if S:
+                    self._ell_tail = (self._assemble_sharded(trow_shards),
+                                      self._assemble_sharded(tidx_shards),
+                                      self._assemble_sharded(tcf_shards))
         _mem_h.release()           # stream staging gone; tables resident
         obs_memory.sample_watermark("plan_upload/distributed")
 
@@ -3434,14 +3447,20 @@ class DistributedEngine:
             x, qin, gidx, coeff, diag = (
                 a[0] for a in (x, qin, gidx, coeff, diag))
             batched = x.ndim == nd_base + 1
+            # the named scopes are metadata on the operations (their
+            # ``op_name``; a device trace carries it per event): no
+            # operation is added, moved or split for them
             if D > 1:
-                S = x[qin]                      # [D, C] + x.shape[1:]
-                R = jax.lax.all_to_all(S, SHARD_AXIS, 0, 0, tiled=True)
-                xx = jnp.concatenate(
-                    [x, R.reshape((D * C,) + x.shape[1:])], axis=0)
+                with jax.named_scope("apply/pack"):
+                    S = x[qin]                  # [D, C] + x.shape[1:]
+                with jax.named_scope("apply/exchange"):
+                    R = jax.lax.all_to_all(S, SHARD_AXIS, 0, 0, tiled=True)
+                    xx = jnp.concatenate(
+                        [x, R.reshape((D * C,) + x.shape[1:])], axis=0)
             else:
                 xx = x
-            gx = prep_gather(xx, dtype, use_sg)
+            with jax.named_scope("apply/split"):
+                gx = prep_gather(xx, dtype, use_sg)
 
             def contrib(c, g):
                 if is_pair:
@@ -3460,15 +3479,20 @@ class DistributedEngine:
                                         (gidx[:width], coeff[:width]))
                 return y
 
-            d = diag.reshape(diag.shape + (1,) * (x.ndim - 1)).astype(dtype)
-            y = terms(d * x, gidx, coeff, T0)
+            with jax.named_scope("apply/diag"):
+                d = diag.reshape(
+                    diag.shape + (1,) * (x.ndim - 1)).astype(dtype)
+                y = d * x
+            with jax.named_scope("apply/terms"):
+                y = terms(y, gidx, coeff, T0)
             if has_tail:
-                rows, idx_t, cf_t = (a[0] for a in tail)
-                zshape = rows.shape + x.shape[1:]
-                acc = terms(jax.lax.pcast(jnp.zeros(zshape, dtype),
-                                          SHARD_AXIS, to="varying"),
-                            idx_t, cf_t, idx_t.shape[0])
-                y = y.at[rows].add(acc, mode="drop")
+                with jax.named_scope("apply/tail"):
+                    rows, idx_t, cf_t = (a[0] for a in tail)
+                    zshape = rows.shape + x.shape[1:]
+                    acc = terms(jax.lax.pcast(jnp.zeros(zshape, dtype),
+                                              SHARD_AXIS, to="varying"),
+                                idx_t, cf_t, idx_t.shape[0])
+                    y = y.at[rows].add(acc, mode="drop")
             return y[None]
 
         mesh = self.mesh
@@ -3903,7 +3927,7 @@ class DistributedEngine:
         # telemetry measures eager *dispatch* wall time only (async queue —
         # NO block_until_ready here: recording must never add a sync)
         _t0 = time.perf_counter()
-        with self.timer.scope("matvec"), annotate("matvec/distributed"):
+        with self.timer.scope("matvec"):
             xh = jnp.asarray(xh)
             if self.pair and (xh.ndim not in (3, 4) or xh.shape[-1] != 2):
                 raise ValueError(

@@ -42,7 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models.operator import Operator
-from ..obs import annotate, counter, emit, gauge, histogram
+from ..obs import counter, emit, gauge, histogram
 from ..obs import phases as obs_phases
 from ..obs import trace as obs_trace
 from ..obs import health as obs_health
@@ -127,7 +127,8 @@ def precompile(name: str, statics: tuple, jit_fn, args, timer) -> Any:
         if seen and shapes not in seen:
             counter("retrace_count").inc()
         seen.add(shapes)
-        with timer.scope("compile"), annotate(f"compile/{name}"):
+        with timer.scope("compile"), \
+                obs_trace.span(f"compile/{name}", kind="phase"):
             ex = jit_fn.lower(*args).compile()
         _PROGRAM_CACHE[key] = ex
         # compile-time memory facts for every AOT-cached executable:
@@ -738,7 +739,8 @@ class LocalEngine:
         # ops/bits.build_sorted_lookup): device arrays + static ints.
         pair, dir_tab, self._lk_shift, self._lk_probes = build_sorted_lookup(
             reps, basis.number_bits)
-        with self.timer.scope("transfer"), annotate("engine_init/transfer"):
+        with self.timer.scope("transfer"), \
+                obs_trace.span("engine_init/transfer", kind="phase"):
             self._lk_pair = jnp.asarray(pair)         # [N, 2] u32
             self._lk_dir = jnp.asarray(dir_tab)       # [2^b + 1] i32
             self._alphas = jnp.asarray(alphas)        # [N_pad]
@@ -775,7 +777,8 @@ class LocalEngine:
                                    structure_cache is not None)
             if not self.structure_restored:
                 with self.timer.scope("build_structure"), \
-                        annotate("engine_init/build_structure"):
+                        obs_trace.span("engine_init/build_structure",
+                                       kind="build"):
                     try:
                         self._build_ell()
                     except Exception as e:
@@ -790,7 +793,8 @@ class LocalEngine:
                                    structure_cache is not None)
             if not self.structure_restored:
                 with self.timer.scope("build_structure"), \
-                        annotate("engine_init/build_structure"):
+                        obs_trace.span("engine_init/build_structure",
+                                       kind="build"):
                     try:
                         self._build_compact()
                     except Exception as e:
@@ -956,28 +960,37 @@ class LocalEngine:
                       f"(full-width {full_bytes/1e9:.1f} GB)")
             return self._build_ell_lowmem()
 
-        idx_buf = jnp.zeros((T, self.n_padded), jnp.int32)
-        cshape = (T, self.n_padded, 2) if is_pair else (T, self.n_padded)
-        coeff_buf = jnp.zeros(cshape, jnp.float64 if (self.real or is_pair)
-                              else jnp.complex128)
-        bad = jnp.zeros((), jnp.int64)
-        if C:
-            jfn = jax.jit(partial(_ell_fill_chunk, shift=self._lk_shift,
-                                  probes=self._lk_probes, is_pair=is_pair),
-                          donate_argnums=(0, 1, 2))
-            fill = precompile(
-                "ell_fill_chunk", self._builder_statics(), jfn,
-                (idx_buf, coeff_buf, bad, self.tables, self._lk_pair,
-                 self._lk_dir, alphas_c[0], norms_c[0], jnp.int32(0)),
-                self.timer)
-            for ci in range(C):
-                log_debug(f"ell build chunk {ci}/{C}")
-                idx_buf, coeff_buf, bad = fill(
-                    idx_buf, coeff_buf, bad, self.tables, self._lk_pair,
-                    self._lk_dir, alphas_c[ci], norms_c[ci], jnp.int32(ci * b))
-        if int(bad):
+        # one span a pass; ``device_wait`` where the host blocks on the
+        # device, so that a pass's self time is the host's own work
+        with obs_trace.span("ell/fill", kind="phase"):
+            idx_buf = jnp.zeros((T, self.n_padded), jnp.int32)
+            cshape = (T, self.n_padded, 2) if is_pair \
+                else (T, self.n_padded)
+            coeff_buf = jnp.zeros(
+                cshape, jnp.float64 if (self.real or is_pair)
+                else jnp.complex128)
+            bad = jnp.zeros((), jnp.int64)
+            if C:
+                jfn = jax.jit(
+                    partial(_ell_fill_chunk, shift=self._lk_shift,
+                            probes=self._lk_probes, is_pair=is_pair),
+                    donate_argnums=(0, 1, 2))
+                fill = precompile(
+                    "ell_fill_chunk", self._builder_statics(), jfn,
+                    (idx_buf, coeff_buf, bad, self.tables, self._lk_pair,
+                     self._lk_dir, alphas_c[0], norms_c[0], jnp.int32(0)),
+                    self.timer)
+                for ci in range(C):
+                    log_debug(f"ell build chunk {ci}/{C}")
+                    idx_buf, coeff_buf, bad = fill(
+                        idx_buf, coeff_buf, bad, self.tables, self._lk_pair,
+                        self._lk_dir, alphas_c[ci], norms_c[ci],
+                        jnp.int32(ci * b))
+            with obs_trace.span("device_wait", kind="phase", at="ell_fill"):
+                bad = int(bad)
+        if bad:
             raise RuntimeError(
-                f"{int(bad)} generated matrix elements map outside the basis "
+                f"{bad} generated matrix elements map outside the basis "
                 "— operator does not preserve the chosen sector"
             )
         self._split_ell(idx_buf, coeff_buf)
@@ -1006,13 +1019,17 @@ class LocalEngine:
             return
 
         # Phase 1 — row-nnz histogram only; no table-sized allocation.
-        count = precompile(
-            "ell_split_count", (T, is_pair),
-            jax.jit(partial(_split_count, T=T, is_pair=is_pair)),
-            (coeff_buf,), self.timer)
-        nnz, hist = count(coeff_buf)
-        T0, S, Tmax = choose_ell_split(np.asarray(hist), n_pad, T,
-                                       real_rows=self.n_states)
+        with obs_trace.span("ell/split_count", kind="phase"):
+            count = precompile(
+                "ell_split_count", (T, is_pair),
+                jax.jit(partial(_split_count, T=T, is_pair=is_pair)),
+                (coeff_buf,), self.timer)
+            nnz, hist = count(coeff_buf)
+            with obs_trace.span("device_wait", kind="phase",
+                                at="ell_split_count"):
+                hist = np.asarray(hist)
+            T0, S, Tmax = choose_ell_split(hist, n_pad, T,
+                                           real_rows=self.n_states)
         self._ell_T0 = T0
         final_entries = n_pad * T if T0 == T \
             else n_pad * T0 + S * (Tmax - T0)
@@ -1028,29 +1045,32 @@ class LocalEngine:
         # the full-width input tables + the [T0, N_pad] packed outputs +
         # O(T·b) chunk scratch (≈1.6× one full-width table at 50% fill);
         # the argsort order array only ever exists per chunk.
-        out_idx = jnp.zeros((T0, n_pad), jnp.int32)
-        out_cf = jnp.zeros((T0, n_pad) + ((2,) if is_pair else ()),
-                           coeff_buf.dtype)
-        pack = precompile(
-            "ell_split_pack", (T, T0, b, is_pair),
-            jax.jit(partial(_split_pack_chunk, T=T, T0=T0, b=b,
-                            is_pair=is_pair), donate_argnums=(0, 1)),
-            (out_idx, out_cf, idx_buf, coeff_buf, jnp.int32(0)), self.timer)
-        for ci in range(C):
-            out_idx, out_cf = pack(out_idx, out_cf, idx_buf,
-                                   coeff_buf, jnp.int32(ci * b))
+        with obs_trace.span("ell/split_pack", kind="phase"):
+            out_idx = jnp.zeros((T0, n_pad), jnp.int32)
+            out_cf = jnp.zeros((T0, n_pad) + ((2,) if is_pair else ()),
+                               coeff_buf.dtype)
+            pack = precompile(
+                "ell_split_pack", (T, T0, b, is_pair),
+                jax.jit(partial(_split_pack_chunk, T=T, T0=T0, b=b,
+                                is_pair=is_pair), donate_argnums=(0, 1)),
+                (out_idx, out_cf, idx_buf, coeff_buf, jnp.int32(0)),
+                self.timer)
+            for ci in range(C):
+                out_idx, out_cf = pack(out_idx, out_cf, idx_buf,
+                                       coeff_buf, jnp.int32(ci * b))
         self._ell_idx = out_idx
         self._ell_coeff = out_cf
         if S == 0:
             self._ell_tail = None
             return
 
-        build_tail = precompile(
-            "ell_split_tail", (T0, Tmax, S, is_pair),
-            jax.jit(partial(_split_build_tail, T0=T0, Tmax=Tmax, S=S,
-                            is_pair=is_pair)),
-            (idx_buf, coeff_buf, nnz), self.timer)
-        self._ell_tail = build_tail(idx_buf, coeff_buf, nnz)
+        with obs_trace.span("ell/split_tail", kind="phase"):
+            build_tail = precompile(
+                "ell_split_tail", (T0, Tmax, S, is_pair),
+                jax.jit(partial(_split_build_tail, T0=T0, Tmax=Tmax, S=S,
+                                is_pair=is_pair)),
+                (idx_buf, coeff_buf, nnz), self.timer)
+            self._ell_tail = build_tail(idx_buf, coeff_buf, nnz)
 
     def _count_row_nnz(self, alphas_c, norms_c):
         """Counting pass shared by the low-memory builds: per-chunk row-nnz
@@ -1333,7 +1353,11 @@ class LocalEngine:
             idx, coeff, diag, tail = operands
             x = jnp.asarray(x).astype(dtype)
             batched = x.ndim == nd_base + 1
-            gx = prep_gather(x, dtype, use_sg)
+            # the named scopes are metadata on the operations (their
+            # ``op_name``; a device trace carries it per event): no
+            # operation is added, moved or split for them
+            with jax.named_scope("apply/split"):
+                gx = prep_gather(x, dtype, use_sg)
 
             def contrib(c, g):
                 # c: per-row coefficient [rows(, 2)]; g: gathered x rows
@@ -1356,15 +1380,18 @@ class LocalEngine:
                                         (idx[:width], coeff[:width]))
                 return y
 
-            d = diag[:n].astype(dtype)
-            y = d.reshape((n,) + (1,) * (x.ndim - 1)) * x
-            y = terms(y, idx, coeff, T0, sl=True)
+            with jax.named_scope("apply/diag"):
+                d = diag[:n].astype(dtype)
+                y = d.reshape((n,) + (1,) * (x.ndim - 1)) * x
+            with jax.named_scope("apply/terms"):
+                y = terms(y, idx, coeff, T0, sl=True)
             if has_tail:
-                rows, idx_t, cf_t = tail
-                zshape = rows.shape + x.shape[1:]
-                acc = terms(jnp.zeros(zshape, dtype), idx_t, cf_t,
-                            idx_t.shape[0])
-                y = y.at[rows].add(acc, mode="drop")
+                with jax.named_scope("apply/tail"):
+                    rows, idx_t, cf_t = tail
+                    zshape = rows.shape + x.shape[1:]
+                    acc = terms(jnp.zeros(zshape, dtype), idx_t, cf_t,
+                                idx_t.shape[0])
+                    y = y.at[rows].add(acc, mode="drop")
             return y, jnp.zeros((), jnp.int64)
 
         self._apply_fn = apply_fn
@@ -1460,7 +1487,7 @@ class LocalEngine:
         # telemetry measures eager *dispatch* wall time only (async queue —
         # NO block_until_ready here: recording must never add a sync)
         _t0 = time.perf_counter()
-        with self.timer.scope("matvec"), annotate("matvec/local"):
+        with self.timer.scope("matvec"):
             was_complex = self.pair and np.iscomplexobj(x)
             if was_complex:
                 x = K.pair_from_complex(np.asarray(x))

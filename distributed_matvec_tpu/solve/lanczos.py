@@ -30,6 +30,7 @@ exact).
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
 from functools import partial
 from typing import Callable, Optional
@@ -767,17 +768,24 @@ def _make_block_runner(mv, mcap, shape, dtype, n_reorth, pair=False):
             m = m0 + i
             Vf = V.reshape(nrows, nflat)
             vm = jax.lax.dynamic_index_in_dim(Vf, m, keepdims=False)
-            w = mv(vm.reshape(shape), operands)
-            a = jnp.real(_vdot(vm, w))
-            wf = w.reshape(nflat)
-            for _ in range(n_reorth):
-                wf = mgs_pass(wf, Vf, m)
-            b = jnp.sqrt(jnp.real(_vdot(wf, wf)))
-            vnew = (wf / jnp.where(b <= 1e-300, 1.0, b)).astype(dtype)
-            V = jax.lax.dynamic_update_index_in_dim(
-                Vf, vnew, m + 1, axis=0).reshape(V.shape)
-            alph = alph.at[m].set(a)
-            bet = bet.at[m].set(b)
+            # named scopes: metadata on the operations (``op_name``), so
+            # a device trace tells the phases of an iteration apart
+            with jax.named_scope("lanczos/apply"):
+                w = mv(vm.reshape(shape), operands)
+            with jax.named_scope("lanczos/recurrence"):
+                a = jnp.real(_vdot(vm, w))
+                wf = w.reshape(nflat)
+            with jax.named_scope("lanczos/reorth"):
+                for _ in range(n_reorth):
+                    wf = mgs_pass(wf, Vf, m)
+            with jax.named_scope("lanczos/recurrence"):
+                b = jnp.sqrt(jnp.real(_vdot(wf, wf)))
+                vnew = (wf / jnp.where(b <= 1e-300, 1.0, b)).astype(dtype)
+            with jax.named_scope("lanczos/store"):
+                V = jax.lax.dynamic_update_index_in_dim(
+                    Vf, vnew, m + 1, axis=0).reshape(V.shape)
+                alph = alph.at[m].set(a)
+                bet = bet.at[m].set(b)
             return V, alph, bet
 
         return jax.lax.fori_loop(0, nsteps, body, (V, alph, bet))
@@ -840,24 +848,29 @@ def _make_window_runner(mv, mcap, shape, dtype, n_reorth, nsteps,
 
         def step(W, _i):
             vm = W[W_ROWS - 1]
-            w = mv(vm.reshape(shape), operands)
-            a = jnp.real(_vdot(vm, w))
-            wf = w.reshape(nflat)
-            for _ in range(n_local):
-                wf = project(wf, W)
-                if pair:
-                    wf = project(wf, J_rows(W))
-            b = jnp.sqrt(jnp.real(_vdot(wf, wf)))
-            vnew = (wf / jnp.where(b <= 1e-300, 1.0, b)).astype(dtype)
-            W = jnp.concatenate([W[1:], vnew[None]], axis=0)
+            with jax.named_scope("lanczos/apply"):
+                w = mv(vm.reshape(shape), operands)
+            with jax.named_scope("lanczos/recurrence"):
+                a = jnp.real(_vdot(vm, w))
+                wf = w.reshape(nflat)
+            with jax.named_scope("lanczos/reorth"):
+                for _ in range(n_local):
+                    wf = project(wf, W)
+                    if pair:
+                        wf = project(wf, J_rows(W))
+            with jax.named_scope("lanczos/recurrence"):
+                b = jnp.sqrt(jnp.real(_vdot(wf, wf)))
+                vnew = (wf / jnp.where(b <= 1e-300, 1.0, b)).astype(dtype)
+                W = jnp.concatenate([W[1:], vnew[None]], axis=0)
             return W, (vnew, a, b)
 
         _, (Vnew, a_blk, b_blk) = jax.lax.scan(
             step, W, jnp.arange(nsteps))
-        Vf = jax.lax.dynamic_update_slice(
-            Vf, Vnew, (m0 + 1, jnp.zeros((), m0.dtype)))
-        alph = jax.lax.dynamic_update_slice(alph, a_blk, (m0,))
-        bet = jax.lax.dynamic_update_slice(bet, b_blk, (m0,))
+        with jax.named_scope("lanczos/store"):
+            Vf = jax.lax.dynamic_update_slice(
+                Vf, Vnew, (m0 + 1, jnp.zeros((), m0.dtype)))
+            alph = jax.lax.dynamic_update_slice(alph, a_blk, (m0,))
+            bet = jax.lax.dynamic_update_slice(bet, b_blk, (m0,))
         return Vf.reshape(V.shape), alph, bet
 
     return run_window
@@ -1419,8 +1432,8 @@ def lanczos(matvec: Callable, *args, **kwargs) -> LanczosResult:
     :func:`_lanczos_impl` for the full contract."""
     with obs_trace.span("lanczos", kind="solve",
                         k=int(kwargs.get("k", args[1] if len(args) > 1
-                                          else 1))):
-        return _lanczos_impl(matvec, *args, **kwargs)
+                                          else 1))) as root:
+        return _lanczos_impl(matvec, *args, root=root, **kwargs)
 
 
 def _lanczos_impl(
@@ -1440,6 +1453,8 @@ def _lanczos_impl(
     checkpoint_path: Optional[str] = None,
     checkpoint_every: int = 4,
     reorth: Optional[str] = None,
+    *,
+    root=obs_trace.NULL_SPAN,
 ) -> LanczosResult:
     """Lowest-``k`` eigenpairs of the Hermitian operator behind ``matvec``.
 
@@ -1478,6 +1493,21 @@ def _lanczos_impl(
     restart or resume is always full — the arrowhead coupling row must be
     projected out).  ``"full"`` is the pre-round-9 behavior: full MGS
     sweeps every iteration.
+
+    ``root`` is the solve's open span (:func:`lanczos` passes it).  It
+    takes the solve's counts as they happen, so a preempted solve's event
+    has them too: ``steps_counted`` (iterations this call counted),
+    ``steps_run`` (every step a block program executed, redone blocks
+    included), ``probe_applies``, ``programs_built`` (block programs this
+    call had to trace and compile or load).  Blocks are the ``iteration``
+    spans, redone ones carry ``redo=True``, and restarts are on the result.
+    Under the root the host loop is named where the device can wait for it:
+    ``lanczos/start`` (start vector and probe apply; the Krylov buffer),
+    then per ``iteration`` ``lanczos/dispatch`` (``built`` says whether the
+    block program was new to this call), ``lanczos/wait`` and
+    ``lanczos/check`` (the recurrence's copies to the host, the ω tracker,
+    the Ritz solve, the convergence test, the checkpoint),
+    ``lanczos/restart`` and ``lanczos/epilogue``.
     """
     # Engines expose (apply_fn, operands) so the block runner can pass the
     # matrix tables as jit arguments; plain callables fall back to empty
@@ -1506,30 +1536,38 @@ def _lanczos_impl(
         raise ValueError(
             f"unknown reorth policy {reorth!r} (use selective | full)")
 
-    if v0 is None:
-        if n is None:
-            raise ValueError("pass v0 or n")
-        v0 = _rand_like((n, 2) if pair else (n,), np.float64, seed)
-    elif pair and np.iscomplexobj(v0):
-        # warm starts may arrive in complex form; the recurrence (and the
-        # engine's bound apply_fn) runs on (re, im)-f64 pair vectors
-        from ..ops.kernels import pair_from_complex
-        v0 = pair_from_complex(np.asarray(v0))
-    v = jnp.asarray(v0)
-    shape = v.shape
-    if pair and (len(shape) < 2 or shape[-1] != 2):
-        raise ValueError(
-            f"pair-mode Lanczos needs an [..., 2] (re, im) f64 start vector "
-            f"(or complex v0), got shape {shape}")
+    # every key of the solve's counts is on the root span's event, zeros
+    # included
+    root.add(steps_counted=0, steps_run=0, probe_applies=0,
+             programs_built=0)
+    # lanczos/start: the start vector and the eager probe apply (and below,
+    # once more, the Krylov buffer)
+    with obs_trace.span("lanczos/start", kind="phase"):
+        if v0 is None:
+            if n is None:
+                raise ValueError("pass v0 or n")
+            v0 = _rand_like((n, 2) if pair else (n,), np.float64, seed)
+        elif pair and np.iscomplexobj(v0):
+            # warm starts may arrive in complex form; the recurrence (and the
+            # engine's bound apply_fn) runs on (re, im)-f64 pair vectors
+            from ..ops.kernels import pair_from_complex
+            v0 = pair_from_complex(np.asarray(v0))
+        v = jnp.asarray(v0)
+        shape = v.shape
+        if pair and (len(shape) < 2 or shape[-1] != 2):
+            raise ValueError(
+                f"pair-mode Lanczos needs an [..., 2] (re, im) f64 start "
+                f"vector (or complex v0), got shape {shape}")
 
-    # Probe matvec once eagerly: fixes the recurrence dtype (a complex
-    # Hermitian operator promotes a real start vector) and lets engines run
-    # their first-apply counter checks outside of jit.
-    w_probe = matvec(v)
-    if isinstance(w_probe, tuple):
-        w_probe = w_probe[0]
-    dtype = jnp.promote_types(v.dtype, w_probe.dtype)
-    del w_probe
+        # Probe matvec once eagerly: fixes the recurrence dtype (a complex
+        # Hermitian operator promotes a real start vector) and lets engines run
+        # their first-apply counter checks outside of jit.
+        w_probe = matvec(v)
+        root.add(probe_applies=1)
+        if isinstance(w_probe, tuple):
+            w_probe = w_probe[0]
+        dtype = jnp.promote_types(v.dtype, w_probe.dtype)
+        del w_probe
 
     if (owner is not None and hasattr(owner, "bound_matvec")
             and getattr(matvec, "__func__", None)
@@ -1548,11 +1586,12 @@ def _lanczos_impl(
     l_restart = int(np.clip(l_restart, k, mcap - 2))
     n_reorth = 2 if full_reorth else 1
 
-    V = jnp.zeros((_buffer_rows(mcap),) + shape, dtype)
-    nrm = jnp.sqrt(jnp.real(_vdot(v, v)))
-    V = V.at[0].set((v / nrm.astype(dtype)).astype(dtype))
-    alph_d = jnp.zeros(mcap, jnp.float64)
-    bet_d = jnp.zeros(mcap, jnp.float64)
+    with obs_trace.span("lanczos/start", kind="phase"):
+        V = jnp.zeros((_buffer_rows(mcap),) + shape, dtype)
+        nrm = jnp.sqrt(jnp.real(_vdot(v, v)))
+        V = V.at[0].set((v / nrm.astype(dtype)).astype(dtype))
+        alph_d = jnp.zeros(mcap, jnp.float64)
+        bet_d = jnp.zeros(mcap, jnp.float64)
 
     # Block programs compiled lazily: ONE full-sweep runner (dynamic step
     # count) and, in selective mode, a window runner per distinct block
@@ -1562,19 +1601,26 @@ def _lanczos_impl(
     _runners: dict = {}
 
     def run_steps(full_pass: bool, V, alph, bet, m, nsteps, operands):
-        if full_pass:
-            rb = _runners.get("full")
-            if rb is None:
-                rb = _runners["full"] = _make_block_runner(
-                    mv, mcap, shape, dtype, n_reorth, pair=pair)
-            return rb(V, alph, bet, jnp.int32(m), jnp.int32(nsteps),
-                      operands)
-        key = ("window", int(nsteps))
-        rw = _runners.get(key)
-        if rw is None:
-            rw = _runners[key] = _make_window_runner(
-                mv, mcap, shape, dtype, n_reorth, int(nsteps), pair=pair)
-        return rw(V, alph, bet, jnp.int32(m), operands)
+        key = "full" if full_pass else ("window", int(nsteps))
+        built = key not in _runners
+        # lanczos/dispatch: until the block program's call returns.  A
+        # program new to this call is traced, lowered and compiled or
+        # loaded from the persistent cache inside that call
+        with obs_trace.span("lanczos/dispatch", kind="phase",
+                            built=built, full=bool(full_pass),
+                            steps=int(nsteps)):
+            root.add(steps_run=int(nsteps), programs_built=int(built))
+            if full_pass:
+                if built:
+                    _runners[key] = _make_block_runner(
+                        mv, mcap, shape, dtype, n_reorth, pair=pair)
+                return _runners[key](V, alph, bet, jnp.int32(m),
+                                     jnp.int32(nsteps), operands)
+            if built:
+                _runners[key] = _make_window_runner(
+                    mv, mcap, shape, dtype, n_reorth, int(nsteps),
+                    pair=pair)
+            return _runners[key](V, alph, bet, jnp.int32(m), operands)
 
     restart_fn = _make_restart(mcap, shape, dtype, l_restart)
 
@@ -1737,23 +1783,25 @@ def _lanczos_impl(
         V, alph_d, bet_d = run_steps(True, V, alph_d, bet_d, m, 0,
                                      operands)
 
+    redo = False
     while total_iters < max_iters and not converged:
         if m == mcap:
-            # Thick restart at the TOP of the loop (a resumed checkpoint
-            # may arrive with a full buffer): keep the l lowest Ritz
-            # vectors + the residual vector; the projection becomes
-            # arrowhead + tridiagonal.
-            alph = np.asarray(alph_d)
-            bet = np.asarray(bet_d)
-            T = _projected_matrix(alph, bet, lock_theta, lock_sigma, m)
-            l = l_restart   # clipped to <= mcap-2 at setup; restart_fn
-            theta_all, S_all = eigh(T)   # hard-codes the residual row at l
-            V = restart_fn(V, jnp.asarray(S_all[:, :l]))
-            lock_theta = theta_all[:l].copy()
-            lock_sigma = bet[m - 1] * S_all[m - 1, :l]
-            m = l
-            pending_full = True
-            n_restarts += 1
+            with obs_trace.span("lanczos/restart", kind="phase"):
+                # Thick restart at the TOP of the loop (a resumed checkpoint
+                # may arrive with a full buffer): keep the l lowest Ritz
+                # vectors + the residual vector; the projection becomes
+                # arrowhead + tridiagonal.
+                alph = np.asarray(alph_d)
+                bet = np.asarray(bet_d)
+                T = _projected_matrix(alph, bet, lock_theta, lock_sigma, m)
+                l = l_restart   # clipped to <= mcap-2 at setup; restart_fn
+                theta_all, S_all = eigh(T)   # hard-codes the residual row at l
+                V = restart_fn(V, jnp.asarray(S_all[:, :l]))
+                lock_theta = theta_all[:l].copy()
+                lock_sigma = bet[m - 1] * S_all[m - 1, :l]
+                m = l
+                pending_full = True
+                n_restarts += 1
         nsteps = min(check_every, mcap - m, max_iters - total_iters)
         # tiny remainder stubs (< half a block) reuse the prewarmed
         # dynamic-step full runner: a fresh window program would spend
@@ -1764,132 +1812,140 @@ def _lanczos_impl(
         used_full = (not selective or pending_full
                      or nsteps < max(check_every // 2, 1))
         pending_full = False
-        t0 = _time.perf_counter()
+        if not redo:
+            t0 = _time.perf_counter()
         # iteration span: one convergence-check block of nsteps Lanczos
         # steps (the applies run INSIDE the jitted block program, so the
-        # block is the finest host-visible iteration granule here)
-        with obs_trace.span("iteration", kind="iteration",
-                            solver="lanczos", iter=int(total_iters),
-                            steps=int(nsteps)):
+        # block is the finest host-visible iteration granule here) and the
+        # host's check of it, which runs to the end of the loop body: the
+        # stack closes ``lanczos/check`` and then the iteration.  ``redo``
+        # marks the full-sweep rerun of a window block the ω gate discarded
+        with contextlib.ExitStack() as block:
+            block.enter_context(obs_trace.span(
+                "iteration", kind="iteration", solver="lanczos",
+                iter=int(total_iters), steps=int(nsteps),
+                **({"redo": True} if redo else {})))
             V, alph_d, bet_d = run_steps(
                 used_full, V, alph_d, bet_d, m, nsteps, operands)
-            jax.block_until_ready(V)   # one collective program in flight
-        if selective and not used_full:
-            om_acc = omega_tr.advance(np.asarray(alph_d),
-                                      np.asarray(bet_d), m + nsteps)
-            if om_acc >= obs_health.OMEGA_WARN:   # √ε — Simon's bound
-                # ω crossed √ε inside the window block: semiorthogonality
-                # is no longer guaranteed and cannot be repaired after the
-                # fact — but the block only WROTE rows above m, so the
-                # pre-block state is intact.  Discard it and redo the same
-                # steps with the full sweep (iterations are counted once;
-                # only the wall clock pays).
-                # level "info": a trigger near convergence is the scheme
-                # WORKING (loss grows exactly as Ritz pairs converge),
-                # not a health problem — the zero-warning gate of `make
-                # health-check` must not fail a healthy converged solve
-                obs_emit("solver_health",
-                         check="selective_reorth_fallback", level="info",
-                         solver="lanczos", iter=int(total_iters + nsteps),
-                         omega=float(om_acc))
-                with obs_trace.span("iteration", kind="iteration",
-                                    solver="lanczos",
-                                    iter=int(total_iters),
-                                    steps=int(nsteps), redo=True):
-                    V, alph_d, bet_d = run_steps(
-                        True, V, alph_d, bet_d, m, nsteps, operands)
-                    jax.block_until_ready(V)
-                used_full = True
-        dt = _time.perf_counter() - t0
-        if first_block_iters == 0:
-            first_block_s, first_block_iters = dt, nsteps
-        else:
-            steady_s += dt
-        alph = np.asarray(alph_d)
-        bet = np.asarray(bet_d)
-        m += nsteps
-        total_iters += nsteps
+            with obs_trace.span("lanczos/wait", kind="phase"):
+                jax.block_until_ready(V)   # one collective program in flight
+            block.enter_context(
+                obs_trace.span("lanczos/check", kind="phase"))
+            redo = False
+            if selective and not used_full:
+                om_acc = omega_tr.advance(np.asarray(alph_d),
+                                          np.asarray(bet_d), m + nsteps)
+                if om_acc >= obs_health.OMEGA_WARN:   # √ε — Simon's bound
+                    # ω crossed √ε inside the window block: semiorthogonality
+                    # is no longer guaranteed and cannot be repaired after the
+                    # fact — but the block only WROTE rows above m, so the
+                    # pre-block state is intact.  Discard it and redo the same
+                    # steps with the full sweep on the loop's next pass
+                    # (counted once; only the wall clock pays).
+                    # level "info": a trigger near convergence is the scheme
+                    # WORKING (loss grows exactly as Ritz pairs converge),
+                    # not a health problem — the zero-warning gate of `make
+                    # health-check` must not fail a healthy converged solve
+                    obs_emit("solver_health",
+                             check="selective_reorth_fallback", level="info",
+                             solver="lanczos", iter=int(total_iters + nsteps),
+                             omega=float(om_acc))
+                    pending_full = redo = True
+                    continue
+            dt = _time.perf_counter() - t0
+            if first_block_iters == 0:
+                first_block_s, first_block_iters = dt, nsteps
+            else:
+                steady_s += dt
+            alph = np.asarray(alph_d)
+            bet = np.asarray(bet_d)
+            m += nsteps
+            total_iters += nsteps
+            root.add(steps_counted=int(nsteps))
 
-        # Breakdown: a ~zero β means the Krylov space closed at that step;
-        # discard the garbage steps after it.
-        lo = len(lock_theta)
-        broke = None
-        for i in range(max(lo, m - nsteps), m):
-            if bet[i] < 1e-14:
-                broke = i
+            # Breakdown: a ~zero β means the Krylov space closed at that step;
+            # discard the garbage steps after it.
+            lo = len(lock_theta)
+            broke = None
+            for i in range(max(lo, m - nsteps), m):
+                if bet[i] < 1e-14:
+                    broke = i
+                    break
+            if broke is not None:
+                m = broke + 1
+
+            if selective and used_full:
+                # the full sweep left every new vector orthogonal to the
+                # whole live basis — the ω table restarts at roundoff
+                omega_tr.reset(m)
+
+            kk = min(k, m)
+            T = _projected_matrix(alph, bet, lock_theta, lock_sigma, m)
+            theta, S = eigh(T, subset_by_index=(0, kk - 1))
+            res = np.abs(bet[m - 1] * S[m - 1, :])
+            omega = obs_health.omega_estimate(
+                alph, bet, max(lo, m - nsteps), m) \
+                if obs_health.probes_enabled() else None
+            _emit_trace("lanczos", total_iters, m, theta, res, omega)
+            if m >= k and np.all(res < tol * np.maximum(1.0, np.abs(theta))):
+                converged = True
                 break
-        if broke is not None:
-            m = broke + 1
+            watchdog.report_omega(omega, total_iters)
+            if broke is not None:
+                # Krylov space closed without meeting the tolerance
+                watchdog.breakdown(total_iters, float(bet[broke]),
+                                   converged=False)
+                break
+            watchdog.check_stagnation(res, total_iters)
 
-        if selective and used_full:
-            # the full sweep left every new vector orthogonal to the
-            # whole live basis — the ω table restarts at roundoff
-            omega_tr.reset(m)
-
-        kk = min(k, m)
-        T = _projected_matrix(alph, bet, lock_theta, lock_sigma, m)
-        theta, S = eigh(T, subset_by_index=(0, kk - 1))
-        res = np.abs(bet[m - 1] * S[m - 1, :])
-        omega = obs_health.omega_estimate(alph, bet, max(lo, m - nsteps), m) \
-            if obs_health.probes_enabled() else None
-        _emit_trace("lanczos", total_iters, m, theta, res, omega)
-        if m >= k and np.all(res < tol * np.maximum(1.0, np.abs(theta))):
-            converged = True
-            break
-        watchdog.report_omega(omega, total_iters)
-        if broke is not None:
-            # Krylov space closed without meeting the tolerance
-            watchdog.breakdown(total_iters, float(bet[broke]),
-                               converged=False)
-            break
-        watchdog.check_stagnation(res, total_iters)
-
-        blocks_done += 1
-        # chaos site at the block boundary: `delay=` stretches a solve so
-        # the chaos gate can land a kill mid-iteration deterministically;
-        # inert (shared no-op) when DMT_FAULT is unset
-        faults.check("solver_block", exc=RuntimeError, solver="lanczos",
-                     iter=int(total_iters))
-        # safe point: the recurrence state is host-consistent and no
-        # collective is in flight — the latch verdict is agreed across
-        # ranks so every rank checkpoints the SAME generation and exits
-        # together (DESIGN.md §21).  ckpt_meta (four D2H fetches) is built
-        # only when a save actually happens — the plain hot loop pays
-        # nothing here.
-        cadence_due = bool(checkpoint_path) \
-            and blocks_done % max(checkpoint_every, 1) == 0
-        preempted = preempt.agreed(agree_multi)
-        if cadence_due or (preempted and checkpoint_path):
-            _soft_save_ckpt(
-                checkpoint_path, ckpt_fp, owner, V, {
-                    "alph": np.asarray(alph_d), "bet": np.asarray(bet_d),
-                    "lock_theta": np.asarray(lock_theta),
-                    "lock_sigma": np.asarray(lock_sigma),
-                    "m": int(m), "total_iters": int(total_iters)},
-                m, sharded_ckpt,
-                reason="cadence" if cadence_due else "preempt")
-        if preempted:
-            obs_emit("solver_preempted", solver="lanczos",
-                     iters=int(total_iters),
-                     checkpoint=checkpoint_path or "")
-            obs_flush()
-            mem_h.release()
-            raise preempt.Preempted("lanczos", total_iters,
-                                    checkpoint_path)
+            blocks_done += 1
+            # chaos site at the block boundary: `delay=` stretches a solve so
+            # the chaos gate can land a kill mid-iteration deterministically;
+            # inert (shared no-op) when DMT_FAULT is unset
+            faults.check("solver_block", exc=RuntimeError, solver="lanczos",
+                         iter=int(total_iters))
+            # safe point: the recurrence state is host-consistent and no
+            # collective is in flight — the latch verdict is agreed across
+            # ranks so every rank checkpoints the SAME generation and exits
+            # together (DESIGN.md §21).  ckpt_meta (four D2H fetches) is built
+            # only when a save actually happens — the plain hot loop pays
+            # nothing here.
+            cadence_due = bool(checkpoint_path) \
+                and blocks_done % max(checkpoint_every, 1) == 0
+            preempted = preempt.agreed(agree_multi)
+            if cadence_due or (preempted and checkpoint_path):
+                _soft_save_ckpt(
+                    checkpoint_path, ckpt_fp, owner, V, {
+                        "alph": np.asarray(alph_d), "bet": np.asarray(bet_d),
+                        "lock_theta": np.asarray(lock_theta),
+                        "lock_sigma": np.asarray(lock_sigma),
+                        "m": int(m), "total_iters": int(total_iters)},
+                    m, sharded_ckpt,
+                    reason="cadence" if cadence_due else "preempt")
+            if preempted:
+                obs_emit("solver_preempted", solver="lanczos",
+                         iters=int(total_iters),
+                         checkpoint=checkpoint_path or "")
+                obs_flush()
+                mem_h.release()
+                raise preempt.Preempted("lanczos", total_iters,
+                                        checkpoint_path)
 
     kk = min(k, m)
     evecs = None
     if compute_eigenvectors and m:
-        Vf = V.reshape(_buffer_rows(mcap), -1)
-        Sj = jnp.asarray(S[:, :kk].astype(
-            np.complex128 if np.issubdtype(np.dtype(dtype), np.complexfloating)
-            else np.float64), dtype=dtype)
-        E = _combine_rows(Sj, Vf)              # the first m rows of Vf
-        evecs = []
-        for i in range(kk):
-            e = E[i]
-            enrm = jnp.sqrt(jnp.real(_vdot(e, e)))
-            evecs.append((e / enrm.astype(dtype)).reshape(shape))
+        with obs_trace.span("lanczos/epilogue", kind="phase"):
+            Vf = V.reshape(_buffer_rows(mcap), -1)
+            Sj = jnp.asarray(S[:, :kk].astype(
+                np.complex128
+                if np.issubdtype(np.dtype(dtype), np.complexfloating)
+                else np.float64), dtype=dtype)
+            E = _combine_rows(Sj, Vf)              # the first m rows of Vf
+            evecs = []
+            for i in range(kk):
+                e = E[i]
+                enrm = jnp.sqrt(jnp.real(_vdot(e, e)))
+                evecs.append((e / enrm.astype(dtype)).reshape(shape))
     obs_emit("solver_end", solver="lanczos", iters=int(total_iters),
              converged=bool(converged),
              eigenvalues=[float(t) for t in np.atleast_1d(theta)[:kk]]

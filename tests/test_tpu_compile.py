@@ -21,7 +21,9 @@ device executable can be written to it but not read back), and all such
 tests live in this one file so one xdist worker holds the TPU library.
 """
 
+import contextlib
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -115,15 +117,111 @@ def _local_ell_engine(sh, pair):
     return eng, S((N,) + ctail, jnp.float64)
 
 
+def _distributed_ell_engine(topo):
+    """A DistributedEngine shell carrying chain_32_symm's hash-sharded ELL
+    shapes on a 4-device mesh built from the described devices, and the
+    ``S(shape, dtype)`` that places an array on it."""
+    from distributed_matvec_tpu.parallel.distributed import DistributedEngine
+    from distributed_matvec_tpu.parallel.mesh import SHARD_AXIS
+
+    D = 4
+    mesh = Mesh(np.array(topo.devices[:D]), (SHARD_AXIS,))
+
+    def S(shape, dtype):
+        spec = P(SHARD_AXIS, *([None] * (len(shape) - 1)))
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, spec))
+
+    M = -(-N // D // 1024) * 1024 + 4096    # shard rows incl. hash imbalance
+    C = M // 2                              # per-peer query capacity
+    s_tail = S_TAIL // D + 1024
+    eng = object.__new__(DistributedEngine)
+    eng.n_devices, eng.query_capacity, eng.mesh = D, C, mesh
+    eng.pair, eng._dtype, eng._ell_T0 = False, jnp.float64, T0
+    eng._qin = S((D, D, C), jnp.int32)
+    eng._ell_idx = S((D, T0, M), jnp.int32)
+    eng._ell_coeff = S((D, T0, M), jnp.float64)
+    eng._diag = S((D, M), jnp.float64)
+    eng._ell_tail = (S((D, s_tail), jnp.int32),
+                     S((D, T_TAIL, s_tail), jnp.int32),
+                     S((D, T_TAIL, s_tail), jnp.float64))
+    eng._make_ell_matvec()
+    return eng, S((D, M), jnp.float64), mesh
+
+
+def _compile(name, topo):
+    """One program of the main path, compiled for the described chip(s):
+    ``ell_apply``, ``window`` and ``full`` on one chip, ``distributed_apply``
+    and ``distributed_window`` on the 4-device mesh."""
+    from distributed_matvec_tpu.solve.lanczos import (
+        _buffer_rows, _make_block_runner, _make_window_runner)
+
+    if name.startswith("distributed"):
+        from distributed_matvec_tpu.parallel.mesh import SHARD_AXIS
+
+        eng, x, mesh = _distributed_ell_engine(topo)
+        if name == "distributed_apply":
+            return jax.jit(eng._apply_fn).lower(x, eng._operands).compile()
+
+        # the Lanczos window over hashed [D, M] vectors that
+        # `apps/diagonalize.py --devices 4` runs, Krylov buffer sharded
+        # with them
+        def mv(v, ops):
+            return eng._apply_fn(v, ops)[0].astype(jnp.float64)
+
+        rep = NamedSharding(mesh, P())
+        V = jax.ShapeDtypeStruct(
+            (_buffer_rows(M_CAP),) + x.shape, jnp.float64,
+            sharding=NamedSharding(mesh, P(None, SHARD_AXIS, None)))
+        ab = jax.ShapeDtypeStruct((M_CAP,), jnp.float64, sharding=rep)
+        m0 = jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)
+        fn = _make_window_runner(mv, M_CAP, x.shape, jnp.float64, 2, 16)
+        return fn.lower(V, ab, ab, m0, eng._operands).compile()
+
+    sh = SingleDeviceSharding(topo.devices[0])
+    eng, x = _local_ell_engine(sh, pair=False)
+    apply_fn, operands = eng.bound_matvec()
+    if name == "ell_apply":
+        return jax.jit(apply_fn).lower(x, operands).compile()
+
+    def mv(v, ops):
+        return apply_fn(v, ops)[0].astype(jnp.float64)
+
+    S = _shapes(sh)
+    V = S((_buffer_rows(M_CAP), N))
+    ab, i32 = S((M_CAP,)), S((), jnp.int32)
+    if name == "window":
+        fn = _make_window_runner(mv, M_CAP, (N,), jnp.float64, 2, 16)
+        return fn.lower(V, ab, ab, i32, operands).compile()
+    assert name == "full", name
+    fn = _make_block_runner(mv, M_CAP, (N,), jnp.float64, 2)
+    return fn.lower(V, ab, ab, i32, i32, operands).compile()
+
+
+@pytest.fixture(scope="module")
+def compiled(topo):
+    """``compiled(name)``: :func:`_compile`, once a module — the memory
+    tests and the scope tests read the same executables."""
+    done = {}
+
+    def get(name):
+        if name not in done:
+            done[name] = _compile(name, topo)
+        return done[name]
+    return get
+
+
 @pytest.mark.parametrize("pair", [False, True],
                          ids=["real_sector", "re_im_pair_momentum_sector"])
-def test_ell_apply_compiles(one_chip, tpu_knobs, pair):
+def test_ell_apply_compiles(one_chip, tpu_knobs, compiled, pair):
     """The LocalEngine ELL apply with triple-f32 split gathers — and its
     (re, im)-pair form, which complex momentum sectors take on a TPU."""
+    if not pair:
+        _fits(compiled("ell_apply"), "ell apply")
+        return
     eng, x = _local_ell_engine(one_chip, pair)
     apply_fn, operands = eng.bound_matvec()
-    compiled = jax.jit(apply_fn).lower(x, operands).compile()
-    _fits(compiled, "ell apply")
+    _fits(jax.jit(apply_fn).lower(x, operands).compile(), "ell apply")
 
 
 def test_structure_build_chunk_compiles(one_chip):
@@ -155,93 +253,80 @@ def test_structure_build_chunk_compiles(one_chip):
 
 @pytest.mark.parametrize("program",
                          ["window", "full", "restart", "ritz_vectors"])
-def test_lanczos_programs_compile(one_chip, tpu_knobs, program):
+def test_lanczos_programs_compile(one_chip, tpu_knobs, compiled, program):
     """The Lanczos programs at n = 4.7M with the 96-row Krylov buffer: the
     16-step selective window and the full-sweep block (ELL apply traced in,
     buffer donated), the thick restart, and the Ritz-vector assembly.  The
     last two were ``tensordot``s that XLA:TPU expanded into an
     ``f32[8, 96, n]`` temporary — 23.85 GB, refused on the chip (PR 22)."""
     from distributed_matvec_tpu.solve.lanczos import (
-        _buffer_rows, _combine_rows, _make_block_runner, _make_restart,
-        _make_window_runner)
-
-    eng, x = _local_ell_engine(one_chip, pair=False)
-    apply_fn, operands = eng.bound_matvec()
-
-    def mv(v, ops):
-        return apply_fn(v, ops)[0].astype(jnp.float64)
+        _buffer_rows, _combine_rows, _make_restart)
 
     S = _shapes(one_chip)
     V = S((_buffer_rows(M_CAP), N))
-    ab, i32 = S((M_CAP,)), S((), jnp.int32)
-    if program == "window":
-        fn = _make_window_runner(mv, M_CAP, (N,), jnp.float64, 2, 16)
-        compiled = fn.lower(V, ab, ab, i32, operands).compile()
-    elif program == "full":
-        fn = _make_block_runner(mv, M_CAP, (N,), jnp.float64, 2)
-        compiled = fn.lower(V, ab, ab, i32, i32, operands).compile()
+    if program in ("window", "full"):
+        exe = compiled(program)
     elif program == "restart":
         fn = _make_restart(M_CAP, (N,), jnp.float64, 24)
-        compiled = fn.lower(V, S((M_CAP, 24))).compile()
+        exe = fn.lower(V, S((M_CAP, 24))).compile()
     else:
-        compiled = _combine_rows.lower(
-            S((M_CAP, 1)), S((_buffer_rows(M_CAP), N))).compile()
+        exe = _combine_rows.lower(S((M_CAP, 1)), V).compile()
     # beside the program: the ELL tables it does not take as arguments
-    assert _fits(compiled, f"lanczos {program}") + 1.2e9 < HBM_BYTES
+    assert _fits(exe, f"lanczos {program}") + 1.2e9 < HBM_BYTES
 
 
-def test_distributed_ell_apply_compiles_on_four_devices(topo, tpu_knobs):
+def test_distributed_ell_apply_compiles_on_four_devices(tpu_knobs, compiled):
     """The hash-sharded ELL apply on a 4-device mesh built from the
     described devices: the emulated-f64 ``all_to_all`` under ``shard_map``
-    must partition, and each device's share must fit its HBM."""
-    from distributed_matvec_tpu.parallel.distributed import DistributedEngine
-    from distributed_matvec_tpu.parallel.mesh import SHARD_AXIS
-
-    D = 4
-    mesh = Mesh(np.array(topo.devices[:D]), (SHARD_AXIS,))
-
-    def S(shape, dtype):
-        spec = P(SHARD_AXIS, *([None] * (len(shape) - 1)))
-        return jax.ShapeDtypeStruct(shape, dtype,
-                                    sharding=NamedSharding(mesh, spec))
-
-    M = -(-N // D // 1024) * 1024 + 4096    # shard rows incl. hash imbalance
-    C = M // 2                              # per-peer query capacity
-    s_tail = S_TAIL // D + 1024
-    eng = object.__new__(DistributedEngine)
-    eng.n_devices, eng.query_capacity, eng.mesh = D, C, mesh
-    eng.pair, eng._dtype, eng._ell_T0 = False, jnp.float64, T0
-    eng._qin = S((D, D, C), jnp.int32)
-    eng._ell_idx = S((D, T0, M), jnp.int32)
-    eng._ell_coeff = S((D, T0, M), jnp.float64)
-    eng._diag = S((D, M), jnp.float64)
-    eng._ell_tail = (S((D, s_tail), jnp.int32),
-                     S((D, T_TAIL, s_tail), jnp.int32),
-                     S((D, T_TAIL, s_tail), jnp.float64))
-    eng._make_ell_matvec()
-    compiled = jax.jit(eng._apply_fn).lower(
-        S((D, M), jnp.float64), eng._operands).compile()
-    assert "all-to-all" in compiled.as_text()
+    must partition, and each device's share must fit its HBM — and the
+    Lanczos window over the hashed vectors."""
+    exe = compiled("distributed_apply")
+    assert "all-to-all" in exe.as_text()
     # memory_analysis() of a partitioned program is per device
-    _fits(compiled, "distributed ell apply (per device)")
-
-    # ... and the Lanczos window over hashed [D, M] vectors that
-    # `apps/diagonalize.py --devices 4` runs, Krylov buffer sharded with them
-    from distributed_matvec_tpu.solve.lanczos import (_buffer_rows,
-                                                      _make_window_runner)
-
-    def mv(v, ops):
-        return eng._apply_fn(v, ops)[0].astype(jnp.float64)
-
-    rep = NamedSharding(mesh, P())
-    V = jax.ShapeDtypeStruct(
-        (_buffer_rows(M_CAP), D, M), jnp.float64,
-        sharding=NamedSharding(mesh, P(None, SHARD_AXIS, None)))
-    ab = jax.ShapeDtypeStruct((M_CAP,), jnp.float64, sharding=rep)
-    m0 = jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)
-    fn = _make_window_runner(mv, M_CAP, (D, M), jnp.float64, 2, 16)
-    _fits(fn.lower(V, ab, ab, m0, eng._operands).compile(),
+    _fits(exe, "distributed ell apply (per device)")
+    _fits(compiled("distributed_window"),
           "distributed lanczos window (per device)")
+
+
+# what ``jax.named_scope`` leaves in the optimised HLO: every instruction's
+# ``metadata={op_name="jit(f)/.../<scope>/<primitive>"}``.  A device trace
+# carries that string per operation (stat ``tf_op``), so the scopes are how
+# a trace tells the phases of an apply and of an iteration apart.
+APPLY_SCOPES = ["apply/split", "apply/diag", "apply/terms", "apply/tail"]
+EXCHANGE_SCOPES = ["apply/pack", "apply/exchange"]
+LANCZOS_SCOPES = ["lanczos/apply", "lanczos/reorth", "lanczos/recurrence",
+                  "lanczos/store"]
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s.*?[\w\-]+\(",
+                          re.M)
+
+
+@pytest.mark.parametrize("program, scopes", [
+    ("ell_apply", APPLY_SCOPES),
+    ("distributed_apply", APPLY_SCOPES + EXCHANGE_SCOPES),
+    ("window", LANCZOS_SCOPES + APPLY_SCOPES),
+    ("full", LANCZOS_SCOPES + APPLY_SCOPES),
+], ids=["ell_apply", "distributed_apply", "window", "full"])
+def test_named_scopes_reach_the_tpu_hlo(topo, tpu_knobs, compiled,
+                                        monkeypatch, program, scopes):
+    """The TPU-optimised HLO of the programs a cell runs carries every
+    named scope in some instruction's ``op_name``, and the scopes are
+    metadata only: compiled with ``jax.named_scope`` switched off, each
+    program has the same number of instructions."""
+    text = compiled(program).as_text()
+    op_names = re.findall(r'op_name="([^"]*)"', text)
+    for scope in scopes:
+        assert any(f"/{scope}/" in n for n in op_names), scope
+    if program == "distributed_apply":
+        # the gather that fills the send buffer is the pack, by name
+        assert any(" gather(" in line and "/apply/pack/" in line
+                   for line in text.splitlines())
+        assert any(" all-to-all(" in line and "/apply/exchange/" in line
+                   for line in text.splitlines())
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = _compile(program, topo).as_text()
+    assert "apply/" not in bare and "lanczos/" not in bare
+    assert len(_INSTRUCTION.findall(bare)) == len(_INSTRUCTION.findall(text))
 
 
 def test_complex128_is_refused_not_hung(one_chip):

@@ -176,9 +176,10 @@ def test_solver_spans_root_and_nest(clean_trace, rng):
 
     op = build_heisenberg(10)
     eng = LocalEngine(op, mode="ell")
+    built = len(obs.events("span"))     # the engine build's own spans
     lanczos(eng.matvec, op.basis.number_states, k=1, tol=1e-8,
             max_iters=48)
-    spans = obs.events("span")
+    spans = obs.events("span")[built:]
     solves = [e for e in spans if e["cat"] == "solve"]
     iters = [e for e in spans if e["cat"] == "iteration"]
     assert len(solves) == 1 and solves[0]["name"] == "lanczos"
@@ -545,7 +546,8 @@ def test_multihost_trace_two_ranks(tmp_path):
         assert len(solves) == 1
         kinds = {e["cat"] for e in spans}
         assert {"solve", "iteration", "apply", "chunk"} <= kinds
-        # acyclic, rooted at the solve span
+        # acyclic, rooted at the solve span; the engine build's spans
+        # closed before the solve opened and root at themselves
         for e in spans:
             seen = set()
             cur = e
@@ -553,7 +555,9 @@ def test_multihost_trace_two_ranks(tmp_path):
                 assert cur["span_id"] not in seen
                 seen.add(cur["span_id"])
                 cur = by_id[cur["parent_span_id"]]
-            assert cur["span_id"] == solves[0]["span_id"]
+            assert cur["span_id"] == solves[0]["span_id"] or (
+                cur["cat"] in ("build", "phase")
+                and cur["ts"] <= solves[0]["t0"])
         # every event of a traced run carries trace_id; in-span events
         # carry span_id pointing at a recorded span
         for e in events:
