@@ -1,12 +1,13 @@
 """Exact triple-float32 split gathers for 64-bit values on TPU.
 
 TPU has no native 64-bit types: XLA emulates f64/c128 as 32-bit pairs, and
-an emulated-f64 gather issues *two* index-rate-bound gathers (measured on
-v5e: 42 M elem/s for f64 vs 110 M for f32 — gathers pay per index, not per
-byte).  Splitting ``x`` into three f32 parts ``x = a + b + c`` (24-bit
-mantissa each, 72 ≥ 53 bits total) turns every table gather into ONE gather
-of a ``[..., 3]`` f32 row at the f32 index rate — measured 3.6× faster
-(147 M elem/s) and **bit-exact**:
+an emulated-f64 gather issues *two* index-rate-bound gathers — gathers pay
+per index, not per byte.  Splitting ``x`` into three f32 parts
+``x = a + b + c`` (24-bit mantissa each, 72 ≥ 53 bits total) turns every
+table gather into ONE gather of a ``[..., 3]`` f32 row at the f32 index
+rate — 165 M rows/s (6.05 ns a slot) over chain_32_symm's 4.7 M-row table
+on the attached v5e (PERF.md §5, ledger PR 26; an earlier round read 42 M
+elem/s for the f64 gather it replaced) — and **bit-exact**:
 
 * ``a = f32(x)``, ``b = f32(x − a)``, ``c = f32(x − a − b)`` — consecutive
   roundings, so ``b ≲ ulp32(a)``, ``c ≲ ulp32(b)``.

@@ -15,8 +15,8 @@ which XLA lowers to plain gathers + a row reduction — no scatter, no atomics.
 Two execution modes (``mode=``):
 
 * ``"ell"`` (default): one pass of the device kernels *precomputes* the static
-  sparse structure — int32 column indices and f64/c128 coefficients in ELL
-  layout ``[N_pad, T]`` — after which every matvec is a pure
+  sparse structure — int32 column indices and f64/c128 coefficients in
+  staircase ELL levels (``_stair_ell``) — after which every matvec is a pure
   gather·multiply·row-reduce with **no u64 bit manipulation at all**.  This is
   the right trade for iterative eigensolvers (the reference re-runs its
   kernels every PRIMME iteration because it cannot afford the memory; on TPU
@@ -180,63 +180,106 @@ def _dead_mask(cf, is_pair: bool):
 # (program, statics, shapes) through :func:`precompile`.
 
 
-def _ell_fill_chunk(idx_buf, coeff_buf, bad, tables, pair, dir_tab, alphas,
-                    norms_a, start, *, shift, probes, is_pair):
-    """One-pass ELL build step: chunk kernels → transposed table update.
+def _left_pack(idx_t, cf_t, is_pair: bool):
+    """Move each row's live entries to the low slots of a [T, b(, 2)] slab,
+    in their original order (stable); dead slots stay (index 0, coeff 0)."""
+    order = jnp.argsort(_dead_mask(cf_t, is_pair), axis=0, stable=True)
+    return (jnp.take_along_axis(idx_t, order, axis=0),
+            jnp.take_along_axis(
+                cf_t, order[..., None] if is_pair else order, axis=0))
 
-    Transposed [T, N_pad(, 2)] layout: the matvec walks terms outermost, so
-    per-term rows are contiguous (measured ~2× over [N_pad, T] + axis-1
-    reduce on v5e)."""
+
+def _packed_chunk(tables, pair, dir_tab, alphas, norms_a, *, shift, probes,
+                  is_pair):
+    """Chunk kernels → left-packed transposed slab ``(idx [T, b] i32,
+    coeff [T, b(, 2)], invalid)``.
+
+    Transposed layout: the matvec walks terms outermost, so per-term rows
+    are contiguous (measured ~2× over [N_pad, T] + axis-1 reduce on v5e)."""
     idx, cf, invalid = _chunk_structure_ops(tables, pair, dir_tab, alphas,
                                             norms_a, shift, probes)
+    idx_p, cf_p = _left_pack(idx.T.astype(jnp.int32), jnp.moveaxis(cf, 0, 1),
+                             is_pair)
+    return idx_p, cf_p, invalid
+
+
+def _ell_fill_chunk(idx_buf, coeff_buf, nnz_buf, bad, tables, pair, dir_tab,
+                    alphas, norms_a, start, *, shift, probes, is_pair):
+    """One-pass ELL build step: chunk kernels → left-packed slab → update of
+    the donated full-width [T, N_pad(, 2)] tables and row-nnz vector."""
+    idx_p, cf_p, invalid = _packed_chunk(
+        tables, pair, dir_tab, alphas, norms_a, shift=shift, probes=probes,
+        is_pair=is_pair)
     zero = jnp.zeros((), start.dtype)
     starts2 = (zero, start)
-    idx_buf = jax.lax.dynamic_update_slice(
-        idx_buf, idx.T.astype(jnp.int32), starts2)
+    idx_buf = jax.lax.dynamic_update_slice(idx_buf, idx_p, starts2)
     coeff_buf = jax.lax.dynamic_update_slice(
-        coeff_buf, jnp.moveaxis(cf, 0, 1),
-        starts2 + ((zero,) if is_pair else ()))
-    return idx_buf, coeff_buf, bad + invalid
+        coeff_buf, cf_p, starts2 + ((zero,) if is_pair else ()))
+    nnz = (~_dead_mask(cf_p, is_pair)).sum(axis=0, dtype=nnz_buf.dtype)
+    nnz_buf = jax.lax.dynamic_update_slice(nnz_buf, nnz, (start,))
+    return idx_buf, coeff_buf, nnz_buf, bad + invalid
 
 
-def _split_count(cf_buf, *, T, is_pair):
-    """Row-nnz vector + histogram of a full-width [T, N_pad(, 2)] table."""
-    nnz = (~_dead_mask(cf_buf, is_pair)).sum(axis=0)
-    hist = jnp.zeros(T + 1, jnp.int64).at[nnz].add(1)
-    return nnz, hist
+def _nnz_hist(nnz, *, T):
+    """Histogram of a row-nnz vector over 0..T."""
+    return jnp.zeros(T + 1, jnp.int64).at[nnz].add(1)
 
 
-def _split_pack_chunk(out_idx, out_cf, idx_b, cf_b, start, *, T, T0, b,
-                      is_pair):
-    """Left-pack one chunk's nonzeros into the width-T0 main table."""
-    zero = jnp.zeros((), start.dtype)
-    pstart = ((zero,) if is_pair else ())
-    psize = ((2,) if is_pair else ())
-    idx_c = jax.lax.dynamic_slice(idx_b, (zero, start), (T, b))
-    cf_c = jax.lax.dynamic_slice(
-        cf_b, (zero, start) + pstart, (T, b) + psize)
-    order = jnp.argsort(_dead_mask(cf_c, is_pair), axis=0, stable=True)[:T0]
-    out_idx = jax.lax.dynamic_update_slice(
-        out_idx, jnp.take_along_axis(idx_c, order, axis=0), (zero, start))
-    cf_o = jnp.take_along_axis(
-        cf_c, order[..., None] if is_pair else order, axis=0)
-    out_cf = jax.lax.dynamic_update_slice(
-        out_cf, cf_o, (zero, start) + pstart)
-    return out_idx, out_cf
+#: Column lengths of the staircase are rounded up to this many rows (the
+#: tile of an s32 index vector on the TPU).
+INDEX_TILE = 1024
 
 
-def _split_build_tail(idx_b, cf_b, nnz, *, T0, Tmax, S, is_pair):
-    """The S wide rows' packed slots T0..Tmax.  The stable argsort is
-    deterministic per column, so recomputing it on the gathered columns
-    partitions exactly where the main pack left off."""
-    rows = jnp.nonzero(nnz > T0, size=S, fill_value=0)[0]
-    rows = rows.astype(jnp.int32)
-    idx_r, cf_r = idx_b[:, rows], cf_b[:, rows]
-    order = jnp.argsort(_dead_mask(cf_r, is_pair), axis=0,
-                        stable=True)[T0:Tmax]
-    return (rows, jnp.take_along_axis(idx_r, order, axis=0),
-            jnp.take_along_axis(
-                cf_r, order[..., None] if is_pair else order, axis=0))
+def staircase_levels(hist: np.ndarray, n_rows: int):
+    """Read the ELL form off a row-nnz histogram.
+
+    With rows left-packed and ordered by non-zero count (descending),
+    column ``t`` is live only in its first ``rows_gt[t]`` positions (rows
+    with more than ``t`` entries); ``L_t`` is that count rounded up to
+    :data:`INDEX_TILE`.  Returns ``(stair, levels)``: ``levels`` is a tuple
+    of ``(t0, k, L)`` — ``k`` consecutive columns from ``t0`` of equal
+    length ``L``, longest first.  The staircase costs ``sum(L_t)`` table
+    slots plus ``n_rows`` for the gather that puts the result back in
+    basis order; where that is not below the ``n_rows * Tmax`` of the plain
+    table (rows of near-equal width), ``stair`` is False and the one level
+    is the full table in basis order, ``(0, Tmax, n_rows)``.
+    """
+    hist = np.asarray(hist, np.int64)
+    live = np.nonzero(hist[1:])[0]
+    Tmax = int(live.max()) + 1 if live.size else 0
+    rows_gt = hist[::-1].cumsum()[::-1][1:Tmax + 1]
+    lengths = -(-rows_gt // INDEX_TILE) * INDEX_TILE
+    if int(lengths.sum()) + n_rows >= n_rows * Tmax:
+        return False, ((0, Tmax, int(n_rows)),)
+    starts = np.flatnonzero(np.diff(lengths, prepend=-1))
+    widths = np.diff(np.append(starts, Tmax))
+    return True, tuple((int(t0), int(k), int(lengths[t0]))
+                       for t0, k in zip(starts, widths))
+
+
+def _stair_order(nnz):
+    """``(row_of, pos_of)``: the padded rows by non-zero count, descending
+    and stable (``row_of[r]`` is the row at packed position ``r``), and the
+    inverse."""
+    n_pad = nnz.shape[0]
+    row_of = jnp.argsort(-nnz, stable=True).astype(jnp.int32)
+    pos_of = jnp.zeros(n_pad, jnp.int32).at[row_of].set(
+        jnp.arange(n_pad, dtype=jnp.int32))
+    return row_of, pos_of
+
+
+def _stair_level(tab, row_of, *, t0, k, L):
+    """One staircase level of a left-packed full-width table (indices, or
+    coefficients): columns ``t0..t0+k`` at rows ``row_of[:L]``.  Positions
+    past a column's live rows hold rows whose slot is dead already (index 0,
+    coeff 0)."""
+    n_pad = row_of.shape[0]
+    rows = jnp.pad(row_of, (0, max(L - n_pad, 0)))[:L]
+    out = tab[t0:t0 + k][:, rows]
+    if L > n_pad:           # a basis shorter than one tile: blank the fill
+        real = (jnp.arange(L) < n_pad).reshape((1, L) + (1,) * (tab.ndim - 2))
+        out = jnp.where(real, out, 0)
+    return out
 
 
 def _count_chunk_nnz(tables, pair, dir_tab, alphas, norms_a, *, shift,
@@ -248,36 +291,30 @@ def _count_chunk_nnz(tables, pair, dir_tab, alphas, norms_a, *, shift,
     return live.sum(axis=1), invalid
 
 
-def _lowmem_pack_chunk(out_idx, out_cf, t_rows, t_idx, t_cf, tables, pair,
-                       dir_tab, alphas, norms_a, start, toff, *, shift,
-                       probes, is_pair, T0, Tmax, Ct):
-    """Two-pass ELL build step: re-run the kernels for one chunk and pack
-    its nonzeros straight into the donated final buffers + tail slab."""
-    idx, cf, _ = _chunk_structure_ops(tables, pair, dir_tab, alphas,
-                                      norms_a, shift, probes)
-    idx_t = idx.T.astype(jnp.int32)           # [T, b]
-    cf_t = jnp.moveaxis(cf, 0, 1)             # [T, b(, 2)]
-    dm = _dead_mask(cf_t, is_pair)
-    order = jnp.argsort(dm, axis=0, stable=True)
-    idx_p = jnp.take_along_axis(idx_t, order, axis=0)
-    cf_p = jnp.take_along_axis(
-        cf_t, order[..., None] if is_pair else order, axis=0)
+def _lowmem_pack_chunk(bufs, tables, pair, dir_tab, alphas, norms_a, start,
+                       *, shift, probes, is_pair, levels):
+    """Two-pass ELL build step: re-run the kernels for ``b`` rows taken in
+    packed order and write each level's columns straight into its donated
+    buffer.  A buffer is a whole number of chunks long, so a chunk that
+    starts inside a level lands unclamped; one that starts past it is
+    written back unchanged."""
+    idx_p, cf_p, _ = _packed_chunk(
+        tables, pair, dir_tab, alphas, norms_a, shift=shift, probes=probes,
+        is_pair=is_pair)
     zero = jnp.zeros((), start.dtype)
-    out_idx = jax.lax.dynamic_update_slice(
-        out_idx, idx_p[:T0], (zero, start))
-    out_cf = jax.lax.dynamic_update_slice(
-        out_cf, cf_p[:T0], (zero, start) + ((zero,) if is_pair else ()))
-    if Ct:
-        nnzc = (~dm).sum(axis=0)              # [b]
-        tr = jnp.nonzero(nnzc > T0, size=Ct, fill_value=0)[0]
-        tr = tr.astype(jnp.int32)
-        t_rows = jax.lax.dynamic_update_slice(t_rows, tr + start, (toff,))
-        t_idx = jax.lax.dynamic_update_slice(
-            t_idx, idx_p[T0:Tmax][:, tr], (zero, toff))
-        t_cf = jax.lax.dynamic_update_slice(
-            t_cf, cf_p[T0:Tmax][:, tr],
-            (zero, toff) + ((zero,) if is_pair else ()))
-    return out_idx, out_cf, t_rows, t_idx, t_cf
+    pz = (zero,) if is_pair else ()
+    out = []
+    for (t0, k, L), (idx_l, cf_l) in zip(levels, bufs):
+        inside = start < L
+        new_i, new_c = idx_p[t0:t0 + k], cf_p[t0:t0 + k]
+        old_i = jax.lax.dynamic_slice(idx_l, (zero, start), new_i.shape)
+        old_c = jax.lax.dynamic_slice(cf_l, (zero, start) + pz, new_c.shape)
+        out.append((
+            jax.lax.dynamic_update_slice(
+                idx_l, jnp.where(inside, new_i, old_i), (zero, start)),
+            jax.lax.dynamic_update_slice(
+                cf_l, jnp.where(inside, new_c, old_c), (zero, start) + pz)))
+    return tuple(out)
 
 
 def _compact_pack_chunk(out_idx, t_rows, t_idx, bad_ratio, tables, pair,
@@ -366,6 +403,7 @@ def emit_engine_init(eng, engine_kind: str, init_s: Optional[float] = None
          kernels_s=round(build_s - compile_s, 6),
          transfer_s=round(t.scope_total("transfer"), 6),
          diag_s=round(t.scope_total("diag"), 6),
+         **getattr(eng, "_ell_counts", {}),
          **({} if init_s is None else {"init_s": round(init_s, 6)}))
 
 
@@ -409,7 +447,8 @@ def register_engine_memory(eng, engine_kind: str) -> None:
                n_states=int(eng.n_states), num_terms=int(eng.num_terms),
                pair=bool(eng.pair), real=bool(eng.real),
                batch_size=int(eng.batch_size),
-               T0=int(getattr(eng, "_ell_T0", 0) or 0),
+               T0=int(getattr(eng, "ell_width", None)
+                      or getattr(eng, "_ell_T0", 0) or 0),
                table_bytes=table_bytes)
     if hasattr(eng, "n_padded"):
         ctx["n_padded"] = int(eng.n_padded)
@@ -778,12 +817,13 @@ class LocalEngine:
             if not self.structure_restored:
                 with self.timer.scope("build_structure"), \
                         obs_trace.span("engine_init/build_structure",
-                                       kind="build"):
+                                       kind="build") as build_span:
                     try:
                         self._build_ell()
                     except Exception as e:
                         oom_reraise(e, engine="local", mode=mode,
                                     phase="init", n_states=int(n))
+                    build_span.add(**self._ell_counts)
                 self._save_structure(structure_cache, soft=soft_save)
             self._matvec = self._make_ell_matvec()
             self._checked = True                  # validated at build time
@@ -842,8 +882,11 @@ class LocalEngine:
 
         h = hashlib.sha256()
         hash_basis_operator(h, self.operator)
+        # the ell layout is v2 (staircase levels); a v1 file (main table +
+        # tail) has another fingerprint and is rebuilt, not misread
+        layout = "v2" if self.mode == "ell" else "v1"
         h.update(f"{self.mode}|{self.pair}|{self.real}|{self.batch_size}"
-                 f"|{self.n_states}|{self.n_padded}|v1".encode())
+                 f"|{self.n_states}|{self.n_padded}|{layout}".encode())
         self._fp_cache = h.hexdigest()
         return self._fp_cache
 
@@ -860,16 +903,19 @@ class LocalEngine:
         data = load_engine_structure(sidecar, self._structure_fingerprint())
         if data is None:
             return False
-        self._ell_T0 = int(data["T0"])
         if self.mode == "ell":
-            self._ell_idx = jnp.asarray(data["idx"])
-            self._ell_coeff = jnp.asarray(data["coeff"])
-            self._ell_tail = None
-            if "tail_rows" in data:
-                self._ell_tail = (jnp.asarray(data["tail_rows"]),
-                                  jnp.asarray(data["tail_idx"]),
-                                  jnp.asarray(data["tail_coeff"]))
+            if "levels" not in data:
+                return False
+            self._ell_levels = tuple(
+                (jnp.asarray(data[f"level{i}_idx"]),
+                 jnp.asarray(data[f"level{i}_coeff"]))
+                for i in range(int(data["levels"])))
+            self._ell_pos_of = jnp.asarray(data["pos_of"]) \
+                if "pos_of" in data else None
+            self._ell_counts = {k: int(data[k]) for k in
+                                ("gather_slots", "live_entries", "levels")}
         else:
+            self._ell_T0 = int(data["T0"])
             self._c_W = float(data["W"])
             self._c_idx = jnp.asarray(data["idx"])
             self._c_tail = None
@@ -891,14 +937,12 @@ class LocalEngine:
         from ..io.hdf5 import save_engine_structure
 
         if self.mode == "ell":
-            payload = {"T0": self._ell_T0,
-                       "idx": np.asarray(self._ell_idx),
-                       "coeff": np.asarray(self._ell_coeff)}
-            if self._ell_tail is not None:
-                rows, idx_t, cf_t = self._ell_tail
-                payload.update(tail_rows=np.asarray(rows),
-                               tail_idx=np.asarray(idx_t),
-                               tail_coeff=np.asarray(cf_t))
+            payload = dict(self._ell_counts)
+            for i, (idx_l, cf_l) in enumerate(self._ell_levels):
+                payload[f"level{i}_idx"] = np.asarray(idx_l)
+                payload[f"level{i}_coeff"] = np.asarray(cf_l)
+            if self._ell_pos_of is not None:
+                payload["pos_of"] = np.asarray(self._ell_pos_of)
         else:
             payload = {"T0": self._ell_T0, "W": self._c_W,
                        "idx": np.asarray(self._c_idx)}
@@ -933,15 +977,16 @@ class LocalEngine:
         return (self._lk_shift, self._lk_probes, self.pair)
 
     def _build_ell(self) -> None:
-        """One device pass of the kernels → static [N_pad, T] idx/coeff.
+        """One device pass of the kernels → the staircase ELL levels.
 
         Everything runs on device: the orbit scan (canonical β + rescale),
         the u64 basis lookup (``searchsorted``; ~0.65 s per 64k-row chunk at
-        N=4.7M on v5e), and table assembly into donated buffers via
-        ``dynamic_update_slice``.  Nothing but the representative array ever
-        crosses the host↔device link — a host-assembled build moves
-        O(N·T·24 B) through it (~4 GB for chain_32_symm).  Peak HBM stays at
-        final tables + O(B·T) chunk scratch.
+        N=4.7M on v5e), the left-pack of each chunk's rows and table
+        assembly into donated buffers via ``dynamic_update_slice``.  Nothing
+        but the representative array ever crosses the host↔device link — a
+        host-assembled build moves O(N·T·24 B) through it (~4 GB for
+        chain_32_symm).  Peak HBM is the full-width tables + the levels
+        (one slot a non-zero) + O(B·T) chunk scratch.
         """
         b, C = self.batch_size, self.num_chunks
         alphas_c = self._alphas.reshape(C, b)
@@ -950,9 +995,9 @@ class LocalEngine:
         is_pair = self.pair
 
         # One-pass build materializes full-width [T, N_pad] idx+coeff buffers
-        # before packing (peak ≈ 1.6× their size).  When that exceeds the
-        # device budget, fall back to the two-pass build: count, then pack
-        # chunk-by-chunk straight into the final buffers.
+        # before cutting the levels (peak ≈ 1.6× their size).  When that
+        # exceeds the device budget, fall back to the two-pass build: count,
+        # then pack chunk-by-chunk straight into the level buffers.
         cf_item = 8 if (self.real and not is_pair) else 16
         full_bytes = self.n_padded * T * (4 + cf_item)
         if 1.6 * full_bytes > get_config().ell_build_budget_gb * 1e9:
@@ -969,23 +1014,24 @@ class LocalEngine:
             coeff_buf = jnp.zeros(
                 cshape, jnp.float64 if (self.real or is_pair)
                 else jnp.complex128)
+            nnz = jnp.zeros(self.n_padded, jnp.int32)
             bad = jnp.zeros((), jnp.int64)
             if C:
                 jfn = jax.jit(
                     partial(_ell_fill_chunk, shift=self._lk_shift,
                             probes=self._lk_probes, is_pair=is_pair),
-                    donate_argnums=(0, 1, 2))
+                    donate_argnums=(0, 1, 2, 3))
                 fill = precompile(
                     "ell_fill_chunk", self._builder_statics(), jfn,
-                    (idx_buf, coeff_buf, bad, self.tables, self._lk_pair,
-                     self._lk_dir, alphas_c[0], norms_c[0], jnp.int32(0)),
-                    self.timer)
+                    (idx_buf, coeff_buf, nnz, bad, self.tables,
+                     self._lk_pair, self._lk_dir, alphas_c[0], norms_c[0],
+                     jnp.int32(0)), self.timer)
                 for ci in range(C):
                     log_debug(f"ell build chunk {ci}/{C}")
-                    idx_buf, coeff_buf, bad = fill(
-                        idx_buf, coeff_buf, bad, self.tables, self._lk_pair,
-                        self._lk_dir, alphas_c[ci], norms_c[ci],
-                        jnp.int32(ci * b))
+                    idx_buf, coeff_buf, nnz, bad = fill(
+                        idx_buf, coeff_buf, nnz, bad, self.tables,
+                        self._lk_pair, self._lk_dir, alphas_c[ci],
+                        norms_c[ci], jnp.int32(ci * b))
             with obs_trace.span("device_wait", kind="phase", at="ell_fill"):
                 bad = int(bad)
         if bad:
@@ -993,84 +1039,80 @@ class LocalEngine:
                 f"{bad} generated matrix elements map outside the basis "
                 "— operator does not preserve the chosen sector"
             )
-        self._split_ell(idx_buf, coeff_buf)
+        self._stair_ell(idx_buf, coeff_buf, nnz)
 
-    def _split_ell(self, idx_buf, coeff_buf) -> None:
-        """Pack each row's nonzeros left and split the table in two levels.
+    def _plan_levels(self, hist: np.ndarray):
+        """``staircase_levels`` of the build's histogram, and the counts
+        that say how far the format engages (``_ell_counts``: on the build
+        span and in the ``engine_init`` event)."""
+        stair, levels = staircase_levels(hist, self.n_padded)
+        slots = sum(k * L for _, k, L in levels)
+        self._ell_counts = {
+            "gather_slots": slots + (self.n_padded if stair else 0),
+            "live_entries": int(np.dot(np.arange(hist.size), hist)),
+            "levels": len(levels)}
+        log_debug(f"ell levels: T={self.num_terms} stair={stair} "
+                  f"levels={levels} entries {self.n_padded * self.num_terms}"
+                  f" -> {slots}")
+        return stair, levels
 
-        ELL fill is typically ~50% (mean row nnz ≈ T/2 while the width is
-        max-row nnz), and the matvec cost is per-*entry* (TPU gathers run at
-        a fixed element rate regardless of locality — measured 74 M elem/s —
-        so zero slots cost as much as real ones).  Split: a width-``T0`` main
-        table covering every row plus a ``[Tmax-T0, S]`` tail over only the
-        S rows with nnz > T0 (Tmax = widest actual row); ``T0`` minimizes
-        ``N·T0 + 2·S(T0)·(Tmax−T0)`` — tail entries are scatter-accumulated,
-        hence the 2× weight — subject to S ≤ N/4 so the scatter stays small.
-        Cuts gather work ≈2× at ~50% fill.
+    def _stair_ell(self, idx_buf, coeff_buf, nnz) -> None:
+        """Cut the left-packed full-width tables into staircase levels.
+
+        The matvec cost is per gathered *slot*, whatever the slot holds (TPU
+        row gathers run at a fixed index rate regardless of locality — 165 M
+        rows/s, 6.05 ns a slot, at chain_32_symm on v5e: PERF.md §5), while
+        the table's width is the widest row's and the mean row holds about
+        half of that.  With rows ordered by non-zero count, column ``t``
+        needs only the rows that have more than ``t`` entries
+        (:func:`staircase_levels`), so the levels hold one slot a non-zero
+        plus the rounding of each column to :data:`INDEX_TILE`; the matvec
+        accumulates in that order and one more gather puts the result back
+        in basis order.  Rows of near-equal width keep the plain table.
         """
         T = self.num_terms
-        n_pad = self.n_padded
-        b, C = self.batch_size, self.num_chunks
-        is_pair = self.pair
-        if n_pad == 0:
-            self._ell_T0 = T
-            self._ell_idx, self._ell_coeff = idx_buf, coeff_buf
-            self._ell_tail = None
-            return
-
-        # Phase 1 — row-nnz histogram only; no table-sized allocation.
-        with obs_trace.span("ell/split_count", kind="phase"):
-            count = precompile(
-                "ell_split_count", (T, is_pair),
-                jax.jit(partial(_split_count, T=T, is_pair=is_pair)),
-                (coeff_buf,), self.timer)
-            nnz, hist = count(coeff_buf)
+        with obs_trace.span("ell/count", kind="phase"):
+            count = precompile("ell_nnz_hist", (T,),
+                               jax.jit(partial(_nnz_hist, T=T)), (nnz,),
+                               self.timer)
+            hist = count(nnz)
             with obs_trace.span("device_wait", kind="phase",
-                                at="ell_split_count"):
+                                at="ell_count"):
                 hist = np.asarray(hist)
-            T0, S, Tmax = choose_ell_split(hist, n_pad, T,
-                                           real_rows=self.n_states)
-        self._ell_T0 = T0
-        final_entries = n_pad * T if T0 == T \
-            else n_pad * T0 + S * (Tmax - T0)
-        log_debug(f"ell split: T={T} Tmax={Tmax} T0={T0} tail_rows={S} "
-                  f"entries {n_pad * T} -> {final_entries}")
-        if T0 == T:
-            self._ell_idx = idx_buf
-            self._ell_coeff = coeff_buf
-            self._ell_tail = None
+            stair, levels = self._plan_levels(hist)
+        self._ell_pos_of = None
+        if not stair:
+            Tmax = levels[0][1]
+            self._ell_levels = ((idx_buf, coeff_buf) if Tmax == T else
+                                (idx_buf[:Tmax], coeff_buf[:Tmax]),)
             return
 
-        # Phase 2 — chunked pack into donated output buffers.  Peak HBM is
-        # the full-width input tables + the [T0, N_pad] packed outputs +
-        # O(T·b) chunk scratch (≈1.6× one full-width table at 50% fill);
-        # the argsort order array only ever exists per chunk.
-        with obs_trace.span("ell/split_pack", kind="phase"):
-            out_idx = jnp.zeros((T0, n_pad), jnp.int32)
-            out_cf = jnp.zeros((T0, n_pad) + ((2,) if is_pair else ()),
-                               coeff_buf.dtype)
-            pack = precompile(
-                "ell_split_pack", (T, T0, b, is_pair),
-                jax.jit(partial(_split_pack_chunk, T=T, T0=T0, b=b,
-                                is_pair=is_pair), donate_argnums=(0, 1)),
-                (out_idx, out_cf, idx_buf, coeff_buf, jnp.int32(0)),
-                self.timer)
-            for ci in range(C):
-                out_idx, out_cf = pack(out_idx, out_cf, idx_buf,
-                                       coeff_buf, jnp.int32(ci * b))
-        self._ell_idx = out_idx
-        self._ell_coeff = out_cf
-        if S == 0:
-            self._ell_tail = None
-            return
+        # One program a level and table, the index table's first and then
+        # let go: a program over an f64 table holds a 32-bit half of the
+        # WHOLE table as a temporary (the TPU's f64 emulation splits the
+        # argument before it is sliced), so the build's peak is the
+        # coefficient table + that half + the levels.
+        with obs_trace.span("ell/stair_levels", kind="phase"):
+            order = precompile("ell_stair_order", (), jax.jit(_stair_order),
+                               (nnz,), self.timer)
+            row_of, self._ell_pos_of = order(nnz)
 
-        with obs_trace.span("ell/split_tail", kind="phase"):
-            build_tail = precompile(
-                "ell_split_tail", (T0, Tmax, S, is_pair),
-                jax.jit(partial(_split_build_tail, T0=T0, Tmax=Tmax, S=S,
-                                is_pair=is_pair)),
-                (idx_buf, coeff_buf, nnz), self.timer)
-            self._ell_tail = build_tail(idx_buf, coeff_buf, nnz)
+            def cut(name, tab):
+                return [precompile(
+                    name, (t0, k, L),
+                    jax.jit(partial(_stair_level, t0=t0, k=k, L=L)),
+                    (tab, row_of), self.timer)(tab, row_of)
+                    for t0, k, L in levels]
+
+            idx_levels = cut("ell_stair_idx", idx_buf)
+            # let the index table go before the coefficient levels are
+            # allocated (the caller's frame still names it, hence delete)
+            with obs_trace.span("device_wait", kind="phase",
+                                at="ell_stair_idx"):
+                jax.block_until_ready(idx_levels)
+            idx_buf.delete()
+            self._ell_levels = tuple(
+                zip(idx_levels, cut("ell_stair_coeff", coeff_buf)))
 
     def _count_row_nnz(self, alphas_c, norms_c):
         """Counting pass shared by the low-memory builds: per-chunk row-nnz
@@ -1108,8 +1150,7 @@ class LocalEngine:
 
     @staticmethod
     def _tail_layout(nnz_chunks, T0, S, Tmax):
-        """Tail bookkeeping shared by the chunked pack loops (low-memory ELL
-        and compact builds).
+        """Tail bookkeeping of the compact build's chunked pack loop.
 
         Tail slabs are written sequentially with one fixed capacity ``Ct``:
         chunk k writes at host offset ``offs[k] = Σ_{j<k} real_j``, so a
@@ -1128,63 +1169,60 @@ class LocalEngine:
         return Tw, Ct, offs
 
     def _build_ell_lowmem(self) -> None:
-        """Two-pass ELL build bounded by the *packed* table size.
+        """Two-pass ELL build bounded by the *level* tables' size.
 
         Pass 1 runs the kernels chunk-by-chunk and keeps only per-row nnz
-        counts (a [b] vector per chunk) to build the global histogram; pass 2
-        re-runs the kernels and packs each chunk's nonzeros directly into the
-        donated final [T0, N_pad] buffers plus a sequentially-assembled tail.
-        The kernels run twice, but peak device memory is the packed output +
+        counts (a [b] vector per chunk): the histogram gives the levels and
+        the counts' stable descending sort the row order.  Pass 2 re-runs
+        the kernels on the states taken in that order and writes each
+        chunk's left-packed columns directly into the donated level buffers.
+        The kernels run twice, but peak device memory is the levels +
         O(b·T) chunk scratch instead of the full-width [T, N_pad] tables —
         what makes square_6x6 (N=15.8M, T=72: 13.7 GB full-width vs ~7 GB
-        packed) buildable on one 16 GB chip.  Tail slabs are assembled
-        sequentially per the invariant documented in :meth:`_tail_layout`.
+        packed) buildable on one 16 GB chip.  Same arrays as the one-pass
+        build.
         """
         b, C = self.batch_size, self.num_chunks
-        alphas_c = self._alphas.reshape(C, b)
-        norms_c = self._norms.reshape(C, b)
-        T = self.num_terms
-        n_pad = self.n_padded
+        alphas, norms = self._alphas, self._norms
         is_pair = self.pair
         cdtype = jnp.float64 if (self.real or is_pair) else jnp.complex128
         pz = ((2,) if is_pair else ())
 
-        hist, nnz_chunks = self._count_row_nnz(alphas_c, norms_c)
+        hist, nnz_chunks = self._count_row_nnz(alphas.reshape(C, b),
+                                               norms.reshape(C, b))
+        stair, levels = self._plan_levels(hist)
+        self._ell_pos_of = None
+        if stair:
+            row_of = np.argsort(-np.concatenate(nnz_chunks), kind="stable")
+            pos_of = np.empty(row_of.size, np.int32)
+            pos_of[row_of] = np.arange(row_of.size, dtype=np.int32)
+            self._ell_pos_of = jnp.asarray(pos_of)
+            row_of = jnp.asarray(row_of.astype(np.int32))
+            alphas, norms = alphas[row_of], norms[row_of]
+        alphas_c, norms_c = alphas.reshape(C, b), norms.reshape(C, b)
 
-        T0, S, Tmax = choose_ell_split(hist, n_pad, T,
-                                       real_rows=self.n_states)
-        self._ell_T0 = T0
-        log_debug(f"ell lowmem split: T={T} Tmax={Tmax} T0={T0} "
-                  f"tail_rows={S}")
-        Tw, Ct, offs = self._tail_layout(nnz_chunks, T0, S, Tmax)
-
-        # -- pass 2: pack into donated final buffers ----------------------
-        out_idx = jnp.zeros((T0, n_pad), jnp.int32)
-        out_cf = jnp.zeros((T0, n_pad) + pz, cdtype)
-        S_buf = S + Ct
-        t_rows = jnp.zeros(max(S_buf, 1), jnp.int32)
-        t_idx = jnp.zeros((max(Tw, 1), max(S_buf, 1)), jnp.int32)
-        t_cf = jnp.zeros((max(Tw, 1), max(S_buf, 1)) + pz, cdtype)
+        # -- pass 2: pack into donated level buffers, a whole number of
+        # chunks long each (``_lowmem_pack_chunk``), cut to length after
+        bufs = tuple(
+            (jnp.zeros((k, pad_to_multiple(L, b)), jnp.int32),
+             jnp.zeros((k, pad_to_multiple(L, b)) + pz, cdtype))
+            for _, k, L in levels)
         if C:
             pack_chunk = precompile(
-                "ell_lowmem_pack", self._builder_statics() + (T0, Tmax, Ct),
+                "ell_lowmem_pack", self._builder_statics() + (levels,),
                 jax.jit(partial(_lowmem_pack_chunk, shift=self._lk_shift,
                                 probes=self._lk_probes, is_pair=is_pair,
-                                T0=T0, Tmax=Tmax, Ct=Ct),
-                        donate_argnums=(0, 1, 2, 3, 4)),
-                (out_idx, out_cf, t_rows, t_idx, t_cf, self.tables,
-                 self._lk_pair, self._lk_dir, alphas_c[0], norms_c[0],
-                 jnp.int32(0), jnp.int32(0)), self.timer)
+                                levels=levels), donate_argnums=(0,)),
+                (bufs, self.tables, self._lk_pair, self._lk_dir,
+                 alphas_c[0], norms_c[0], jnp.int32(0)), self.timer)
         for ci in range(C):
             log_debug(f"ell lowmem pack chunk {ci}/{C}")
-            out_idx, out_cf, t_rows, t_idx, t_cf = pack_chunk(
-                out_idx, out_cf, t_rows, t_idx, t_cf, self.tables,
-                self._lk_pair, self._lk_dir, alphas_c[ci], norms_c[ci],
-                jnp.int32(ci * b), jnp.int32(offs[ci]))
-        self._ell_idx = out_idx
-        self._ell_coeff = out_cf
-        self._ell_tail = None if S == 0 else (
-            t_rows[:S], t_idx[:, :S], t_cf[:, :S])
+            bufs = pack_chunk(bufs, self.tables, self._lk_pair,
+                              self._lk_dir, alphas_c[ci], norms_c[ci],
+                              jnp.int32(ci * b))
+        self._ell_levels = tuple(
+            (idx_l[:, :L], cf_l[:, :L])
+            for (_, _, L), (idx_l, cf_l) in zip(levels, bufs))
 
     def _build_compact(self) -> None:
         """4-bytes-per-entry structure for real sectors with one off-diagonal
@@ -1341,16 +1379,19 @@ class LocalEngine:
         return lambda x: _mv(x, self._operands)
 
     def _make_ell_matvec(self):
-        n = self.n_states
-        T0 = self._ell_T0
+        n, n_pad = self.n_states, self.n_padded
         dtype = self._dtype
-        has_tail = self._ell_tail is not None
         use_sg = split_gather_enabled()
         is_pair = self.pair
         nd_base = 2 if is_pair else 1    # ndim of one unbatched vector
 
+        def grown(acc, rows):
+            """``acc`` with zero rows appended up to ``rows``."""
+            short = max(rows - acc.shape[0], 0)
+            return jnp.pad(acc, [(0, short)] + [(0, 0)] * (acc.ndim - 1))
+
         def apply_fn(x, operands):
-            idx, coeff, diag, tail = operands
+            levels, pos_of, diag = operands
             x = jnp.asarray(x).astype(dtype)
             batched = x.ndim == nd_base + 1
             # the named scopes are metadata on the operations (their
@@ -1358,6 +1399,12 @@ class LocalEngine:
             # operation is added, moved or split for them
             with jax.named_scope("apply/split"):
                 gx = prep_gather(x, dtype, use_sg)
+            # one verdict for the whole apply: the levels' gathers do not
+            # depend on each other, so unrolled they can all be live at once
+            slots = sum(idx.shape[0] * idx.shape[1] for idx, _ in levels)
+            width = sum(idx.shape[0] for idx, _ in levels)
+            unroll = unroll_terms_ok(width, -(-slots // max(width, 1)),
+                                     x.shape)
 
             def contrib(c, g):
                 # c: per-row coefficient [rows(, 2)]; g: gathered x rows
@@ -1365,38 +1412,36 @@ class LocalEngine:
                     return K.cmul_pair(c[:, None, :] if batched else c, g)
                 return (c[:, None] if batched else c) * g
 
-            def terms(y, idx, coeff, width, sl=None):
-                if unroll_terms_ok(width, idx.shape[1], x.shape):
+            def terms(acc, idx, coeff):
+                if unroll:
                     # Unrolled per-term gathers — contiguous coeff rows.
-                    for t in range(width):
-                        acc = contrib(coeff[t], gx(idx[t]))
-                        y = y + (acc[:n] if sl else acc)
+                    for t in range(idx.shape[0]):
+                        acc = acc + contrib(coeff[t], gx(idx[t]))
                 else:
-                    def step(y, args):
+                    def step(acc, args):
                         i, c = args
-                        acc = contrib(c, gx(i))
-                        return y + (acc[:n] if sl else acc), None
-                    y, _ = jax.lax.scan(step, y,
-                                        (idx[:width], coeff[:width]))
-                return y
+                        return acc + contrib(c, gx(i)), None
+                    acc, _ = jax.lax.scan(step, acc, (idx, coeff))
+                return acc
 
+            # ``acc`` is in packed row order: from the shortest level (the
+            # widest rows' last columns) to the longest, each level adding
+            # to the head of the next accumulator
+            with jax.named_scope("apply/terms"):
+                acc = jnp.zeros((0,) + x.shape[1:], dtype)
+                for idx, coeff in reversed(levels):
+                    acc = terms(grown(acc, idx.shape[1]), idx, coeff)
+            if pos_of is not None:
+                with jax.named_scope("apply/unpermute"):
+                    acc = prep_gather(grown(acc, n_pad), dtype,
+                                      use_sg)(pos_of)
             with jax.named_scope("apply/diag"):
                 d = diag[:n].astype(dtype)
-                y = d.reshape((n,) + (1,) * (x.ndim - 1)) * x
-            with jax.named_scope("apply/terms"):
-                y = terms(y, idx, coeff, T0, sl=True)
-            if has_tail:
-                with jax.named_scope("apply/tail"):
-                    rows, idx_t, cf_t = tail
-                    zshape = rows.shape + x.shape[1:]
-                    acc = terms(jnp.zeros(zshape, dtype), idx_t, cf_t,
-                                idx_t.shape[0])
-                    y = y.at[rows].add(acc, mode="drop")
+                y = d.reshape((n,) + (1,) * (x.ndim - 1)) * x + acc[:n]
             return y, jnp.zeros((), jnp.int64)
 
         self._apply_fn = apply_fn
-        self._operands = (self._ell_idx, self._ell_coeff, self._diag,
-                          self._ell_tail)
+        self._operands = (self._ell_levels, self._ell_pos_of, self._diag)
         _mv = jax.jit(apply_fn)
         return lambda x: _mv(x, self._operands)
 
@@ -1559,8 +1604,10 @@ class LocalEngine:
         * ``compute``   one x-row gather per structure entry (table slots
           including ELL padding — the gather executes for every slot) plus
           the streamed coefficient; fused mode adds the orbit-scan ops.
-        * ``accumulate`` the tail scatter-add rows (ell/compact two-level
-          tail); zero in fused mode (pure row form).
+        * ``accumulate`` what puts the terms' sums in their rows: ell mode's
+          gather back to basis order (one row of the accumulator a padded
+          row; nothing where the table is in basis order), compact mode's
+          tail scatter-add rows; zero in fused mode (pure row form).
         """
         cache = getattr(self, "_phase_count_cache", None)
         if cache is None:
@@ -1575,20 +1622,22 @@ class LocalEngine:
         c = obs_phases.zero_counts()
         if self.mode in ("ell", "compact"):
             if self.mode == "ell":
-                tail = self._ell_tail
                 cfb = 16 if cplx else 8   # streamed f64/pair coefficient
+                g = sum(int(idx.size) for idx, _ in self._ell_levels)
+                rows_t = self._ell_counts["gather_slots"] - g
+                flops_t = 0               # a gather adds nothing
             else:
                 tail = self._c_tail
                 cfb = 4 + 8               # sign-tagged i32 + gathered norm
-            T0 = self._ell_T0
-            g_main = T0 * self.n_padded
-            g_tail = int(tail[1].shape[0] * tail[1].shape[1]) if tail else 0
-            rows_t = int(tail[0].shape[0]) if tail else 0
-            g = g_main + g_tail
+                g_tail = int(tail[1].shape[0] * tail[1].shape[1]) \
+                    if tail else 0
+                g = self._ell_T0 * self.n_padded + g_tail
+                rows_t = int(tail[0].shape[0]) if tail else 0
+                flops_t = rows_t * k * (2 if cplx else 1)
             c["compute"] = {"bytes": g * (vb * k + cfb), "gathers": g,
                             "flops": g * k * fmul}
             c["accumulate"] = {"bytes": rows_t * vb * k, "gathers": rows_t,
-                               "flops": rows_t * k * (2 if cplx else 1)}
+                               "flops": flops_t}
         else:                             # fused: scan + route per apply
             grp = getattr(self.operator.basis, "group", None)
             G = max(len(grp), 1) if grp is not None else 1
@@ -1628,10 +1677,10 @@ class LocalEngine:
         tables actually resident (the parity tests in
         ``tests/test_memory_obs.py`` pin each mode's expected contents)."""
         if self.mode == "ell":
-            out = {"idx": self._ell_idx, "coeff": self._ell_coeff}
-            if self._ell_tail is not None:
-                rows, t_idx, t_cf = self._ell_tail
-                out.update(tail_rows=rows, tail_idx=t_idx, tail_coeff=t_cf)
+            out = {"idx": tuple(i for i, _ in self._ell_levels),
+                   "coeff": tuple(c for _, c in self._ell_levels)}
+            if self._ell_pos_of is not None:
+                out["pos_of"] = self._ell_pos_of
             return out
         if self.mode == "compact":
             out = {"idx": self._c_idx, "inv_n": self._c_inv_n,
@@ -1665,6 +1714,18 @@ class LocalEngine:
             shape = (self.n_states, 2) if self.pair else (self.n_states,)
             x = jnp.zeros(shape, self._dtype)   # f64, or c128 native-complex
         return analyze_bound_apply(self, "local", x)
+
+    @property
+    def ell_width(self) -> int:
+        """Table slots a padded row, rounded up: the ``T0`` of the memory
+        ledger's context and of ``tools/capacity.py``'s per-row model (the
+        main table's width in compact mode, the levels' mean in ell)."""
+        if self.mode == "compact":
+            return int(self._ell_T0)
+        if self.mode != "ell" or not self.n_padded:
+            return 0
+        slots = sum(int(idx.size) for idx, _ in self._ell_levels)
+        return -(-slots // self.n_padded)
 
     @property
     def ell_nbytes(self) -> int:

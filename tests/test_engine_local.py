@@ -96,67 +96,267 @@ def test_non_hermitian_rejected():
         LocalEngine(op)
 
 
-def test_ell_split_tail_path_exercised(rng):
-    """Deterministically drive the two-level ELL split (main + scatter tail).
+def _ring(n):
+    return [*range(1, n), 0]
 
-    A periodic Heisenberg chain in the hamming sector has skewed row widths
-    (~50% fill), so the split must trigger; assert it did — a tail bug must
-    not be able to hide behind an unsplit table — and that the split matvec
-    still matches the host path at golden tolerances.
-    """
-    op = build_heisenberg(16, 8, None)
+
+# rings whose row widths (a row's domain walls: 2, 4, ... n) spread enough
+# for the staircase to engage at CPU-test sizes (thousands of rows)
+STAIR_RINGS = {
+    "ring16": (16, 8, None, ()),                        # 12,870 rows, real
+    "symm_ring20": (20, 10, 1, [(_ring(20), 0),         # 2,518 rows, |G|=80
+                                ([*reversed(range(20))], 0)]),
+    "momentum_ring18": (18, 9, None, [(_ring(18), 1)]),  # 2,700, complex
+}
+
+
+@pytest.fixture
+def pair_form():
+    """Complex sectors in (re, im)-f64 pair form, as on a TPU."""
+    from distributed_matvec_tpu.utils.config import get_config, update_config
+
+    prev = get_config().complex_pair
+    update_config(complex_pair="on")
+    yield
+    update_config(complex_pair=prev)
+
+
+def _stair_engine(name, **kw):
+    op = build_heisenberg(*STAIR_RINGS[name])
     op.basis.build()
-    eng = LocalEngine(op, mode="ell")
-    assert eng._ell_T0 < eng.num_terms, "split did not trigger"
-    assert eng._ell_tail is not None, "tail path not exercised"
-    n = op.basis.number_states
-    x = rng.random(n) - 0.5
-    np.testing.assert_allclose(np.asarray(eng.matvec(x)), op.matvec_host(x),
-                               atol=1e-13, rtol=1e-12)
-    X = np.stack([x, rng.random(n) - 0.5], axis=1)
-    Y = np.asarray(eng.matvec(X))
-    for k in range(2):
-        np.testing.assert_allclose(Y[:, k], op.matvec_host(X[:, k]),
-                                   atol=1e-13, rtol=1e-12)
+    eng = LocalEngine(op, mode="ell", **kw)
+    assert eng._ell_pos_of is not None and len(eng._ell_levels) > 1, \
+        "staircase did not engage"
+    return op, eng
 
 
-def test_lowmem_build_matches_onepass(rng):
-    """The two-pass low-memory ELL build (count → pack) produces the exact
-    tables of the one-pass build: same split point, bit-identical matvec.
-    Exercised on a config with a scatter tail (the tricky sequential-slab
-    assembly) and on a complex momentum sector in pair form."""
+def _independent_apply(op, X):
+    """H·X from the definitions alone: the symmetry isometry of
+    ``dense_ref`` around ``independent_ref``'s bit-operation ring apply on
+    the whole fixed-magnetisation sector."""
+    import dense_ref
+    import independent_ref
+
+    basis = op.basis
+    n = basis.number_spins
+    states = independent_ref.enumerate_fixed_hw(n, basis.hamming_weight)
+    B = dense_ref.symmetry_isometry(n, basis.representatives, basis.norms,
+                                    basis.group)[states.astype(np.int64)]
+    cols = []
+    for x in np.atleast_2d(X.T):
+        full = B @ x
+        hx = independent_ref.heisenberg_ring_apply(states, n, full.real) \
+            + 1j * independent_ref.heisenberg_ring_apply(states, n,
+                                                         full.imag)
+        cols.append(B.getH() @ hx)
+    Y = np.stack(cols, axis=1)
+    Y = Y if np.iscomplexobj(X) else Y.real
+    return Y if X.ndim == 2 else Y[:, 0]
+
+
+def _primitives(jaxpr):
+    """Names of every primitive in a jaxpr, sub-jaxprs included."""
+    names = []
+    for eqn in jaxpr.eqns:
+        names.append(eqn.primitive.name)
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    names += _primitives(sub)
+    return names
+
+
+def _apply_primitives(eng, x):
+    import jax
+
+    apply_fn, operands = eng.bound_matvec()
+    return _primitives(jax.make_jaxpr(apply_fn)(x, operands).jaxpr)
+
+
+@pytest.mark.parametrize("term_loop", ["auto", "scan"])
+@pytest.mark.parametrize("name", list(STAIR_RINGS))
+def test_staircase_matches_independent_reference(name, term_loop, rng,
+                                                 pair_form):
+    """The staircase apply — rows ordered by non-zero count, one level a
+    column length, the result gathered back to basis order — against the
+    independent reference: a plain ring, a fully symmetric one and a
+    complex momentum sector in pair form; one vector and a batch; padded
+    rows beyond the basis (batch_size 1000); unrolled and scan term loops."""
     from distributed_matvec_tpu.utils.config import update_config
 
-    cases = [
-        (16, 8, None, (), "auto"),       # real, tail path triggers
-        (12, 6, None,
-         [([1, 2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 0], 2)], "on"),  # pair
-    ]
-    from distributed_matvec_tpu.utils.config import get_config
+    update_config(term_loop=term_loop)
+    try:
+        op, eng = _stair_engine(name, batch_size=1000)
+        assert eng.n_padded > eng.n_states
+        assert eng.pair == (not op.effective_is_real)
+        n = op.basis.number_states
+        X = rng.random((n, 3)) - 0.5
+        if eng.pair:
+            X = X + 1j * (rng.random((n, 3)) - 0.5)
+        Y_ref = _independent_apply(op, X)
+        np.testing.assert_allclose(np.asarray(eng.matvec(X[:, 0])),
+                                   Y_ref[:, 0], atol=1e-13, rtol=1e-12)
+        if eng.pair:    # a batch of pair vectors is [N, k, 2]
+            from distributed_matvec_tpu.ops import kernels as K
 
+            Y = K.complex_from_pair(np.asarray(eng.matvec(
+                np.moveaxis(K.pair_from_complex(X.T), 0, 1))))
+        else:
+            Y = np.asarray(eng.matvec(X))
+        np.testing.assert_allclose(Y, Y_ref, atol=1e-13, rtol=1e-12)
+    finally:
+        update_config(term_loop="auto")
+
+
+def test_staircase_accounting(rng):
+    """``gather_slots``, ``live_entries`` and ``levels`` against the ring's
+    own arithmetic: a row has one entry a domain wall."""
+    op, eng = _stair_engine("ring16", batch_size=1000)
+    n, n_pad = eng.n_states, eng.n_padded
+    s = op.basis.representatives
+    walls = s ^ ((s >> np.uint64(1)) | ((s & np.uint64(1)) << np.uint64(15)))
+    nnz = np.array([bin(int(w)).count("1") for w in walls])
+    Tmax = int(nnz.max())
+    rows_gt = np.array([(nnz > t).sum() for t in range(Tmax)])
+    lengths = -(-rows_gt // 1024) * 1024
+    assert eng._ell_counts == {
+        "gather_slots": int(lengths.sum()) + n_pad,
+        "live_entries": int(nnz.sum()),
+        "levels": len(set(lengths))}
+    # the level arrays are those columns, longest first, and nothing else
+    assert [i.shape for i, _ in eng._ell_levels] == \
+        [(int((lengths == L).sum()), int(L))
+         for L in sorted(set(lengths), reverse=True)]
+    live = sum(int(np.count_nonzero(np.asarray(c)))
+               for _, c in eng._ell_levels)
+    assert live == nnz.sum()
+    # row_of: a permutation of the padded rows, widest rows first, ties in
+    # basis order; the same on a second build
+    pos_of = np.asarray(eng._ell_pos_of)
+    row_of = np.argsort(pos_of)
+    np.testing.assert_array_equal(np.sort(pos_of), np.arange(n_pad))
+    nnz_pad = np.concatenate([nnz, np.zeros(n_pad - n, int)])
+    np.testing.assert_array_equal(
+        row_of, np.argsort(-nnz_pad, kind="stable"))
+    _, eng2 = _stair_engine("ring16", batch_size=1000)
+    np.testing.assert_array_equal(pos_of, np.asarray(eng2._ell_pos_of))
+    # the ledger's per-row width and the phase counts follow the levels
+    slots = int(lengths.sum())
+    assert eng.ell_width == -(-slots // n_pad)
+    counts = eng._phase_counts(1)
+    assert counts["compute"]["gathers"] == slots
+    assert counts["accumulate"]["gathers"] == n_pad
+
+
+@pytest.mark.parametrize("term_loop", ["auto", "scan"])
+def test_staircase_apply_has_no_scatter(term_loop):
+    """One gather a table column and one back to basis order; no scatter
+    (the two-level format's tail paid one), in either term-loop form."""
+    from distributed_matvec_tpu.utils.config import update_config
+
+    update_config(term_loop=term_loop)
+    try:
+        _, eng = _stair_engine("symm_ring20")
+        prims = _apply_primitives(eng, np.zeros(eng.n_states))
+    finally:
+        update_config(term_loop="auto")
+    assert not [p for p in prims if "scatter" in p]
+    # unrolled: a gather a column + the un-permute; scan: one a level
+    width = sum(i.shape[0] for i, _ in eng._ell_levels)
+    assert prims.count("gather") == \
+        (width if term_loop == "auto" else len(eng._ell_levels)) + 1
+
+
+def test_equal_width_rows_keep_plain_table(rng):
+    """An operator whose rows are equally wide (a transverse field on the
+    full basis: every state flips at each of its n sites) reads one level
+    in basis order off its histogram: no row order, no un-permute gather."""
+    from distributed_matvec_tpu.models.operator import Operator
+
+    n = 12
+    basis = SpinBasis(n)
+    op = Operator.from_expressions(basis, [("σˣ₀", [[i] for i in range(n)])])
+    basis.build()
+    eng = LocalEngine(op, mode="ell", batch_size=1024)
+    assert eng._ell_pos_of is None
+    assert [i.shape for i, _ in eng._ell_levels] == [(n, eng.n_padded)]
+    assert eng._ell_counts == {"gather_slots": n * eng.n_padded,
+                               "live_entries": n * 2 ** n, "levels": 1}
+    x = rng.random(2 ** n) - 0.5
+    prims = _apply_primitives(eng, x)
+    assert prims.count("gather") == n and "scatter-add" not in prims
+    np.testing.assert_allclose(np.asarray(eng.matvec(x)), op.matvec_host(x),
+                               atol=1e-13, rtol=1e-12)
+
+
+def test_staircase_levels_reads_the_histogram():
+    """staircase_levels: the form is a function of the row-nnz histogram
+    alone — equal rows and tiny bases keep the plain table, spread rows
+    take one level a distinct rounded column length."""
+    from distributed_matvec_tpu.parallel.engine import (INDEX_TILE,
+                                                        staircase_levels)
+
+    T, n = 16, 100_000
+    hist = np.zeros(T + 1, np.int64)
+    hist[T] = n                              # all rows full width
+    assert staircase_levels(hist, n) == (False, ((0, T, n),))
+    hist = np.zeros(T + 1, np.int64)
+    hist[4] = n                              # uniform narrow rows: truncate
+    assert staircase_levels(hist, n) == (False, ((0, 4, n),))
+    hist = np.zeros(T + 1, np.int64)
+    hist[4], hist[T] = n - 10, 10            # a few wide rows over a bulk
+    stair, levels = staircase_levels(hist, n)
+    assert stair and levels == ((0, 4, 100_352), (4, T - 4, INDEX_TILE))
+    hist = np.zeros(T + 1, np.int64)
+    hist[2], hist[6] = 300, 200              # spread, but under one tile
+    assert staircase_levels(hist, 512) == (False, ((0, 6, 512),))
+    hist = np.zeros(T + 1, np.int64)         # pad rows only / empty basis
+    hist[0] = 64
+    assert staircase_levels(hist, 64) == (False, ((0, 0, 64),))
+    assert staircase_levels(np.zeros(T + 1, np.int64), 0) \
+        == (False, ((0, 0, 0),))
+
+
+@pytest.mark.parametrize("name, batch_size", [
+    ("ring16", 61),              # staircase, chunks that straddle levels
+    ("momentum_ring18", 512),    # staircase, pair-form coefficients
+    ("small_momentum_ring12", 61),   # plain table (under one index tile)
+])
+def test_lowmem_build_matches_onepass(name, batch_size, rng, pair_form):
+    """The two-pass low-memory ELL build (count → pack in packed row order)
+    produces the arrays of the one-pass build, level for level, and a
+    bit-identical matvec."""
+    from distributed_matvec_tpu.utils.config import get_config, update_config
+
+    spec = STAIR_RINGS.get(name) or (12, 6, None, [(_ring(12), 2)])
+    op = build_heisenberg(*spec)
+    op.basis.build()
     prev_budget = get_config().ell_build_budget_gb
-    prev_pair = get_config().complex_pair
-    for n, hw, inv, syms, pairmode in cases:
-        op = build_heisenberg(n, hw, inv, syms)
-        op.basis.build()
-        update_config(complex_pair=pairmode)
-        try:
-            eng_ref = LocalEngine(op, batch_size=61, mode="ell")
-            update_config(ell_build_budget_gb=1e-9)   # force two-pass
-            eng_lm = LocalEngine(op, batch_size=61, mode="ell")
-        finally:
-            update_config(ell_build_budget_gb=prev_budget,
-                          complex_pair=prev_pair)
-        assert eng_lm._ell_T0 == eng_ref._ell_T0
-        if eng_ref._ell_tail is not None:
-            assert eng_lm._ell_tail is not None
-        N = op.basis.number_states
-        x = rng.random(N) - 0.5
-        if not op.effective_is_real:
-            x = x + 1j * (rng.random(N) - 0.5)
-        y_ref = np.asarray(eng_ref.matvec(x))
-        y_lm = np.asarray(eng_lm.matvec(x))
-        np.testing.assert_array_equal(y_ref, y_lm)
+    try:
+        eng_ref = LocalEngine(op, batch_size=batch_size, mode="ell")
+        update_config(ell_build_budget_gb=1e-9)   # force two-pass
+        eng_lm = LocalEngine(op, batch_size=batch_size, mode="ell")
+    finally:
+        update_config(ell_build_budget_gb=prev_budget)
+    assert (eng_ref._ell_pos_of is not None) == (name in STAIR_RINGS)
+    assert eng_lm._ell_counts == eng_ref._ell_counts
+    assert len(eng_lm._ell_levels) == len(eng_ref._ell_levels)
+    for (i_lm, c_lm), (i_ref, c_ref) in zip(eng_lm._ell_levels,
+                                            eng_ref._ell_levels):
+        np.testing.assert_array_equal(np.asarray(i_lm), np.asarray(i_ref))
+        np.testing.assert_array_equal(np.asarray(c_lm), np.asarray(c_ref))
+    if eng_ref._ell_pos_of is None:
+        assert eng_lm._ell_pos_of is None
+    else:
+        np.testing.assert_array_equal(np.asarray(eng_lm._ell_pos_of),
+                                      np.asarray(eng_ref._ell_pos_of))
+    N = op.basis.number_states
+    x = rng.random(N) - 0.5
+    if not op.effective_is_real:
+        x = x + 1j * (rng.random(N) - 0.5)
+    np.testing.assert_array_equal(np.asarray(eng_ref.matvec(x)),
+                                  np.asarray(eng_lm.matvec(x)))
 
 
 def test_compact_mode_matches_dense(rng):
@@ -263,6 +463,63 @@ def test_structure_cache_roundtrip(tmp_path, rng):
                                    LocalEngine(op, batch_size=61,
                                                mode="ell").matvec(x)),
                                atol=1e-13)
+
+
+def test_structure_cache_staircase_layout(tmp_path, rng):
+    """The staircase checkpoints and restores level for level, with its
+    row order and counts; a file in the old layout (main table + tail) is
+    refused — by fingerprint as an old build wrote it, by its keys should
+    the fingerprint ever match — and rebuilt, not misread."""
+    import hashlib
+
+    from distributed_matvec_tpu.io.hdf5 import (load_engine_structure,
+                                                save_engine_structure)
+    from distributed_matvec_tpu.parallel.engine import hash_basis_operator
+
+    path = str(tmp_path / "stair.h5")
+    sidecar = LocalEngine._structure_sidecar(path)
+    op, eng1 = _stair_engine("symm_ring20", structure_cache=path)
+    x = rng.random(eng1.n_states) - 0.5
+    y1 = np.asarray(eng1.matvec(x))
+    eng2 = LocalEngine(op, mode="ell", structure_cache=path)
+    assert eng2.structure_restored
+    assert eng2._ell_counts == eng1._ell_counts
+    np.testing.assert_array_equal(np.asarray(eng2._ell_pos_of),
+                                  np.asarray(eng1._ell_pos_of))
+    for (i2, c2), (i1, c1) in zip(eng2._ell_levels, eng1._ell_levels):
+        np.testing.assert_array_equal(np.asarray(i2), np.asarray(i1))
+        np.testing.assert_array_equal(np.asarray(c2), np.asarray(c1))
+    np.testing.assert_array_equal(y1, np.asarray(eng2.matvec(x)))
+    # the capacity planner reads the same file: rows, mean width, bytes
+    import importlib.util
+    import os
+
+    spec = importlib.util.spec_from_file_location(
+        "dmt_capacity_levels", os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            "tools", "capacity.py"))
+    capacity = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(capacity)
+    st = capacity.load_structure(sidecar)
+    assert (st["mode"], st["n_padded"], st["T0"], st["pair"]) == \
+        ("ell", eng1.n_padded, eng1.ell_width, False)
+    assert st["table_bytes"] == eng1.ell_nbytes
+
+    T0 = 8
+    old = {"T0": T0, "idx": np.zeros((T0, eng1.n_padded), np.int32),
+           "coeff": np.zeros((T0, eng1.n_padded))}
+    h = hashlib.sha256()
+    hash_basis_operator(h, op)
+    h.update(f"ell|False|True|{eng1.batch_size}|{eng1.n_states}"
+             f"|{eng1.n_padded}|v1".encode())
+    for fingerprint in (h.hexdigest(), eng1._structure_fingerprint()):
+        save_engine_structure(sidecar, fingerprint, "ell", old)
+        eng3 = LocalEngine(op, mode="ell", structure_cache=path)
+        assert not eng3.structure_restored
+        np.testing.assert_array_equal(y1, np.asarray(eng3.matvec(x)))
+        # ... and the rebuild replaced the file with the new layout
+        assert "levels" in load_engine_structure(
+            sidecar, eng1._structure_fingerprint())
 
 
 def test_structure_cache_pair_roundtrip(tmp_path, rng):

@@ -157,16 +157,22 @@ def _leaf_bytes(tree):
     return sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(tree))
 
 
-@pytest.mark.parametrize("mode", ["ell", "compact", "fused"])
+@pytest.mark.parametrize("mode", ["ell", "ell_staircase", "compact",
+                                  "fused"])
 def test_local_ell_nbytes_parity(clean_obs, mode):
     from distributed_matvec_tpu.parallel.engine import LocalEngine
 
-    op = build_heisenberg(10, 5, None, ())
+    # 252 rows keep the plain table; 12,870 take the staircase and its
+    # row-order array
+    stair = mode == "ell_staircase"
+    op = build_heisenberg(*((16, 8) if stair else (10, 5)), None, ())
+    mode = "ell" if stair else mode
     eng = LocalEngine(op, mode=mode)
     if mode == "ell":
-        expected = eng._ell_idx.nbytes + eng._ell_coeff.nbytes
-        if eng._ell_tail is not None:
-            expected += sum(a.nbytes for a in eng._ell_tail)
+        assert (eng._ell_pos_of is not None) == stair
+        expected = sum(i.nbytes + c.nbytes for i, c in eng._ell_levels)
+        if stair:
+            expected += eng._ell_pos_of.nbytes
     elif mode == "compact":
         expected = (eng._c_idx.nbytes + eng._c_inv_n.nbytes
                     + eng._c_n_parts.nbytes)
@@ -226,7 +232,9 @@ def test_engine_init_emits_ledger_with_planner_context(clean_obs):
     assert ev["mode"] == "ell" and ev["engine"] == "local"
     assert ev["n_states"] == op.basis.number_states
     assert ev["table_bytes"] == eng.ell_nbytes
-    assert ev["T0"] == eng._ell_T0 and ev["num_terms"] == eng.num_terms
+    assert ev["T0"] == eng.ell_width and ev["num_terms"] == eng.num_terms
+    assert eng.ell_width == -(-sum(
+        i.shape[0] * i.shape[1] for i, _ in eng._ell_levels) // eng.n_padded)
     assert ev["total_bytes"] >= ev["table_bytes"]
     # every resident group is attributed under this engine instance
     base = f"engine/{eng._mem_instance}"

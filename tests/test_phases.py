@@ -67,9 +67,12 @@ def _assert_totals_exact(ev):
 # engine instrumentation
 
 
-def test_local_ell_phases_exact(clean_obs, rng):
-    op = build_heisenberg(10, 5, None, ())
+@pytest.mark.parametrize("sites, stair", [(10, False), (16, True)],
+                         ids=["plain_table", "staircase"])
+def test_local_ell_phases_exact(clean_obs, rng, sites, stair):
+    op = build_heisenberg(sites, sites // 2, None, ())
     eng = LocalEngine(op, mode="ell")
+    assert (eng._ell_pos_of is not None) == stair
     x = rng.random(op.basis.number_states) - 0.5
     eng.matvec(x)
     # satellite: LocalEngine now emits matvec_apply (engine="local")
@@ -79,11 +82,13 @@ def test_local_ell_phases_exact(clean_obs, rng):
     ev = _phase_event("local")
     assert ev["mode"] == "ell" and ev["columns"] == 1
     _assert_totals_exact(ev)
-    # structural gather count: one gather per table slot (main + tail)
-    g_main = eng._ell_T0 * eng.n_padded
-    g_tail = int(eng._ell_tail[1].shape[0] * eng._ell_tail[1].shape[1]) \
-        if eng._ell_tail is not None else 0
-    assert ev["phases"]["compute"]["gathers"] == g_main + g_tail
+    # structural gather count: one gather per table slot (every level's),
+    # and under ``accumulate`` the gather back to basis order, if any
+    g_levels = sum(int(i.shape[0] * i.shape[1]) for i, _ in eng._ell_levels)
+    assert ev["phases"]["compute"]["gathers"] == g_levels
+    assert ev["phases"]["accumulate"]["gathers"] \
+        == (eng.n_padded if eng._ell_pos_of is not None else 0)
+    assert ev["gathers_total"] == eng._ell_counts["gather_slots"]
     assert ev["phases"]["exchange"]["bytes"] == 0
     assert ev["phases"]["plan_h2d"]["bytes"] == 0
 

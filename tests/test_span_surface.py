@@ -347,11 +347,11 @@ def test_structure_build_pass_spans_on_one_device(clean_trace, annotations):
     assert "engine_init/build_structure" not in opened
     assert "ell/fill" in opened and "device_wait" in opened
     passes = [e["name"] for e in children[build[0]["span_id"]]]
-    assert passes[:2] == ["ell/fill", "ell/split_count"]
-    assert set(passes) <= {"ell/fill", "ell/split_count", "ell/split_pack",
-                           "ell/split_tail"}
+    assert passes[:2] == ["ell/fill", "ell/count"]
+    assert set(passes) <= {"ell/fill", "ell/count",
+                           "ell/stair_levels"}
     for name, at in [("ell/fill", "ell_fill"),
-                     ("ell/split_count", "ell_split_count")]:
+                     ("ell/count", "ell_count")]:
         span = next(e for e in spans if e["name"] == name)
         waits = [k for k in children[span["span_id"]]
                  if k["name"] == "device_wait"]
@@ -368,6 +368,53 @@ def test_structure_build_pass_spans_on_one_device(clean_trace, annotations):
     assert eng.timer.scope_total("build_structure", "compile") > 0.0
     assert [e["name"] for e in spans if e["cat"] == "phase"
             and e["parent_span_id"] is None][:1] == ["engine_init/transfer"]
+
+
+def test_build_span_counts_how_far_the_staircase_engages(clean_trace):
+    """``gather_slots``, ``live_entries`` and ``levels`` on the build span
+    and in the ``engine_init`` event, and the benchmark's reader of their
+    ratio (``benchmark/metrics/gather_fill_pct.py``): 16-site ring, 12,870
+    rows, one entry a domain wall."""
+    import importlib.util
+    from types import SimpleNamespace
+
+    from distributed_matvec_tpu.parallel.engine import LocalEngine
+
+    eng = LocalEngine(build_heisenberg(16, hw=8), mode="ell")
+    assert eng._ell_pos_of is not None
+    build = [e for e in obs.events("span")
+             if e["name"] == "engine_init/build_structure"]
+    assert len(build) == 1 and "ell/stair_levels" in {
+        e["name"] for e in obs.events("span")
+        if e["parent_span_id"] == build[0]["span_id"]}
+    counts = {k: build[0][k] for k in ("gather_slots", "live_entries",
+                                       "levels")}
+    assert counts == eng._ell_counts
+    assert counts["live_entries"] == 109_824    # 16 bonds x 2 x C(14, 7)
+    init = obs.events("engine_init")[-1]
+    assert {k: init[k] for k in counts} == counts
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    spec = importlib.util.spec_from_file_location(
+        "gather_fill_pct",
+        os.path.join(root, "benchmark", "metrics", "gather_fill_pct.py"))
+    reader = importlib.util.module_from_spec(spec)
+    import sys
+    sys.path.insert(0, root)
+    try:
+        spec.loader.exec_module(reader)
+    finally:
+        sys.path.remove(root)
+    run = SimpleNamespace(
+        config={"engine": {"kind": "local"}},
+        timers={"structure_build_s":
+                eng.timer.scope_total("build_structure")})
+    assert reader.read(run) == pytest.approx(
+        100.0 * counts["live_entries"] / counts["gather_slots"])
+    # a build span without the counts (the parent commit's) reads nothing
+    for key in counts:
+        del build[0][key]
+    assert reader.read(run) is None
 
 
 def test_an_eager_apply_is_one_span_and_one_annotation(clean_trace,

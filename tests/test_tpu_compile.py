@@ -38,12 +38,18 @@ HBM_BYTES = 16 * 1024 ** 3          # one TPU v5e chip
 FULL_YAML = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "data", "heisenberg_chain_32_symm.yaml")
 
-# heisenberg_chain_32_symm as LocalEngine builds it (CPU build of the engine
-# from data/heisenberg_chain_32_symm.yaml): 32 off-diagonal terms, main ELL
-# table of width 20 over the padded rows, tail of 249,601 wide rows × 12,
-# bucketed lookup with a 2^24 directory.
+# heisenberg_chain_32_symm as the engines build it (CPU build from
+# data/heisenberg_chain_32_symm.yaml): 32 off-diagonal terms and a bucketed
+# lookup with a 2^24 directory.  LocalEngine: the staircase levels
+# ``(columns, rows)``, rows ordered by non-zero count and each column cut to
+# the rows that reach it (a row's count is its domain walls: 2, 4, ... 32),
+# rounded up to 1024.  DistributedEngine: a main ELL table of width 20 over
+# the padded rows and a tail of 249,601 wide rows × 12, hash-sharded.
 N = 4_707_969
 N_PAD = 4_718_592
+LEVELS = [(6, 4_708_352), (2, 4_707_328), (2, 4_694_016), (2, 4_600_832),
+          (2, 4_224_000), (2, 3_326_976), (2, 2_030_592), (2, 878_592),
+          (2, 249_856), (2, 44_032), (2, 5_120), (6, 1_024)]
 T, T0, T_TAIL, S_TAIL = 32, 20, 12, 249_601
 CHUNK = 1 << 16
 LK_DIR, LK_SHIFT, LK_PROBES = (1 << 24) + 1, 8, 6
@@ -105,14 +111,13 @@ def _local_ell_engine(sh, pair):
     S = _shapes(sh)
     ctail = (2,) if pair else ()
     eng = object.__new__(LocalEngine)
-    eng.n_states, eng.pair, eng._dtype = N, pair, jnp.float64
-    eng._ell_T0 = T0
-    eng._ell_idx = S((T0, N_PAD), jnp.int32)
-    eng._ell_coeff = S((T0, N_PAD) + ctail, jnp.float64)
+    eng.n_states, eng.n_padded = N, N_PAD
+    eng.pair, eng._dtype = pair, jnp.float64
+    eng._ell_levels = tuple((S((k, L), jnp.int32),
+                             S((k, L) + ctail, jnp.float64))
+                            for k, L in LEVELS)
+    eng._ell_pos_of = S((N_PAD,), jnp.int32)
     eng._diag = S((N_PAD,), jnp.float64)
-    eng._ell_tail = (S((S_TAIL,), jnp.int32),
-                     S((T_TAIL, S_TAIL), jnp.int32),
-                     S((T_TAIL, S_TAIL) + ctail, jnp.float64))
     eng._make_ell_matvec()
     return eng, S((N,) + ctail, jnp.float64)
 
@@ -217,17 +222,33 @@ def test_ell_apply_compiles(one_chip, tpu_knobs, compiled, pair):
     """The LocalEngine ELL apply with triple-f32 split gathers — and its
     (re, im)-pair form, which complex momentum sectors take on a TPU."""
     if not pair:
-        _fits(compiled("ell_apply"), "ell apply")
-        return
-    eng, x = _local_ell_engine(one_chip, pair)
-    apply_fn, operands = eng.bound_matvec()
-    _fits(jax.jit(apply_fn).lower(x, operands).compile(), "ell apply")
+        exe = compiled("ell_apply")
+    else:
+        eng, x = _local_ell_engine(one_chip, pair)
+        apply_fn, operands = eng.bound_matvec()
+        exe = jax.jit(apply_fn).lower(x, operands).compile()
+    _fits(exe, "ell apply")
+    # a pair vector doubles the gathers' scratch past ``unroll_terms_ok``'s
+    # budget: its levels take the scan form, one gather a level
+    _gathers_the_staircase(exe, parts=6 if pair else 3, unrolled=not pair)
+
+
+def _gathers_the_staircase(exe, parts=3, unrolled=True):
+    """The optimised HLO gathers ``x``'s f32 parts once a table column, at
+    that column's length, and the accumulator's once at the padded rows —
+    and scatters nothing (the two-level format's tail did)."""
+    text = exe.as_text()
+    assert "scatter" not in text
+    gathered = [int(rows) for rows in re.findall(
+        rf"= f32\[(\d+),{parts}\]\S* gather\(", text)]
+    want = [L for k, L in LEVELS for _ in range(k if unrolled else 1)]
+    assert sorted(gathered) == sorted(want + [N_PAD])
 
 
 def test_structure_build_chunk_compiles(one_chip):
     """One ``ell_fill_chunk`` step: the |G| = 128 orbit scan over a 65,536-
-    row chunk, the u64 bucketed basis lookup, and the update of the donated
-    full-width tables."""
+    row chunk, the u64 bucketed basis lookup, the left-pack of the chunk's
+    rows and the update of the donated full-width tables and row counts."""
     from functools import partial
 
     from distributed_matvec_tpu.models.yaml_io import load_config_from_yaml
@@ -242,10 +263,11 @@ def test_structure_build_chunk_compiles(one_chip):
     assert tables.off.x.shape[0] == T
     assert tables.group.char_real.shape[0] == 128
     fn = jax.jit(partial(_ell_fill_chunk, shift=LK_SHIFT, probes=LK_PROBES,
-                         is_pair=False), donate_argnums=(0, 1, 2))
+                         is_pair=False), donate_argnums=(0, 1, 2, 3))
     compiled = fn.lower(
         S((T, N_PAD), jnp.int32), S((T, N_PAD), jnp.float64),
-        S((), jnp.int64), tables, S((N, 2), jnp.uint32),
+        S((N_PAD,), jnp.int32), S((), jnp.int64), tables,
+        S((N, 2), jnp.uint32),
         S((LK_DIR,), jnp.int32), S((CHUNK,), jnp.uint64),
         S((CHUNK,), jnp.float64), S((), jnp.int32)).compile()
     _fits(compiled, "ell_fill_chunk")
@@ -273,6 +295,8 @@ def test_lanczos_programs_compile(one_chip, tpu_knobs, compiled, program):
         exe = _combine_rows.lower(S((M_CAP, 1)), V).compile()
     # beside the program: the ELL tables it does not take as arguments
     assert _fits(exe, f"lanczos {program}") + 1.2e9 < HBM_BYTES
+    if program in ("window", "full"):
+        _gathers_the_staircase(exe)
 
 
 def test_distributed_ell_apply_compiles_on_four_devices(tpu_knobs, compiled):
@@ -292,8 +316,9 @@ def test_distributed_ell_apply_compiles_on_four_devices(tpu_knobs, compiled):
 # ``metadata={op_name="jit(f)/.../<scope>/<primitive>"}``.  A device trace
 # carries that string per operation (stat ``tf_op``), so the scopes are how
 # a trace tells the phases of an apply and of an iteration apart.
-APPLY_SCOPES = ["apply/split", "apply/diag", "apply/terms", "apply/tail"]
-EXCHANGE_SCOPES = ["apply/pack", "apply/exchange"]
+APPLY_SCOPES = ["apply/split", "apply/diag", "apply/terms"]
+LOCAL_SCOPES = APPLY_SCOPES + ["apply/unpermute"]
+EXCHANGE_SCOPES = ["apply/pack", "apply/exchange", "apply/tail"]
 LANCZOS_SCOPES = ["lanczos/apply", "lanczos/reorth", "lanczos/recurrence",
                   "lanczos/store"]
 _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s.*?[\w\-]+\(",
@@ -301,10 +326,10 @@ _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s.*?[\w\-]+\(",
 
 
 @pytest.mark.parametrize("program, scopes", [
-    ("ell_apply", APPLY_SCOPES),
+    ("ell_apply", LOCAL_SCOPES),
     ("distributed_apply", APPLY_SCOPES + EXCHANGE_SCOPES),
-    ("window", LANCZOS_SCOPES + APPLY_SCOPES),
-    ("full", LANCZOS_SCOPES + APPLY_SCOPES),
+    ("window", LANCZOS_SCOPES + LOCAL_SCOPES),
+    ("full", LANCZOS_SCOPES + LOCAL_SCOPES),
 ], ids=["ell_apply", "distributed_apply", "window", "full"])
 def test_named_scopes_reach_the_tpu_hlo(topo, tpu_knobs, compiled,
                                         monkeypatch, program, scopes):

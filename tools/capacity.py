@@ -80,8 +80,9 @@ def load_snapshot(path: str) -> dict:
 
 def load_structure(path: str) -> dict:
     """Table geometry straight from a structure sidecar (h5).  Handles
-    both the LocalEngine layout (``idx``/``coeff`` datasets) and the
-    DistributedEngine per-shard layout (``idx_<d>``/``coeff_<d>``)."""
+    the LocalEngine layouts (ell: ``level<i>_idx``/``level<i>_coeff``;
+    compact: ``idx``) and the DistributedEngine per-shard layout
+    (``idx_<d>``/``coeff_<d>``)."""
     import h5py
 
     with h5py.File(path, "r") as f:
@@ -91,14 +92,26 @@ def load_structure(path: str) -> dict:
         mode = str(g.attrs.get("mode", "ell"))
         idx_keys = [k for k in g
                     if k == "idx" or k.startswith("idx_")]
-        if not idx_keys:
+        level_keys = [k for k in g
+                      if k.startswith("level") and k.endswith("_idx")]
+        if not idx_keys and not level_keys:
             raise ValueError(f"{path}: no idx table in the sidecar")
-        T0 = int(g.attrs.get("T0", g[idx_keys[0]].shape[0]))
-        # local: one [T0, N_pad] table; distributed: [T0, M] per shard
-        n_pad = sum(int(g[k].shape[-1]) for k in idx_keys)
+        if level_keys:
+            # LocalEngine ell: levels [k, L] and, where the rows are
+            # ordered by width, ``pos_of`` over the padded rows (else the
+            # one level is that long); T0 is the mean width
+            n_pad = int(g["pos_of"].shape[0]) if "pos_of" in g \
+                else max(int(g[k].shape[-1]) for k in level_keys)
+            slots = sum(int(g[k].size) for k in level_keys)
+            T0 = -(-slots // max(n_pad, 1))
+        else:
+            T0 = int(g.attrs.get("T0", g[idx_keys[0]].shape[0]))
+            # local: one [T0, N_pad] table; distributed: [T0, M] per shard
+            n_pad = sum(int(g[k].shape[-1]) for k in idx_keys)
         table_bytes = sum(int(g[k].size) * g[k].dtype.itemsize for k in g)
         coeff_keys = [k for k in g
-                      if k == "coeff" or k.startswith("coeff_")]
+                      if k == "coeff" or k.startswith("coeff_")
+                      or (k.startswith("level") and k.endswith("_coeff"))]
         pair = cplx = False
         if coeff_keys:
             c = g[coeff_keys[0]]

@@ -1,8 +1,9 @@
 #!/usr/bin/env python
 """Measure the TPU gather roofline that bounds the ELL matvec.
 
-The symmetry-adapted SpMV is index-rate-bound: each of the ~N·T0 ELL
-entries costs one row gather of a [., 3] triple-f32 row (the exact f64
+The symmetry-adapted SpMV is index-rate-bound: each ELL table slot (one a
+non-zero in the staircase levels, ``LocalEngine._ell_counts``) costs one
+row gather of a [., 3] triple-f32 row (the exact f64
 split, ops/split_gather.py).  This script measures, on the current backend:
 
   1. the raw row-gather rate vs table size, index locality, and row width;
@@ -22,8 +23,8 @@ attached chip; leads to re-measure, not current results — ROADMAP S3):
     single-vreg (8×128) source ("Multiple source vregs along gather
     dimension" is unimplemented), so no VMEM-blocked gather kernel exists
     on this generation;
-  * chain_32_symm (N=4 707 969, T0=20 + tail): gathers alone are ~593 ms
-    of the ~660 ms apply — the engine runs at ≈93% of the gather roofline;
+  * chain_32_symm (N=4 707 969, then a width-20 table + tail): gathers
+    alone are ~593 ms of the ~660 ms apply — the engine runs at ≈93% of the gather roofline;
     coefficient streams + f64 multiply-accumulate add only ~20 ms.
 
 Usage: python tools/gather_bound.py [--full]   (--full includes the
@@ -126,7 +127,8 @@ def engine_breakdown():
     print("building chain_32_symm basis + engine (minutes)...", flush=True)
     basis.build()
     eng = LocalEngine(op, mode="ell")
-    N, Npad, T0 = eng.n_states, eng.n_padded, eng._ell_T0
+    N, Npad = eng.n_states, eng.n_padded
+    counts = eng._ell_counts
     x = jnp.asarray(np.random.default_rng(0).standard_normal(N))
     x = x / jnp.linalg.norm(x)
     apply_fn, operands = eng.bound_matvec()
@@ -139,11 +141,18 @@ def engine_breakdown():
     full = _time_chain(jax.jit(chain_full), x, operands)
 
     def gathers_only(x, ops):
-        idx = ops[0]
+        # every table slot's x-row gather, level by level as the engine
+        # walks them (shortest first), and the gather back to basis order
+        levels, pos_of, _ = ops
         xs = split_parts(x)
-        acc = jnp.zeros((Npad, 3), jnp.float32)
-        for t in range(T0):
-            acc = acc + xs[idx[t]]
+        acc = jnp.zeros((0, 3), jnp.float32)
+        for idx, _ in reversed(levels):
+            acc = jnp.pad(acc, ((0, idx.shape[1] - acc.shape[0]), (0, 0)))
+            for t in range(idx.shape[0]):
+                acc = acc + xs[idx[t]]
+        acc = jnp.pad(acc, ((0, max(Npad - acc.shape[0], 0)), (0, 0)))
+        if pos_of is not None:
+            acc = acc[pos_of]
         return acc.sum(axis=-1).astype(jnp.float64)
 
     def chain_g(x, ops):
@@ -152,13 +161,14 @@ def engine_breakdown():
         return x
 
     g_only = _time_chain(jax.jit(chain_g), x, operands)
-    n_gathers = Npad * T0
-    out = {"config": "chain_32_symm", "n_states": int(N), "T0": int(T0),
+    n_gathers = counts["gather_slots"]
+    out = {"config": "chain_32_symm", "n_states": int(N), **counts,
            "full_ms": round(full * 1e3, 3),
            "gathers_only_ms": round(g_only * 1e3, 3),
            "engine_rows_per_s": n_gathers / g_only,
            "gather_share": g_only / full}
-    print(f"chain_32_symm: N={N} T0={T0}  full {full*1e3:.0f} ms, "
+    print(f"chain_32_symm: N={N} slots={n_gathers} "
+          f"levels={counts['levels']}  full {full*1e3:.0f} ms, "
           f"gathers-only {g_only*1e3:.0f} ms "
           f"({n_gathers/g_only/1e6:.0f} M rows/s; engine at "
           f"{100*g_only/full:.0f}% gather share)")
