@@ -94,6 +94,7 @@ __all__ = [
     "trace_id",
     "job_id",
     "span",
+    "current_span",
     "current_span_id",
     "open_spans",
     "deepest_span",
@@ -353,6 +354,13 @@ def job_scope(jid: Optional[str]):
     if not trace_enabled() or not jid:
         return nullcontext()
     return _job_scope_cm(str(jid))
+
+
+def current_span():
+    """The innermost open span's handle (``add(**counts)``), or the null
+    handle when none is open or tracing is off."""
+    with _lock:
+        return _stack[-1] if _stack else NULL_SPAN
 
 
 def current_span_id() -> Optional[str]:

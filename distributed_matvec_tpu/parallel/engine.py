@@ -404,6 +404,7 @@ def emit_engine_init(eng, engine_kind: str, init_s: Optional[float] = None
          transfer_s=round(t.scope_total("transfer"), 6),
          diag_s=round(t.scope_total("diag"), 6),
          **getattr(eng, "_ell_counts", {}),
+         **getattr(eng, "_ell_form", {}),
          **({} if init_s is None else {"init_s": round(init_s, 6)}))
 
 
@@ -610,15 +611,23 @@ def check_complex_backend(effective_is_real: bool,
 
 
 def unroll_terms_ok(width: int, rows: int, x_shape=()) -> bool:
-    """Whether the per-term gather loop should be Python-unrolled.
+    """Whether a per-term gather loop should be Python-unrolled: the rule
+    of ``compact`` mode and of ``DistributedEngine``'s term loops, neither
+    of which has run on the chip in the other form.  (``LocalEngine``'s
+    ELL levels no longer ask: :func:`ell_term_loop`.)
 
-    Unrolling lets XLA schedule ALL term gathers concurrently — fastest, but
-    peak scratch is ≈ width·rows·vec_width·20 B of live gather outputs
-    (observed: a T0=40, N=15.9M table ran the matvec program to 11.9 GB and
-    OOM'd 16 GB HBM).  ``vec_width``, derived from ``x_shape``'s trailing
-    axes, covers batch columns and the (re, im) pair axis — both scale
-    every gather output.  Beyond ~2 GB of estimated scratch, ``lax.scan``
-    serializes the terms: same math, one term's scratch at a time.
+    Unrolled, every column's gather is an operation of its own and the
+    compiler may keep all their outputs live at once; under ``lax.scan``
+    the columns go one step at a time.  The unrolled form's scratch is
+    estimated at width·rows·vec_width·20 B (``vec_width``, from
+    ``x_shape``'s trailing axes, covers batch columns and the (re, im) pair
+    axis: both scale every gather output); past 2 GB of it the scan form
+    is taken.  Against the staircase levels on a v5e the estimate reads
+    about twice the compiler's own count for one apply (2.70 GB estimated,
+    1.45 GB of temporaries at square_5x5) and a fraction of it where the
+    apply is traced into a Lanczos block program (9.47 GB unrolled against
+    5.98 scanned in the full-sweep block: my compiles for a described v5e,
+    PR 28).
     """
     from ..utils.config import get_config
 
@@ -629,6 +638,36 @@ def unroll_terms_ok(width: int, rows: int, x_shape=()) -> bool:
     if form == "unroll":
         return width <= 64
     return width <= 64 and width * rows * vec_width * 20 <= 2_000_000_000
+
+
+def ell_term_loop(levels):
+    """``(unroll, counts)`` for an apply of ``LocalEngine``'s ELL ``levels``
+    (``(idx, coeff)`` a level): a ``lax.scan`` over each level's columns,
+    unless the ``term_loop`` test hook says ``unroll``.  ``counts`` says
+    which form the columns take: ``unrolled_columns`` and
+    ``scanned_columns``, one of them 0.
+
+    Both forms ran on a v5e at the benchmark's two Hamiltonians (my chip
+    runs, PR 28; PERF.md §6).  On the device they are the same gathers at
+    the same times; a coefficient row reaches its multiply by a dynamic
+    slice a step where the unrolled form cuts one static slice a level,
+    and the scan's is the cheaper: ``apply_device_ms`` 879.17 scanned
+    against 880.86 unrolled at square_5x5 (40 columns in 14 levels), 509.52
+    against 511.09 at chain_32_symm (26 in 12).  A level is one traced
+    gather where unrolled it is one a column, so a solver's block programs
+    build faster: the device idles 0.47 s under the dispatch that builds
+    the Lanczos window program against 1.84 s, and a whole chain_32_symm
+    solve reads ``lanczos_iter_ms`` 670.3 against 700.7 (-4.3%), square_5x5
+    1,086.9 against 1,120.1.  The scan form also needs the less memory
+    (``peak_hbm_gb`` 9.4106 against 9.4222 in the chain's solve).  So there
+    is nothing for an estimate to trade off on this chip.
+    """
+    from ..utils.config import get_config
+
+    width = sum(idx.shape[0] for idx, _ in levels)
+    unroll = get_config().term_loop == "unroll" and width <= 64
+    return unroll, {"unrolled_columns": width if unroll else 0,
+                    "scanned_columns": 0 if unroll else width}
 
 
 def hash_basis_operator(h, operator, include_arrays: bool = True) -> None:
@@ -912,8 +951,12 @@ class LocalEngine:
                 for i in range(int(data["levels"])))
             self._ell_pos_of = jnp.asarray(data["pos_of"]) \
                 if "pos_of" in data else None
-            self._ell_counts = {k: int(data[k]) for k in
-                                ("gather_slots", "live_entries", "levels")}
+            self._ell_counts = {
+                **{k: int(data[k]) for k in
+                   ("gather_slots", "live_entries", "levels")},
+                "terms": self.num_terms,
+                "widest_row": sum(int(idx.shape[0])
+                                  for idx, _ in self._ell_levels)}
         else:
             self._ell_T0 = int(data["T0"])
             self._c_W = float(data["W"])
@@ -1044,13 +1087,17 @@ class LocalEngine:
     def _plan_levels(self, hist: np.ndarray):
         """``staircase_levels`` of the build's histogram, and the counts
         that say how far the format engages (``_ell_counts``: on the build
-        span and in the ``engine_init`` event)."""
+        span and in the ``engine_init`` event; ``terms`` is the build
+        table's width, a slot an off-diagonal term, ``widest_row`` the
+        columns the levels keep)."""
         stair, levels = staircase_levels(hist, self.n_padded)
         slots = sum(k * L for _, k, L in levels)
         self._ell_counts = {
             "gather_slots": slots + (self.n_padded if stair else 0),
             "live_entries": int(np.dot(np.arange(hist.size), hist)),
-            "levels": len(levels)}
+            "levels": len(levels),
+            "terms": self.num_terms,
+            "widest_row": sum(k for _, k, _ in levels)}
         log_debug(f"ell levels: T={self.num_terms} stair={stair} "
                   f"levels={levels} entries {self.n_padded * self.num_terms}"
                   f" -> {slots}")
@@ -1399,12 +1446,10 @@ class LocalEngine:
             # operation is added, moved or split for them
             with jax.named_scope("apply/split"):
                 gx = prep_gather(x, dtype, use_sg)
-            # one verdict for the whole apply: the levels' gathers do not
-            # depend on each other, so unrolled they can all be live at once
-            slots = sum(idx.shape[0] * idx.shape[1] for idx, _ in levels)
-            width = sum(idx.shape[0] for idx, _ in levels)
-            unroll = unroll_terms_ok(width, -(-slots // max(width, 1)),
-                                     x.shape)
+            unroll, form = ell_term_loop(levels)
+            # on the span this trace runs under (``apply``, or the solver's
+            # ``lanczos/dispatch``): which form the program it builds takes
+            obs_trace.current_span().add(**form)
 
             def contrib(c, g):
                 # c: per-row coefficient [rows(, 2)]; g: gathered x rows
@@ -1442,6 +1487,8 @@ class LocalEngine:
 
         self._apply_fn = apply_fn
         self._operands = (self._ell_levels, self._ell_pos_of, self._diag)
+        #: the form the apply takes (``engine_init`` event)
+        self._ell_form = ell_term_loop(self._ell_levels)[1]
         _mv = jax.jit(apply_fn)
         return lambda x: _mv(x, self._operands)
 

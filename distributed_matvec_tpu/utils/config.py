@@ -176,12 +176,14 @@ class RuntimeConfig:
     #   ones.  DMT_TUNE_WINDOW overrides the live update window (8)
     split_gather: str = "auto"             # triple-f32 gathers: auto | on | off
     #   (auto = on for the TPU backend; see ops/split_gather.py)
-    term_loop: str = "auto"                # ELL/compact per-term loop form:
-    #   auto (unroll until the estimated gather scratch would exceed ~2 GB,
-    #   then lax.scan — see engine.unroll_terms_ok) | scan (force the
-    #   serialized low-memory form everywhere) | unroll (force concurrent
-    #   gathers whenever width permits).  "scan" lets small configs exercise
-    #   the large-T0 code path the big bases take.
+    term_loop: str = "auto"                # ELL/compact per-term loop form
+    #   (a test hook): auto (LocalEngine's ELL levels: lax.scan, the form
+    #   that is no slower on the chip and builds faster into a solver's
+    #   programs — engine.ell_term_loop; compact mode and DistributedEngine:
+    #   unrolled until the estimated gather scratch would exceed ~2 GB, then
+    #   lax.scan — engine.unroll_terms_ok) | scan (force the serialized
+    #   form everywhere) | unroll (force one gather a column wherever the
+    #   width permits), so that tests exercise both forms at small sizes.
     complex_pair: str = "auto"             # (re,im)-f64 pair engines for
     #   complex sectors: auto | on | off.  auto = pair form on the TPU
     #   backend (whose compiler refuses complex128 — see below),

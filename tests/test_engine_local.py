@@ -174,7 +174,7 @@ def _apply_primitives(eng, x):
     return _primitives(jax.make_jaxpr(apply_fn)(x, operands).jaxpr)
 
 
-@pytest.mark.parametrize("term_loop", ["auto", "scan"])
+@pytest.mark.parametrize("term_loop", ["auto", "unroll"])
 @pytest.mark.parametrize("name", list(STAIR_RINGS))
 def test_staircase_matches_independent_reference(name, term_loop, rng,
                                                  pair_form):
@@ -182,7 +182,8 @@ def test_staircase_matches_independent_reference(name, term_loop, rng,
     column length, the result gathered back to basis order — against the
     independent reference: a plain ring, a fully symmetric one and a
     complex momentum sector in pair form; one vector and a batch; padded
-    rows beyond the basis (batch_size 1000); unrolled and scan term loops."""
+    rows beyond the basis (batch_size 1000); the scan term loop (``auto``)
+    and the unrolled one (the test hook)."""
     from distributed_matvec_tpu.utils.config import update_config
 
     update_config(term_loop=term_loop)
@@ -223,7 +224,8 @@ def test_staircase_accounting(rng):
     assert eng._ell_counts == {
         "gather_slots": int(lengths.sum()) + n_pad,
         "live_entries": int(nnz.sum()),
-        "levels": len(set(lengths))}
+        "levels": len(set(lengths)),
+        "terms": 16, "widest_row": Tmax}
     # the level arrays are those columns, longest first, and nothing else
     assert [i.shape for i, _ in eng._ell_levels] == \
         [(int((lengths == L).sum()), int(L))
@@ -249,7 +251,7 @@ def test_staircase_accounting(rng):
     assert counts["accumulate"]["gathers"] == n_pad
 
 
-@pytest.mark.parametrize("term_loop", ["auto", "scan"])
+@pytest.mark.parametrize("term_loop", ["auto", "unroll"])
 def test_staircase_apply_has_no_scatter(term_loop):
     """One gather a table column and one back to basis order; no scatter
     (the two-level format's tail paid one), in either term-loop form."""
@@ -262,10 +264,11 @@ def test_staircase_apply_has_no_scatter(term_loop):
     finally:
         update_config(term_loop="auto")
     assert not [p for p in prims if "scatter" in p]
-    # unrolled: a gather a column + the un-permute; scan: one a level
+    # scan (``auto``): a gather a level + the un-permute; unrolled: one a
+    # column
     width = sum(i.shape[0] for i, _ in eng._ell_levels)
     assert prims.count("gather") == \
-        (width if term_loop == "auto" else len(eng._ell_levels)) + 1
+        (width if term_loop == "unroll" else len(eng._ell_levels)) + 1
 
 
 def test_equal_width_rows_keep_plain_table(rng):
@@ -282,10 +285,12 @@ def test_equal_width_rows_keep_plain_table(rng):
     assert eng._ell_pos_of is None
     assert [i.shape for i, _ in eng._ell_levels] == [(n, eng.n_padded)]
     assert eng._ell_counts == {"gather_slots": n * eng.n_padded,
-                               "live_entries": n * 2 ** n, "levels": 1}
+                               "live_entries": n * 2 ** n, "levels": 1,
+                               "terms": n, "widest_row": n}
     x = rng.random(2 ** n) - 0.5
     prims = _apply_primitives(eng, x)
-    assert prims.count("gather") == n and "scatter-add" not in prims
+    # one level, its columns scanned: one gather, and none to un-permute
+    assert prims.count("gather") == 1 and "scatter-add" not in prims
     np.testing.assert_allclose(np.asarray(eng.matvec(x)), op.matvec_host(x),
                                atol=1e-13, rtol=1e-12)
 

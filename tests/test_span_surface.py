@@ -388,8 +388,9 @@ def test_build_span_counts_how_far_the_staircase_engages(clean_trace):
         e["name"] for e in obs.events("span")
         if e["parent_span_id"] == build[0]["span_id"]}
     counts = {k: build[0][k] for k in ("gather_slots", "live_entries",
-                                       "levels")}
+                                       "levels", "terms", "widest_row")}
     assert counts == eng._ell_counts
+    assert (counts["terms"], counts["widest_row"]) == (16, 16)
     assert counts["live_entries"] == 109_824    # 16 bonds x 2 x C(14, 7)
     init = obs.events("engine_init")[-1]
     assert {k: init[k] for k in counts} == counts

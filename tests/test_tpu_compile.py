@@ -228,21 +228,19 @@ def test_ell_apply_compiles(one_chip, tpu_knobs, compiled, pair):
         apply_fn, operands = eng.bound_matvec()
         exe = jax.jit(apply_fn).lower(x, operands).compile()
     _fits(exe, "ell apply")
-    # a pair vector doubles the gathers' scratch past ``unroll_terms_ok``'s
-    # budget: its levels take the scan form, one gather a level
-    _gathers_the_staircase(exe, parts=6 if pair else 3, unrolled=not pair)
+    _gathers_the_staircase(exe, parts=6 if pair else 3)
 
 
-def _gathers_the_staircase(exe, parts=3, unrolled=True):
-    """The optimised HLO gathers ``x``'s f32 parts once a table column, at
-    that column's length, and the accumulator's once at the padded rows —
-    and scatters nothing (the two-level format's tail did)."""
+def _gathers_the_staircase(exe, parts=3):
+    """The optimised HLO gathers ``x``'s f32 parts once a level (the body
+    of the ``lax.scan`` over the level's columns), at that level's length,
+    and the accumulator's once at the padded rows — and scatters nothing
+    (the two-level format's tail did)."""
     text = exe.as_text()
     assert "scatter" not in text
     gathered = [int(rows) for rows in re.findall(
         rf"= f32\[(\d+),{parts}\]\S* gather\(", text)]
-    want = [L for k, L in LEVELS for _ in range(k if unrolled else 1)]
-    assert sorted(gathered) == sorted(want + [N_PAD])
+    assert sorted(gathered) == sorted([L for _, L in LEVELS] + [N_PAD])
 
 
 def test_structure_build_chunk_compiles(one_chip):
