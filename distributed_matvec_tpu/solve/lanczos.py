@@ -132,6 +132,39 @@ class _Watchdog:
 _GS_BLOCK = 8
 
 
+def _omega_row(xp, w, wp, alph, bet, j, eps):
+    """Row j+1 of the ω table (Paige's recurrence; ω_{j,i} ≈ ⟨v_j, v_i⟩)
+    from rows j (``w``) and j−1 (``wp``) and the recurrence's (α, β) through
+    step j, and the largest |ω_{j+1,i}| over i < j, the estimate the √ε gate
+    reads.  Rows are padded to one fixed length (``mcap + 1``; entries past
+    the diagonal are zero), so that this one function is the host's step
+    (``xp`` = numpy, in :class:`_OmegaTracker`) and the device's (``xp`` =
+    ``jax.numpy`` with a traced ``j``, in the window program): they decide
+    the same crossing from the same arithmetic.
+
+    SIGNED arithmetic, exactly the Paige recurrence — an absolute-value
+    upper bound compounds ~(Σβ)/β per step and saturates √ε within one
+    16-step block, forcing a full sweep every other block (measured:
+    the whole selective win evaporates); the signed form keeps the
+    cancellation that makes real loss grow only as Ritz pairs converge.
+    """
+    zero = xp.zeros(1, w.dtype)
+    a = xp.concatenate([alph, zero])
+    b = xp.concatenate([bet, zero])
+    i = xp.arange(w.shape[0])
+    bj = xp.maximum(b[j], 1e-300)
+    up = b * xp.concatenate([w[1:], zero])            # β_i·ω_{j,i+1}
+    mid = (a - a[j]) * w
+    dn = xp.concatenate([zero, (b * w)[:-1]])         # β_{i−1}·ω_{j,i−1}
+    back = b[j - 1] * wp
+    # ϑ ≈ ε(β_i + β_j): the local roundoff injected per step
+    row = (up + mid + dn - back + eps * (b + bj)) / bj
+    # fresh adjacent pair (ψ term) at i = j, the diagonal at j + 1
+    new = xp.where(i < j, row, xp.where(
+        i == j, eps, xp.where(i == j + 1, 1.0, 0.0)))
+    return new, xp.max(xp.where(i < j, xp.abs(row), 0.0))
+
+
 class _OmegaTracker:
     """Accumulated ω-recurrence (Paige/Simon) across selective-reorth blocks.
 
@@ -143,13 +176,35 @@ class _OmegaTracker:
     (max ω ≤ √ε, Simon '84) is lost.  A full-reorth block (or a thick
     restart, which rebuilds the basis from Ritz combinations) resets the
     table to roundoff via :meth:`reset`.
+
+    The host's table is the authority on which steps are kept; the window
+    program evolves a copy of the two rows (:func:`_omega_row`) only to
+    know when to stop working.
     """
 
-    def __init__(self, eps: float = 2.0 ** -52):
+    def __init__(self, mcap: int, eps: float = 2.0 ** -52):
         self.eps = eps
-        self.reset(0)
+        self.rows = int(mcap) + 1
+        self.limit = obs_health.OMEGA_WARN     # √ε — Simon's bound
+        self.m = 0
+        self.w_curr = self._roundoff_row(0)
+        self.w_prev = self._roundoff_row(-1)
+
+    @property
+    def state(self):
+        """What the window program carries: the two rows, and the ε and the
+        limit it evolves and reads them with."""
+        return self.w_curr, self.w_prev, self.eps, self.limit
 
     def reset(self, m: int) -> None:
+        """The basis was just (re)orthogonalized by full sweeps up to row
+        ``m``: row m restarts at roundoff, and so does row m−1 — unless
+        only ONE vector was swept since the row this tracker stands at.
+        Simon's scheme needs two consecutive fully reorthogonalized vectors
+        before the table may be called roundoff (the recurrence reads both
+        rows), so after a single full-sweep step the true row m−1 stays."""
+        prev = self.w_curr if int(m) == self.m + 1 \
+            else self._roundoff_row(int(m) - 1)
         self.m = int(m)
         # w_curr[i] = ω_{m,i} for i <= m (1 on the diagonal); w_prev the
         # m-1 row.  Baseline ε: the basis was just (re)orthogonalized.
@@ -158,47 +213,35 @@ class _OmegaTracker:
         # i = j−1, and an ε there instead of 1 leaves an O(β/β) ~ O(1)
         # residue that falsely trips the √ε gate on the first window block
         # after every full sweep.
-        self.w_curr = np.full(self.m + 1, self.eps)
-        self.w_curr[-1] = 1.0
-        self.w_prev = np.full(max(self.m, 1), self.eps)
-        if self.m >= 1:
-            self.w_prev[-1] = 1.0
+        self.w_curr = self._roundoff_row(self.m)
+        self.w_prev = prev
+
+    def _roundoff_row(self, m: int) -> np.ndarray:
+        row = np.zeros(self.rows)
+        if m >= 0:
+            row[:m] = self.eps
+            row[m] = 1.0
+        return row
 
     def advance(self, alph: np.ndarray, bet: np.ndarray, m_new: int
                 ) -> float:
-        """Evolve the table through steps ``self.m .. m_new-1`` using the
-        recorded (α, β) and return the max off-pair estimate at m_new.
-
-        SIGNED arithmetic, exactly the Paige recurrence — an absolute-value
-        upper bound compounds ~(Σβ)/β per step and saturates √ε within one
-        16-step block, forcing a full sweep every other block (measured:
-        the whole selective win evaporates); the signed form keeps the
-        cancellation that makes real loss grow only as Ritz pairs converge.
-        """
+        """Evolve the table step by step through ``self.m .. m_new-1`` over
+        the recorded (α, β) and STOP before the first step whose row
+        reaches the limit: that step's new vector is not semiorthogonal,
+        the ones before it are.  ``self.m`` then stands at that step (at
+        ``m_new`` when none crossed); returned is the largest estimate
+        seen, the crossing row's included."""
         a = np.asarray(alph, np.float64)
         b = np.asarray(bet, np.float64)
         worst = 0.0
         for j in range(self.m, int(m_new)):
-            bj = max(float(b[j]), 1e-300)
-            w, wp = self.w_curr, self.w_prev
-            new = np.empty(j + 2)
-            if j:
-                i = np.arange(j)
-                up = b[i] * w[i + 1]
-                mid = (a[i] - a[j]) * w[i]
-                dn = np.zeros(j)
-                dn[1:] = b[i[1:] - 1] * w[i[1:] - 1]
-                back = b[j - 1] * wp[i]
-                # ϑ ≈ ε(β_i + β_j): the local roundoff injected per step
-                new[:j] = (up + mid + dn - back
-                           + self.eps * (b[i] + bj)) / bj
-            new[j] = self.eps          # fresh adjacent pair (ψ term)
-            new[j + 1] = 1.0
-            self.w_prev = w
-            self.w_curr = new
-            if j:
-                worst = max(worst, float(np.max(np.abs(new[:j]))))
-        self.m = int(m_new)
+            new, row_max = _omega_row(np, self.w_curr, self.w_prev, a, b, j,
+                                      self.eps)
+            worst = max(worst, float(row_max))
+            if not row_max < self.limit:
+                break
+            self.w_prev, self.w_curr = self.w_curr, new
+            self.m = j + 1
         return worst
 
 
@@ -712,7 +755,9 @@ def _make_block_runner(mv, mcap, shape, dtype, n_reorth, pair=False):
 
     State: V [_buffer_rows, *shape] basis buffer (donated), alph/bet [mcap]
     f64.  Each iteration: w = H·V[m]; α = ⟨v, w⟩; ``n_reorth`` passes of
-    blocked MGS against the live rows; β = ‖w‖; V[m+1] = w/β.
+    blocked MGS against the live rows; β = ‖w‖; V[m+1] = w/β.  Returns the
+    state and the number of steps it ran (its trip count: the loop has no
+    other exit), as the window program does.
 
     ``pair=True`` marks (re, im)-f64 pair vectors (trailing axis 2, the
     TPU-safe complex form).  The realified operator commutes with
@@ -788,30 +833,50 @@ def _make_block_runner(mv, mcap, shape, dtype, n_reorth, pair=False):
                 bet = bet.at[m].set(b)
             return V, alph, bet
 
-        return jax.lax.fori_loop(0, nsteps, body, (V, alph, bet))
+        return jax.lax.fori_loop(0, nsteps, body, (V, alph, bet)) + (nsteps,)
 
     return run_block
 
 
 def _make_window_runner(mv, mcap, shape, dtype, n_reorth, nsteps,
                         pair=False):
-    """Selective-reorthogonalization block: ``nsteps`` iterations whose MGS
-    passes project only against the trailing ``W_ROWS`` rows.
+    """Selective-reorthogonalization block: up to ``nsteps`` iterations
+    whose MGS passes project only against the trailing ``W_ROWS`` rows, and
+    which stops working at the step where the ω estimate crosses √ε.
+
+    The program carries the host tracker's ``state`` (``omega``: its two
+    ω rows, padded to ``mcap + 1``, its ε and its limit) and evolves the
+    rows after each step with the host's own arithmetic
+    (:func:`_omega_row`).  Once a step's row has reached the limit no
+    further apply runs: everything after that step would be thrown away by
+    the host loop, which runs the rest of the block under the full sweep.
+    Returned beside ``V``, ``alph``, ``bet`` is the number of steps that
+    ran, the crossing one included.  The estimate only decides when the
+    device stops working; what is kept is the host's decision, from the
+    (α, β) that come back.
 
     Structured around a SMALL ring buffer, not the big V carry: the full
-    runner's ``fori_loop`` carries the whole [_buffer_rows, N] basis and
-    XLA's CPU runtime copies that carry on every iteration (measured 28
-    ms/iter for chain_20's 83 MB buffer — a floor that swallowed the whole
-    selective win).  Here the loop carries only the [W_ROWS, N] window,
-    ``lax.scan`` stacks the new vectors in place, and the basis buffer is
-    written ONCE per block — the per-iteration traffic drops from O(mcap·N)
-    to O(window·N).  ``nsteps`` is a compile-time constant (scan needs a
-    static length); a solve sees at most a handful of distinct block
-    lengths, each compiled once.
+    runner's ``fori_loop`` carries the whole [_buffer_rows, N] basis, which
+    it reads and writes, and XLA's CPU runtime copies that carry on every
+    iteration (measured 28 ms/iter for chain_20's 83 MB buffer — a floor
+    that swallowed the whole selective win).  Here the loop carries only
+    the [W_ROWS, N] window, ``lax.scan`` stacks the new vectors in place,
+    and the basis buffer is written ONCE per block — the per-iteration
+    traffic drops from O(mcap·N) to O(window·N).  ``nsteps`` is a
+    compile-time constant (scan needs a static length); a solve sees at
+    most a handful of distinct block lengths, each compiled once.  The
+    early end is a ``lax.cond`` on the carried flag inside the scan's step:
+    after the crossing the remaining trips do nothing (and stack zero rows
+    above the live ones, which the full sweep overwrites).  The other form,
+    a ``lax.while_loop`` on ``(i < nsteps) & ~crossed`` that writes each
+    new row into the carried basis buffer (written there, never read, and
+    NOT copied per iteration by the CPU runtime: 1.4 ms a step against
+    this form's 1.5 at chain_20's size), measured 1-2% slower end to end on
+    a v5e at both one-chip cells (PERF.md, PR 29).
 
     The ω-gated host loop guarantees the window is enough: whenever the
-    accumulated orthogonality estimate threatens √ε, the next block runs
-    the full sweep via :func:`_make_block_runner`."""
+    accumulated orthogonality estimate reaches √ε, the rest of the block
+    runs the full sweep via :func:`_make_block_runner`."""
     nflat = int(np.prod(shape))
     nrows = _buffer_rows(mcap)
     # the trailing window: v_m and v_{m-1} (the recurrence pair) plus two
@@ -829,7 +894,8 @@ def _make_window_runner(mv, mcap, shape, dtype, n_reorth, nsteps,
                          axis=-1).reshape(A.shape)
 
     @partial(jax.jit, donate_argnums=(0, 1, 2))
-    def run_window(V, alph, bet, m0, operands):
+    def run_window(V, alph, bet, m0, omega, operands):
+        om_curr, om_prev, eps, limit = omega
         Vf = V.reshape(nrows, nflat)
         r0 = jnp.maximum(m0 - (W_ROWS - 1), 0)
         W = jax.lax.dynamic_slice(
@@ -846,7 +912,9 @@ def _make_window_runner(mv, mcap, shape, dtype, n_reorth, nsteps,
             c = jnp.sum(Vb.conj() * wf[None, :], axis=1)
             return wf - jnp.sum(c[:, None] * Vb, axis=0)
 
-        def step(W, _i):
+        def work(carry):
+            ran, _, W, alph, bet, om, omp = carry
+            m = m0 + ran
             vm = W[W_ROWS - 1]
             with jax.named_scope("lanczos/apply"):
                 w = mv(vm.reshape(shape), operands)
@@ -862,16 +930,25 @@ def _make_window_runner(mv, mcap, shape, dtype, n_reorth, nsteps,
                 b = jnp.sqrt(jnp.real(_vdot(wf, wf)))
                 vnew = (wf / jnp.where(b <= 1e-300, 1.0, b)).astype(dtype)
                 W = jnp.concatenate([W[1:], vnew[None]], axis=0)
-            return W, (vnew, a, b)
+            with jax.named_scope("lanczos/store"):
+                alph = alph.at[m].set(a)
+                bet = bet.at[m].set(b)
+            with jax.named_scope("lanczos/omega"):
+                new, worst = _omega_row(jnp, om, omp, alph, bet, m, eps)
+            # a NaN row counts as crossed, as on the host
+            return (ran + 1, ~(worst < limit), W, alph, bet, new, om), vnew
 
-        _, (Vnew, a_blk, b_blk) = jax.lax.scan(
-            step, W, jnp.arange(nsteps))
+        def rest(carry):
+            return carry, jnp.zeros((nflat,), dtype)
+
+        (ran, _, _, alph, bet, _, _), Vnew = jax.lax.scan(
+            lambda carry, _: jax.lax.cond(carry[1], rest, work, carry),
+            (jnp.int32(0), jnp.bool_(False), W, alph, bet, om_curr, om_prev),
+            None, length=nsteps)
         with jax.named_scope("lanczos/store"):
             Vf = jax.lax.dynamic_update_slice(
                 Vf, Vnew, (m0 + 1, jnp.zeros((), m0.dtype)))
-            alph = jax.lax.dynamic_update_slice(alph, a_blk, (m0,))
-            bet = jax.lax.dynamic_update_slice(bet, b_blk, (m0,))
-        return Vf.reshape(V.shape), alph, bet
+        return Vf.reshape(V.shape), alph, bet, ran
 
     return run_window
 
@@ -1486,21 +1563,30 @@ def _lanczos_impl(
     ``reorth`` picks the reorthogonalization policy (default: the
     ``lanczos_reorth`` config knob, ``"selective"``): ``"selective"`` runs
     each iteration's MGS pass against only a trailing window of recent
-    vectors and, when the accumulated ω-recurrence estimate crosses √ε,
-    DISCARDS the block and redoes it with the full sweep (window blocks
-    never touch rows ≤ m, so rollback is free; an info-level
-    ``solver_health`` event marks each trigger; the first block after a
-    restart or resume is always full — the arrowhead coupling row must be
-    projected out).  ``"full"`` is the pre-round-9 behavior: full MGS
-    sweeps every iteration.
+    vectors.  The window program evolves the ω-recurrence estimate beside
+    the steps and ends itself at the step where it reaches √ε; the host,
+    which evolves the same table over the (α, β) that came back and is the
+    authority, KEEPS the steps before the crossing (sound by the rule that
+    condemns the crossing one) and runs the rest of that block with the
+    full sweep, so the Ritz check and every later block fall where they
+    would have (window blocks never touch rows ≤ m, so dropping a step is
+    free; an info-level ``solver_health`` event marks each trigger with
+    the ``step`` within the block; the first block after a restart or
+    resume is always full — the arrowhead coupling row must be projected
+    out).  ``"full"`` is the pre-round-9 behavior: full MGS sweeps every
+    iteration.
 
     ``root`` is the solve's open span (:func:`lanczos` passes it).  It
     takes the solve's counts as they happen, so a preempted solve's event
     has them too: ``steps_counted`` (iterations this call counted),
-    ``steps_run`` (every step a block program executed, redone blocks
-    included), ``probe_applies``, ``programs_built`` (block programs this
-    call had to trace and compile or load).  Blocks are the ``iteration``
-    spans, redone ones carry ``redo=True``, and restarts are on the result.
+    ``steps_run`` (every step a block program reports it ran, a window
+    block's crossing step included), ``omega_stops`` (window blocks that
+    ended themselves early), ``steps_discarded`` (steps run and not kept:
+    one a stop when host and device agree), ``probe_applies``,
+    ``programs_built`` (block programs this call had to trace and compile
+    or load).  A block program's run is an ``iteration`` span, the
+    full-sweep run of a stopped block's remainder carries ``redo=True``
+    with its own ``steps``, and restarts are on the result.
     Under the root the host loop is named where the device can wait for it:
     ``lanczos/start`` (start vector and probe apply; the Krylov buffer),
     then per ``iteration`` ``lanczos/dispatch`` (``built`` says whether the
@@ -1539,7 +1625,7 @@ def _lanczos_impl(
     # every key of the solve's counts is on the root span's event, zeros
     # included
     root.add(steps_counted=0, steps_run=0, probe_applies=0,
-             programs_built=0)
+             programs_built=0, omega_stops=0, steps_discarded=0)
     # lanczos/start: the start vector and the eager probe apply (and below,
     # once more, the Krylov buffer)
     with obs_trace.span("lanczos/start", kind="phase"):
@@ -1601,6 +1687,9 @@ def _lanczos_impl(
     _runners: dict = {}
 
     def run_steps(full_pass: bool, V, alph, bet, m, nsteps, operands):
+        """Dispatch one block program: ``(V, alph, bet, ran)``, ``ran`` the
+        device's count of the steps it ran (a window block may end itself
+        short of ``nsteps``)."""
         key = "full" if full_pass else ("window", int(nsteps))
         built = key not in _runners
         # lanczos/dispatch: until the block program's call returns.  A
@@ -1609,7 +1698,7 @@ def _lanczos_impl(
         with obs_trace.span("lanczos/dispatch", kind="phase",
                             built=built, full=bool(full_pass),
                             steps=int(nsteps)):
-            root.add(steps_run=int(nsteps), programs_built=int(built))
+            root.add(programs_built=int(built))
             if full_pass:
                 if built:
                     _runners[key] = _make_block_runner(
@@ -1620,7 +1709,8 @@ def _lanczos_impl(
                 _runners[key] = _make_window_runner(
                     mv, mcap, shape, dtype, n_reorth, int(nsteps),
                     pair=pair)
-            return _runners[key](V, alph, bet, jnp.int32(m), operands)
+            return _runners[key](V, alph, bet, jnp.int32(m),
+                                 omega_tr.state, operands)
 
     restart_fn = _make_restart(mcap, shape, dtype, l_restart)
 
@@ -1772,7 +1862,7 @@ def _lanczos_impl(
     # every thick restart (the arrowhead coupling row must be projected
     # out of w = H·v_l against ALL locked rows) is always full.
     selective = reorth == "selective"
-    omega_tr = _OmegaTracker() if selective else None
+    omega_tr = _OmegaTracker(mcap) if selective else None
     pending_full = bool(m)
     if selective:
         # warm the dynamic-step full runner with a ZERO-step call: short
@@ -1780,9 +1870,13 @@ def _lanczos_impl(
         # compiled program instead of landing a compile inside the
         # steady-rate window (the window program compiles in the first —
         # rate-excluded — block)
-        V, alph_d, bet_d = run_steps(True, V, alph_d, bet_d, m, 0,
-                                     operands)
+        V, alph_d, bet_d, _ = run_steps(True, V, alph_d, bet_d, m, 0,
+                                        operands)
 
+    # steps of the current block kept from its window program: non-zero
+    # only on the pass that runs the rest of that block under the full
+    # sweep, after the ω gate stopped it
+    head = 0
     redo = False
     while total_iters < max_iters and not converged:
         if m == mcap:
@@ -1814,44 +1908,56 @@ def _lanczos_impl(
         pending_full = False
         if not redo:
             t0 = _time.perf_counter()
-        # iteration span: one convergence-check block of nsteps Lanczos
-        # steps (the applies run INSIDE the jitted block program, so the
-        # block is the finest host-visible iteration granule here) and the
-        # host's check of it, which runs to the end of the loop body: the
-        # stack closes ``lanczos/check`` and then the iteration.  ``redo``
-        # marks the full-sweep rerun of a window block the ω gate discarded
+        # iteration span: one block program's run (the applies run INSIDE
+        # the jitted program, so this is the finest host-visible iteration
+        # granule here) and the host's check of it, which runs to the end
+        # of the loop body: the stack closes ``lanczos/check`` and then the
+        # iteration.  A convergence-check block of nsteps Lanczos steps is
+        # one of them, or two where the ω gate stopped its window program:
+        # ``redo`` marks the full-sweep run of the rest of that block
         with contextlib.ExitStack() as block:
             block.enter_context(obs_trace.span(
                 "iteration", kind="iteration", solver="lanczos",
-                iter=int(total_iters), steps=int(nsteps),
+                iter=int(total_iters + head), steps=int(nsteps - head),
                 **({"redo": True} if redo else {})))
-            V, alph_d, bet_d = run_steps(
-                used_full, V, alph_d, bet_d, m, nsteps, operands)
+            V, alph_d, bet_d, ran = run_steps(
+                used_full, V, alph_d, bet_d, m + head, nsteps - head,
+                operands)
             with obs_trace.span("lanczos/wait", kind="phase"):
                 jax.block_until_ready(V)   # one collective program in flight
             block.enter_context(
                 obs_trace.span("lanczos/check", kind="phase"))
-            redo = False
+            # what the program reports it ran, the crossing step included
+            ran = int(ran)
+            root.add(steps_run=ran)
             if selective and not used_full:
                 om_acc = omega_tr.advance(np.asarray(alph_d),
-                                          np.asarray(bet_d), m + nsteps)
-                if om_acc >= obs_health.OMEGA_WARN:   # √ε — Simon's bound
-                    # ω crossed √ε inside the window block: semiorthogonality
-                    # is no longer guaranteed and cannot be repaired after the
-                    # fact — but the block only WROTE rows above m, so the
-                    # pre-block state is intact.  Discard it and redo the same
-                    # steps with the full sweep on the loop's next pass
-                    # (counted once; only the wall clock pays).
+                                          np.asarray(bet_d), m + ran)
+                if omega_tr.m < m + nsteps:
+                    # ω reached √ε inside the window block (or the device's
+                    # copy of the estimate did, and stopped it): from the
+                    # step the host's table stands at, semiorthogonality is
+                    # no longer guaranteed and cannot be repaired after the
+                    # fact.  The steps before it are sound by the same rule
+                    # and are kept; the block only WROTE rows above them, so
+                    # the rest of it runs under the full sweep on the loop's
+                    # next pass.  The host's reading wins a disagreement: a
+                    # step the device ran on is dropped, one it stopped
+                    # short of runs under the full sweep.
                     # level "info": a trigger near convergence is the scheme
                     # WORKING (loss grows exactly as Ritz pairs converge),
                     # not a health problem — the zero-warning gate of `make
                     # health-check` must not fail a healthy converged solve
+                    head = omega_tr.m - m
+                    root.add(omega_stops=int(ran < nsteps),
+                             steps_discarded=ran - head)
                     obs_emit("solver_health",
                              check="selective_reorth_fallback", level="info",
                              solver="lanczos", iter=int(total_iters + nsteps),
-                             omega=float(om_acc))
+                             step=int(head), omega=float(om_acc))
                     pending_full = redo = True
                     continue
+            head, redo = 0, False
             dt = _time.perf_counter() - t0
             if first_block_iters == 0:
                 first_block_s, first_block_iters = dt, nsteps

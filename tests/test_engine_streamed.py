@@ -280,9 +280,11 @@ def test_selective_reorth_matches_full(rng):
 
 
 def test_selective_reorth_fallback_event(rng, monkeypatch):
-    """When ω crosses √ε the block is redone with the full sweep and a
-    solver_health event marks the trigger — forced here by dropping the
-    threshold to 0."""
+    """When ω crosses √ε the window block stops, the steps before the
+    crossing are kept and the rest of the block runs the full sweep; a
+    solver_health event marks the trigger with the step within the block —
+    forced here by dropping the threshold to 0, which stops every window
+    block at its first step."""
     from distributed_matvec_tpu import obs
     from distributed_matvec_tpu.obs import health as obs_health
     from distributed_matvec_tpu.parallel.engine import LocalEngine
@@ -301,6 +303,11 @@ def test_selective_reorth_fallback_event(rng, monkeypatch):
         evs = [e for e in obs.events("solver_health")
                if e.get("check") == "selective_reorth_fallback"]
         assert evs, "no fallback event despite a zero threshold"
+        assert all(e["step"] == 0 for e in evs)
+        root = [e for e in obs.events("span") if e["name"] == "lanczos"][-1]
+        # one step run and thrown away a stop, no block run twice
+        assert root["omega_stops"] == root["steps_discarded"] == len(evs)
+        assert root["steps_run"] == res.num_iters + len(evs)
         ref = lanczos(eng.matvec, n, k=1, tol=1e-10, seed=6, reorth="full")
         np.testing.assert_allclose(res.eigenvalues, ref.eigenvalues,
                                    rtol=1e-12)

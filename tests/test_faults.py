@@ -15,6 +15,7 @@ import gc
 import json
 import os
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -263,6 +264,12 @@ def test_os_replace_concurrent_writers(tmp_path):
         t.start()
     good = 0
     try:
+        # the reads below race the writers only once a first file is there:
+        # on a loaded machine 200 reads can finish before the first save
+        deadline = time.monotonic() + 60
+        while not os.path.exists(path) and not errors \
+                and time.monotonic() < deadline:
+            time.sleep(0.01)
         for _ in range(200):
             for fp in ("fp-w0", "fp-w1"):
                 got = load_engine_structure(path, fp)

@@ -176,10 +176,10 @@ def _tree(spans):
 
 
 def test_lanczos_span_tree_and_counts(clean_trace, monkeypatch):
-    """A 16-site solve with one block redone: ``lanczos > iteration >
-    {lanczos/dispatch, lanczos/wait, lanczos/check}``, ``lanczos/start``
-    and ``lanczos/epilogue`` under the root, and the root's counts equal to
-    what the solve did."""
+    """A 16-site solve with one window block stopped by the ω gate at its
+    sixth step: ``lanczos > iteration > {lanczos/dispatch, lanczos/wait,
+    lanczos/check}``, ``lanczos/start`` and ``lanczos/epilogue`` under the
+    root, and the root's counts equal to what the solve did."""
     import importlib
 
     from distributed_matvec_tpu.parallel.engine import LocalEngine
@@ -190,16 +190,15 @@ def test_lanczos_span_tree_and_counts(clean_trace, monkeypatch):
     op = build_heisenberg(16, hw=8)
     eng = LocalEngine(op, mode="ell")
 
-    # the omega gate trips on the second window block, and only there
-    advance = module._OmegaTracker.advance
-    calls = []
+    # the omega gate trips at step 5 of the second window block, and only
+    # there: host and window program read the same ``_omega_row``
+    omega_row = module._omega_row
 
-    def tripping(self, alph, bet, m):
-        calls.append(m)
-        real = advance(self, alph, bet, m)
-        return 1.0 if len(calls) == 2 else min(real, 1e-12)
+    def tripping(xp, w, wp, alph, bet, j, eps):
+        new, worst = omega_row(xp, w, wp, alph, bet, j, eps)
+        return new, xp.where(j == 21, 1.0, xp.minimum(worst, 1e-12))
 
-    monkeypatch.setattr(module._OmegaTracker, "advance", tripping)
+    monkeypatch.setattr(module, "_omega_row", tripping)
     built = len(obs.events("span"))
     res = lanczos(eng.matvec, op.basis.number_states, k=1, tol=1e-9,
                   max_iters=96, compute_eigenvectors=True)
@@ -228,18 +227,31 @@ def test_lanczos_span_tree_and_counts(clean_trace, monkeypatch):
             ["lanczos/dispatch", "lanczos/wait", "lanczos/check"]
         assert all(e["cat"] == "phase" for e in children[it["span_id"]])
 
+    # the stopped block's head (steps 16..20) is kept; the rest of the same
+    # block runs under the full sweep, in the span marked ``redo``
     redone = [it for it in iterations if it.get("redo")]
-    assert len(redone) == 1 and len(calls) >= 2
+    assert len(redone) == 1
     i = iterations.index(redone[0])
-    assert i == 2 and iterations[i - 1]["iter"] == redone[0]["iter"] == 16
+    assert i == 2 and (iterations[i - 1]["iter"],
+                       iterations[i - 1]["steps"]) == (16, 16)
+    assert (redone[0]["iter"], redone[0]["steps"]) == (21, 11)
+    assert iterations[i + 1]["iter"] == 32
     dispatches = [e for e in spans if e["name"] == "lanczos/dispatch"]
     assert [d["full"] for d in dispatches[:4]] == [True, False, False, True]
     steps_counted = res.num_iters
     assert root["steps_counted"] == steps_counted
-    assert root["steps_run"] == sum(d["steps"] for d in dispatches) \
+    # asked of the programs: the stopped block's 16 and its remainder
+    assert sum(d["steps"] for d in dispatches) \
         == steps_counted + redone[0]["steps"]
-    # blocks are the iteration spans, redone ones carry ``redo``, restarts
-    # are on the result: the root repeats none of them
+    # what they report they ran: the crossing step is the one step run
+    # and not kept
+    assert root["steps_run"] == steps_counted + 1
+    assert (root["omega_stops"], root["steps_discarded"]) == (1, 1)
+    (trip,) = [e for e in obs.events("solver_health")
+               if e.get("check") == "selective_reorth_fallback"]
+    assert (trip["step"], trip["iter"]) == (5, 32)
+    # a block program's run is an iteration span, the remainder's carries
+    # ``redo``, restarts are on the result: the root repeats none of them
     assert not {"blocks", "blocks_redone", "restarts"} & set(root)
     assert len(iterations) == steps_counted // 16 + 1
     assert root["programs_built"] == sum(d["built"] for d in dispatches) == 2

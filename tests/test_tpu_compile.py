@@ -179,9 +179,12 @@ def _compile(name, topo):
             (_buffer_rows(M_CAP),) + x.shape, jnp.float64,
             sharding=NamedSharding(mesh, P(None, SHARD_AXIS, None)))
         ab = jax.ShapeDtypeStruct((M_CAP,), jnp.float64, sharding=rep)
+        om = jax.ShapeDtypeStruct((M_CAP + 1,), jnp.float64, sharding=rep)
+        f64 = jax.ShapeDtypeStruct((), jnp.float64, sharding=rep)
         m0 = jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)
         fn = _make_window_runner(mv, M_CAP, x.shape, jnp.float64, 2, 16)
-        return fn.lower(V, ab, ab, m0, eng._operands).compile()
+        return fn.lower(V, ab, ab, m0, (om, om, f64, f64),
+                        eng._operands).compile()
 
     sh = SingleDeviceSharding(topo.devices[0])
     eng, x = _local_ell_engine(sh, pair=False)
@@ -196,8 +199,11 @@ def _compile(name, topo):
     V = S((_buffer_rows(M_CAP), N))
     ab, i32 = S((M_CAP,)), S((), jnp.int32)
     if name == "window":
+        # the host tracker's state rides beside (alpha, beta): its two
+        # omega rows, its epsilon and its limit
+        omega = (S((M_CAP + 1,)), S((M_CAP + 1,)), S(()), S(()))
         fn = _make_window_runner(mv, M_CAP, (N,), jnp.float64, 2, 16)
-        return fn.lower(V, ab, ab, i32, operands).compile()
+        return fn.lower(V, ab, ab, i32, omega, operands).compile()
     assert name == "full", name
     fn = _make_block_runner(mv, M_CAP, (N,), jnp.float64, 2)
     return fn.lower(V, ab, ab, i32, i32, operands).compile()
@@ -326,7 +332,7 @@ _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s.*?[\w\-]+\(",
 @pytest.mark.parametrize("program, scopes", [
     ("ell_apply", LOCAL_SCOPES),
     ("distributed_apply", APPLY_SCOPES + EXCHANGE_SCOPES),
-    ("window", LANCZOS_SCOPES + LOCAL_SCOPES),
+    ("window", LANCZOS_SCOPES + ["lanczos/omega"] + LOCAL_SCOPES),
     ("full", LANCZOS_SCOPES + LOCAL_SCOPES),
 ], ids=["ell_apply", "distributed_apply", "window", "full"])
 def test_named_scopes_reach_the_tpu_hlo(topo, tpu_knobs, compiled,
