@@ -55,7 +55,7 @@ sys.path.insert(0, REPO)
 
 FULL_YAML = os.path.join(REPO, "data", "heisenberg_chain_32_symm.yaml")
 FULL_STATES = 4_707_969
-#: ``lanczos_e0`` of BENCH_RECORDED_r02.json (round-2 builder record, 40
+#: ``lanczos_e0`` of a round-2 builder's run on other hardware (40
 #: iterations, not converged) — a lead printed beside ours, not a gate
 R02_LANCZOS_E0 = -56.8261101
 ANCHOR_SITES = 16

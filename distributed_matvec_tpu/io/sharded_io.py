@@ -280,7 +280,7 @@ def hashed_shard_reader(path: str,
 
     A checkpoint restore reads O(m·D) shard slices; per-call
     :func:`load_hashed_shard` scans would bill ~m·D glob+open+close
-    cycles to the trend-gated ``resume_reshard_s``.  The generation
+    cycles to the restore's ``reshard_s``.  The generation
     filter is a correctness matter, not an optimization: barrier-free
     per-rank saves mean mixed-generation ``.r*`` files can coexist under
     one fingerprint, and a fetch that fell through to a stale file would

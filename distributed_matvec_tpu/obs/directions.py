@@ -1,7 +1,7 @@
 """Metric direction registry — the ONE place that says which way is up.
 
-Every gate in the repo (``obs_report diff``, ``bench_trend gate``, the
-check scripts that wrap them) needs the same answer to the same
+Every gate in the repo (``obs_report diff`` and the check scripts that
+wrap it) needs the same answer to the same
 question: for metric X, is a LOWER new value the regression (rates,
 speedups, throughputs) or a HIGHER one (walls, bytes, error bounds)?
 Until the solve service each tool carried its own copy of that list;
@@ -75,7 +75,7 @@ def is_higher_better(metric: str) -> bool:
 #: Help strings for the exporter's ``# HELP`` lines, keyed by the BASE
 #: instrument name (no labels).  This table rides next to the direction
 #: tags deliberately: the OpenMetrics exporter (``obs/export.py``) and the
-#: trend gates (``bench_trend``, ``obs_report diff``) read the SAME file,
+#: diff gate (``obs_report diff``) read the SAME file,
 #: so a metric's type, direction and meaning are registered exactly once
 #: and the scrape plane can never drift from the gate plane.  A metric
 #: absent here still exports (help falls back to the name) — the table is

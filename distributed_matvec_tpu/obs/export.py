@@ -1,7 +1,7 @@
 """OpenMetrics export — the scrape plane over the metrics registry.
 
 Fifteen PRs of telemetry answer questions *after* a run (JSONL streams,
-``obs_report``, ``bench_trend``); nothing exposes a LIVE fleet to a
+``obs_report``); nothing exposes a LIVE fleet to a
 monitoring stack.  This module renders :func:`~.metrics.snapshot` —
 the exact registry the harnesses already emit as ``metrics_snapshot``
 events — into the Prometheus / OpenMetrics text exposition format, and
@@ -26,7 +26,7 @@ registry's labels, counters gain the OpenMetrics ``_total`` suffix,
 histograms export cumulative ``_bucket{le=...}``/``_sum``/``_count``,
 and a ``rank`` label pins each sample to its producer.  HELP text and
 gate direction both come from ``obs/directions.py`` — the exporter and
-``bench_trend`` read the same table, so the scrape plane can never
+``obs_report diff`` read the same table, so the scrape plane can never
 disagree with the gate plane about what a metric means.  Values are
 rendered with ``repr`` (shortest round-trip form), so a scraped number
 is **exactly** the registry value — parity with the JSONL-recovered

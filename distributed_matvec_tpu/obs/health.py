@@ -296,7 +296,7 @@ def omega_estimate(alph: np.ndarray, bet: np.ndarray, lo: int, m: int,
 def health_event_count() -> int:
     """Total warn/critical ``health`` + ``solver_health`` events in this
     process's in-memory buffer, after draining pending probe fetches —
-    the one shared tally harnesses (bench, the health-check gate) diff
+    the one shared tally harnesses (the health-check gate) diff
     before/after a run, so the kind list cannot drift between them.
     ``info``-level events (e.g. the selective-reorthogonalization
     fallback marker, which fires on perfectly healthy converging solves)

@@ -21,8 +21,7 @@ racing the config cache):
 * ``triggered`` — no cadence; only :func:`trigger_capture` fires.
 
 **Triggered deep capture** (active in both non-off modes): an SLO
-burn-rate alert (obs/slo.py) or a ``bench_trend`` gate failure calls
-:func:`trigger_capture`, which snapshots the hottest HLO ops, the
+burn-rate alert (obs/slo.py) calls :func:`trigger_capture`, which snapshots the hottest HLO ops, the
 newest sampled-trace directory, and the overhead ledger into one
 flight-recorder bundle (PR 17 format, ``trace_id``/``job_id`` stamped
 by ``flight_dump`` itself) so the incident carries its own profile.
@@ -124,8 +123,8 @@ def measured_overhead_pct() -> float:
 
 
 def overhead_snapshot() -> Dict[str, float]:
-    """Copy of the overhead ledger (bench deltas read this before and
-    after a config to attribute per-config overhead)."""
+    """Copy of the overhead ledger, with the measured overhead percent
+    under ``overhead_pct``."""
     with _lock:
         snap = dict(_state)
     snap["overhead_pct"] = measured_overhead_pct()

@@ -272,8 +272,8 @@ def attribute_phases(phases: Dict[str, dict], wall_ms: float,
 #: exchange/compute overlap plus the whole hideable plan stream) is at
 #: least this share of the apply's total bound — below it the pipeline's
 #: bookkeeping (split programs, prefetch workers) cannot pay for itself
-#: (measured ~7% schedule overhead on a latency-free 8-chunk CPU
-#: stream, BENCH_PIPELINE_r10.json).
+#: (a CPU run of round 10 read ~7% schedule overhead on a latency-free
+#: 8-chunk stream; never measured on a TPU, ROADMAP D7).
 AUTO_PIPELINE_MIN_FRACTION = 0.10
 
 #: Depth ``auto`` picks when the plan stream (``plan_h2d``) carries a

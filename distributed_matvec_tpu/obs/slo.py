@@ -286,8 +286,8 @@ def check_slos(specs: Optional[List[SloSpec]] = None,
                events: Optional[List[dict]] = None) -> List[dict]:
     """Evaluate in-process (over the live event ring by default) and emit
     ``slo_alert`` events on state TRANSITIONS: ``state="firing"`` (also
-    bumping the ``slo_alert_count`` counter — the bench_trend gate
-    metric) when an ok SLO starts burning, ``state="clear"`` when a
+    bumping the ``slo_alert_count`` counter) when an ok SLO starts
+    burning, ``state="clear"`` when a
     firing one recovers.  Steady states emit nothing, so a healthy
     service's stream stays alert-free.  Inert when the layer is off or
     in standalone (reader) mode."""

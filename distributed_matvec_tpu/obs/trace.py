@@ -40,7 +40,7 @@ wall clock, which is what the cross-rank merge aligns.  The handle the
 they close with the span, on its event.  The canonical taxonomy
 (DESIGN.md §24)::
 
-    run (diagonalize / bench)  >  solve (one solver call)
+    run (diagonalize)  >  solve (one solver call)
       >  iteration (one convergence block / block step / segment)
         >  phase (a named stretch of host work: ``lanczos/dispatch``,
            ``plan/pack``, ``device_wait``)

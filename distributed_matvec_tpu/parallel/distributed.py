@@ -1847,7 +1847,7 @@ class DistributedEngine:
         "off" still bitpacks ``rok`` — the free lossless win).  From here
         on the host-RAM copy, the sidecar, and the per-apply H2D stream
         all carry the encoded bytes; ``plan_bytes_raw`` keeps the
-        uncompressed total for the ratio the trend gate guards."""
+        uncompressed total for ``compress_ratio``."""
         from ..ops import plan_codec as PC
 
         D = self.n_devices
@@ -2128,8 +2128,8 @@ class DistributedEngine:
         hyb_ctx = {}
         if self.mode == "hybrid":
             # the split's identity card, read by tools/capacity.py
-            # snapshots and the hybrid bench leg: which fraction of the
-            # terms travel in the stream, under which policy
+            # snapshots: which fraction of the terms travel in the
+            # stream, under which policy
             hyb_ctx = {"hybrid_split": str(self._hybrid_split),
                        "stream_terms": int(self._hybrid_mask.sum()),
                        "num_terms": int(self.num_terms),
@@ -4212,7 +4212,7 @@ class DistributedEngine:
         x-row indices, EXACT (lossless-path) coefficients and quantization
         deltas as device-resident arrays for the first addressable shard.
         None when the probe does not apply — non-quantized tier, complex /
-        pair sector (the bench-gated quantized tiers are real), or a
+        pair sector (the quantized tiers are real-valued), or a
         sidecar-restored raw-fallback plan whose exact f64 coefficients
         are no longer recoverable (dict-coded plans keep the originals as
         the searchsorted key space, so restore still probes)."""

@@ -79,10 +79,10 @@ def pad_to_multiple(n: int, b: int) -> int:
 # local one, the test suite's dozens of small engines — pays zero trace or
 # compile time.  AOT lowering (``.lower().compile()``) rather than plain
 # ``jax.jit`` both pins the fixed-shape contract and lets the engines put the
-# compile under its own timer scope, which bench.py reports as the
-# build-vs-compile-vs-transfer split.  Executables also hit JAX's persistent
-# compilation cache (utils/artifacts.py ``xla/`` tree) so a fresh process
-# skips XLA backend compilation too.
+# compile under its own timer scope, which the ``engine_init`` event
+# reports as the build-vs-compile-vs-transfer split.  Executables also hit
+# JAX's persistent compilation cache (utils/artifacts.py ``xla/`` tree) so a
+# fresh process skips XLA backend compilation too.
 
 _PROGRAM_CACHE: Dict[tuple, Any] = {}
 
@@ -384,8 +384,8 @@ def emit_engine_init(eng, engine_kind: str, init_s: Optional[float] = None
     """One ``engine_init`` telemetry event carrying the construction split
     the timer tree measured (structure/plan build with its compile child,
     transfer, diag) plus the cache outcome flags — the machine-readable
-    form of the warm-start story bench.py reports, shared by both engines
-    so the event schema cannot drift."""
+    form of the warm-start story, shared by both engines so the event
+    schema cannot drift."""
     t = eng.timer
     build_s = (t.scope_total("build_structure")
                + t.scope_total("build_plan"))

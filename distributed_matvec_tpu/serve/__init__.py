@@ -21,8 +21,8 @@ Quickstart::
     sched.drain()
     sched.queue.result("j0")["eigenvalues"]
 
-Load-generate with ``python bench.py --serve``; run a spool-backed
-service with ``python apps/solve_service.py DIR``; submit from the CLI
+A scripted burst of mixed jobs is ``make serve-check``
+(``tools/serve_check.py``); run a spool-backed service with ``python apps/solve_service.py DIR``; submit from the CLI
 with ``python apps/diagonalize.py model.yaml --submit --serve-dir DIR``.
 """
 

@@ -482,8 +482,7 @@ def exact_moments(eigenvalues, scale: Tuple[float, float],
     side of broadening-aware DOS comparisons: push these through
     :func:`reconstruct_dos` with the SAME kernel as the stochastic
     moments and the residual is pure trace noise, never resolution
-    mismatch (used by the bench's ``kpm_dos_rel_err`` and
-    ``make dynamics-check``)."""
+    mismatch (used by ``make dynamics-check``)."""
     a, b = float(scale[0]), float(scale[1])
     ang = np.arccos(np.clip(
         (np.asarray(eigenvalues, np.float64) - b) / a, -1.0, 1.0))

@@ -278,8 +278,8 @@ def agree_config(cfg: TunedConfig, multi: bool) -> TunedConfig:
 
 def timed_choose(stats: dict, calibration: dict,
                  mode: str) -> Tuple[TunedConfig, float]:
-    """:func:`choose_config` plus its wall time (the ``tune_search_s``
-    metric bench records)."""
+    """:func:`choose_config` plus its wall time (``search_s`` of the
+    ``tune_config`` event)."""
     t0 = time.perf_counter()
     cfg = choose_config(stats, calibration, mode)
     return cfg, time.perf_counter() - t0

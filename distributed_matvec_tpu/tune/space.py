@@ -78,9 +78,9 @@ HYBRID_SPLIT_CANDIDATES = ("auto", "all-stream", "all-recompute")
 LIVE_FRACTION = 0.55
 
 #: Pipeline bookkeeping cost as a share of the sequential bound (split
-#: programs, prefetch threads, per-chunk dispatch): measured ~7% on a
-#: latency-free 8-chunk CPU stream (BENCH_PIPELINE_r10.json) — the same
-#: figure behind ``roofline.AUTO_PIPELINE_MIN_FRACTION``.
+#: programs, prefetch threads, per-chunk dispatch): a CPU run of round 10
+#: read ~7% on a latency-free 8-chunk stream — the same figure behind
+#: ``roofline.AUTO_PIPELINE_MIN_FRACTION`` (never measured on a TPU).
 PIPELINE_OVERHEAD_FRACTION = 0.07
 
 #: Modeled disk-tier chunk read-back rate (sequential h5py reads + CRC).

@@ -26,7 +26,7 @@ def maybe_profile(create_perfetto_link: bool = False,
             y = eng.matvec(x)
 
     ``profile_dir`` overrides the global ``config.profile_dir`` field for
-    this one block — harnesses (bench.py) can profile exactly one apply per
+    this one block — a harness can profile exactly one apply per
     config into its own directory without mutating process-global config or
     env vars.  An explicit empty string forces the no-op regardless of the
     config field; ``None`` (default) defers to the config.

@@ -79,8 +79,7 @@ class TreeTimer:
 
     def to_dict(self) -> dict:
         """Nested ``{name: {total, count, children}}`` snapshot of the tree
-        — machine-readable counterpart of :meth:`report` (bench.py records
-        the engine-init build/compile/transfer split from it)."""
+        — machine-readable counterpart of :meth:`report`."""
         def walk(node: _Node) -> dict:
             return {"total": node.total, "count": node.count,
                     "children": {k: walk(c)

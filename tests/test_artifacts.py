@@ -176,7 +176,7 @@ def test_batched_multi_rhs_matches_single(rng):
 
 def test_warm_cache_tool(artifact_root_dir, tmp_path):
     """tools/warm_cache.py fills the cache; a second run restores
-    everything (the `make warm-cache` → fast-bench contract)."""
+    everything (the `make warm-cache` contract)."""
     env = dict(os.environ, JAX_PLATFORMS="cpu", JAX_ENABLE_X64="true")
     env.pop("DMT_ARTIFACT_CACHE", None)
     root = str(tmp_path / "warmroot")
@@ -270,7 +270,7 @@ def test_entry_points_and_engine_work_without_a_usable_home(tmp_path):
     """HOME read-only, then HOME missing: every entry point imports, and a
     default (artifact layer ON) engine builds — cache writes fail soft."""
     code = """
-import apps.diagonalize, apps.dynamics, apps.solve_service, bench
+import apps.diagonalize, apps.dynamics, apps.solve_service
 import __graft_entry__
 from distributed_matvec_tpu.models.basis import SpinBasis
 from distributed_matvec_tpu.models.lattices import (chain_edges,

@@ -11,7 +11,6 @@ agreement by the DMT_MH_PROF worker leg here.
 """
 
 import contextlib
-import importlib.util
 import json
 import os
 import subprocess
@@ -359,20 +358,6 @@ def test_profile_diff_cli_and_obs_report_profile(tmp_path):
         [sys.executable, os.path.join(REPO, "tools", "obs_report.py"),
          "profile", str(empty)], capture_output=True, text=True)
     assert r.returncode == 2
-
-
-def test_bench_trend_gates_profile_metrics():
-    spec = importlib.util.spec_from_file_location(
-        "bench_trend", os.path.join(REPO, "tools", "bench_trend.py"))
-    bt = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bt)
-    assert "profile_overhead_pct" in bt.DEFAULT_GATE
-    for m in ("hlo_flops", "hlo_bytes", "profile_overhead_pct"):
-        assert m in bt.METRIC_WHITELIST
-    from distributed_matvec_tpu.obs.directions import is_higher_better
-    assert not is_higher_better("hlo_bytes")
-    assert not is_higher_better("hlo_flops")
-    assert not is_higher_better("profile_overhead_pct")
 
 
 # ---------------------------------------------------------------------------

@@ -430,8 +430,7 @@ def test_reshard_cross_mesh_agreement(tmp_path):
     """``reshard_shards`` 8→4 plus the state-keyed probe: the re-routed
     file must hold exactly the HashedLayout-4 partition, and fused engines
     on the two mesh sizes must produce the same global ⟨x, Hx⟩ / ‖Hx‖ —
-    the cross-mesh verification protocol the chain_40 scale run uses
-    (tools/scale_apply.py)."""
+    the cross-mesh verification protocol of a chain_40-scale run."""
     import jax as _jax
 
     if len(_jax.devices()) < 8:

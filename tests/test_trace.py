@@ -4,8 +4,8 @@ Covers: trace-id resolution (env pin, run-dir file agreement, random
 fallback), span stack nesting + envelope stamping, the provable-no-op
 contracts (DMT_OBS=off, DMT_TRACE=off), engine apply spans, the
 stall-report span attachment, the Perfetto export's B/E pairing +
-nesting, a golden `watch --once` frame, bench-trend run identity, and
-the REAL 2-process spawned leg asserting cross-rank trace agreement and
+nesting, a golden `watch --once` frame, and the REAL 2-process spawned
+leg asserting cross-rank trace agreement and
 a Perfetto round-trip.
 """
 
@@ -452,42 +452,6 @@ def test_watch_seed_consumes_exact_offsets(tmp_path):
                                "kind": "c"}) + "\n")
     got = rep._follow_poll([f], state, partial)
     assert [e["kind"] for e in got] == ["b", "c"]
-
-
-# ---------------------------------------------------------------------------
-# bench-trend run identity
-
-
-def test_bench_trend_record_identity(tmp_path):
-    bt = _load_tool("bench_trend")
-    rec = bt.compact_record(
-        {"cfg": {"config": "c", "device_ms": 1.0, "n_states": 10}},
-        mode="smoke", backend="cpu", ts=1.0,
-        trace_id="feedc0de", job_id="job-7", obs_dir="/tmp/run")
-    assert rec["trace_id"] == "feedc0de"
-    assert rec["job_id"] == "job-7"
-    assert rec["obs_dir"] == "/tmp/run"
-    p = str(tmp_path / "PROGRESS.jsonl")
-    assert bt.append_record(p, rec)
-    got = bt.load_records(p)[0]
-    assert got["trace_id"] == "feedc0de"
-
-
-def test_bench_trend_gates_drift_metrics():
-    """compress_rel_err / compress_drift_max are default-gated and
-    cost-like: error growth fires the gate."""
-    bt = _load_tool("bench_trend")
-    recs = [
-        {"kind": "bench_trend", "ts": 1.0, "mode": "full", "backend":
-         "cpu", "configs": {"s": {"n_states": 10, "compress_rel_err":
-                                  1e-7, "compress_drift_max": 1e-7}}},
-        {"kind": "bench_trend", "ts": 2.0, "mode": "full", "backend":
-         "cpu", "configs": {"s": {"n_states": 10, "compress_rel_err":
-                                  1e-5, "compress_drift_max": 1e-5}}},
-    ]
-    rows, regressions, newest = bt.gate(recs, threshold=0.3)
-    assert {m for _, m, *_ in regressions} == {"compress_rel_err",
-                                               "compress_drift_max"}
 
 
 # ---------------------------------------------------------------------------

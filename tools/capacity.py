@@ -528,9 +528,8 @@ def plan(n_states: int, num_terms: int, T0: int, pair: bool,
 
 #: Solve-length model for :func:`price_job`: Lanczos columns to
 #: convergence per requested eigenpair (Heisenberg-class spectra reach
-#: 1e-10 residuals well inside this on the bench configs).  A documented
-#: model constant, same standing as ``LIVE_FRACTION`` — the measured
-#: trend record wins once the service has run the config.
+#: 1e-10 residuals well inside this on the chain configs).  A documented
+#: model constant, same standing as ``LIVE_FRACTION``.
 EST_COLUMNS_PER_EIGENPAIR = 48
 
 #: Dynamics solve-length models (DESIGN.md §29), in the same matvec-
@@ -542,8 +541,7 @@ EST_COLUMNS_PER_EIGENPAIR = 48
 #:    time at the default tolerance, each step krylov_dim applies of a
 #:    2-column (Re, Im) block.
 #: Documented model constants with the same standing as
-#: EST_COLUMNS_PER_EIGENPAIR — the measured trend record wins once the
-#: service has run the config.
+#: EST_COLUMNS_PER_EIGENPAIR.
 KPM_BOUNDS_COLUMNS = 64
 EVOLVE_STEPS_PER_UNIT_TIME = 8
 

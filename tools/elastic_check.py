@@ -35,9 +35,6 @@ other gate uses):
    the queue drains with every job converged.
 7. **Plan re-fingerprinting** — a streamed engine rebuilt at D′ next to
    a D-era sidecar emits ``plan_reshard`` with the rebuild wall.
-8. **Trend gate** — ``resume_reshard_s`` / ``resume_rebuild_plan_s``
-   are recorded as bench_trend metrics: the gate passes on a healthy
-   repeat and FIRES on a synthetic 10× regression.
 
 Deterministic seeds/faults throughout; ~90 s warm on the CPU rig
 (up to ~4 min cold).
@@ -53,7 +50,6 @@ import time
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
-sys.path.insert(0, os.path.join(_REPO, "tools"))
 
 # platform pins BEFORE any jax import (parent process runs the in-process
 # plan-reshard leg on up to 4 virtual devices)
@@ -187,8 +183,7 @@ def _reshard_events(scratch, obs_tag, status="resharded"):
 
 
 def leg_resize(scratch, d_kill, d_resume, tag, e0_ref):
-    """Kill at ``d_kill``, resume at ``d_resume``; returns the reshard
-    wall of the resumed restore."""
+    """Kill at ``d_kill``, resume at ``d_resume``."""
     _kill_once_checkpointed(scratch, "chain12.yaml", tag, d_kill,
                             obs_tag=f"{tag}_kill")
     rc, out = _run_driver(scratch, "chain12.yaml", tag, d_resume,
@@ -203,7 +198,6 @@ def leg_resize(scratch, d_kill, d_resume, tag, e0_ref):
     assert ev["d_from"] == d_kill and ev["d_to"] == d_resume, ev
     _assert_close(_e0(scratch, tag), e0_ref,
                   f"{tag} (kill@{d_kill} → resume@{d_resume})")
-    return float(ev["reshard_s"])
 
 
 def leg_cycle(scratch, e0_ref16):
@@ -359,42 +353,9 @@ def leg_plan_rebuild(scratch):
                       structure_cache=cache)
     evs = obs.events("plan_reshard")
     assert evs and evs[-1]["d_from"] == [2] and evs[-1]["d_to"] == 4, evs
-    rebuild_s = float(evs[-1]["rebuild_s"])
-    assert rebuild_s > 0
-    _log(f"plan_reshard: per-D′ rebuild observable ({rebuild_s:.3f} s)")
-    return rebuild_s
-
-
-def leg_trend(scratch, reshard_s, rebuild_s):
-    """Record the elastic walls as trend metrics; the gate passes on a
-    healthy repeat and fires on a synthetic 10× regression."""
-    import bench_trend
-
-    detail = {"elastic": {"config": "elastic",
-                          "resume_reshard_s": round(reshard_s, 6),
-                          "resume_rebuild_plan_s": round(rebuild_s, 6)}}
-    progress = os.path.join(scratch, "gate.jsonl")
-    for ts in (1.0, 2.0):
-        bench_trend.append_record(progress, bench_trend.compact_record(
-            detail, mode="elastic", backend="cpu", ts=ts))
-    rc = bench_trend.main(["gate", "--progress", progress,
-                           "--config", "elastic"])
-    assert rc == 0, "trend gate failed on a healthy repeat"
-    bad = {"elastic": dict(detail["elastic"],
-                           resume_reshard_s=detail["elastic"]
-                           ["resume_reshard_s"] * 10 + 1.0,
-                           resume_rebuild_plan_s=detail["elastic"]
-                           ["resume_rebuild_plan_s"] * 10 + 1.0)}
-    bench_trend.append_record(progress, bench_trend.compact_record(
-        bad, mode="elastic", backend="cpu", ts=3.0))
-    rc = bench_trend.main(["gate", "--progress", progress,
-                           "--config", "elastic"])
-    assert rc != 0, "trend gate did NOT fire on a 10x elastic regression"
-    _log("trend gate: passes on healthy repeat, fires on 10x regression")
-    # the repo ledger accumulates the healthy record (soft-fail append)
-    bench_trend.append_record(os.path.join(_REPO, "PROGRESS.jsonl"),
-                              bench_trend.compact_record(
-                                  detail, mode="elastic", backend="cpu"))
+    assert "rebuild_s" in evs[-1], evs[-1]
+    _log("plan_reshard: per-D′ rebuild observable "
+         f"({float(evs[-1]['rebuild_s']):.3f} s)")
 
 
 def main() -> int:
@@ -415,15 +376,13 @@ def main() -> int:
     e0_ref16 = _e0(scratch, "base16")
     _log(f"chain_16 baseline E0 = {e0_ref16:.12f}")
 
-    reshard_s = leg_resize(scratch, 4, 2, "shrink", e0_ref)
-    reshard_s = max(reshard_s,
-                    leg_resize(scratch, 2, 4, "grow", e0_ref))
+    leg_resize(scratch, 4, 2, "shrink", e0_ref)
+    leg_resize(scratch, 2, 4, "grow", e0_ref)
     leg_cycle(scratch, e0_ref16)
     leg_matching_d(scratch)
     leg_reshard_fault(scratch, e0_ref)
     leg_serve(scratch)
-    rebuild_s = leg_plan_rebuild(scratch)
-    leg_trend(scratch, reshard_s, rebuild_s)
+    leg_plan_rebuild(scratch)
 
     _log(f"PASS ({time.time() - t_start:.1f} s)")
     return 0

@@ -33,10 +33,6 @@ verdict below is deterministic on any machine):
    (`tools/capacity.py --hybrid`'s table), `recommend` points at
    `hybrid` with the priced split when it beats both pure tiers, and
    `price_job` prices a hybrid-mode spec.
-6. **Trend gate wiring** — a bench-trend record carrying
-   `hybrid_plan_bytes`/`hybrid_steady_apply_ms` passes
-   `tools/bench_trend.py gate`, and a synthetic 3x plan-bytes
-   regression FIRES it (exit 1).
 """
 
 import os
@@ -257,35 +253,6 @@ def main() -> int:
           f"({hyb_est:.0f} < streamed {str_est:.0f} / fused "
           f"{fus_est:.0f} ms), price_job est "
           f"{priced['est_apply_ms']} ms/apply")
-
-    # -- 6. trend gate wiring ----------------------------------------------
-    import bench_trend
-
-    progress = os.path.join(scratch, "PROGRESS.jsonl")
-    good = {"kind": "bench_trend", "ts": 1.0, "mode": "gate",
-            "backend": "cpu", "configs": {"hybrid_gate": {
-                "n_states": int(fb.number_states),
-                "hybrid_plan_bytes": int(eh0.plan_bytes),
-                "hybrid_steady_apply_ms": 25.0}}}
-    bench_trend.append_record(progress, good)
-    bench_trend.append_record(progress, dict(good, ts=2.0))
-    r = subprocess.run(
-        [sys.executable, os.path.join(_REPO, "tools", "bench_trend.py"),
-         "gate", "--progress", progress])
-    assert r.returncode == 0, "trend gate failed on an identical record"
-    bad = {"kind": "bench_trend", "ts": 3.0, "mode": "gate",
-           "backend": "cpu", "configs": {"hybrid_gate": {
-               "n_states": int(fb.number_states),
-               "hybrid_plan_bytes": int(eh0.plan_bytes) * 3,
-               "hybrid_steady_apply_ms": 25.0}}}
-    bench_trend.append_record(progress, bad)
-    r = subprocess.run(
-        [sys.executable, os.path.join(_REPO, "tools", "bench_trend.py"),
-         "gate", "--progress", progress], capture_output=True, text=True)
-    assert r.returncode == 1, \
-        f"trend gate missed a 3x hybrid_plan_bytes regression: {r.stdout}"
-    print("[hybrid-check] trend gate: passes on appended record, fires "
-          "on a synthetic 3x plan-bytes regression")
 
     print("[hybrid-check] PASS")
     return 0

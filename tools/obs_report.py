@@ -8,9 +8,9 @@ either
   ``rank_<r>/events.jsonl`` per process — the pre-rank
   ``events.p<proc>.jsonl`` layout is still read),
 * a single ``.jsonl`` event file, or
-* a bench detail JSON (``BENCH_DETAIL.json`` — ``{config_key: {metrics}}``),
-  which is treated as a run containing only ``bench_result`` events so the
-  recorded benchmark artifacts diff directly against live runs.
+* a detail JSON (``{config_key: {metrics}}``, what the check scripts
+  write for ``diff``), which is treated as a run containing only
+  ``bench_result`` events so such rows diff directly against live runs.
 
 Subcommands::
 
@@ -75,8 +75,9 @@ Subcommands::
         cost-like), so a plan-compression PR can assert "H2D phase bytes
         down, compute phase flat" with
         ``--phases`` or ``--metric phase_plan_h2d_bytes``.  A gate entry
-        ending in ``*`` matches by prefix.  This is the CI perf gate
-        `make obs-check` runs against the recorded BENCH_DETAIL.json.
+        ending in ``*`` matches by prefix.  The check scripts gate
+        structural counts with it (``make compress-check``,
+        ``make hybrid-check``); times are left to the benchmark's cells.
 
     trace RUN [-o OUT.json]
         Chrome/Perfetto trace-event export of the merged span tree
@@ -135,7 +136,7 @@ from typing import Dict, List, Optional
 
 # Metric directions live in ONE shared table
 # (distributed_matvec_tpu/obs/directions.py) consumed by every gate
-# (this tool, bench_trend via this tool, the check scripts) —
+# (this tool and the check scripts) —
 # registering a new metric's direction happens exactly once there.  The
 # module is loaded by FILE so this standalone reader never imports the
 # package (and therefore never initializes a JAX backend just to read
@@ -265,7 +266,7 @@ _warned_mixed: set = set()
 
 def load_events(path: str) -> List[dict]:
     """Events of one run, ordered by (rank, seq).  Accepts a run directory,
-    one .jsonl file, or a BENCH_DETAIL-style .json (synthesized into
+    one .jsonl file, or a ``{config: {metrics}}`` .json (synthesized into
     ``bench_result`` events)."""
     if os.path.isdir(path):
         files = _run_files(path)
@@ -1977,7 +1978,7 @@ def main(argv=None) -> int:
     sub = ap.add_subparsers(dest="cmd", required=True)
 
     p = sub.add_parser("summarize", help="one run -> human/JSON summary")
-    p.add_argument("run", help="run dir, .jsonl file, or BENCH_DETAIL.json")
+    p.add_argument("run", help="run dir, .jsonl file, or detail .json")
     p.add_argument("--json", action="store_true",
                    help="print the machine-readable summary dict")
 

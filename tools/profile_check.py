@@ -12,16 +12,17 @@ Asserts, on the CPU rig (2 virtual devices, chain_<spins>_symm):
 2. **HLO byte-identity** — the local ell and distributed fused apply
    programs are byte-identical with `DMT_PROFILE=sampled` vs off:
    `jax.profiler.trace` observes the program, it never alters it.
-3. **Measured overhead < budget** — sampled windows at a cadence priced
-   from the rig's own measured capture cost keep the overhead ledger
-   under the 2% budget (`profile_overhead_pct`), with PROFILE_META.json
-   stamped into every captured directory.
+3. **Sampled windows on the cadence** — `profile_every=8` over 16 eager
+   applies captures exactly two trace windows (the overhead ledger
+   counts 16 applies, 2 profiled), the newest capture directory stamped
+   with PROFILE_META.json.  What the windows cost is a time and is not
+   gated here; the latch that turns sampling off over budget is
+   `tests/test_profile.py::test_overhead_guard_latches_and_says_so`.
 4. **HLO-vs-measured reconciliation** — `obs_report roofline` carries a
    third per-phase column (`hlo ms`) whose sum equals the measured
    apply wall (the normalization contract; the signal is the split).
-5. **Triggered deep capture** — a bench_trend gate failure forced on a
-   scratch ledger triggers a flight-recorder bundle naming the hottest
-   ops.
+5. **Triggered deep capture** — `obs.trigger_capture` on an incident
+   dumps a flight-recorder bundle naming the hottest ops.
 6. **Differential profiling** — `tools/profile_diff.py` passes on an
    artifact diffed against itself, then FIRES (exit 1) naming the op
    whose bytes were synthetically grown 10x, in the top regression row.
@@ -47,8 +48,6 @@ _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
 sys.path.insert(0, os.path.join(_REPO, "tools"))
 
-OVERHEAD_BUDGET_PCT = 2.0
-TARGET_PCT = 1.0            # cadence priced to aim well under the budget
 RECONCILE_TOL = 0.02        # sum(hlo_ms) vs wall: normalization + rounding
 
 
@@ -57,7 +56,6 @@ def main() -> int:
     import json
     import math
     import tempfile
-    import time
 
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--spins", type=int, default=16,
@@ -107,7 +105,7 @@ def main() -> int:
     xj = jnp.asarray(x)
     xh = ef.to_hashed(x)
     # the apply programs record their cost profiles through the offline
-    # AOT analysis path (analyze_bound_apply), same as bench.py does
+    # AOT analysis path (analyze_bound_apply)
     el.apply_memory_analysis(xj)
     ef.apply_memory_analysis(xh)
     jax.block_until_ready(el.matvec(xj))
@@ -155,81 +153,26 @@ def main() -> int:
         "distributed fused apply HLO changed with DMT_PROFILE=sampled"
     print("[profile-check] HLO byte-identity (profile sampled/off): OK")
 
-    # -- 3. sampled windows under the overhead budget --------------------
-    # absorb the profiler's one-time init (the first trace start pays
-    # backend setup, and the next captures still ride the decay) and
-    # measure the rig's steady per-capture cost from the settled tail
-    warm = os.path.join(scratch, "warmup")
-    warm_ms = []
-    for i in range(4):
-        t0 = time.perf_counter()
-        with jax.profiler.trace(os.path.join(warm, str(i))):
-            el.matvec(xj)
-        warm_ms.append((time.perf_counter() - t0) * 1e3)
-    capture_ms = min(warm_ms[-2:])
-    # calibrate the per-apply wall with the LEDGER's own clock (a
-    # sampled-mode pass at an unreachable cadence): the overhead ratio
-    # is extra/apply as the ledger measures them, so pricing the cadence
-    # from any other clock (e.g. a sync-heavy wall loop) lands off by
-    # the dispatch-vs-sync gap
-    update_config(profile_every=10 ** 9)
+    # -- 3. sampled windows on the cadence ---------------------------------
+    # 2 * every consecutive applies hold exactly two indices divisible by
+    # `every`; the overhead guard can only latch AFTER the second window,
+    # so the count does not depend on what the captures cost
+    every = 8
+    update_config(profile_every=every)
     P.reset_profile()
-    for _ in range(300):
+    for _ in range(2 * every):
         y = el.matvec(xj)
     jax.block_until_ready(y)
-    cal = P.overhead_snapshot()
-    apply_ms = max(cal["apply_ms"] / max(cal["applies"], 1), 1e-3)
-    # cadence priced so two captures amortize to ~TARGET_PCT of the
-    # apply wall; the stop cost of a capture is noisy run-to-run
-    # (70-300 ms on this rig), so a failed attempt RE-PRICES the
-    # cadence from its own measured per-capture cost — only a rig
-    # whose capture cost can't be amortized inside the per-attempt
-    # wall cap fails every attempt
-    capture_est = capture_ms
-    max_attempt_ms = 35000.0           # per-attempt apply-wall cap
-    pct = None
-    snap = None
-    for attempt in range(1, 5):
-        every = int(max(capture_est * 100.0 / (TARGET_PCT * apply_ms), 8))
-        n_applies = 2 * every + 2
-        if n_applies * apply_ms > max_attempt_ms:
-            n_applies = int(max_attempt_ms / apply_ms)
-            every = max(n_applies // 2 - 1, 8)
-        update_config(profile_every=every)
-        print(f"[profile-check] overhead attempt {attempt}: capture "
-              f"~{capture_est:.1f} ms, apply ~{apply_ms:.3f} ms -> "
-              f"profile_every={every}, {n_applies} applies")
-        P.reset_profile()
-        for _ in range(n_applies):
-            y = el.matvec(xj)
-        jax.block_until_ready(y)
-        snap = P.overhead_snapshot()
-        pct = snap["overhead_pct"]
-        if snap["profiled"] >= 2 and pct < OVERHEAD_BUDGET_PCT \
-                and not P.overhead_latched():
-            break
-        print(f"[profile-check] overhead attempt {attempt}: "
-              f"{snap['profiled']} capture(s) at {pct:.2f}% >= "
-              f"{OVERHEAD_BUDGET_PCT}%; re-pricing the cadence from the "
-              f"measured capture cost")
-        if snap["profiled"]:
-            capture_est = snap["extra_ms"] / snap["profiled"]
-        apply_ms = max((snap["apply_ms"] - snap["extra_ms"])
-                       / max(snap["applies"], 1), 1e-3)
-    else:
-        raise AssertionError(
-            f"sampled overhead {pct:.2f}% blew the "
-            f"{OVERHEAD_BUDGET_PCT}% budget on every attempt")
-    # the newest capture directory is stamped with its identity (the
-    # events ring buffer may have evicted the announcement under ~100k
-    # apply_phases events, so read the ledger, not the buffer)
+    snap = P.overhead_snapshot()
+    assert (snap["applies"], snap["profiled"]) == (2 * every, 2), snap
+    # the newest capture directory is stamped with its identity
     assert snap["last_dir"], "no sampled capture directory recorded"
     meta = os.path.join(snap["last_dir"], "PROFILE_META.json")
     assert os.path.exists(meta), f"capture dir not stamped: {meta}"
     stamp = json.load(open(meta))
     assert stamp["capture"] == "sampled" and stamp["engine"] == "local"
-    print(f"[profile-check] overhead: {snap['profiled']} captures, "
-          f"measured {pct:.3f}% < {OVERHEAD_BUDGET_PCT}% budget, "
+    print(f"[profile-check] sampling: {snap['profiled']} captures in "
+          f"{snap['applies']} applies at profile_every={every}, "
           f"PROFILE_META stamped: OK")
 
     # -- 4. roofline third column: sum(hlo ms) == measured wall ----------
@@ -260,26 +203,11 @@ def main() -> int:
     print(f"[profile-check] reconciliation: sum(hlo_ms) {hlo_sum:.3f} vs "
           f"wall {wall:.3f} ms ({err:.2%} <= {RECONCILE_TOL:.0%}): OK")
 
-    # -- 5. triggered deep capture on a forced trend-gate failure --------
-    import bench_trend
-
-    progress = os.path.join(scratch, "PROGRESS.jsonl")
-    detail = {"cfg": {"config": "profile_gate", "n_states": int(n),
-                      "device_ms": 5.0, "hlo_bytes": 1.0e6}}
-    bench_trend.append_record(
-        progress, bench_trend.compact_record(detail, "profile-check", "cpu"))
-    bad = {"cfg": dict(detail["cfg"], device_ms=50.0, hlo_bytes=1.0e7)}
-    bench_trend.append_record(
-        progress, bench_trend.compact_record(bad, "profile-check", "cpu"))
-    _, regs, _ = bench_trend.gate(bench_trend.load_records(progress), 0.3)
-    assert regs, "forced 10x regression did not fire the trend gate"
-    bundle = obs.trigger_capture(
-        "trend_gate", regressions=[
-            dict(zip(("config", "metric", "baseline", "value",
-                      "rel_change"), r)) for r in regs[:8]])
+    # -- 5. triggered deep capture ----------------------------------------
+    bundle = obs.trigger_capture("incident")
     assert bundle and os.path.exists(bundle), \
         f"no flight bundle from the triggered capture: {bundle}"
-    assert "profile_trend_gate" in os.path.basename(bundle), bundle
+    assert "profile_incident" in os.path.basename(bundle), bundle
     payload = json.load(open(bundle))
     hot = payload["profile"]["hlo"]
     assert any(p["program"] == "local_ell_apply" and p["top_ops"]
@@ -287,8 +215,8 @@ def main() -> int:
     trig = [e for e in obs.events("profile_captured")
             if e.get("capture") == "triggered"]
     assert trig and trig[-1]["bundle"] == bundle
-    print(f"[profile-check] triggered capture: trend gate fired "
-          f"({len(regs)} regression(s)) -> {os.path.basename(bundle)}: OK")
+    print(f"[profile-check] triggered capture -> "
+          f"{os.path.basename(bundle)}: OK")
 
     # -- 6. differential profiling: pass, then FIRE on a 10x op ----------
     base_art = next(p["artifact"] for p in H.executable_costs().values()

@@ -14,7 +14,7 @@ cost-like, growth is the regression — the same gate semantics as
 
 For diffing whole RUNS (resolving the newest artifact through their
 ``hlo_cost`` events) use ``obs_report profile <run> <run>``; this tool
-is the artifact-level primitive a fired trend gate shells out to.
+is the artifact-level primitive.
 
 Standalone by construction: loads ``obs/hlo.py`` by file (its
 import-dual header keeps the pure diff surface), never imports the

@@ -12,11 +12,9 @@ Asserts, on the CPU rig (2 virtual devices, chain_<spins>_symm):
    `obs_report roofline` report attributes per-phase wall times that sum
    to the measured apply wall within RECONCILE_TOL (10%), names a binding
    resource from the phase taxonomy, and prints a finite pipelined-apply
-   speedup estimate >= 1.
-3. **Trend gate** — a bench-trend record built from the measured applies
-   appends to a scratch PROGRESS ledger and `bench_trend gate` passes on
-   it; a synthetically regressed record then FAILS the gate (the gate can
-   actually fire).
+   speedup estimate >= 1.  (The sum is an identity of the attribution,
+   which divides the measured wall among the phases: it holds on any
+   machine at any speed.)
 """
 
 import os
@@ -36,7 +34,6 @@ for var in ("DMT_PHASES", "DMT_OBS", "DMT_OBS_DIR"):
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
-sys.path.insert(0, os.path.join(_REPO, "tools"))
 
 RECONCILE_TOL = 0.10
 
@@ -45,14 +42,10 @@ def main() -> int:
     import argparse
     import json
     import tempfile
-    import time
 
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--spins", type=int, default=16,
-                    help="chain length of the gate config (default 16; "
-                         "the recorded chain_24_symm evidence lives in "
-                         "BENCH_STREAM_r05.json — the live gate uses a "
-                         "smaller sector for CI speed)")
+                    help="chain length of the gate config (default 16)")
     args = ap.parse_args()
 
     scratch = tempfile.mkdtemp(prefix="dmt_roofline_check_")
@@ -124,12 +117,9 @@ def main() -> int:
     # pipelined-apply overlap estimate prices a real chunk pipeline
     es = DistributedEngine(op, n_devices=2, mode="streamed", batch_size=32)
     xs = es.to_hashed(x)
-    repeats = 6
-    t0 = time.perf_counter()
-    for _ in range(repeats):
+    for _ in range(6):
         yh = es.matvec(xs)
     jax.block_until_ready(yh)
-    steady_ms = (time.perf_counter() - t0) / repeats * 1e3
     obs.flush()
 
     r = subprocess.run(
@@ -156,8 +146,7 @@ def main() -> int:
     assert sp >= 1.0 and np.isfinite(sp), sp
     print(f"[roofline-check] reconciliation: phases sum {phase_sum:.3f} ms "
           f"vs wall {wall:.3f} ms ({err:.2%} <= {RECONCILE_TOL:.0%}); "
-          f"binding: {grp['binding_resource']}; pipelined est {sp:.2f}x "
-          f"(loop-measured steady {steady_ms:.2f} ms)")
+          f"binding: {grp['binding_resource']}; pipelined est {sp:.2f}x")
 
     # the human-readable rendering must carry the same story
     r = subprocess.run(
@@ -165,36 +154,6 @@ def main() -> int:
          "roofline", run_dir], capture_output=True, text=True)
     assert r.returncode == 0 and "binding resource" in r.stdout \
         and "pipelined-apply estimate" in r.stdout, r.stdout
-
-    # -- 3. trend gate on an appended record -------------------------------
-    import bench_trend
-
-    progress = os.path.join(scratch, "PROGRESS.jsonl")
-    detail = {"gate_cfg": {"config": "roofline_gate", "n_states": int(n),
-                           "streamed_steady_apply_ms": round(steady_ms, 3),
-                           "device_ms": round(steady_ms, 3)}}
-    for _ in range(2):     # baseline + current, same measurement
-        rec = bench_trend.compact_record(detail, "roofline-check", "cpu")
-        assert bench_trend.append_record(progress, rec)
-    r = subprocess.run(
-        [sys.executable, os.path.join(_REPO, "tools", "bench_trend.py"),
-         "gate", "--progress", progress])
-    assert r.returncode == 0, "trend gate failed on an identical record"
-    # and a 10x regression must FAIL the gate
-    bad = {"gate_cfg": dict(detail["gate_cfg"],
-                            streamed_steady_apply_ms=steady_ms * 10,
-                            device_ms=steady_ms * 10)}
-    bench_trend.append_record(
-        progress, bench_trend.compact_record(bad, "roofline-check", "cpu"))
-    r = subprocess.run(
-        [sys.executable, os.path.join(_REPO, "tools", "bench_trend.py"),
-         "gate", "--progress", progress], capture_output=True, text=True)
-    assert r.returncode == 1, \
-        f"trend gate missed a 10x regression: {r.stdout}"
-    # the repo's real ledger parses (may hold zero records on a fresh PR)
-    bench_trend.load_records(bench_trend.default_progress_path())
-    print("[roofline-check] trend gate: passes on appended record, fires "
-          "on a 10x regression")
 
     print("[roofline-check] PASS")
     return 0

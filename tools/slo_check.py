@@ -30,8 +30,10 @@ Asserts, on the CPU rig (isolated scratch run dirs, artifact cache off):
    ``rank_0/postmortem/`` naming the stuck chunk span, and
    ``obs_report postmortem`` verifies it (exit 0).
 
-Deterministic (the injected delay dwarfs scheduler noise), ~60 s on the
-CPU rig.
+Deterministic: the SLOs that grade a wall clock against the run's own
+first quartile are pinned out of reach in the clean legs, so only what
+the gate injects can burn (the injected delay dwarfs scheduler noise).
+~140 s on the CPU rig.
 """
 
 import json
@@ -60,6 +62,19 @@ sys.path.insert(0, os.path.join(_REPO, "tools"))
 
 _CHAIN = {"number_spins": 10, "hamming_weight": 5}
 _N_JOBS = 6
+#: Self-baselined SLOs fire on scheduler noise in a run of a few seconds
+#: (one slow iteration against the median of the first quartile): the
+#: clean legs count alerts of the other SLOs only, and pin these two out
+#: of reach where `obs_report slo` grades a clean stream.
+_SELF_BASELINED = ("steady_apply_ms", "solver_iteration_ms",
+                   "serve_p99_latency_ms", "serve_solves_per_min")
+_WALLS_OFF = ["--target", "steady_apply_ms=1e12",
+              "--target", "solver_iteration_ms=1e12"]
+
+
+def _alerts(events):
+    return [e for e in events if e.get("kind") == "slo_alert"
+            and e.get("slo") not in _SELF_BASELINED]
 
 
 # ---------------------------------------------------------------------------
@@ -230,14 +245,14 @@ def main() -> int:
     # zero alerts on the clean stream, and the snapshot recovered from
     # events.jsonl equals what was scraped (the ISSUE parity acceptance)
     obs.check_slos()
-    alerts = [e for e in obs.events() if e.get("kind") == "slo_alert"]
+    alerts = _alerts(obs.events())
     assert not alerts, f"clean run fired alerts: {alerts}"
     obs.emit("metrics_snapshot", metrics=snap)
     obs.flush()
     recovered = [e for e in _read_events(clean_dir)
                  if e.get("kind") == "metrics_snapshot"][-1]["metrics"]
     assert recovered == scraped, "events.jsonl snapshot != scraped metrics"
-    assert obs_report.main(["slo", clean_dir]) == 0
+    assert obs_report.main(["slo", clean_dir, *_WALLS_OFF]) == 0
     print("[slo-check] clean run: zero alerts, `obs_report slo` exit 0")
 
     # -- 2. DMT_OBS=off no-op ---------------------------------------------
@@ -257,12 +272,12 @@ def main() -> int:
     max_ms = float([ln for ln in out.splitlines()
                     if ln.startswith("MAX_LATENCY_MS=")][0].split("=")[1])
     clean_events = _read_events(serve_clean)
-    assert not [e for e in clean_events if e.get("kind") == "slo_alert"], \
-        "clean serve drain fired alerts"
+    assert not _alerts(clean_events), "clean serve drain fired alerts"
     # the pinned objective: generous over the measured clean worst case,
     # so only the injected delay — never scheduler noise — can burn it
     target = f"serve_p99_latency_ms={1.5 * max_ms:.3f}"
-    assert obs_report.main(["slo", serve_clean, "--target", target]) == 0
+    assert obs_report.main(["slo", serve_clean, "--target", target,
+                            *_WALLS_OFF]) == 0
     print(f"[slo-check] clean drain p99 <= {max_ms:.0f} ms; "
           f"pinned target {target}")
 

@@ -10,12 +10,13 @@ Asserts, on a small |G|>1 config over 2 virtual CPU devices:
    ``exchange_overflow`` / ``exchange_invalid`` series exist in the
    metrics registry (zero being the healthy reading), exactly as fused
    mode reports them.
-3. **Steady-state speedup** — second-and-later streamed applies beat
-   fused, gated through ``tools/obs_report.py diff`` (the direction-aware
-   CI gate: fused is the baseline run, streamed the candidate, threshold
-   ``1/min_speedup − 1`` so missing the speedup exits 1).  Retried like
-   `make obs-check` — wall-clock noise on a shared host passes on a later
-   attempt, a genuine regression fails all three.
+3. **Less work an apply, by count** — the streamed apply's
+   ``apply_phases`` event counts no orbit-scan gathers and fewer compute
+   flops than the fused apply's (the plan was resolved once, at build),
+   moves exactly ``plan_bytes`` host-to-device, and leaves the
+   accumulate phase's counts as they were.  Structural counts from the
+   shapes: they repeat exactly on any machine; what the apply takes is a
+   time and is left to the benchmark's cells.
 4. **Pure host-RAM streaming** — the whole main phase runs with
    ``DMT_ARTIFACT_CACHE=off`` and must write NOTHING under the (scratch)
    artifact root: no disk tier, no sidecars, plan held in RAM only.
@@ -25,7 +26,6 @@ Asserts, on a small |G|>1 config over 2 virtual CPU devices:
 """
 
 import os
-import subprocess
 import sys
 
 # platform pins BEFORE any jax import (same discipline as tests/conftest)
@@ -36,24 +36,15 @@ os.environ["JAX_ENABLE_X64"] = "true"
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, _REPO)
-sys.path.insert(0, os.path.join(_REPO, "tools"))
 
 
 def main() -> int:
     import argparse
-    import json
     import tempfile
-    import time
 
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
-    ap.add_argument("--min-speedup", type=float, default=1.5,
-                    help="required steady-state streamed-vs-fused speedup "
-                         "(default 1.5; the CPU rig measures ~5x+ on "
-                         "chain_24_symm-class configs, this small gate "
-                         "config keeps headroom for shared-host noise)")
     ap.add_argument("--spins", type=int, default=18,
                     help="chain length of the gate config (default 18)")
-    ap.add_argument("--attempts", type=int, default=3)
     args = ap.parse_args()
 
     scratch = tempfile.mkdtemp(prefix="dmt_stream_check_")
@@ -123,45 +114,22 @@ def main() -> int:
         f"DMT_ARTIFACT_CACHE=off still wrote under {art_root}"
     print("[stream-check] cache-off leg: pure host-RAM, no disk writes")
 
-    # -- 3. steady-state speedup via the obs_report diff gate --------------
-    import obs_report
-
-    threshold = 1.0 / args.min_speedup - 1.0
-    repeats = 10
-    ok = False
-    for attempt in range(1, args.attempts + 1):
-        t0 = time.perf_counter()
-        for _ in range(repeats):
-            yh = eng_f.matvec(xf)
-        jax.block_until_ready(yh)
-        fused_ms = (time.perf_counter() - t0) / repeats * 1e3
-        t0 = time.perf_counter()
-        for _ in range(repeats):
-            yh = eng_s.matvec(xs)
-        jax.block_until_ready(yh)
-        stream_ms = (time.perf_counter() - t0) / repeats * 1e3
-        base_j = os.path.join(scratch, f"fused{attempt}.json")
-        new_j = os.path.join(scratch, f"streamed{attempt}.json")
-        for path, ms in ((base_j, fused_ms), (new_j, stream_ms)):
-            with open(path, "w") as f:
-                json.dump({"stream_gate": {"config": "stream_gate",
-                                           "steady_apply_ms": ms}}, f)
-        r = subprocess.run(
-            [sys.executable, os.path.join(_REPO, "tools", "obs_report.py"),
-             "diff", base_j, new_j, "--config", "stream_gate",
-             "--metric", "steady_apply_ms",
-             "--threshold", str(threshold)])
-        print(f"[stream-check] attempt {attempt}: fused {fused_ms:.2f} ms, "
-              f"streamed {stream_ms:.2f} ms "
-              f"({fused_ms / max(stream_ms, 1e-9):.1f}x)")
-        if r.returncode == 0:
-            ok = True
-            break
-        print("[stream-check] speedup gate missed; retrying "
-              "(noise vs a genuine regression resolves by attempt "
-              f"{args.attempts})")
-    assert ok, (f"steady streamed applies never reached "
-                f"{args.min_speedup}x over fused")
+    # -- 3. less work an apply, by count -----------------------------------
+    pev = {e["mode"]: e["phases"] for e in obs.events("apply_phases")
+           if e.get("engine") == "distributed"}
+    pf, ps = pev["fused"], pev["streamed"]
+    assert pf["compute"]["gathers"] > 0 and ps["compute"]["gathers"] == 0, \
+        (pf["compute"], ps["compute"])
+    assert ps["compute"]["flops"] < pf["compute"]["flops"], \
+        (pf["compute"], ps["compute"])
+    assert ps["plan_h2d"]["bytes"] == eng_s.plan_bytes \
+        and pf["plan_h2d"]["bytes"] == 0, (ps["plan_h2d"], eng_s.plan_bytes)
+    assert ps["accumulate"] == pf["accumulate"], \
+        (pf["accumulate"], ps["accumulate"])
+    print(f"[stream-check] counts an apply: compute flops "
+          f"{pf['compute']['flops']} fused -> {ps['compute']['flops']} "
+          f"streamed, orbit-scan gathers {pf['compute']['gathers']} -> 0, "
+          f"plan_h2d {ps['plan_h2d']['bytes']} B = plan_bytes")
 
     # -- 5. artifact-cache round-trip --------------------------------------
     os.environ["DMT_ARTIFACT_CACHE"] = "on"

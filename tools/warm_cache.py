@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Pre-warm the artifact caches for the bench configs.
+"""Pre-warm the artifact caches for a few chain and lattice configs.
 
 Builds, for each selected config, the three construction products the
 default-on artifact layer (``distributed_matvec_tpu/utils/artifacts.py``)
@@ -10,8 +10,8 @@ checkpoints:
   * XLA compiled programs  (``JAX_COMPILATION_CACHE_DIR``, else the
     checkout's ``.cache/xla`` — ``utils/cache.py``)
 
-so the *next* process — ``bench.py``, the CLI, a driver inside a short
-accelerator window — constructs its engines in seconds instead of minutes
+so the *next* process — the CLI, a driver inside a short accelerator
+window — constructs its engines in seconds instead of minutes
 (``make warm-cache``).  Prints one JSON line per config with the cold/warm
 signal: ``basis_restored``/``structure_restored`` are False on the run that
 fills the cache and True on every run after it.
@@ -27,13 +27,19 @@ import argparse
 import json
 import os
 import sys
-import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
+def _chain_symm(n):
+    """The fully symmetric sector of an ``n``-site ring (the basis of
+    ``data/heisenberg_chain_32_symm.yaml`` at ``n`` = 32)."""
+    return dict(number_spins=n, hamming_weight=n // 2, spin_inversion=1,
+                symmetries=[([*range(1, n), 0], 0),
+                            ([*reversed(range(n))], 0)])
+
+
 def _configs(which):
-    from bench import CHAIN_24_SYMM, CHAIN_32_SYMM
     smoke = [("chain_16", dict(number_spins=16, hamming_weight=8), None)]
     if which == "smoke":
         return smoke
@@ -45,28 +51,28 @@ def _configs(which):
          kagome_16_edges()),
         ("square_4x4", dict(number_spins=16, hamming_weight=8),
          square_edges(4, 4)),
-        ("chain_24_symm", CHAIN_24_SYMM, None),
+        ("chain_24_symm", _chain_symm(24), None),
     ]
     if which == "cpu":
         return cpu
-    return cpu + [("chain_32_symm", CHAIN_32_SYMM, None)]
+    return cpu + [("chain_32_symm", _chain_symm(32), None)]
 
 
 def warm_one(name, basis_args, edges):
     import jax
 
-    from bench import _build_op
+    from distributed_matvec_tpu.models.basis import SpinBasis
+    from distributed_matvec_tpu.models.lattices import (chain_edges,
+                                                        heisenberg_from_edges)
     from distributed_matvec_tpu.parallel.engine import LocalEngine
     from distributed_matvec_tpu.utils.artifacts import make_or_restore_basis
 
-    t0 = time.perf_counter()
-    op = _build_op(basis_args, basis_args["number_spins"], edges)
+    op = heisenberg_from_edges(
+        SpinBasis(**basis_args),
+        edges if edges is not None
+        else chain_edges(basis_args["number_spins"]))
     basis_restored = make_or_restore_basis(op.basis)
-    basis_s = time.perf_counter() - t0
-
-    t0 = time.perf_counter()
     eng = LocalEngine(op, mode="ell")          # default artifact cache
-    init_s = time.perf_counter() - t0
     # one apply so the matvec program lands in the XLA cache too
     x = jax.numpy.zeros(op.basis.number_states).at[0].set(1.0)
     jax.block_until_ready(eng._matvec(x)[0])
@@ -74,9 +80,7 @@ def warm_one(name, basis_args, edges):
         "config": name,
         "n_states": op.basis.number_states,
         "basis_restored": bool(basis_restored),
-        "basis_s": round(basis_s, 3),
         "structure_restored": bool(eng.structure_restored),
-        "engine_init_s": round(init_s, 3),
     }
 
 
