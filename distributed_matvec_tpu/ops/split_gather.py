@@ -5,9 +5,16 @@ an emulated-f64 gather issues *two* index-rate-bound gathers — gathers pay
 per index, not per byte.  Splitting ``x`` into three f32 parts
 ``x = a + b + c`` (24-bit mantissa each, 72 ≥ 53 bits total) turns every
 table gather into ONE gather of a ``[..., 3]`` f32 row at the f32 index
-rate — 165 M rows/s (6.05 ns a slot) over chain_32_symm's 4.7 M-row table
-on the attached v5e (PERF.md §5, ledger PR 26; an earlier round read 42 M
-elem/s for the f64 gather it replaced) — and **bit-exact**:
+rate (an earlier round read 42 M elem/s for the f64 gather it replaced).
+On the attached v5e that rate is one of two, whatever the table's size or
+the indices' locality (PERF.md §5 and §6, PR 31): 4.32 ns a slot (232 M
+rows/s) where the table, the indices and the gathered rows fit the chip's
+128 MiB of VMEM together, so that the compiler leaves the gather's result
+there (memory space ``S(1)`` in the optimised HLO; a row of 3 is padded to
+4 lanes, 16 B), and 6.06 ns (165 M rows/s) where the result goes to HBM:
+over chain_32_symm's 4.7 M-row table the step lies between gathers of 2.0
+and 3.3 M rows.  ``LocalEngine`` cuts its gathers to the fitting side
+(``parallel/engine.py::gather_row_blocks``).  The split is **bit-exact**:
 
 * ``a = f32(x)``, ``b = f32(x − a)``, ``c = f32(x − a − b)`` — consecutive
   roundings, so ``b ≲ ulp32(a)``, ``c ≲ ulp32(b)``.

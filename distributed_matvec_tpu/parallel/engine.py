@@ -257,6 +257,52 @@ def staircase_levels(hist: np.ndarray, n_rows: int):
                        for t0, k in zip(starts, widths))
 
 
+#: Bytes of the chip's fast memory (VMEM, 128 MiB on a v5e) that one row
+#: gather's table, indices and gathered rows may take together if the
+#: compiler is to leave the gather's result there (memory space ``S(1)`` in
+#: the optimised HLO) and not write it to HBM.
+GATHER_VMEM_BYTES = 118 * 2 ** 20
+
+
+def gather_row_blocks(n_rows: int, parts: int):
+    """``(nb, B)``: the packed rows of a staircase over ``n_rows`` padded
+    rows cut into ``nb`` contiguous blocks of ``B`` rows (a multiple of
+    :data:`INDEX_TILE`; the last block may be short), ``B`` the most for
+    which one gather of ``B`` rows fits :data:`GATHER_VMEM_BYTES`.
+
+    A row gather whose table, indices and result all fit the chip's VMEM
+    writes there and runs at 4.32 ns a slot on a v5e; one whose result goes
+    to HBM at 6.06 (PERF.md §6, PR 31).  In the chip's layout
+    (``f32[rows, parts]{0,1:T(4,128)}``) a row of ``parts`` f32 values (3
+    for a real vector's split parts, 6 for a pair-form one's) is padded to
+    a multiple of 4 lanes, 16 B a row of 3, in the table and in the result
+    alike, and an index is 4 B.  The table is ``n_rows`` long (``x`` for
+    the levels' gathers, the accumulator for the one that puts the result
+    back in basis order).  Where the table alone leaves no room for a tile
+    of rows, or everything fits as it is, ``nb`` is 1.
+    """
+    row = pad_to_multiple(parts, 4) * 4
+    b_max = (GATHER_VMEM_BYTES - n_rows * row) // (row + 4) \
+        // INDEX_TILE * INDEX_TILE
+    nb = max(-(-n_rows // b_max), 1) if b_max > 0 else 1
+    return int(nb), int(pad_to_multiple(-(-n_rows // nb), INDEX_TILE))
+
+
+def block_pieces(levels, B: int):
+    """The staircase ``levels`` (``(t0, k, L)``, longest first) cut at
+    every ``B`` packed rows: a tuple of blocks, each the tuple of
+    ``(level, r0, rows)`` pieces that lie in it, the longest level's
+    first.  A level is a prefix of the packed order, so it reaches blocks
+    ``0 .. ceil(L / B) - 1`` and every piece but its last is ``B`` long;
+    the pieces' slots add up to the levels'."""
+    blocks = []
+    for b in range(-(-levels[0][2] // B)):
+        blocks.append(tuple(
+            (li, b * B, min(L, (b + 1) * B) - b * B)
+            for li, (_, _, L) in enumerate(levels) if L > b * B))
+    return tuple(blocks)
+
+
 def _stair_order(nnz):
     """``(row_of, pos_of)``: the padded rows by non-zero count, descending
     and stable (``row_of[r]`` is the row at packed position ``r``), and the
@@ -268,16 +314,19 @@ def _stair_order(nnz):
     return row_of, pos_of
 
 
-def _stair_level(tab, row_of, *, t0, k, L):
-    """One staircase level of a left-packed full-width table (indices, or
-    coefficients): columns ``t0..t0+k`` at rows ``row_of[:L]``.  Positions
-    past a column's live rows hold rows whose slot is dead already (index 0,
-    coeff 0)."""
+def _stair_level(tab, row_of, t0, r0, *, k, rows):
+    """One piece of a staircase level of a left-packed full-width table
+    (indices, or coefficients): columns ``t0..t0+k`` at the ``rows`` packed
+    positions from ``r0`` (both run-time scalars: pieces of one shape are
+    one program).  Positions past a column's live rows hold rows whose slot
+    is dead already (index 0, coeff 0)."""
     n_pad = row_of.shape[0]
-    rows = jnp.pad(row_of, (0, max(L - n_pad, 0)))[:L]
-    out = tab[t0:t0 + k][:, rows]
-    if L > n_pad:           # a basis shorter than one tile: blank the fill
-        real = (jnp.arange(L) < n_pad).reshape((1, L) + (1,) * (tab.ndim - 2))
+    fill = -n_pad % INDEX_TILE      # a level's length is rounded to a tile
+    sel = jax.lax.dynamic_slice(jnp.pad(row_of, (0, fill)), (r0,), (rows,))
+    out = jax.lax.dynamic_slice_in_dim(tab, t0, k, axis=0)[:, sel]
+    if fill:                        # blank what lies past the padded rows
+        real = (r0 + jnp.arange(rows) < n_pad).reshape(
+            (1, rows) + (1,) * (tab.ndim - 2))
         out = jnp.where(real, out, 0)
     return out
 
@@ -642,10 +691,11 @@ def unroll_terms_ok(width: int, rows: int, x_shape=()) -> bool:
 
 def ell_term_loop(levels):
     """``(unroll, counts)`` for an apply of ``LocalEngine``'s ELL ``levels``
-    (``(idx, coeff)`` a level): a ``lax.scan`` over each level's columns,
-    unless the ``term_loop`` test hook says ``unroll``.  ``counts`` says
-    which form the columns take: ``unrolled_columns`` and
-    ``scanned_columns``, one of them 0.
+    (``(idx, coeff)`` a level; where the rows are cut into blocks, the
+    first block's pieces, which every level reaches): a ``lax.scan`` over
+    each level's columns, unless the ``term_loop`` test hook says
+    ``unroll``.  ``counts`` says which form the columns take:
+    ``unrolled_columns`` and ``scanned_columns``, one of them 0.
 
     Both forms ran on a v5e at the benchmark's two Hamiltonians (my chip
     runs, PR 28; PERF.md §6).  On the device they are the same gathers at
@@ -921,9 +971,10 @@ class LocalEngine:
 
         h = hashlib.sha256()
         hash_basis_operator(h, self.operator)
-        # the ell layout is v2 (staircase levels); a v1 file (main table +
-        # tail) has another fingerprint and is rebuilt, not misread
-        layout = "v2" if self.mode == "ell" else "v1"
+        # the ell layout is v3 (staircase levels in row blocks); a v2 file
+        # (whole levels) or a v1 file (main table + tail) has another
+        # fingerprint and is rebuilt, not misread
+        layout = "v3" if self.mode == "ell" else "v1"
         h.update(f"{self.mode}|{self.pair}|{self.real}|{self.batch_size}"
                  f"|{self.n_states}|{self.n_padded}|{layout}".encode())
         self._fp_cache = h.hexdigest()
@@ -943,12 +994,14 @@ class LocalEngine:
         if data is None:
             return False
         if self.mode == "ell":
-            if "levels" not in data:
+            if "block_pieces" not in data:
                 return False
-            self._ell_levels = tuple(
-                (jnp.asarray(data[f"level{i}_idx"]),
-                 jnp.asarray(data[f"level{i}_coeff"]))
-                for i in range(int(data["levels"])))
+            sizes = [int(v) for v in str(data["block_pieces"]).split(",")]
+            self._ell_blocks = tuple(
+                tuple((jnp.asarray(data[f"level{i}_idx"]),
+                       jnp.asarray(data[f"level{i}_coeff"]))
+                      for i in range(end - size, end))
+                for size, end in zip(sizes, np.cumsum(sizes)))
             self._ell_pos_of = jnp.asarray(data["pos_of"]) \
                 if "pos_of" in data else None
             self._ell_counts = {
@@ -956,7 +1009,9 @@ class LocalEngine:
                    ("gather_slots", "live_entries", "levels")},
                 "terms": self.num_terms,
                 "widest_row": sum(int(idx.shape[0])
-                                  for idx, _ in self._ell_levels)}
+                                  for idx, _ in self._ell_blocks[0]),
+                "row_blocks": int(data["row_blocks"]),
+                "gather_pieces": int(data["gather_pieces"])}
         else:
             self._ell_T0 = int(data["T0"])
             self._c_W = float(data["W"])
@@ -980,7 +1035,10 @@ class LocalEngine:
         from ..io.hdf5 import save_engine_structure
 
         if self.mode == "ell":
-            payload = dict(self._ell_counts)
+            # the pieces block by block under the levels' names (they are
+            # the levels where the rows are not cut), and how many a block
+            payload = dict(self._ell_counts, block_pieces=",".join(
+                str(len(blk)) for blk in self._ell_blocks))
             for i, (idx_l, cf_l) in enumerate(self._ell_levels):
                 payload[f"level{i}_idx"] = np.asarray(idx_l)
                 payload[f"level{i}_coeff"] = np.asarray(cf_l)
@@ -1006,6 +1064,12 @@ class LocalEngine:
         log_debug(f"engine structure checkpointed to {sidecar}")
 
     # -- structure build (ell mode) -----------------------------------------
+
+    @property
+    def _ell_levels(self):
+        """Every ``(idx, coeff)`` piece of the staircase, block by block:
+        the levels themselves where the rows are not cut."""
+        return tuple(p for blk in self._ell_blocks for p in blk)
 
     def _chunk_structure(self, tables, pair, dir_tab, alphas, norms_a):
         """Shared device pass for one row chunk: kernels → basis lookup →
@@ -1085,37 +1149,53 @@ class LocalEngine:
         self._stair_ell(idx_buf, coeff_buf, nnz)
 
     def _plan_levels(self, hist: np.ndarray):
-        """``staircase_levels`` of the build's histogram, and the counts
-        that say how far the format engages (``_ell_counts``: on the build
-        span and in the ``engine_init`` event; ``terms`` is the build
-        table's width, a slot an off-diagonal term, ``widest_row`` the
-        columns the levels keep)."""
+        """``staircase_levels`` of the build's histogram, their cut into
+        row blocks (:func:`gather_row_blocks`, :func:`block_pieces`), and
+        the counts that say how far the format engages (``_ell_counts``: on
+        the build span and in the ``engine_init`` event; ``terms`` is the
+        build table's width, a slot an off-diagonal term, ``widest_row``
+        the columns the levels keep, ``row_blocks`` 1 where the rows are
+        not cut, ``gather_pieces`` the row gathers of one apply)."""
         stair, levels = staircase_levels(hist, self.n_padded)
         slots = sum(k * L for _, k, L in levels)
+        # the plain table stays whole: it is the build's own, in basis order
+        nb, B, plan = 1, levels[0][2], (((0, 0, levels[0][2]),),)
+        if stair:
+            nb, B = gather_row_blocks(self.n_padded, 3 if self.real else 6)
+            plan = block_pieces(levels, B)
+            if len(plan) == 1:      # the levels end inside the first block
+                nb = 1
         self._ell_counts = {
             "gather_slots": slots + (self.n_padded if stair else 0),
             "live_entries": int(np.dot(np.arange(hist.size), hist)),
             "levels": len(levels),
             "terms": self.num_terms,
-            "widest_row": sum(k for _, k, _ in levels)}
+            "widest_row": sum(k for _, k, _ in levels),
+            "row_blocks": nb,
+            "gather_pieces": sum(map(len, plan)) + (nb if stair else 0)}
         log_debug(f"ell levels: T={self.num_terms} stair={stair} "
                   f"levels={levels} entries {self.n_padded * self.num_terms}"
-                  f" -> {slots}")
-        return stair, levels
+                  f" -> {slots} in {nb} row blocks of {B}")
+        return stair, levels, plan
 
     def _stair_ell(self, idx_buf, coeff_buf, nnz) -> None:
         """Cut the left-packed full-width tables into staircase levels.
 
         The matvec cost is per gathered *slot*, whatever the slot holds (TPU
-        row gathers run at a fixed index rate regardless of locality — 165 M
-        rows/s, 6.05 ns a slot, at chain_32_symm on v5e: PERF.md §5), while
-        the table's width is the widest row's and the mean row holds about
+        row gathers run at an index rate regardless of locality — on a v5e
+        4.32 ns a slot where table, indices and result fit the chip's VMEM
+        and 6.06 where the result goes to HBM: PERF.md §5), while the
+        table's width is the widest row's and the mean row holds about
         half of that.  With rows ordered by non-zero count, column ``t``
         needs only the rows that have more than ``t`` entries
         (:func:`staircase_levels`), so the levels hold one slot a non-zero
         plus the rounding of each column to :data:`INDEX_TILE`; the matvec
         accumulates in that order and one more gather puts the result back
         in basis order.  Rows of near-equal width keep the plain table.
+        The packed rows are cut into blocks short enough for every gather
+        to write to VMEM (:func:`gather_row_blocks`), a level into one
+        piece for each block it reaches; the cut is made here and not in
+        the apply, where slices of whole levels would be materialised.
         """
         T = self.num_terms
         with obs_trace.span("ell/count", kind="phase"):
@@ -1126,40 +1206,44 @@ class LocalEngine:
             with obs_trace.span("device_wait", kind="phase",
                                 at="ell_count"):
                 hist = np.asarray(hist)
-            stair, levels = self._plan_levels(hist)
+            stair, levels, plan = self._plan_levels(hist)
         self._ell_pos_of = None
         if not stair:
             Tmax = levels[0][1]
-            self._ell_levels = ((idx_buf, coeff_buf) if Tmax == T else
-                                (idx_buf[:Tmax], coeff_buf[:Tmax]),)
+            self._ell_blocks = (((idx_buf, coeff_buf) if Tmax == T else
+                                 (idx_buf[:Tmax], coeff_buf[:Tmax]),),)
             return
 
-        # One program a level and table, the index table's first and then
-        # let go: a program over an f64 table holds a 32-bit half of the
-        # WHOLE table as a temporary (the TPU's f64 emulation splits the
-        # argument before it is sliced), so the build's peak is the
-        # coefficient table + that half + the levels.
+        # One program a piece shape and table, the index table's first and
+        # then let go: a program over an f64 table holds a 32-bit half of
+        # the WHOLE table as a temporary (the TPU's f64 emulation splits
+        # the argument before it is sliced), so the build's peak is the
+        # coefficient table + that half + the pieces.
         with obs_trace.span("ell/stair_levels", kind="phase"):
             order = precompile("ell_stair_order", (), jax.jit(_stair_order),
                                (nnz,), self.timer)
             row_of, self._ell_pos_of = order(nnz)
 
             def cut(name, tab):
-                return [precompile(
-                    name, (t0, k, L),
-                    jax.jit(partial(_stair_level, t0=t0, k=k, L=L)),
-                    (tab, row_of), self.timer)(tab, row_of)
-                    for t0, k, L in levels]
+                def piece(li, r0, rows):
+                    t0, k, _ = levels[li]
+                    args = (tab, row_of, jnp.int32(t0), jnp.int32(r0))
+                    return precompile(
+                        name, (k, rows),
+                        jax.jit(partial(_stair_level, k=k, rows=rows)),
+                        args, self.timer)(*args)
+                return [[piece(*p) for p in blk] for blk in plan]
 
-            idx_levels = cut("ell_stair_idx", idx_buf)
-            # let the index table go before the coefficient levels are
+            idx_blocks = cut("ell_stair_idx", idx_buf)
+            # let the index table go before the coefficient pieces are
             # allocated (the caller's frame still names it, hence delete)
             with obs_trace.span("device_wait", kind="phase",
                                 at="ell_stair_idx"):
-                jax.block_until_ready(idx_levels)
+                jax.block_until_ready(idx_blocks)
             idx_buf.delete()
-            self._ell_levels = tuple(
-                zip(idx_levels, cut("ell_stair_coeff", coeff_buf)))
+            self._ell_blocks = tuple(
+                tuple(zip(ib, cb)) for ib, cb in
+                zip(idx_blocks, cut("ell_stair_coeff", coeff_buf)))
 
     def _count_row_nnz(self, alphas_c, norms_c):
         """Counting pass shared by the low-memory builds: per-chunk row-nnz
@@ -1227,7 +1311,7 @@ class LocalEngine:
         O(b·T) chunk scratch instead of the full-width [T, N_pad] tables —
         what makes square_6x6 (N=15.8M, T=72: 13.7 GB full-width vs ~7 GB
         packed) buildable on one 16 GB chip.  Same arrays as the one-pass
-        build.
+        build, piece for piece.
         """
         b, C = self.batch_size, self.num_chunks
         alphas, norms = self._alphas, self._norms
@@ -1237,7 +1321,7 @@ class LocalEngine:
 
         hist, nnz_chunks = self._count_row_nnz(alphas.reshape(C, b),
                                                norms.reshape(C, b))
-        stair, levels = self._plan_levels(hist)
+        stair, levels, plan = self._plan_levels(hist)
         self._ell_pos_of = None
         if stair:
             row_of = np.argsort(-np.concatenate(nnz_chunks), kind="stable")
@@ -1249,7 +1333,7 @@ class LocalEngine:
         alphas_c, norms_c = alphas.reshape(C, b), norms.reshape(C, b)
 
         # -- pass 2: pack into donated level buffers, a whole number of
-        # chunks long each (``_lowmem_pack_chunk``), cut to length after
+        # chunks long each (``_lowmem_pack_chunk``), cut to pieces after
         bufs = tuple(
             (jnp.zeros((k, pad_to_multiple(L, b)), jnp.int32),
              jnp.zeros((k, pad_to_multiple(L, b)) + pz, cdtype))
@@ -1267,9 +1351,10 @@ class LocalEngine:
             bufs = pack_chunk(bufs, self.tables, self._lk_pair,
                               self._lk_dir, alphas_c[ci], norms_c[ci],
                               jnp.int32(ci * b))
-        self._ell_levels = tuple(
-            (idx_l[:, :L], cf_l[:, :L])
-            for (_, _, L), (idx_l, cf_l) in zip(levels, bufs))
+        self._ell_blocks = tuple(
+            tuple((bufs[li][0][:, r0:r0 + rows], bufs[li][1][:, r0:r0 + rows])
+                  for li, r0, rows in blk)
+            for blk in plan)
 
     def _build_compact(self) -> None:
         """4-bytes-per-entry structure for real sectors with one off-diagonal
@@ -1438,7 +1523,7 @@ class LocalEngine:
             return jnp.pad(acc, [(0, short)] + [(0, 0)] * (acc.ndim - 1))
 
         def apply_fn(x, operands):
-            levels, pos_of, diag = operands
+            blocks, pos_of, diag = operands
             x = jnp.asarray(x).astype(dtype)
             batched = x.ndim == nd_base + 1
             # the named scopes are metadata on the operations (their
@@ -1446,7 +1531,7 @@ class LocalEngine:
             # operation is added, moved or split for them
             with jax.named_scope("apply/split"):
                 gx = prep_gather(x, dtype, use_sg)
-            unroll, form = ell_term_loop(levels)
+            unroll, form = ell_term_loop(blocks[0])
             # on the span this trace runs under (``apply``, or the solver's
             # ``lanczos/dispatch``): which form the program it builds takes
             obs_trace.current_span().add(**form)
@@ -1469,26 +1554,35 @@ class LocalEngine:
                     acc, _ = jax.lax.scan(step, acc, (idx, coeff))
                 return acc
 
-            # ``acc`` is in packed row order: from the shortest level (the
-            # widest rows' last columns) to the longest, each level adding
-            # to the head of the next accumulator
+            # ``acc`` is in packed row order, a row block at a time: from
+            # the block's shortest piece (the widest rows' last columns) to
+            # its longest, each adding to the head of the next accumulator.
+            # Every block but the last is as long as its longest piece, so
+            # the blocks' accumulators laid end to end are the whole one.
             with jax.named_scope("apply/terms"):
-                acc = jnp.zeros((0,) + x.shape[1:], dtype)
-                for idx, coeff in reversed(levels):
-                    acc = terms(grown(acc, idx.shape[1]), idx, coeff)
+                accs = []
+                for pieces in blocks:
+                    acc = jnp.zeros((0,) + x.shape[1:], dtype)
+                    for idx, coeff in reversed(pieces):
+                        acc = terms(grown(acc, idx.shape[1]), idx, coeff)
+                    accs.append(acc)
+                acc = accs[0] if len(accs) == 1 else jnp.concatenate(accs)
             if pos_of is not None:
+                # back to basis order, a block's length of rows a gather
                 with jax.named_scope("apply/unpermute"):
-                    acc = prep_gather(grown(acc, n_pad), dtype,
-                                      use_sg)(pos_of)
+                    ga = prep_gather(grown(acc, n_pad), dtype, use_sg)
+                    B = accs[0].shape[0]
+                    acc = ga(pos_of) if len(accs) == 1 else jnp.concatenate(
+                        [ga(pos_of[r0:r0 + B]) for r0 in range(0, n_pad, B)])
             with jax.named_scope("apply/diag"):
                 d = diag[:n].astype(dtype)
                 y = d.reshape((n,) + (1,) * (x.ndim - 1)) * x + acc[:n]
             return y, jnp.zeros((), jnp.int64)
 
         self._apply_fn = apply_fn
-        self._operands = (self._ell_levels, self._ell_pos_of, self._diag)
+        self._operands = (self._ell_blocks, self._ell_pos_of, self._diag)
         #: the form the apply takes (``engine_init`` event)
-        self._ell_form = ell_term_loop(self._ell_levels)[1]
+        self._ell_form = ell_term_loop(self._ell_blocks[0])[1]
         _mv = jax.jit(apply_fn)
         return lambda x: _mv(x, self._operands)
 

@@ -5,6 +5,8 @@ rtol 1e-12, full pipeline) applied to the device path, in both engine modes
 (precomputed-ELL and fused/on-the-fly).
 """
 
+from functools import partial
+
 import numpy as np
 import pytest
 
@@ -130,6 +132,21 @@ def _stair_engine(name, **kw):
     return op, eng
 
 
+def _cut_into(monkeypatch, eng, nb):
+    """Steer the block rule through its input, not through an option of
+    the program: the VMEM number under which ``eng``'s padded rows are cut
+    into ``nb`` blocks.  Returns the block length."""
+    from distributed_matvec_tpu.parallel import engine
+
+    n_pad, parts = eng.n_padded, 3 if eng.real else 6
+    row = engine.pad_to_multiple(parts, 4) * 4
+    B = engine.pad_to_multiple(-(-n_pad // nb), engine.INDEX_TILE)
+    monkeypatch.setattr(engine, "GATHER_VMEM_BYTES",
+                        n_pad * row + B * (row + 4))
+    assert engine.gather_row_blocks(n_pad, parts) == (nb, B)
+    return B
+
+
 def _independent_apply(op, X):
     """H·X from the definitions alone: the symmetry isometry of
     ``dense_ref`` around ``independent_ref``'s bit-operation ring apply on
@@ -225,7 +242,8 @@ def test_staircase_accounting(rng):
         "gather_slots": int(lengths.sum()) + n_pad,
         "live_entries": int(nnz.sum()),
         "levels": len(set(lengths)),
-        "terms": 16, "widest_row": Tmax}
+        "terms": 16, "widest_row": Tmax,
+        "row_blocks": 1, "gather_pieces": len(set(lengths)) + 1}
     # the level arrays are those columns, longest first, and nothing else
     assert [i.shape for i, _ in eng._ell_levels] == \
         [(int((lengths == L).sum()), int(L))
@@ -271,6 +289,109 @@ def test_staircase_apply_has_no_scatter(term_loop):
         (width if term_loop == "unroll" else len(eng._ell_levels)) + 1
 
 
+def test_row_block_rule_reads_the_shapes():
+    """gather_row_blocks: the block length from the table's rows, the lanes
+    of one gathered row and one number for the chip's VMEM — the two
+    benchmark bases, what the trace measured on either side of the step,
+    a pair-form table, and the cases where the rows stay whole."""
+    from distributed_matvec_tpu.parallel.engine import (
+        GATHER_VMEM_BYTES, INDEX_TILE, block_pieces, gather_row_blocks)
+
+    assert gather_row_blocks(4_718_592, 3) == (2, 2_359_296)   # chain_32_symm
+    assert gather_row_blocks(5_242_880, 3) == (3, 1_747_968)   # square_5x5
+    # what ran at 4.317 ns a slot fits by the rule, what ran at 6.056 does
+    # not (PERF.md §5: table rows x 16 B + gathered rows x 20 B)
+    for rows, gathered, fits in ((4_707_969, 2_030_592, True),
+                                 (4_707_969, 3_326_976, False),
+                                 (5_200_300, 1_949_696, True),
+                                 (5_200_300, 3_198_976, False)):
+        assert (rows * 16 + gathered * 20 <= GATHER_VMEM_BYTES) == fits
+    # six parts take eight lanes: half the rows of the chain cut in two,
+    # the chain itself (151 MB of table) and square_6x6 (253 MB) left whole
+    assert gather_row_blocks(2_359_296, 6) == (2, 1_179_648)
+    assert gather_row_blocks(4_718_592, 6)[0] == 1
+    assert gather_row_blocks(15_859_712, 3)[0] == 1
+    # a basis shorter than one block, and an empty one
+    assert gather_row_blocks(13_000, 3) == (1, 13 * INDEX_TILE)
+    assert gather_row_blocks(0, 3) == (1, 0)
+    # a level is a prefix of the packed rows: one piece a block it reaches
+    levels = ((0, 6, 5 * INDEX_TILE), (6, 2, 3 * INDEX_TILE),
+              (8, 4, INDEX_TILE))
+    T = INDEX_TILE
+    assert block_pieces(levels, 2 * T) == (
+        ((0, 0, 2 * T), (1, 0, 2 * T), (2, 0, T)),
+        ((0, 2 * T, 2 * T), (1, 2 * T, T)),
+        ((0, 4 * T, T),))
+    assert block_pieces(levels, 5 * T) == (
+        tuple((li, 0, L) for li, (_, _, L) in enumerate(levels)),)
+
+
+@pytest.mark.parametrize("name, batch_size, nb", [
+    ("ring16", 1000, 2),             # plain, padded rows (13,000 of 12,870)
+    ("ring16", 1000, 3),
+    ("ring16", 1000, 5),
+    ("symm_ring20", None, 2),        # symmetric; rows not a tile multiple
+    ("symm_ring20", None, 3),
+    ("momentum_ring18", 512, 2),     # pair form: eight lanes a row
+    ("momentum_ring18", 512, 3),
+])
+@pytest.mark.parametrize("term_loop", ["auto", "unroll"])
+def test_row_blocked_apply_is_the_unblocked_one(name, batch_size, nb,
+                                                term_loop, rng, pair_form,
+                                                monkeypatch):
+    """The staircase cut into 2, 3 and 5 row blocks: the counts of the
+    uncut one but for ``row_blocks`` and ``gather_pieces``, its levels
+    piece for piece, and the same apply bit for bit — one vector and a
+    batch, in both forms of the term loop."""
+    from distributed_matvec_tpu.utils.config import update_config
+
+    kw = {} if batch_size is None else {"batch_size": batch_size}
+    op, whole = _stair_engine(name, **kw)
+    B = _cut_into(monkeypatch, whole, nb)
+    cut = LocalEngine(op, mode="ell", **kw)
+    assert cut._ell_counts["row_blocks"] == nb == len(cut._ell_blocks)
+    assert cut._ell_counts["gather_pieces"] == len(cut._ell_levels) + nb
+    assert {**cut._ell_counts, "row_blocks": 1,
+            "gather_pieces": len(whole._ell_levels) + 1} == whole._ell_counts
+    np.testing.assert_array_equal(np.asarray(cut._ell_pos_of),
+                                  np.asarray(whole._ell_pos_of))
+    # block b holds rows [b B, (b + 1) B) of every level that reaches it,
+    # the longest level first
+    for b, pieces in enumerate(cut._ell_blocks):
+        reach = [lv for lv in whole._ell_levels if lv[0].shape[1] > b * B]
+        assert len(pieces) == len(reach)
+        for (i_c, c_c), (i_w, c_w) in zip(pieces, reach):
+            rows = slice(b * B, (b + 1) * B)
+            np.testing.assert_array_equal(np.asarray(i_c),
+                                          np.asarray(i_w)[:, rows])
+            np.testing.assert_array_equal(np.asarray(c_c),
+                                          np.asarray(c_w)[:, rows])
+    assert cut.ell_nbytes == whole.ell_nbytes
+    assert cut._phase_counts(1) == whole._phase_counts(1)
+    n = cut.n_states
+    X = rng.random((n, 3)) - 0.5
+    if cut.pair:
+        from distributed_matvec_tpu.ops import kernels as K
+
+        X = K.pair_from_complex(X + 1j * (rng.random((n, 3)) - 0.5))
+    update_config(term_loop=term_loop)
+    try:
+        # the scan form, which every engine takes, repeats bit for bit;
+        # unrolled, the compiler sees all of a row's adds and may contract
+        # them differently in two programs
+        same = np.testing.assert_array_equal if term_loop == "auto" else \
+            partial(np.testing.assert_allclose, rtol=1e-13, atol=1e-14)
+        for x in (X[:, 0], X):
+            same(np.asarray(cut.matvec(x)), np.asarray(whole.matvec(x)))
+        prims = _apply_primitives(cut, X[:, 0])
+    finally:
+        update_config(term_loop="auto")
+    assert "scatter-add" not in prims
+    width = sum(i.shape[0] for i, _ in cut._ell_levels)
+    assert prims.count("gather") == cut._ell_counts["gather_pieces"] \
+        + (width - len(cut._ell_levels) if term_loop == "unroll" else 0)
+
+
 def test_equal_width_rows_keep_plain_table(rng):
     """An operator whose rows are equally wide (a transverse field on the
     full basis: every state flips at each of its n sites) reads one level
@@ -286,7 +407,8 @@ def test_equal_width_rows_keep_plain_table(rng):
     assert [i.shape for i, _ in eng._ell_levels] == [(n, eng.n_padded)]
     assert eng._ell_counts == {"gather_slots": n * eng.n_padded,
                                "live_entries": n * 2 ** n, "levels": 1,
-                               "terms": n, "widest_row": n}
+                               "terms": n, "widest_row": n,
+                               "row_blocks": 1, "gather_pieces": 1}
     x = rng.random(2 ** n) - 0.5
     prims = _apply_primitives(eng, x)
     # one level, its columns scanned: one gather, and none to un-permute
@@ -323,15 +445,20 @@ def test_staircase_levels_reads_the_histogram():
         == (False, ((0, 0, 0),))
 
 
-@pytest.mark.parametrize("name, batch_size", [
-    ("ring16", 61),              # staircase, chunks that straddle levels
-    ("momentum_ring18", 512),    # staircase, pair-form coefficients
-    ("small_momentum_ring12", 61),   # plain table (under one index tile)
+@pytest.mark.parametrize("name, batch_size, nb", [
+    ("ring16", 61, 1),           # staircase, chunks that straddle levels
+    ("momentum_ring18", 512, 1),     # staircase, pair-form coefficients
+    ("small_momentum_ring12", 61, 1),    # plain table (under one index tile)
+    ("ring16", 61, 3),           # ... cut into row blocks (12,871 rows)
+    ("ring16", 1000, 5),
+    ("momentum_ring18", 512, 2),
 ])
-def test_lowmem_build_matches_onepass(name, batch_size, rng, pair_form):
+def test_lowmem_build_matches_onepass(name, batch_size, nb, rng, pair_form,
+                                      monkeypatch):
     """The two-pass low-memory ELL build (count → pack in packed row order)
-    produces the arrays of the one-pass build, level for level, and a
-    bit-identical matvec."""
+    produces the arrays of the one-pass build, level for level and, where
+    the rows are cut into blocks, piece for piece, and a bit-identical
+    matvec."""
     from distributed_matvec_tpu.utils.config import get_config, update_config
 
     spec = STAIR_RINGS.get(name) or (12, 6, None, [(_ring(12), 2)])
@@ -340,6 +467,10 @@ def test_lowmem_build_matches_onepass(name, batch_size, rng, pair_form):
     prev_budget = get_config().ell_build_budget_gb
     try:
         eng_ref = LocalEngine(op, batch_size=batch_size, mode="ell")
+        if nb > 1:
+            _cut_into(monkeypatch, eng_ref, nb)
+            eng_ref = LocalEngine(op, batch_size=batch_size, mode="ell")
+            assert len(eng_ref._ell_blocks) == nb
         update_config(ell_build_budget_gb=1e-9)   # force two-pass
         eng_lm = LocalEngine(op, batch_size=batch_size, mode="ell")
     finally:
@@ -347,6 +478,8 @@ def test_lowmem_build_matches_onepass(name, batch_size, rng, pair_form):
     assert (eng_ref._ell_pos_of is not None) == (name in STAIR_RINGS)
     assert eng_lm._ell_counts == eng_ref._ell_counts
     assert len(eng_lm._ell_levels) == len(eng_ref._ell_levels)
+    assert [len(b) for b in eng_lm._ell_blocks] == \
+        [len(b) for b in eng_ref._ell_blocks]
     for (i_lm, c_lm), (i_ref, c_ref) in zip(eng_lm._ell_levels,
                                             eng_ref._ell_levels):
         np.testing.assert_array_equal(np.asarray(i_lm), np.asarray(i_ref))
@@ -470,11 +603,13 @@ def test_structure_cache_roundtrip(tmp_path, rng):
                                atol=1e-13)
 
 
-def test_structure_cache_staircase_layout(tmp_path, rng):
-    """The staircase checkpoints and restores level for level, with its
-    row order and counts; a file in the old layout (main table + tail) is
-    refused — by fingerprint as an old build wrote it, by its keys should
-    the fingerprint ever match — and rebuilt, not misread."""
+@pytest.mark.parametrize("nb", [1, 3], ids=["whole", "three_row_blocks"])
+def test_structure_cache_staircase_layout(tmp_path, rng, nb, monkeypatch):
+    """The staircase checkpoints and restores piece for piece (level for
+    level where the rows are not cut), with its row order and counts; a
+    file in an older layout (v1: main table + tail; v2: whole levels and
+    no blocks) is refused — by fingerprint as an old build wrote it, by its
+    keys should the fingerprint ever match — and rebuilt, not misread."""
     import hashlib
 
     from distributed_matvec_tpu.io.hdf5 import (load_engine_structure,
@@ -483,7 +618,11 @@ def test_structure_cache_staircase_layout(tmp_path, rng):
 
     path = str(tmp_path / "stair.h5")
     sidecar = LocalEngine._structure_sidecar(path)
-    op, eng1 = _stair_engine("symm_ring20", structure_cache=path)
+    op, eng1 = _stair_engine("symm_ring20")
+    if nb > 1:
+        _cut_into(monkeypatch, eng1, nb)
+    eng1 = LocalEngine(op, mode="ell", structure_cache=path)
+    assert not eng1.structure_restored and len(eng1._ell_blocks) == nb
     x = rng.random(eng1.n_states) - 0.5
     y1 = np.asarray(eng1.matvec(x))
     eng2 = LocalEngine(op, mode="ell", structure_cache=path)
@@ -491,6 +630,8 @@ def test_structure_cache_staircase_layout(tmp_path, rng):
     assert eng2._ell_counts == eng1._ell_counts
     np.testing.assert_array_equal(np.asarray(eng2._ell_pos_of),
                                   np.asarray(eng1._ell_pos_of))
+    assert [len(b) for b in eng2._ell_blocks] == \
+        [len(b) for b in eng1._ell_blocks]
     for (i2, c2), (i1, c1) in zip(eng2._ell_levels, eng1._ell_levels):
         np.testing.assert_array_equal(np.asarray(i2), np.asarray(i1))
         np.testing.assert_array_equal(np.asarray(c2), np.asarray(c1))
@@ -511,20 +652,26 @@ def test_structure_cache_staircase_layout(tmp_path, rng):
     assert st["table_bytes"] == eng1.ell_nbytes
 
     T0 = 8
-    old = {"T0": T0, "idx": np.zeros((T0, eng1.n_padded), np.int32),
-           "coeff": np.zeros((T0, eng1.n_padded))}
-    h = hashlib.sha256()
-    hash_basis_operator(h, op)
-    h.update(f"ell|False|True|{eng1.batch_size}|{eng1.n_states}"
-             f"|{eng1.n_padded}|v1".encode())
-    for fingerprint in (h.hexdigest(), eng1._structure_fingerprint()):
-        save_engine_structure(sidecar, fingerprint, "ell", old)
-        eng3 = LocalEngine(op, mode="ell", structure_cache=path)
-        assert not eng3.structure_restored
-        np.testing.assert_array_equal(y1, np.asarray(eng3.matvec(x)))
-        # ... and the rebuild replaced the file with the new layout
-        assert "levels" in load_engine_structure(
-            sidecar, eng1._structure_fingerprint())
+    v1 = {"T0": T0, "idx": np.zeros((T0, eng1.n_padded), np.int32),
+          "coeff": np.zeros((T0, eng1.n_padded))}
+    v2 = {"gather_slots": 1, "live_entries": 1, "levels": 1,
+          "level0_idx": np.zeros((T0, eng1.n_padded), np.int32),
+          "level0_coeff": np.zeros((T0, eng1.n_padded)),
+          "pos_of": np.arange(eng1.n_padded, dtype=np.int32)}
+    for layout, old in (("v1", v1), ("v2", v2)):
+        h = hashlib.sha256()
+        hash_basis_operator(h, op)
+        h.update(f"ell|False|True|{eng1.batch_size}|{eng1.n_states}"
+                 f"|{eng1.n_padded}|{layout}".encode())
+        assert h.hexdigest() != eng1._structure_fingerprint()
+        for fingerprint in (h.hexdigest(), eng1._structure_fingerprint()):
+            save_engine_structure(sidecar, fingerprint, "ell", old)
+            eng3 = LocalEngine(op, mode="ell", structure_cache=path)
+            assert not eng3.structure_restored
+            np.testing.assert_array_equal(y1, np.asarray(eng3.matvec(x)))
+            # ... and the rebuild replaced the file with the new layout
+            assert "block_pieces" in load_engine_structure(
+                sidecar, eng1._structure_fingerprint())
 
 
 def test_structure_cache_pair_roundtrip(tmp_path, rng):

@@ -382,16 +382,24 @@ def test_structure_build_pass_spans_on_one_device(clean_trace, annotations):
             and e["parent_span_id"] is None][:1] == ["engine_init/transfer"]
 
 
-def test_build_span_counts_how_far_the_staircase_engages(clean_trace):
+@pytest.mark.parametrize("nb", [1, 3], ids=["whole", "three_row_blocks"])
+def test_build_span_counts_how_far_the_staircase_engages(clean_trace, nb,
+                                                         monkeypatch):
     """``gather_slots``, ``live_entries`` and ``levels`` on the build span
     and in the ``engine_init`` event, and the benchmark's reader of their
     ratio (``benchmark/metrics/gather_fill_pct.py``): 16-site ring, 12,870
-    rows, one entry a domain wall."""
+    rows, one entry a domain wall.  ``row_blocks`` and ``gather_pieces``
+    beside them say how the rows are cut (``row_blocks`` 1: not at all);
+    the cut moves none of the other counts."""
     import importlib.util
     from types import SimpleNamespace
 
+    from distributed_matvec_tpu.parallel import engine
     from distributed_matvec_tpu.parallel.engine import LocalEngine
 
+    if nb > 1:      # the rule's VMEM number that cuts 12,870 rows in three
+        monkeypatch.setattr(engine, "GATHER_VMEM_BYTES",
+                            12_870 * 16 + 5 * 1024 * 20)
     eng = LocalEngine(build_heisenberg(16, hw=8), mode="ell")
     assert eng._ell_pos_of is not None
     build = [e for e in obs.events("span")
@@ -400,10 +408,14 @@ def test_build_span_counts_how_far_the_staircase_engages(clean_trace):
         e["name"] for e in obs.events("span")
         if e["parent_span_id"] == build[0]["span_id"]}
     counts = {k: build[0][k] for k in ("gather_slots", "live_entries",
-                                       "levels", "terms", "widest_row")}
+                                       "levels", "terms", "widest_row",
+                                       "row_blocks", "gather_pieces")}
     assert counts == eng._ell_counts
     assert (counts["terms"], counts["widest_row"]) == (16, 16)
     assert counts["live_entries"] == 109_824    # 16 bonds x 2 x C(14, 7)
+    assert (counts["gather_slots"], counts["levels"]) == (133_702, 5)
+    assert (counts["row_blocks"], counts["gather_pieces"]) == \
+        {1: (1, 6), 3: (3, 12)}[nb]
     init = obs.events("engine_init")[-1]
     assert {k: init[k] for k in counts} == counts
 

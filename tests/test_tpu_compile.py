@@ -43,13 +43,28 @@ FULL_YAML = os.path.join(os.path.dirname(os.path.dirname(
 # lookup with a 2^24 directory.  LocalEngine: the staircase levels
 # ``(columns, rows)``, rows ordered by non-zero count and each column cut to
 # the rows that reach it (a row's count is its domain walls: 2, 4, ... 32),
-# rounded up to 1024.  DistributedEngine: a main ELL table of width 20 over
-# the padded rows and a tail of 249,601 wide rows × 12, hash-sharded.
+# rounded up to 1024; the engine's own rule then cuts the packed rows into
+# blocks (``gather_row_blocks``) and the levels into a piece a block.
+# DistributedEngine: a main ELL table of width 20 over the padded rows and a
+# tail of 249,601 wide rows × 12, hash-sharded.
 N = 4_707_969
 N_PAD = 4_718_592
 LEVELS = [(6, 4_708_352), (2, 4_707_328), (2, 4_694_016), (2, 4_600_832),
           (2, 4_224_000), (2, 3_326_976), (2, 2_030_592), (2, 878_592),
           (2, 249_856), (2, 44_032), (2, 5_120), (6, 1_024)]
+# the benchmark's other histogram, heisenberg_square_5x5 (PERF.md §4), and a
+# pair-form basis of half the chain's rows, each level half as long: the
+# chain's own pair-form table (32 B a row, 151 MB) leaves the rule no room
+HISTOGRAMS = {
+    "chain_32_symm": (N, N_PAD, LEVELS),
+    "square_5x5": (5_200_300, 5_242_880, [
+        (14, 5_200_896), (2, 5_199_872), (2, 5_185_536), (2, 5_120_000),
+        (2, 4_873_216), (2, 4_267_008), (2, 3_198_976), (2, 1_949_696),
+        (2, 921_600), (2, 349_184), (2, 108_544), (2, 28_672), (2, 6_144),
+        (2, 1_024)]),
+    "half_chain": (2_353_985, 2_359_296,
+                   [(k, -(-L // 2048) * 1024) for k, L in LEVELS]),
+}
 T, T0, T_TAIL, S_TAIL = 32, 20, 12, 249_601
 CHUNK = 1 << 16
 LK_DIR, LK_SHIFT, LK_PROBES = (1 << 24) + 1, 8, 6
@@ -103,23 +118,44 @@ def _fits(compiled, what):
     return total
 
 
-def _local_ell_engine(sh, pair):
-    """A LocalEngine shell carrying chain_32_symm's ELL shapes — the apply
-    program depends on nothing else of the engine."""
+def _pieces(histogram, pair=False, whole=False):
+    """``(rows of every level piece, rows of every un-permute piece)`` at a
+    histogram's shapes: the engine's own rule and cut, or, ``whole``, the
+    levels as the parent of PR 31 stored and gathered them."""
+    from distributed_matvec_tpu.parallel.engine import (block_pieces,
+                                                        gather_row_blocks)
+
+    _, n_pad, levels = HISTOGRAMS[histogram]
+    if whole:
+        return [[(k, L) for k, L in levels]], [n_pad]
+    _, B = gather_row_blocks(n_pad, 6 if pair else 3)
+    plan = block_pieces(tuple((0, k, L) for k, L in levels), B)
+    return ([[(levels[li][0], rows) for li, _, rows in blk] for blk in plan],
+            [min(B, n_pad - r0) for r0 in range(0, n_pad, B)])
+
+
+def _local_ell_engine(sh, pair, histogram="chain_32_symm", whole=False,
+                      columns=None):
+    """A LocalEngine shell carrying a histogram's ELL shapes — the apply
+    program depends on nothing else of the engine — and one vector for it
+    (``columns`` of them in a batch)."""
     from distributed_matvec_tpu.parallel.engine import LocalEngine
 
     S = _shapes(sh)
+    n, n_pad, _ = HISTOGRAMS[histogram]
     ctail = (2,) if pair else ()
     eng = object.__new__(LocalEngine)
-    eng.n_states, eng.n_padded = N, N_PAD
+    eng.n_states, eng.n_padded = n, n_pad
     eng.pair, eng._dtype = pair, jnp.float64
-    eng._ell_levels = tuple((S((k, L), jnp.int32),
-                             S((k, L) + ctail, jnp.float64))
-                            for k, L in LEVELS)
-    eng._ell_pos_of = S((N_PAD,), jnp.int32)
-    eng._diag = S((N_PAD,), jnp.float64)
+    eng._ell_blocks = tuple(
+        tuple((S((k, rows), jnp.int32), S((k, rows) + ctail, jnp.float64))
+              for k, rows in blk)
+        for blk in _pieces(histogram, pair, whole)[0])
+    eng._ell_pos_of = S((n_pad,), jnp.int32)
+    eng._diag = S((n_pad,), jnp.float64)
     eng._make_ell_matvec()
-    return eng, S((N,) + ctail, jnp.float64)
+    batch = () if columns is None else (columns,)
+    return eng, S((n,) + batch + ctail, jnp.float64)
 
 
 def _distributed_ell_engine(topo):
@@ -156,7 +192,8 @@ def _distributed_ell_engine(topo):
 
 def _compile(name, topo):
     """One program of the main path, compiled for the described chip(s):
-    ``ell_apply``, ``window`` and ``full`` on one chip, ``distributed_apply``
+    ``ell_apply``, ``window`` and ``full`` on one chip (``<program>@<key of
+    HISTOGRAMS>`` at another histogram than the chain's), ``distributed_apply``
     and ``distributed_window`` on the 4-device mesh."""
     from distributed_matvec_tpu.solve.lanczos import (
         _buffer_rows, _make_block_runner, _make_window_runner)
@@ -187,7 +224,8 @@ def _compile(name, topo):
                         eng._operands).compile()
 
     sh = SingleDeviceSharding(topo.devices[0])
-    eng, x = _local_ell_engine(sh, pair=False)
+    name, _, histogram = name.partition("@")
+    eng, x = _local_ell_engine(sh, False, histogram or "chain_32_symm")
     apply_fn, operands = eng.bound_matvec()
     if name == "ell_apply":
         return jax.jit(apply_fn).lower(x, operands).compile()
@@ -196,16 +234,17 @@ def _compile(name, topo):
         return apply_fn(v, ops)[0].astype(jnp.float64)
 
     S = _shapes(sh)
-    V = S((_buffer_rows(M_CAP), N))
+    n = eng.n_states
+    V = S((_buffer_rows(M_CAP), n))
     ab, i32 = S((M_CAP,)), S((), jnp.int32)
     if name == "window":
         # the host tracker's state rides beside (alpha, beta): its two
         # omega rows, its epsilon and its limit
         omega = (S((M_CAP + 1,)), S((M_CAP + 1,)), S(()), S(()))
-        fn = _make_window_runner(mv, M_CAP, (N,), jnp.float64, 2, 16)
+        fn = _make_window_runner(mv, M_CAP, (n,), jnp.float64, 2, 16)
         return fn.lower(V, ab, ab, i32, omega, operands).compile()
     assert name == "full", name
-    fn = _make_block_runner(mv, M_CAP, (N,), jnp.float64, 2)
+    fn = _make_block_runner(mv, M_CAP, (n,), jnp.float64, 2)
     return fn.lower(V, ab, ab, i32, i32, operands).compile()
 
 
@@ -234,19 +273,106 @@ def test_ell_apply_compiles(one_chip, tpu_knobs, compiled, pair):
         apply_fn, operands = eng.bound_matvec()
         exe = jax.jit(apply_fn).lower(x, operands).compile()
     _fits(exe, "ell apply")
-    _gathers_the_staircase(exe, parts=6 if pair else 3)
+    _gathers_the_staircase(exe, pair=pair)
 
 
-def _gathers_the_staircase(exe, parts=3):
-    """The optimised HLO gathers ``x``'s f32 parts once a level (the body
-    of the ``lax.scan`` over the level's columns), at that level's length,
-    and the accumulator's once at the padded rows — and scatters nothing
+def _gathers_the_staircase(exe, histogram="chain_32_symm", pair=False,
+                           whole=False):
+    """The optimised HLO gathers ``x``'s f32 parts once a piece of a level
+    (the body of the ``lax.scan`` over the piece's columns), at that piece's
+    length, and the accumulator's once a row block — and scatters nothing
     (the two-level format's tail did)."""
     text = exe.as_text()
     assert "scatter" not in text
     gathered = [int(rows) for rows in re.findall(
-        rf"= f32\[(\d+),{parts}\]\S* gather\(", text)]
-    assert sorted(gathered) == sorted([L for _, L in LEVELS] + [N_PAD])
+        rf"= f32\[(\d+),{6 if pair else 3}\]\S* gather\(", text)]
+    blocks, unpermute = _pieces(histogram, pair, whole)
+    assert sorted(gathered) == sorted(
+        [rows for blk in blocks for _, rows in blk] + unpermute)
+
+
+def _gather_results(exe, parts=3):
+    """``(rows, in VMEM)`` of every gather fusion of the optimised HLO whose
+    result is ``f32[rows, parts]``: memory space ``S(1)`` in the result's
+    layout is the chip's VMEM, none is HBM."""
+    found = []
+    for line in exe.as_text().splitlines():
+        m = re.search(rf"= f32\[(\d+),{parts}\](\{{[^}}]*\}}) fusion\(", line)
+        if m and "kind=kCustom" in line:
+            found.append((int(m.group(1)), "S(1)" in m.group(2)))
+    return found
+
+
+@pytest.mark.parametrize("histogram", ["chain_32_symm", "square_5x5"])
+@pytest.mark.parametrize("program", ["ell_apply", "window", "full"])
+def test_every_gather_writes_to_vmem(tpu_knobs, compiled, program,
+                                     histogram):
+    """Where a row gather's result lives decides its rate on a v5e: 4.32 ns
+    a slot in VMEM, 6.06 in HBM (PERF.md §6, PR 31).  With the packed rows
+    cut into blocks by the engine's own rule, every gather of the apply —
+    a piece of a level, a block of the un-permute — writes to VMEM, alone
+    and nested in the solver's block programs, at both of the benchmark's
+    histograms (2 blocks of 2,359,296 rows and 3 of 1,747,968)."""
+    exe = compiled(program if histogram == "chain_32_symm"
+                   else f"{program}@{histogram}")
+    _fits(exe, f"{program} at {histogram}")
+    _gathers_the_staircase(exe, histogram)
+    results = _gather_results(exe)
+    blocks, unpermute = _pieces(histogram)
+    assert len(blocks) == {"chain_32_symm": 2, "square_5x5": 3}[histogram]
+    assert len(results) == sum(map(len, blocks)) + len(unpermute)
+    assert [rows for rows, in_vmem in results if not in_vmem] == []
+
+
+@pytest.mark.parametrize("histogram, pair, in_hbm", [
+    ("chain_32_symm", False, 7), ("square_5x5", False, 8),
+    ("half_chain", True, 7)])
+def test_whole_levels_gather_to_hbm(one_chip, tpu_knobs, histogram, pair,
+                                    in_hbm):
+    """The test above can fail: the levels as PR 31's parent kept them (a
+    whole level a gather; the shape lists above) leave the results of the
+    long gathers in HBM — every level of 3.2 Mrows and more and the
+    un-permute gather, the ones the trace read at 6.06 ns a slot — and
+    only the short ones in VMEM."""
+    eng, x = _local_ell_engine(one_chip, pair, histogram, whole=True)
+    apply_fn, operands = eng.bound_matvec()
+    exe = jax.jit(apply_fn).lower(x, operands).compile()
+    _gathers_the_staircase(exe, histogram, pair, whole=True)
+    results = _gather_results(exe, parts=6 if pair else 3)
+    assert len(results) == len(HISTOGRAMS[histogram][2]) + 1
+    rows_hbm = sorted(rows for rows, in_vmem in results if not in_vmem)
+    assert len(rows_hbm) == in_hbm
+    assert min(rows_hbm) > max(
+        [rows for rows, in_vmem in results if in_vmem])
+
+
+def test_pair_form_blocks_gather_to_vmem(one_chip, tpu_knobs):
+    """A pair-form engine's gathered row is six f32 parts in eight lanes,
+    32 B, so the rule gives it shorter blocks: at half the chain's rows, 2
+    of 1,179,648, and every gather's result in VMEM."""
+    eng, x = _local_ell_engine(one_chip, True, "half_chain")
+    apply_fn, operands = eng.bound_matvec()
+    exe = jax.jit(apply_fn).lower(x, operands).compile()
+    _fits(exe, "pair-form ell apply")
+    _gathers_the_staircase(exe, "half_chain", pair=True)
+    blocks, unpermute = _pieces("half_chain", pair=True)
+    assert unpermute == [1_179_648] * 2
+    results = _gather_results(exe, parts=6)
+    assert len(results) == sum(map(len, blocks)) + 2
+    assert all(in_vmem for _, in_vmem in results)
+
+
+def test_batched_apply_runs_the_blocks_cut_for_one_vector(one_chip,
+                                                          tpu_knobs):
+    """The cut is made at build for one vector; a two-column ``x`` gathers
+    rows of six parts through the same blocks.  Held to fitting the chip
+    only: the placement it gets is PERF.md §7's to record."""
+    eng, x = _local_ell_engine(one_chip, False, columns=2)
+    apply_fn, operands = eng.bound_matvec()
+    exe = jax.jit(apply_fn).lower(x, operands).compile()
+    _fits(exe, "two-column ell apply")
+    results = _gather_results(exe, parts=6)
+    assert len(results) == sum(map(len, _pieces("chain_32_symm")[0])) + 2
 
 
 def test_structure_build_chunk_compiles(one_chip):
@@ -301,6 +427,7 @@ def test_lanczos_programs_compile(one_chip, tpu_knobs, compiled, program):
     assert _fits(exe, f"lanczos {program}") + 1.2e9 < HBM_BYTES
     if program in ("window", "full"):
         _gathers_the_staircase(exe)
+        assert all(in_vmem for _, in_vmem in _gather_results(exe))
 
 
 def test_distributed_ell_apply_compiles_on_four_devices(tpu_knobs, compiled):

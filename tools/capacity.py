@@ -97,9 +97,10 @@ def load_structure(path: str) -> dict:
         if not idx_keys and not level_keys:
             raise ValueError(f"{path}: no idx table in the sidecar")
         if level_keys:
-            # LocalEngine ell: levels [k, L] and, where the rows are
-            # ordered by width, ``pos_of`` over the padded rows (else the
-            # one level is that long); T0 is the mean width
+            # LocalEngine ell: levels [k, L] (cut into a piece a row block
+            # where the engine cut them, under the same names) and, where
+            # the rows are ordered by width, ``pos_of`` over the padded rows
+            # (else the one level is that long); T0 is the mean width
             n_pad = int(g["pos_of"].shape[0]) if "pos_of" in g \
                 else max(int(g[k].shape[-1]) for k in level_keys)
             slots = sum(int(g[k].size) for k in level_keys)

@@ -141,18 +141,25 @@ def engine_breakdown():
     full = _time_chain(jax.jit(chain_full), x, operands)
 
     def gathers_only(x, ops):
-        # every table slot's x-row gather, level by level as the engine
-        # walks them (shortest first), and the gather back to basis order
-        levels, pos_of, _ = ops
+        # every table slot's x-row gather, a row block at a time and piece
+        # by piece as the engine walks them (shortest first), and the
+        # gather back to basis order, a block's length of rows at a time
+        blocks, pos_of, _ = ops
         xs = split_parts(x)
-        acc = jnp.zeros((0, 3), jnp.float32)
-        for idx, _ in reversed(levels):
-            acc = jnp.pad(acc, ((0, idx.shape[1] - acc.shape[0]), (0, 0)))
-            for t in range(idx.shape[0]):
-                acc = acc + xs[idx[t]]
+        accs = []
+        for pieces in blocks:
+            acc = jnp.zeros((0, 3), jnp.float32)
+            for idx, _ in reversed(pieces):
+                acc = jnp.pad(acc, ((0, idx.shape[1] - acc.shape[0]), (0, 0)))
+                for t in range(idx.shape[0]):
+                    acc = acc + xs[idx[t]]
+            accs.append(acc)
+        acc = jnp.concatenate(accs)
         acc = jnp.pad(acc, ((0, max(Npad - acc.shape[0], 0)), (0, 0)))
         if pos_of is not None:
-            acc = acc[pos_of]
+            B = accs[0].shape[0] if len(accs) > 1 else Npad
+            acc = jnp.concatenate([acc[pos_of[r0:r0 + B]]
+                                   for r0 in range(0, Npad, B)])
         return acc.sum(axis=-1).astype(jnp.float64)
 
     def chain_g(x, ops):
