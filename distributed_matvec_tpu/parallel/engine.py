@@ -264,6 +264,27 @@ def staircase_levels(hist: np.ndarray, n_rows: int):
 GATHER_VMEM_BYTES = 118 * 2 ** 20
 
 
+def gather_row_bytes(parts: int) -> int:
+    """Bytes of one gathered row of ``parts`` f32 values in the chip's
+    layout (``f32[rows, parts]{0,1:T(4,128)}``): padded to a multiple of 4
+    lanes, 16 B a row of 3 (a real vector's split parts), 32 B a row of 6
+    (a pair-form one's)."""
+    return pad_to_multiple(parts, 4) * 4
+
+
+#: The most row blocks :func:`gather_row_blocks` cuts.  A table that nearly
+#: fills VMEM leaves room for short blocks only (7.7 M rows of 16 B: 301
+#: blocks of 25,600 rows, 3,600 gathers an apply), and every piece is a
+#: loop of its own in the apply and in the solver's block programs, which
+#: are traced and loaded anew each solve at about 0.1 s a piece (PERF.md §6,
+#: PR 31).  Against that stands what whole levels cost where the table is
+#: in HBM: 15.5 ns a slot for 4.3 (PERF.md §6, PR 32, chain_28).  At 120 M
+#: slots and 66 applies a solve the two meet near 64 blocks; half of that
+#: keeps a clear gain, so up to 7.44 M rows of 16 B the rows are cut as
+#: before and past it they stay whole, as where the table does not fit.
+GATHER_MAX_ROW_BLOCKS = 32
+
+
 def gather_row_blocks(n_rows: int, parts: int):
     """``(nb, B)``: the packed rows of a staircase over ``n_rows`` padded
     rows cut into ``nb`` contiguous blocks of ``B`` rows (a multiple of
@@ -272,19 +293,23 @@ def gather_row_blocks(n_rows: int, parts: int):
 
     A row gather whose table, indices and result all fit the chip's VMEM
     writes there and runs at 4.32 ns a slot on a v5e; one whose result goes
-    to HBM at 6.06 (PERF.md §6, PR 31).  In the chip's layout
-    (``f32[rows, parts]{0,1:T(4,128)}``) a row of ``parts`` f32 values (3
-    for a real vector's split parts, 6 for a pair-form one's) is padded to
-    a multiple of 4 lanes, 16 B a row of 3, in the table and in the result
-    alike, and an index is 4 B.  The table is ``n_rows`` long (``x`` for
-    the levels' gathers, the accumulator for the one that puts the result
-    back in basis order).  Where the table alone leaves no room for a tile
-    of rows, or everything fits as it is, ``nb`` is 1.
+    to HBM at 6.06 (PERF.md §6, PR 31), and one whose table is in HBM at
+    13 to 17 (PR 32).  A row of the table and of the
+    result takes :func:`gather_row_bytes`, and an index is 4 B.  The table
+    is ``n_rows`` long (``x`` for the levels' gathers, the accumulator for
+    the one that puts the result back in basis order).  Where everything
+    fits as it is, where the table alone leaves no room for a tile of rows
+    (7.73 M rows of 16 B: the gathers then read a table in HBM, whole
+    levels long), or where it leaves room only for blocks so short that
+    there would be more than :data:`GATHER_MAX_ROW_BLOCKS` of them, ``nb``
+    is 1.
     """
-    row = pad_to_multiple(parts, 4) * 4
+    row = gather_row_bytes(parts)
     b_max = (GATHER_VMEM_BYTES - n_rows * row) // (row + 4) \
         // INDEX_TILE * INDEX_TILE
     nb = max(-(-n_rows // b_max), 1) if b_max > 0 else 1
+    if nb > GATHER_MAX_ROW_BLOCKS:
+        nb = 1
     return int(nb), int(pad_to_multiple(-(-n_rows // nb), INDEX_TILE))
 
 
@@ -331,13 +356,16 @@ def _stair_level(tab, row_of, t0, r0, *, k, rows):
     return out
 
 
-def _count_chunk_nnz(tables, pair, dir_tab, alphas, norms_a, *, shift,
-                     probes, is_pair):
-    """Counting-pass step: per-row nnz + invalid-target count for a chunk."""
+def _count_chunk_nnz(nnz_buf, bad, tables, pair, dir_tab, alphas, norms_a,
+                     start, *, shift, probes, is_pair):
+    """Counting-pass step: a chunk's per-row nnz written into the donated
+    row-count vector, and its invalid targets added to ``bad``."""
     idx, cf, invalid = _chunk_structure_ops(tables, pair, dir_tab, alphas,
                                             norms_a, shift, probes)
     live = (cf != 0).any(axis=-1) if is_pair else (cf != 0)
-    return live.sum(axis=1), invalid
+    nnz_buf = jax.lax.dynamic_update_slice(
+        nnz_buf, live.sum(axis=1).astype(nnz_buf.dtype), (start,))
+    return nnz_buf, bad + invalid
 
 
 def _lowmem_pack_chunk(bufs, tables, pair, dir_tab, alphas, norms_a, start,
@@ -994,8 +1022,8 @@ class LocalEngine:
         if data is None:
             return False
         if self.mode == "ell":
-            if "block_pieces" not in data:
-                return False
+            if "block_pieces" not in data or "build_passes" not in data:
+                return False    # written before the counts it lacks
             sizes = [int(v) for v in str(data["block_pieces"]).split(",")]
             self._ell_blocks = tuple(
                 tuple((jnp.asarray(data[f"level{i}_idx"]),
@@ -1006,12 +1034,11 @@ class LocalEngine:
                 if "pos_of" in data else None
             self._ell_counts = {
                 **{k: int(data[k]) for k in
-                   ("gather_slots", "live_entries", "levels")},
+                   ("gather_slots", "live_entries", "levels", "row_blocks",
+                    "gather_pieces", "build_passes", "table_bytes")},
                 "terms": self.num_terms,
                 "widest_row": sum(int(idx.shape[0])
-                                  for idx, _ in self._ell_blocks[0]),
-                "row_blocks": int(data["row_blocks"]),
-                "gather_pieces": int(data["gather_pieces"])}
+                                  for idx, _ in self._ell_blocks[0])}
         else:
             self._ell_T0 = int(data["T0"])
             self._c_W = float(data["W"])
@@ -1096,15 +1123,14 @@ class LocalEngine:
         (one slot a non-zero) + O(B·T) chunk scratch.
         """
         b, C = self.batch_size, self.num_chunks
-        alphas_c = self._alphas.reshape(C, b)
-        norms_c = self._norms.reshape(C, b)
         T = self.num_terms
         is_pair = self.pair
 
         # One-pass build materializes full-width [T, N_pad] idx+coeff buffers
         # before cutting the levels (peak ≈ 1.6× their size).  When that
         # exceeds the device budget, fall back to the two-pass build: count,
-        # then pack chunk-by-chunk straight into the level buffers.
+        # then pack chunk-by-chunk straight into the level buffers.  (This
+        # frame holds no array while that build runs.)
         cf_item = 8 if (self.real and not is_pair) else 16
         full_bytes = self.n_padded * T * (4 + cf_item)
         if 1.6 * full_bytes > get_config().ell_build_budget_gb * 1e9:
@@ -1112,6 +1138,8 @@ class LocalEngine:
                       f"(full-width {full_bytes/1e9:.1f} GB)")
             return self._build_ell_lowmem()
 
+        alphas_c = self._alphas.reshape(C, b)
+        norms_c = self._norms.reshape(C, b)
         # one span a pass; ``device_wait`` where the host blocks on the
         # device, so that a pass's self time is the host's own work
         with obs_trace.span("ell/fill", kind="phase"):
@@ -1148,20 +1176,25 @@ class LocalEngine:
             )
         self._stair_ell(idx_buf, coeff_buf, nnz)
 
-    def _plan_levels(self, hist: np.ndarray):
+    def _plan_levels(self, hist: np.ndarray, passes: int):
         """``staircase_levels`` of the build's histogram, their cut into
         row blocks (:func:`gather_row_blocks`, :func:`block_pieces`), and
         the counts that say how far the format engages (``_ell_counts``: on
         the build span and in the ``engine_init`` event; ``terms`` is the
         build table's width, a slot an off-diagonal term, ``widest_row``
         the columns the levels keep, ``row_blocks`` 1 where the rows are
-        not cut, ``gather_pieces`` the row gathers of one apply)."""
+        not cut, ``gather_pieces`` the row gathers of one apply,
+        ``build_passes`` the runs of the kernels this build makes (2: the
+        low-memory build) and ``table_bytes`` what ``x`` takes as a gather
+        table, the number :func:`gather_row_blocks` holds against
+        :data:`GATHER_VMEM_BYTES`: above it the gathers read HBM)."""
         stair, levels = staircase_levels(hist, self.n_padded)
         slots = sum(k * L for _, k, L in levels)
+        parts = 3 if self.real else 6
         # the plain table stays whole: it is the build's own, in basis order
         nb, B, plan = 1, levels[0][2], (((0, 0, levels[0][2]),),)
         if stair:
-            nb, B = gather_row_blocks(self.n_padded, 3 if self.real else 6)
+            nb, B = gather_row_blocks(self.n_padded, parts)
             plan = block_pieces(levels, B)
             if len(plan) == 1:      # the levels end inside the first block
                 nb = 1
@@ -1172,7 +1205,9 @@ class LocalEngine:
             "terms": self.num_terms,
             "widest_row": sum(k for _, k, _ in levels),
             "row_blocks": nb,
-            "gather_pieces": sum(map(len, plan)) + (nb if stair else 0)}
+            "gather_pieces": sum(map(len, plan)) + (nb if stair else 0),
+            "build_passes": passes,
+            "table_bytes": self.n_padded * gather_row_bytes(parts)}
         log_debug(f"ell levels: T={self.num_terms} stair={stair} "
                   f"levels={levels} entries {self.n_padded * self.num_terms}"
                   f" -> {slots} in {nb} row blocks of {B}")
@@ -1199,14 +1234,11 @@ class LocalEngine:
         """
         T = self.num_terms
         with obs_trace.span("ell/count", kind="phase"):
-            count = precompile("ell_nnz_hist", (T,),
-                               jax.jit(partial(_nnz_hist, T=T)), (nnz,),
-                               self.timer)
-            hist = count(nnz)
+            hist = self._row_histogram(nnz)
             with obs_trace.span("device_wait", kind="phase",
                                 at="ell_count"):
                 hist = np.asarray(hist)
-            stair, levels, plan = self._plan_levels(hist)
+            stair, levels, plan = self._plan_levels(hist, passes=1)
         self._ell_pos_of = None
         if not stair:
             Tmax = levels[0][1]
@@ -1246,38 +1278,45 @@ class LocalEngine:
                 zip(idx_blocks, cut("ell_stair_coeff", coeff_buf)))
 
     def _count_row_nnz(self, alphas_c, norms_c):
-        """Counting pass shared by the low-memory builds: per-chunk row-nnz
-        vectors plus the global histogram, keeping only O(b) state per chunk.
-        Raises on out-of-basis targets (the build-time halt)."""
-        T = self.num_terms
+        """Counting pass shared by the low-memory builds: the kernels run
+        chunk by chunk and only each row's nnz is kept, in one vector on the
+        device (the host waits once, for the count of out-of-basis targets,
+        and raises on any: the build-time halt).  Returns that vector."""
         is_pair = self.pair
-
-        hist = np.zeros(T + 1, np.int64)
-        nnz_chunks = []
-        bad = 0
-        C = alphas_c.shape[0]
-        if C:
-            count_chunk = precompile(
-                "count_row_nnz", self._builder_statics(),
-                jax.jit(partial(_count_chunk_nnz, shift=self._lk_shift,
-                                probes=self._lk_probes, is_pair=is_pair)),
-                (self.tables, self._lk_pair, self._lk_dir, alphas_c[0],
-                 norms_c[0]), self.timer)
-        for ci in range(C):
-            log_debug(f"ell count chunk {ci}/{C}")
-            nnz, invalid = count_chunk(self.tables, self._lk_pair,
+        b, C = self.batch_size, alphas_c.shape[0]
+        with obs_trace.span("ell/count_rows", kind="phase"):
+            nnz = jnp.zeros(self.n_padded, jnp.int32)
+            bad = jnp.zeros((), jnp.int64)
+            if C:
+                count_chunk = precompile(
+                    "count_row_nnz", self._builder_statics(),
+                    jax.jit(partial(_count_chunk_nnz, shift=self._lk_shift,
+                                    probes=self._lk_probes, is_pair=is_pair),
+                            donate_argnums=(0, 1)),
+                    (nnz, bad, self.tables, self._lk_pair, self._lk_dir,
+                     alphas_c[0], norms_c[0], jnp.int32(0)), self.timer)
+            for ci in range(C):
+                log_debug(f"ell count chunk {ci}/{C}")
+                nnz, bad = count_chunk(nnz, bad, self.tables, self._lk_pair,
                                        self._lk_dir, alphas_c[ci],
-                                       norms_c[ci])
-            nnz = np.asarray(nnz)
-            bad += int(invalid)
-            hist += np.bincount(nnz, minlength=T + 1)
-            nnz_chunks.append(nnz)
+                                       norms_c[ci], jnp.int32(ci * b))
+            with obs_trace.span("device_wait", kind="phase",
+                                at="ell_count_rows"):
+                bad = int(bad)
         if bad:
             raise RuntimeError(
                 f"{bad} generated matrix elements map outside the basis "
                 "— operator does not preserve the chosen sector"
             )
-        return hist, nnz_chunks
+        return nnz
+
+    def _row_histogram(self, nnz):
+        """The histogram of a row-nnz vector over 0..T, counted on the
+        device; the caller fetches it under its own ``device_wait``."""
+        T = self.num_terms
+        return precompile("ell_nnz_hist", (T,),
+                          jax.jit(partial(_nnz_hist, T=T)), (nnz,),
+                          self.timer)(nnz)
 
     @staticmethod
     def _tail_layout(nnz_chunks, T0, S, Tmax):
@@ -1302,14 +1341,19 @@ class LocalEngine:
     def _build_ell_lowmem(self) -> None:
         """Two-pass ELL build bounded by the *level* tables' size.
 
-        Pass 1 runs the kernels chunk-by-chunk and keeps only per-row nnz
-        counts (a [b] vector per chunk): the histogram gives the levels and
-        the counts' stable descending sort the row order.  Pass 2 re-runs
-        the kernels on the states taken in that order and writes each
-        chunk's left-packed columns directly into the donated level buffers.
+        Pass 1 (``ell/count_rows``) runs the kernels chunk-by-chunk and
+        keeps only each row's nnz, on the device: the histogram gives the
+        levels and the counts' stable descending sort the row order
+        (``ell/row_order``; both as the one-pass build takes them).  Pass 2
+        (``ell/pack``) re-runs the kernels on the states taken in that
+        order and writes each chunk's left-packed columns directly into the
+        donated level buffers, a whole number of chunks long each;
+        ``ell/cut`` then cuts every level to its pieces and lets its buffer
+        go before the next level's pieces are made, so that the levels are
+        held once when the build ends and at most one level twice before.
         The kernels run twice, but peak device memory is the levels +
         O(b·T) chunk scratch instead of the full-width [T, N_pad] tables —
-        what makes square_6x6 (N=15.8M, T=72: 13.7 GB full-width vs ~7 GB
+        what makes chain_28 (N=40.1M, T=28: 13.5 GB full-width vs 7.0 GB
         packed) buildable on one 16 GB chip.  Same arrays as the one-pass
         build, piece for piece.
         """
@@ -1319,42 +1363,68 @@ class LocalEngine:
         cdtype = jnp.float64 if (self.real or is_pair) else jnp.complex128
         pz = ((2,) if is_pair else ())
 
-        hist, nnz_chunks = self._count_row_nnz(alphas.reshape(C, b),
-                                               norms.reshape(C, b))
-        stair, levels, plan = self._plan_levels(hist)
-        self._ell_pos_of = None
-        if stair:
-            row_of = np.argsort(-np.concatenate(nnz_chunks), kind="stable")
-            pos_of = np.empty(row_of.size, np.int32)
-            pos_of[row_of] = np.arange(row_of.size, dtype=np.int32)
-            self._ell_pos_of = jnp.asarray(pos_of)
-            row_of = jnp.asarray(row_of.astype(np.int32))
-            alphas, norms = alphas[row_of], norms[row_of]
+        nnz = self._count_row_nnz(alphas.reshape(C, b), norms.reshape(C, b))
+        with obs_trace.span("ell/row_order", kind="phase"):
+            hist = self._row_histogram(nnz)
+            with obs_trace.span("device_wait", kind="phase",
+                                at="ell_row_order"):
+                hist = np.asarray(hist)
+            stair, levels, plan = self._plan_levels(hist, passes=2)
+            self._ell_pos_of = None
+            if stair:
+                order = precompile("ell_stair_order", (),
+                                   jax.jit(_stair_order), (nnz,), self.timer)
+                row_of, self._ell_pos_of = order(nnz)
+                alphas, norms = alphas[row_of], norms[row_of]
+                del row_of
+            del nnz
         alphas_c, norms_c = alphas.reshape(C, b), norms.reshape(C, b)
 
-        # -- pass 2: pack into donated level buffers, a whole number of
-        # chunks long each (``_lowmem_pack_chunk``), cut to pieces after
-        bufs = tuple(
-            (jnp.zeros((k, pad_to_multiple(L, b)), jnp.int32),
-             jnp.zeros((k, pad_to_multiple(L, b)) + pz, cdtype))
-            for _, k, L in levels)
-        if C:
-            pack_chunk = precompile(
-                "ell_lowmem_pack", self._builder_statics() + (levels,),
-                jax.jit(partial(_lowmem_pack_chunk, shift=self._lk_shift,
-                                probes=self._lk_probes, is_pair=is_pair,
-                                levels=levels), donate_argnums=(0,)),
-                (bufs, self.tables, self._lk_pair, self._lk_dir,
-                 alphas_c[0], norms_c[0], jnp.int32(0)), self.timer)
-        for ci in range(C):
-            log_debug(f"ell lowmem pack chunk {ci}/{C}")
-            bufs = pack_chunk(bufs, self.tables, self._lk_pair,
-                              self._lk_dir, alphas_c[ci], norms_c[ci],
-                              jnp.int32(ci * b))
-        self._ell_blocks = tuple(
-            tuple((bufs[li][0][:, r0:r0 + rows], bufs[li][1][:, r0:r0 + rows])
-                  for li, r0, rows in blk)
-            for blk in plan)
+        with obs_trace.span("ell/pack", kind="phase"):
+            bufs = tuple(
+                (jnp.zeros((k, pad_to_multiple(L, b)), jnp.int32),
+                 jnp.zeros((k, pad_to_multiple(L, b)) + pz, cdtype))
+                for _, k, L in levels)
+            if C:
+                pack_chunk = precompile(
+                    "ell_lowmem_pack", self._builder_statics() + (levels,),
+                    jax.jit(partial(_lowmem_pack_chunk,
+                                    shift=self._lk_shift,
+                                    probes=self._lk_probes, is_pair=is_pair,
+                                    levels=levels), donate_argnums=(0,)),
+                    (bufs, self.tables, self._lk_pair, self._lk_dir,
+                     alphas_c[0], norms_c[0], jnp.int32(0)), self.timer)
+            for ci in range(C):
+                log_debug(f"ell lowmem pack chunk {ci}/{C}")
+                bufs = pack_chunk(bufs, self.tables, self._lk_pair,
+                                  self._lk_dir, alphas_c[ci], norms_c[ci],
+                                  jnp.int32(ci * b))
+            with obs_trace.span("device_wait", kind="phase", at="ell_pack"):
+                jax.block_until_ready(bufs)
+        del alphas, norms, alphas_c, norms_c
+
+        def cut(tab, cuts):
+            """The ``(r0, rows)`` pieces of one level's buffer, which goes
+            (a buffer as long as its one piece is that piece)."""
+            if cuts == [(0, tab.shape[1])]:
+                return {cuts[0]: tab}
+            pieces = {(r0, rows): tab[:, r0:r0 + rows] for r0, rows in cuts}
+            with obs_trace.span("device_wait", kind="phase", at="ell_cut"):
+                jax.block_until_ready(pieces)
+            tab.delete()
+            return pieces
+
+        # one table at a time, its buffer deleted before the next one's
+        # pieces are made: one table of one level is held twice
+        with obs_trace.span("ell/cut", kind="phase"):
+            cuts = [[(r0, rows) for blk in plan for lj, r0, rows in blk
+                     if lj == li] for li in range(len(levels))]
+            tabs = [(cut(idx_l, c), cut(cf_l, c))
+                    for (idx_l, cf_l), c in zip(bufs, cuts)]
+            self._ell_blocks = tuple(
+                tuple((tabs[li][0][r0, rows], tabs[li][1][r0, rows])
+                      for li, r0, rows in blk)
+                for blk in plan)
 
     def _build_compact(self) -> None:
         """4-bytes-per-entry structure for real sectors with one off-diagonal
@@ -1385,7 +1455,9 @@ class LocalEngine:
         W = compact_magnitude(self.operator)
         self._c_W = W
 
-        hist, nnz_chunks = self._count_row_nnz(alphas_c, norms_c)
+        nnz = np.asarray(self._count_row_nnz(alphas_c, norms_c))
+        hist = np.bincount(nnz, minlength=T + 1)
+        nnz_chunks = nnz.reshape(C, b)
         T0, S, Tmax = choose_ell_split(hist, n_pad, T, real_rows=n)
         self._ell_T0 = T0
         log_debug(f"compact split: T={T} Tmax={Tmax} T0={T0} tail_rows={S}")

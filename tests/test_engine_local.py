@@ -243,7 +243,8 @@ def test_staircase_accounting(rng):
         "live_entries": int(nnz.sum()),
         "levels": len(set(lengths)),
         "terms": 16, "widest_row": Tmax,
-        "row_blocks": 1, "gather_pieces": len(set(lengths)) + 1}
+        "row_blocks": 1, "gather_pieces": len(set(lengths)) + 1,
+        "build_passes": 1, "table_bytes": n_pad * 16}
     # the level arrays are those columns, longest first, and nothing else
     assert [i.shape for i, _ in eng._ell_levels] == \
         [(int((lengths == L).sum()), int(L))
@@ -311,6 +312,15 @@ def test_row_block_rule_reads_the_shapes():
     assert gather_row_blocks(2_359_296, 6) == (2, 1_179_648)
     assert gather_row_blocks(4_718_592, 6)[0] == 1
     assert gather_row_blocks(15_859_712, 3)[0] == 1
+    # near the line at which the table alone fills VMEM (7,733,248 rows of
+    # 16 B) it leaves room for short blocks only, 301 of 25,600 rows at
+    # 7.7 M: up to 32 blocks (7.44 M rows) the rows are cut as before, past
+    # it they stay whole, as they do above the line (chain_28's 613 MiB
+    # table, 40,173,568 rows)
+    assert gather_row_blocks(7_000_000, 3) == (12, 583_680)
+    assert gather_row_blocks(7_440_000, 3) == (32, 233_472)
+    for rows in (7_450_000, 7_700_000, 7_733_248, 7_800_000, 40_173_568):
+        assert gather_row_blocks(rows, 3) == (1, -(-rows // 1024) * 1024)
     # a basis shorter than one block, and an empty one
     assert gather_row_blocks(13_000, 3) == (1, 13 * INDEX_TILE)
     assert gather_row_blocks(0, 3) == (1, 0)
@@ -408,7 +418,9 @@ def test_equal_width_rows_keep_plain_table(rng):
     assert eng._ell_counts == {"gather_slots": n * eng.n_padded,
                                "live_entries": n * 2 ** n, "levels": 1,
                                "terms": n, "widest_row": n,
-                               "row_blocks": 1, "gather_pieces": 1}
+                               "row_blocks": 1, "gather_pieces": 1,
+                               "build_passes": 1,
+                               "table_bytes": 16 * eng.n_padded}
     x = rng.random(2 ** n) - 0.5
     prims = _apply_primitives(eng, x)
     # one level, its columns scanned: one gather, and none to un-permute
@@ -476,7 +488,8 @@ def test_lowmem_build_matches_onepass(name, batch_size, nb, rng, pair_form,
     finally:
         update_config(ell_build_budget_gb=prev_budget)
     assert (eng_ref._ell_pos_of is not None) == (name in STAIR_RINGS)
-    assert eng_lm._ell_counts == eng_ref._ell_counts
+    assert eng_lm._ell_counts == {**eng_ref._ell_counts, "build_passes": 2}
+    assert eng_ref._ell_counts["build_passes"] == 1
     assert len(eng_lm._ell_levels) == len(eng_ref._ell_levels)
     assert [len(b) for b in eng_lm._ell_blocks] == \
         [len(b) for b in eng_ref._ell_blocks]

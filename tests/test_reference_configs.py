@@ -148,6 +148,29 @@ def test_full_yaml_matrix_loads():
         assert cfg.hamiltonian.number_off_diag_terms > 0
 
 
+REPO_COPIES = {  # name: (spins, hamming weight, off-diagonal terms)
+    "heisenberg_chain_16.yaml": (16, 8, 16),
+    "heisenberg_chain_28.yaml": (28, 14, 28),
+    "heisenberg_chain_32_symm.yaml": (32, 16, 32),
+    "heisenberg_square_5x5.yaml": (25, 13, 50),
+}
+
+
+@pytest.mark.parametrize("name", sorted(REPO_COPIES))
+def test_repo_yaml_copies_load(name):
+    """The configurations the repo keeps a copy of (``data/``, the
+    benchmark's deployments among them) through the schema loader, as
+    ``test_full_yaml_matrix_loads`` takes upstream's — no build."""
+    import glob
+
+    assert sorted(map(os.path.basename, glob.glob(
+        os.path.join(REPO_DATA, "*.yaml")))) == sorted(REPO_COPIES)
+    cfg = load_config_from_yaml(os.path.join(REPO_DATA, name))
+    assert not cfg.basis.is_built
+    assert (cfg.basis.number_spins, cfg.basis.hamming_weight,
+            cfg.hamiltonian.number_off_diag_terms) == REPO_COPIES[name]
+
+
 @pytest.mark.slow
 def test_square_5x5_engine_vs_host(rng):
     """square_5x5 (N=5.2M, 50 bonds) — the largest config whose host
@@ -161,12 +184,13 @@ def test_square_5x5_engine_vs_host(rng):
         atol=ATOL, rtol=RTOL)
 
 
-@require_data
 @pytest.mark.slow
 def test_chain_28_fused_vs_independent(rng):
     """chain_28 (N=40.1M) — fused (recompute-on-the-fly) engine against
     the term-compiler-independent bit-op apply; host matvec_host is too
-    slow at this size, the independent ring apply is not."""
+    slow at this size, the independent ring apply is not.  (``_load``
+    finds the repo's copy, ``data/heisenberg_chain_28.yaml``, where
+    upstream's is not mounted.)"""
     from independent_ref import heisenberg_ring_apply
 
     cfg = _load("heisenberg_chain_28.yaml")

@@ -382,6 +382,49 @@ def test_structure_build_pass_spans_on_one_device(clean_trace, annotations):
             and e["parent_span_id"] is None][:1] == ["engine_init/transfer"]
 
 
+def test_two_pass_build_pass_spans_on_one_device(clean_trace, annotations):
+    """The low-memory build's passes, kinds as the one-pass build's: the
+    four passes and their ``device_wait``s lie on the profiler's host line,
+    the build span does not; its programs compile under the pass that
+    needs them; the build span says that the kernels ran twice."""
+    from distributed_matvec_tpu.parallel.engine import (LocalEngine,
+                                                        clear_program_cache)
+    from distributed_matvec_tpu.utils.config import get_config, update_config
+
+    clear_program_cache()
+    was = get_config().ell_build_budget_gb
+    update_config(ell_build_budget_gb=1e-9)
+    try:
+        eng = LocalEngine(build_heisenberg(16, hw=8), mode="ell",
+                          batch_size=4096)
+    finally:
+        update_config(ell_build_budget_gb=was)
+    spans = obs.events("span")
+    children, by_id = _tree(spans)
+    (build,) = [e for e in spans
+                if e["name"] == "engine_init/build_structure"]
+    opened = [name for what, name in annotations if what == "open"]
+    assert "engine_init/build_structure" not in opened
+    passes = ["ell/count_rows", "ell/row_order", "ell/pack", "ell/cut"]
+    assert [e["name"] for e in children[build["span_id"]]] == passes
+    assert [n for n in opened if n.startswith("ell/")] == passes
+    ats = {p: {k["at"] for k in children[next(
+        e for e in spans if e["name"] == p)["span_id"]]
+        if k["name"] == "device_wait"} for p in passes}
+    assert ats == {"ell/count_rows": {"ell_count_rows"},
+                   "ell/row_order": {"ell_row_order"},
+                   "ell/pack": {"ell_pack"}, "ell/cut": {"ell_cut"}}
+    under = {e["name"]: by_id[e["parent_span_id"]]["name"] for e in spans
+             if e["name"].startswith("compile/")}
+    assert under["compile/count_row_nnz"] == "ell/count_rows"
+    assert under["compile/ell_lowmem_pack"] == "ell/pack"
+    assert under["compile/ell_stair_order"] == "ell/row_order"
+    assert (build["build_passes"], build["table_bytes"]) == \
+        (2, 16 * eng.n_padded)
+    assert eng.timer.scope_total("build_structure") * 1e3 == pytest.approx(
+        build["dur_ms"], rel=0.05, abs=2.0)
+
+
 @pytest.mark.parametrize("nb", [1, 3], ids=["whole", "three_row_blocks"])
 def test_build_span_counts_how_far_the_staircase_engages(clean_trace, nb,
                                                          monkeypatch):
@@ -409,8 +452,13 @@ def test_build_span_counts_how_far_the_staircase_engages(clean_trace, nb,
         if e["parent_span_id"] == build[0]["span_id"]}
     counts = {k: build[0][k] for k in ("gather_slots", "live_entries",
                                        "levels", "terms", "widest_row",
-                                       "row_blocks", "gather_pieces")}
+                                       "row_blocks", "gather_pieces",
+                                       "build_passes", "table_bytes")}
     assert counts == eng._ell_counts
+    # one run of the kernels; ``x`` as a gather table: 16 B a padded row,
+    # the number the block rule holds against the chip's VMEM
+    assert (counts["build_passes"], counts["table_bytes"]) == \
+        (1, 16 * eng.n_padded)
     assert (counts["terms"], counts["widest_row"]) == (16, 16)
     assert counts["live_entries"] == 109_824    # 16 bonds x 2 x C(14, 7)
     assert (counts["gather_slots"], counts["levels"]) == (133_702, 5)

@@ -64,6 +64,12 @@ HISTOGRAMS = {
         (2, 1_024)]),
     "half_chain": (2_353_985, 2_359_296,
                    [(k, -(-L // 2048) * 1024) for k, L in LEVELS]),
+    # heisenberg_chain_28 (tests/test_chain_28_config.py pins the levels):
+    # its table, 613 MiB, leaves the rule no room, so the levels stay whole
+    "chain_28": (40_116_600, 40_173_568, [
+        (4, 40_117_248), (2, 40_115_200), (2, 40_057_856), (2, 39_485_440),
+        (2, 36_622_336), (2, 28_893_184), (2, 17_114_112), (2, 6_807_552),
+        (2, 1_654_784), (2, 223_232), (2, 15_360), (4, 1_024)]),
 }
 T, T0, T_TAIL, S_TAIL = 32, 20, 12, 249_601
 CHUNK = 1 << 16
@@ -346,6 +352,30 @@ def test_whole_levels_gather_to_hbm(one_chip, tpu_knobs, histogram, pair,
         [rows for rows, in_vmem in results if in_vmem])
 
 
+def test_chain_28_gathers_from_a_table_in_hbm(tpu_knobs, compiled):
+    """Above 7.73 M rows ``x`` as a gather table (16 B a row) cannot fit
+    VMEM, so the rule cuts nothing: 12 whole levels and the un-permute
+    gather read a 643 MB table in HBM.  The compiler still leaves a short
+    gather's result in VMEM (the five levels of 6.8 Mrows and fewer, 2.8%
+    of the slots); the eight of 17.1 to 40.2 Mrows write to HBM.  The
+    apply's temporaries, 4.0 GB beside 7.8 GB of arguments, fit the chip:
+    the prediction of ``chain_28.apply``'s ``peak_hbm_gb`` (PERF.md §6,
+    PR 32)."""
+    exe = compiled("ell_apply@chain_28")
+    assert _fits(exe, "ell apply at chain_28") < 12.5e9
+    assert 3.5e9 < exe.memory_analysis().temp_size_in_bytes < 4.5e9
+    _gathers_the_staircase(exe, "chain_28")
+    blocks, unpermute = _pieces("chain_28")
+    assert len(blocks) == 1 and unpermute == [40_173_568]
+    results = _gather_results(exe)
+    assert len(results) == 13
+    in_hbm = sorted(rows for rows, in_vmem in results if not in_vmem)
+    assert in_hbm == [17_114_112, 28_893_184, 36_622_336, 39_485_440,
+                      40_057_856, 40_115_200, 40_117_248, 40_173_568]
+    # the table itself: no f32[n, 3] of the full length is placed in VMEM
+    assert not re.search(r"f32\[40\d{6},3\]\{[^}]*S\(1\)", exe.as_text())
+
+
 def test_pair_form_blocks_gather_to_vmem(one_chip, tpu_knobs):
     """A pair-form engine's gathered row is six f32 parts in eight lanes,
     32 B, so the rule gives it shorter blocks: at half the chain's rows, 2
@@ -401,6 +431,52 @@ def test_structure_build_chunk_compiles(one_chip):
         S((LK_DIR,), jnp.int32), S((CHUNK,), jnp.uint64),
         S((CHUNK,), jnp.float64), S((), jnp.int32)).compile()
     _fits(compiled, "ell_fill_chunk")
+
+
+def test_two_pass_build_chunks_compile_at_chain_28(one_chip):
+    """``count_row_nnz`` and ``ell_lowmem_pack`` steps of the two-pass
+    build at chain_28's shapes (28 terms, no group, 613 chunks): the pack
+    step takes the 7.0 GB of chunk-padded level buffers donated and writes
+    them in place (its output aliases them), with under 2 GB of
+    temporaries (the f64 emulation splits the longest coefficient buffer
+    whole to update a chunk of it)."""
+    from functools import partial
+
+    from distributed_matvec_tpu.models.yaml_io import load_config_from_yaml
+    from distributed_matvec_tpu.ops import kernels as K
+    from distributed_matvec_tpu.parallel.engine import (
+        _count_chunk_nnz, _lowmem_pack_chunk, pad_to_multiple)
+
+    S = _shapes(one_chip)
+    n, n_pad, shapes = HISTOGRAMS["chain_28"]
+    op = load_config_from_yaml(
+        FULL_YAML.replace("chain_32_symm", "chain_28")).hamiltonian
+    tables = jax.tree_util.tree_map(
+        lambda a: S(a.shape, a.dtype), K.device_tables(op, pair=False))
+    assert tables.off.x.shape[0] == 28
+    levels = tuple((t0, k, L) for t0, (k, L) in zip(
+        np.cumsum([0] + [k for k, _ in shapes]), shapes))
+    lookup = (S((n, 2), jnp.uint32), S(((1 << 20) + 1,), jnp.int32),
+              S((CHUNK,), jnp.uint64), S((CHUNK,), jnp.float64),
+              S((), jnp.int32))
+    statics = dict(shift=LK_SHIFT, probes=LK_PROBES, is_pair=False)
+    count = jax.jit(partial(_count_chunk_nnz, **statics),
+                    donate_argnums=(0, 1)).lower(
+        S((n_pad,), jnp.int32), S((), jnp.int64), tables, *lookup).compile()
+    _fits(count, "count_row_nnz at chain_28")
+    bufs = tuple((S((k, pad_to_multiple(L, CHUNK)), jnp.int32),
+                  S((k, pad_to_multiple(L, CHUNK)), jnp.float64))
+                 for _, k, L in levels)
+    pack = jax.jit(partial(_lowmem_pack_chunk, levels=levels, **statics),
+                   donate_argnums=(0,)).lower(bufs, tables,
+                                              *lookup).compile()
+    _fits(pack, "ell_lowmem_pack at chain_28")
+    m = pack.memory_analysis()
+    held = sum(12 * k * pad_to_multiple(L, CHUNK) for _, k, L in levels)
+    assert held == 7_003_963_392
+    assert m.alias_size_in_bytes == held <= m.output_size_in_bytes \
+        < held + 4096
+    assert m.temp_size_in_bytes < 2e9
 
 
 @pytest.mark.parametrize("program",
