@@ -14,7 +14,10 @@ there (memory space ``S(1)`` in the optimised HLO; a row of 3 is padded to
 4 lanes, 16 B), and 6.06 ns (165 M rows/s) where the result goes to HBM:
 over chain_32_symm's 4.7 M-row table the step lies between gathers of 2.0
 and 3.3 M rows.  ``LocalEngine`` cuts its gathers to the fitting side
-(``parallel/engine.py::gather_row_blocks``).  The split is **bit-exact**:
+(``parallel/engine.py::gather_row_blocks``), and where ``x`` itself is too
+long to be a table in VMEM (13 to 18 ns a slot from HBM: PERF.md §5) it
+cuts the table and gathers each range's own entries from that range
+(``gather_table_ranges``).  The split is **bit-exact**:
 
 * ``a = f32(x)``, ``b = f32(x − a)``, ``c = f32(x − a − b)`` — consecutive
   roundings, so ``b ≲ ulp32(a)``, ``c ≲ ulp32(b)``.

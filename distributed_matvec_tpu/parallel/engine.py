@@ -33,7 +33,9 @@ and checked on first application.
 
 from __future__ import annotations
 
+import os
 import time
+from concurrent.futures import ThreadPoolExecutor
 from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
@@ -277,11 +279,9 @@ def gather_row_bytes(parts: int) -> int:
 #: blocks of 25,600 rows, 3,600 gathers an apply), and every piece is a
 #: loop of its own in the apply and in the solver's block programs, which
 #: are traced and loaded anew each solve at about 0.1 s a piece (PERF.md §6,
-#: PR 31).  Against that stands what whole levels cost where the table is
-#: in HBM: 15.5 ns a slot for 4.3 (PERF.md §6, PR 32, chain_28).  At 120 M
-#: slots and 66 applies a solve the two meet near 64 blocks; half of that
-#: keeps a clear gain, so up to 7.44 M rows of 16 B the rows are cut as
-#: before and past it they stay whole, as where the table does not fit.
+#: PR 31).  Up to 7.44 M rows of 16 B the rows are cut as before; past it
+#: (and where the table alone does not fit) the *table* is cut instead:
+#: :func:`gather_table_ranges`.
 GATHER_MAX_ROW_BLOCKS = 32
 
 
@@ -298,11 +298,11 @@ def gather_row_blocks(n_rows: int, parts: int):
     result takes :func:`gather_row_bytes`, and an index is 4 B.  The table
     is ``n_rows`` long (``x`` for the levels' gathers, the accumulator for
     the one that puts the result back in basis order).  Where everything
-    fits as it is, where the table alone leaves no room for a tile of rows
-    (7.73 M rows of 16 B: the gathers then read a table in HBM, whole
-    levels long), or where it leaves room only for blocks so short that
-    there would be more than :data:`GATHER_MAX_ROW_BLOCKS` of them, ``nb``
-    is 1.
+    fits as it is, ``nb`` is 1.  It is 1 too where the table alone leaves
+    no room for a tile of rows (7.73 M rows of 16 B) or leaves room only
+    for blocks so short that there would be more than
+    :data:`GATHER_MAX_ROW_BLOCKS` of them: there the table itself is cut
+    (:func:`gather_table_ranges`), and this rule has no say.
     """
     row = gather_row_bytes(parts)
     b_max = (GATHER_VMEM_BYTES - n_rows * row) // (row + 4) \
@@ -313,13 +313,43 @@ def gather_row_blocks(n_rows: int, parts: int):
     return int(nb), int(pad_to_multiple(-(-n_rows // nb), INDEX_TILE))
 
 
+def gather_table_ranges(n_rows: int, parts: int):
+    """``(R, W)``: ``x`` as a gather table cut into ``R`` contiguous ranges
+    of ``W`` rows of the basis order (a multiple of :data:`INDEX_TILE`; the
+    last range may be short).  ``R`` is 1 wherever the whole table fits
+    VMEM with room for :func:`gather_row_blocks` to cut the rows.  Past
+    that line (7.44 M rows of 16 B, half of that for a pair-form or a
+    two-column ``x``) a gather from whole ``x`` reads HBM at 13 to 18 ns a
+    slot for 4.32 (PERF.md §5), so the rows are cut into the same ranges
+    and every row's entries into *near* ones, whose column lies in the
+    row's own range and is gathered from that range of ``x`` in VMEM, and
+    *far* ones, gathered from whole ``x`` as before (``_build_ell_ranges``).
+    ``W`` is the most for which a range is at once a table and a whole
+    gather's rows inside :data:`GATHER_VMEM_BYTES` (``W`` table rows,
+    ``W`` gathered rows and their indices: the near levels' gathers and
+    the two that put a range's sums back in basis order, whose table is
+    the accumulator), so no piece is cut inside a range and ``R`` is the
+    fewest ranges that allow it: 12 of 3,348,480 rows at chain_28.
+    """
+    row = gather_row_bytes(parts)
+    if n_rows * (2 * row + 4) <= GATHER_VMEM_BYTES \
+            or gather_row_blocks(n_rows, parts)[0] > 1:
+        return 1, int(n_rows)
+    w_max = max(GATHER_VMEM_BYTES // (2 * row + 4)
+                // INDEX_TILE * INDEX_TILE, INDEX_TILE)
+    R = -(-n_rows // w_max)
+    return int(R), int(pad_to_multiple(-(-n_rows // R), INDEX_TILE))
+
+
 def block_pieces(levels, B: int):
     """The staircase ``levels`` (``(t0, k, L)``, longest first) cut at
     every ``B`` packed rows: a tuple of blocks, each the tuple of
     ``(level, r0, rows)`` pieces that lie in it, the longest level's
     first.  A level is a prefix of the packed order, so it reaches blocks
     ``0 .. ceil(L / B) - 1`` and every piece but its last is ``B`` long;
-    the pieces' slots add up to the levels'."""
+    the pieces' slots add up to the levels'.  (Past the VMEM line, where
+    the gather table is cut, no level is cut: a range's staircases are
+    whole, :func:`gather_table_ranges`.)"""
     blocks = []
     for b in range(-(-levels[0][2] // B)):
         blocks.append(tuple(
@@ -392,6 +422,73 @@ def _lowmem_pack_chunk(bufs, tables, pair, dir_tab, alphas, norms_a, start,
             jax.lax.dynamic_update_slice(
                 cf_l, jnp.where(inside, new_c, old_c), (zero, start) + pz)))
     return tuple(out)
+
+
+def _range_chunk(tables, pair, dir_tab, alphas, norms_a, lo, hi, *, shift,
+                 probes, is_pair):
+    """Build step of one table range (rows and columns ``lo .. hi`` of the
+    basis order): chunk kernels -> each row's entries packed *near* first
+    (column inside the range, stored range-local), then *far* (global
+    column), then dead, in their original order.  Returns the transposed
+    slab ``(idx [T, b] i32, coeff [T, b(, 2)], counts [2, b], invalid)``:
+    a row's near and far counts say where its two parts lie."""
+    idx, cf, invalid = _chunk_structure_ops(tables, pair, dir_tab, alphas,
+                                            norms_a, shift, probes)
+    idx_t, cf_t = idx.T.astype(jnp.int32), jnp.moveaxis(cf, 0, 1)
+    dead = _dead_mask(cf_t, is_pair)
+    near = ~dead & (idx_t >= lo) & (idx_t < hi)
+    order = jnp.argsort(jnp.where(dead, 2, jnp.where(near, 0, 1)), axis=0,
+                        stable=True)
+    idx_p = jnp.take_along_axis(jnp.where(near, idx_t - lo, idx_t), order,
+                                axis=0)
+    cf_p = jnp.take_along_axis(
+        cf_t, order[..., None] if is_pair else order, axis=0)
+    counts = jnp.stack([near.sum(axis=0, dtype=jnp.int32),
+                        (~dead & ~near).sum(axis=0, dtype=jnp.int32)])
+    return idx_p, cf_p, counts, invalid
+
+
+def _range_staircase(tabs, first, count, rows: int, pool):
+    """One staircase of a table range, cut on the host out of the range's
+    class-packed tables (``_range_chunk``; ``tabs`` the index and the
+    coefficient table, ``[T, Wc(, 2)]``): the rows' part that starts at
+    column ``first`` and is ``count`` long (both per row), the first
+    ``rows`` rows real.  Rows are ordered by ``count``, descending and
+    stable, and :func:`staircase_levels` of their histogram gives the
+    levels; a position whose row has no such entry is blank (index 0,
+    coeff 0).  Returns ``(levels, pieces, pos_of)``: ``pieces`` holds one
+    array a table for each ``(t0, k, L)`` level, ``pos_of`` each row's
+    position, or ``None`` where rows of near-equal width keep the plain
+    table in range order; no level at all where no row has an entry.
+    A column of a level is one job of ``pool`` (NumPy's indexing runs
+    outside the interpreter's lock)."""
+    T = tabs[0].shape[0]
+    hist = np.bincount(count[:rows], minlength=T + 1)
+    stair, levels = staircase_levels(hist, rows)
+    if not levels[0][1]:
+        return (), [], None
+    pos_of = None
+    row_of = np.arange(count.size, dtype=np.int32)
+    if stair:
+        row_of = np.argsort(-count, kind="stable").astype(np.int32)
+        pos_of = np.empty(rows, np.int32)
+        pos_of[row_of[:rows]] = np.arange(rows, dtype=np.int32)
+    first, count = first[row_of], count[row_of]      # in packed order
+
+    pieces = [[np.empty((k, L) + tab.shape[2:], tab.dtype) for tab in tabs]
+              for _, k, L in levels]
+
+    def column(job):
+        (t0, k, L), cut, j = job    # a level is no longer than the tables
+        blank = t0 + j >= count[:L]
+        col = np.minimum(first[:L] + (t0 + j), T - 1)
+        for tab, piece in zip(tabs, cut):
+            piece[j] = tab[col, row_of[:L]]
+            piece[j][blank] = 0
+
+    list(pool.map(column, [(level, cut, j) for level, cut in
+                           zip(levels, pieces) for j in range(level[1])]))
+    return levels, pieces, pos_of
 
 
 def _compact_pack_chunk(out_idx, t_rows, t_idx, bad_ratio, tables, pair,
@@ -748,6 +845,17 @@ def ell_term_loop(levels):
                     "scanned_columns": 0 if unroll else width}
 
 
+def widest_pieces(blocks, ranges: bool):
+    """The level pieces that hold the widest rows' columns, for
+    :func:`ell_term_loop`: the first block's (every level reaches it);
+    where the gather table is cut (``ranges``: a near and a far staircase
+    a range), those of the range whose rows are widest."""
+    if not ranges:
+        return blocks[0]
+    return max((blocks[j] + blocks[j + 1] for j in range(0, len(blocks), 2)),
+               key=lambda ps: sum(idx.shape[0] for idx, _ in ps))
+
+
 def hash_basis_operator(h, operator, include_arrays: bool = True) -> None:
     """Feed everything that identifies a (basis, operator) pair into a hash:
     the basis JSON, the ACTUAL representative/norm arrays (they may have been
@@ -944,6 +1052,13 @@ class LocalEngine:
                 self._save_structure(structure_cache, soft=soft_save)
             self._matvec = self._make_ell_matvec()
             self._checked = True                  # validated at build time
+            if self._ell_range_rows:
+                # past the VMEM line HBM is what bounds the basis, and the
+                # build's inputs (states, norms, lookup: 24 B a row, 0.96
+                # GB at chain_28) are read by nothing once the levels are
+                # there: they go, where a window's peak would hold them
+                self._alphas = self._norms = None
+                self._lk_pair = self._lk_dir = None
         elif mode == "compact":
             self.structure_restored = self._try_load_structure(structure_cache)
             record_structure_cache(self.structure_restored,
@@ -999,10 +1114,11 @@ class LocalEngine:
 
         h = hashlib.sha256()
         hash_basis_operator(h, self.operator)
-        # the ell layout is v3 (staircase levels in row blocks); a v2 file
-        # (whole levels) or a v1 file (main table + tail) has another
-        # fingerprint and is rebuilt, not misread
-        layout = "v3" if self.mode == "ell" else "v1"
+        # the ell layout is v4 (staircase levels in row blocks, or, where
+        # the gather table is cut, a near and a far staircase a range); a
+        # v3 file (no ranges), a v2 file (whole levels) or a v1 file (main
+        # table + tail) has another fingerprint and is rebuilt, not misread
+        layout = "v4" if self.mode == "ell" else "v1"
         h.update(f"{self.mode}|{self.pair}|{self.real}|{self.batch_size}"
                  f"|{self.n_states}|{self.n_padded}|{layout}".encode())
         self._fp_cache = h.hexdigest()
@@ -1022,7 +1138,7 @@ class LocalEngine:
         if data is None:
             return False
         if self.mode == "ell":
-            if "block_pieces" not in data or "build_passes" not in data:
+            if "block_pieces" not in data or "table_ranges" not in data:
                 return False    # written before the counts it lacks
             sizes = [int(v) for v in str(data["block_pieces"]).split(",")]
             self._ell_blocks = tuple(
@@ -1030,15 +1146,19 @@ class LocalEngine:
                        jnp.asarray(data[f"level{i}_coeff"]))
                       for i in range(end - size, end))
                 for size, end in zip(sizes, np.cumsum(sizes)))
-            self._ell_pos_of = jnp.asarray(data["pos_of"]) \
-                if "pos_of" in data else None
-            self._ell_counts = {
-                **{k: int(data[k]) for k in
-                   ("gather_slots", "live_entries", "levels", "row_blocks",
-                    "gather_pieces", "build_passes", "table_bytes")},
-                "terms": self.num_terms,
-                "widest_row": sum(int(idx.shape[0])
-                                  for idx, _ in self._ell_blocks[0])}
+            self._ell_range_rows = int(data["range_rows"])
+            if self._ell_range_rows:    # a position array a staircase
+                self._ell_pos_of = tuple(
+                    jnp.asarray(data[f"pos_of{j}"])
+                    if f"pos_of{j}" in data else None
+                    for j in range(len(sizes)))
+            else:
+                self._ell_pos_of = jnp.asarray(data["pos_of"]) \
+                    if "pos_of" in data else None
+            self._ell_counts = {k: int(data[k]) for k in (
+                "gather_slots", "live_entries", "levels", "terms",
+                "widest_row", "row_blocks", "gather_pieces", "build_passes",
+                "table_bytes", "table_ranges", "near_slots", "far_slots")}
         else:
             self._ell_T0 = int(data["T0"])
             self._c_W = float(data["W"])
@@ -1065,11 +1185,16 @@ class LocalEngine:
             # the pieces block by block under the levels' names (they are
             # the levels where the rows are not cut), and how many a block
             payload = dict(self._ell_counts, block_pieces=",".join(
-                str(len(blk)) for blk in self._ell_blocks))
+                str(len(blk)) for blk in self._ell_blocks),
+                range_rows=self._ell_range_rows, n_padded=self.n_padded)
             for i, (idx_l, cf_l) in enumerate(self._ell_levels):
                 payload[f"level{i}_idx"] = np.asarray(idx_l)
                 payload[f"level{i}_coeff"] = np.asarray(cf_l)
-            if self._ell_pos_of is not None:
+            if self._ell_range_rows:
+                for j, pos in enumerate(self._ell_pos_of):
+                    if pos is not None:
+                        payload[f"pos_of{j}"] = np.asarray(pos)
+            elif self._ell_pos_of is not None:
                 payload["pos_of"] = np.asarray(self._ell_pos_of)
         else:
             payload = {"T0": self._ell_T0, "W": self._c_W,
@@ -1125,6 +1250,12 @@ class LocalEngine:
         b, C = self.batch_size, self.num_chunks
         T = self.num_terms
         is_pair = self.pair
+
+        # where ``x`` as a gather table does not fit VMEM the table is cut,
+        # and the structure is built a range at a time, whatever its size
+        R, W = gather_table_ranges(self.n_padded, 3 if self.real else 6)
+        if R > 1:
+            return self._build_ell_ranges(R, W)
 
         # One-pass build materializes full-width [T, N_pad] idx+coeff buffers
         # before cutting the levels (peak ≈ 1.6× their size).  When that
@@ -1187,7 +1318,10 @@ class LocalEngine:
         ``build_passes`` the runs of the kernels this build makes (2: the
         low-memory build) and ``table_bytes`` what ``x`` takes as a gather
         table, the number :func:`gather_row_blocks` holds against
-        :data:`GATHER_VMEM_BYTES`: above it the gathers read HBM)."""
+        :data:`GATHER_VMEM_BYTES`; ``table_ranges`` is 1 here, the table
+        is not cut, so ``near_slots``, the table slots gathered from a
+        range of ``x``, is 0 and ``far_slots``, those gathered from whole
+        ``x``, all of them: ``_build_ell_ranges`` has the other case)."""
         stair, levels = staircase_levels(hist, self.n_padded)
         slots = sum(k * L for _, k, L in levels)
         parts = 3 if self.real else 6
@@ -1207,7 +1341,9 @@ class LocalEngine:
             "row_blocks": nb,
             "gather_pieces": sum(map(len, plan)) + (nb if stair else 0),
             "build_passes": passes,
-            "table_bytes": self.n_padded * gather_row_bytes(parts)}
+            "table_bytes": self.n_padded * gather_row_bytes(parts),
+            "table_ranges": 1, "near_slots": 0, "far_slots": slots}
+        self._ell_range_rows = 0
         log_debug(f"ell levels: T={self.num_terms} stair={stair} "
                   f"levels={levels} entries {self.n_padded * self.num_terms}"
                   f" -> {slots} in {nb} row blocks of {B}")
@@ -1276,6 +1412,131 @@ class LocalEngine:
             self._ell_blocks = tuple(
                 tuple(zip(ib, cb)) for ib, cb in
                 zip(idx_blocks, cut("ell_stair_coeff", coeff_buf)))
+
+    def _build_ell_ranges(self, R: int, W: int) -> None:
+        """The structure where ``x`` is too long to be a gather table in
+        VMEM (:func:`gather_table_ranges`): rows and columns are cut into
+        the same ``R`` contiguous ranges of ``W`` rows of the basis order,
+        and each range ``r`` holds two staircases of its own rows.  The
+        *near* one has the entries whose column lies in the range, with
+        range-local column indices, to be gathered from ``x[r W:(r+1) W]``,
+        a table that fits VMEM beside the gather's indices and result (4.3
+        ns a slot on a v5e).  The *far* one has the others with global
+        indices, gathered from whole ``x`` in HBM (17.6 ns): on a basis
+        sorted by the states' integer value most entries are near (84% at
+        chain_28).  Each is ordered by its own count (``staircase_levels``
+        on the range's near and far histograms) and put back in range
+        order by a gather of its own from an accumulator ``W`` rows long,
+        which fits VMEM too.  ``_ell_blocks`` holds the staircases range
+        by range, near then far, and ``_ell_pos_of`` their positions
+        (``None`` where rows of near-equal width keep the plain table).
+
+        One pass of the kernels, a range at a time (``ell_build_budget_gb``
+        has no say here): the device runs the kernels and packs each
+        chunk's rows near first (``_range_chunk``), the slabs go to the
+        host as they come, and the host cuts the range's levels out of
+        them (``_range_staircase``) and hands the pieces back.  The device
+        holds the finished levels and a few chunks' slabs, never a
+        full-width table: an f64 table updated in place there is split
+        into 32-bit halves and recombined whole at every step, 1.7 times
+        its bytes in temporaries, which chain_28's 16 GB do not have
+        beside 7 GB of levels.  (When the levels are there ``__init__``
+        lets the build's inputs go, too: states, norms and lookup.)
+        """
+        b, T, n_pad = self.batch_size, self.num_terms, self.n_padded
+        Wc = pad_to_multiple(W, b)
+        args = (self.tables, self._lk_pair, self._lk_dir)
+        step = jax.jit(partial(_range_chunk, shift=self._lk_shift,
+                               probes=self._lk_probes, is_pair=self.pair))
+        blocks, pos_of = [], []
+        near_far = [0, 0]               # table slots of each kind
+        live = levels_n = widest = unpermute_rows = 0
+        with ThreadPoolExecutor(min(8, os.cpu_count() or 1)) as pool:
+            for r in range(R):
+                lo = r * W
+                rows = min(W, n_pad - lo)
+                # the host's range-wide tables: indices, coefficients and
+                # the near / far counts
+                tabs = [np.zeros((T, Wc), np.int32),
+                        np.zeros((T, Wc) + ((2,) if self.pair else ()),
+                                 np.dtype(self._dtype)),
+                        np.zeros((2, Wc), np.int32)]
+                bad = 0
+
+                def fetch(ci, *slab):
+                    with obs_trace.span("device_wait", kind="phase",
+                                        at="ell_fill"):
+                        *got, invalid = (np.asarray(a) for a in slab)
+                    for tab, a in zip(tabs, got):
+                        tab[:, ci * b:(ci + 1) * b] = a
+                    return int(invalid)
+
+                with obs_trace.span("ell/fill", kind="phase", table_range=r):
+                    pad = (0, pad_to_multiple(rows, b) - rows)
+                    alphas_c = jnp.pad(
+                        self._alphas[lo:lo + rows], pad,
+                        constant_values=SENTINEL_STATE).reshape(-1, b)
+                    norms_c = jnp.pad(self._norms[lo:lo + rows], pad,
+                                      constant_values=1.0).reshape(-1, b)
+                    span = (jnp.int32(lo), jnp.int32(lo + W))
+                    chunk = precompile(     # compiled in the first range
+                        "ell_range_chunk", self._builder_statics(), step,
+                        args + (alphas_c[0], norms_c[0]) + span, self.timer)
+                    # a slab is fetched two steps after its dispatch, so
+                    # that the copy runs beside the next chunks' kernels
+                    queue = []
+                    for ci in range(alphas_c.shape[0]):
+                        log_debug(f"ell build range {r}/{R} chunk {ci}")
+                        queue.append((ci,) + chunk(*args, alphas_c[ci],
+                                                   norms_c[ci], *span))
+                        if len(queue) > 2:
+                            bad += fetch(*queue.pop(0))
+                    bad += sum(fetch(*item) for item in queue)
+                    del queue, alphas_c, norms_c
+                if bad:
+                    raise RuntimeError(
+                        f"{bad} generated matrix elements map outside the "
+                        "basis — operator does not preserve the chosen "
+                        "sector")
+                with obs_trace.span("ell/stair_levels", kind="phase",
+                                    table_range=r):
+                    *tabs, cnt = tabs
+                    width = 0
+                    for part, first in enumerate((np.zeros_like(cnt[0]),
+                                                  cnt[0])):
+                        levels, pieces, pos = _range_staircase(
+                            tabs, first, cnt[part], rows, pool)
+                        near_far[part] += sum(k * L for _, k, L in levels)
+                        live += int(cnt[part].sum(dtype=np.int64))
+                        levels_n += len(levels)
+                        unpermute_rows += rows if pos is not None else 0
+                        width += sum(k for _, k, _ in levels)
+                        blocks.append(tuple(
+                            (jnp.asarray(i), jnp.asarray(c))
+                            for i, c in pieces))
+                        pos_of.append(pos if pos is None
+                                      else jnp.asarray(pos))
+                        del pieces
+                    widest = max(widest, width)
+                    del tabs, cnt
+        self._ell_blocks = tuple(blocks)
+        self._ell_pos_of = tuple(pos_of)
+        self._ell_range_rows = W
+        self._ell_counts = {
+            "gather_slots": sum(near_far) + unpermute_rows,
+            "live_entries": live,
+            "levels": levels_n,
+            "terms": T,
+            "widest_row": widest,
+            "row_blocks": R,
+            "gather_pieces": levels_n + sum(p is not None for p in pos_of),
+            "build_passes": 1,
+            "table_bytes": n_pad * gather_row_bytes(3 if self.real else 6),
+            "table_ranges": R,
+            "near_slots": near_far[0],
+            "far_slots": near_far[1]}
+        log_debug(f"ell ranges: {R} of {W} rows, near slots {near_far[0]}, "
+                  f"far {near_far[1]}, {levels_n} levels")
 
     def _count_row_nnz(self, alphas_c, norms_c):
         """Counting pass shared by the low-memory builds: the kernels run
@@ -1588,6 +1849,7 @@ class LocalEngine:
         use_sg = split_gather_enabled()
         is_pair = self.pair
         nd_base = 2 if is_pair else 1    # ndim of one unbatched vector
+        W = self._ell_range_rows         # 0: the gather table is not cut
 
         def grown(acc, rows):
             """``acc`` with zero rows appended up to ``rows``."""
@@ -1603,7 +1865,7 @@ class LocalEngine:
             # operation is added, moved or split for them
             with jax.named_scope("apply/split"):
                 gx = prep_gather(x, dtype, use_sg)
-            unroll, form = ell_term_loop(blocks[0])
+            unroll, form = ell_term_loop(widest_pieces(blocks, W > 0))
             # on the span this trace runs under (``apply``, or the solver's
             # ``lanczos/dispatch``): which form the program it builds takes
             obs_trace.current_span().add(**form)
@@ -1614,7 +1876,7 @@ class LocalEngine:
                     return K.cmul_pair(c[:, None, :] if batched else c, g)
                 return (c[:, None] if batched else c) * g
 
-            def terms(acc, idx, coeff):
+            def terms(acc, idx, coeff, gx):
                 if unroll:
                     # Unrolled per-term gathers — contiguous coeff rows.
                     for t in range(idx.shape[0]):
@@ -1626,26 +1888,56 @@ class LocalEngine:
                     acc, _ = jax.lax.scan(step, acc, (idx, coeff))
                 return acc
 
-            # ``acc`` is in packed row order, a row block at a time: from
-            # the block's shortest piece (the widest rows' last columns) to
-            # its longest, each adding to the head of the next accumulator.
-            # Every block but the last is as long as its longest piece, so
-            # the blocks' accumulators laid end to end are the whole one.
-            with jax.named_scope("apply/terms"):
-                accs = []
-                for pieces in blocks:
-                    acc = jnp.zeros((0,) + x.shape[1:], dtype)
-                    for idx, coeff in reversed(pieces):
-                        acc = terms(grown(acc, idx.shape[1]), idx, coeff)
-                    accs.append(acc)
-                acc = accs[0] if len(accs) == 1 else jnp.concatenate(accs)
-            if pos_of is not None:
-                # back to basis order, a block's length of rows a gather
-                with jax.named_scope("apply/unpermute"):
-                    ga = prep_gather(grown(acc, n_pad), dtype, use_sg)
-                    B = accs[0].shape[0]
-                    acc = ga(pos_of) if len(accs) == 1 else jnp.concatenate(
-                        [ga(pos_of[r0:r0 + B]) for r0 in range(0, n_pad, B)])
+            def staircase(pieces, gx):
+                """One accumulator in packed row order: from the shortest
+                piece (the widest rows' last columns) to the longest, each
+                adding to the head of the next accumulator."""
+                acc = jnp.zeros((0,) + x.shape[1:], dtype)
+                for idx, coeff in reversed(pieces):
+                    acc = terms(grown(acc, idx.shape[1]), idx, coeff, gx)
+                return acc
+
+            if not W:
+                # ``acc`` is in packed row order, a row block at a time.
+                # Every block but the last is as long as its longest
+                # piece, so the blocks' accumulators laid end to end are
+                # the whole one.
+                with jax.named_scope("apply/terms"):
+                    accs = [staircase(pieces, gx) for pieces in blocks]
+                    acc = accs[0] if len(accs) == 1 \
+                        else jnp.concatenate(accs)
+                if pos_of is not None:
+                    # back to basis order, a block's length of rows a gather
+                    with jax.named_scope("apply/unpermute"):
+                        ga = prep_gather(grown(acc, n_pad), dtype, use_sg)
+                        B = accs[0].shape[0]
+                        acc = ga(pos_of) if len(accs) == 1 \
+                            else jnp.concatenate(
+                                [ga(pos_of[r0:r0 + B])
+                                 for r0 in range(0, n_pad, B)])
+            else:
+                # the gather table cut (``_build_ell_ranges``): range
+                # ``r``'s near staircase gathers from its own ``W`` rows of
+                # ``x``, a table in VMEM, its far one from whole ``x``;
+                # each is put back in range order from an accumulator ``W``
+                # rows long, and the ranges' sums laid end to end are in
+                # basis order
+                out = []
+                for r, lo in enumerate(range(0, n_pad, W)):
+                    rows = min(W, n_pad - lo)
+                    with jax.named_scope("apply/split"):
+                        gr = prep_gather(x[lo:lo + rows], dtype, use_sg)
+                    sums = []
+                    for j, g in ((2 * r, gr), (2 * r + 1, gx)):
+                        with jax.named_scope("apply/terms"):
+                            acc = grown(staircase(blocks[j], g), rows)
+                        if pos_of[j] is not None:
+                            with jax.named_scope("apply/unpermute"):
+                                acc = prep_gather(acc, dtype,
+                                                  use_sg)(pos_of[j])
+                        sums.append(acc[:rows])
+                    out.append(sums[0] + sums[1])
+                acc = jnp.concatenate(out)
             with jax.named_scope("apply/diag"):
                 d = diag[:n].astype(dtype)
                 y = d.reshape((n,) + (1,) * (x.ndim - 1)) * x + acc[:n]
@@ -1654,7 +1946,8 @@ class LocalEngine:
         self._apply_fn = apply_fn
         self._operands = (self._ell_blocks, self._ell_pos_of, self._diag)
         #: the form the apply takes (``engine_init`` event)
-        self._ell_form = ell_term_loop(self._ell_blocks[0])[1]
+        self._ell_form = ell_term_loop(
+            widest_pieces(self._ell_blocks, W > 0))[1]
         _mv = jax.jit(apply_fn)
         return lambda x: _mv(x, self._operands)
 
@@ -1892,7 +2185,10 @@ class LocalEngine:
         if self.mode == "ell":
             out = {"idx": tuple(i for i, _ in self._ell_levels),
                    "coeff": tuple(c for _, c in self._ell_levels)}
-            if self._ell_pos_of is not None:
+            if self._ell_range_rows:
+                out["pos_of"] = tuple(
+                    p for p in self._ell_pos_of if p is not None)
+            elif self._ell_pos_of is not None:
                 out["pos_of"] = self._ell_pos_of
             return out
         if self.mode == "compact":

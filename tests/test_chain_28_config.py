@@ -1,17 +1,19 @@
 """The chain without symmetries at the size where the vector leaves VMEM
 (upstream's ``data/heisenberg_chain_28.yaml``, the benchmark's ``chain_28``:
-40,116,600 states, a 613 MiB gather table, the two-pass structure build) on
-the normal path, at rings of 16 and 18 sites pushed into the same two
-branches — the low-memory build forced through ``ell_build_budget_gb`` and
-``GATHER_VMEM_BYTES`` patched below the table's bytes — against the
+40,116,600 states, a 613 MiB gather table, cut into 12 ranges since PR 33)
+on the normal path, at rings of 16 and 18 sites pushed into the same
+branch — ``GATHER_VMEM_BYTES`` patched below the table's bytes, so that the
+table is cut and the structure built a range at a time — against the
 benchmark's plain reference (``benchmark/references/lattice_heisenberg.py``,
-which imports nothing of the program); and the full size's numbers that
-need no build: the staircase of its closed-form histogram, the size rule's
-verdict, and the YAML in ``data/``.
+which imports nothing of the program); the two-pass build below that line;
+and the full size's numbers that need no build: the staircase of its
+closed-form histogram and of its ranges' counted ones, the size rules'
+verdicts, and the YAML in ``data/``.
 """
 
 import gc
 import importlib.util
+import json
 import os
 from math import comb
 
@@ -23,7 +25,8 @@ from distributed_matvec_tpu import obs
 from distributed_matvec_tpu.models.yaml_io import load_config_from_yaml
 from distributed_matvec_tpu.parallel import engine
 from distributed_matvec_tpu.parallel.engine import (
-    LocalEngine, block_pieces, gather_row_blocks, staircase_levels)
+    LocalEngine, block_pieces, gather_row_blocks, gather_table_ranges,
+    staircase_levels)
 from distributed_matvec_tpu.utils.config import get_config, update_config
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -92,63 +95,112 @@ def two_pass():
 
 @pytest.fixture
 def table_outside_vmem(monkeypatch):
-    """``table_outside_vmem(n_padded)``: the rule's VMEM number one row
-    short of ``x`` as a gather table, so that it takes its no-room branch
-    (steered through the rule's input, not through an option)."""
+    """``table_outside_vmem(n_padded)``: the rules' VMEM number one row
+    short of ``x`` as a gather table, so that the row-block rule has no
+    room and the table is cut (steered through the rules' input, not
+    through an option).  Returns the ranges: 36 B a row of a range against
+    16 B a row of the table, so three."""
     def patch(n_padded):
         monkeypatch.setattr(engine, "GATHER_VMEM_BYTES", 16 * n_padded - 16)
         assert gather_row_blocks(n_padded, 3) == (
             1, engine.pad_to_multiple(n_padded, engine.INDEX_TILE))
+        R, W = gather_table_ranges(n_padded, 3)
+        assert R == 3 and (R - 1) * W < n_padded <= R * W
+        assert 36 * W <= engine.GATHER_VMEM_BYTES and W % 1024 == 0
+        return R, W
     return patch
 
 
-def _build(op, n_padded, table_outside_vmem):
-    table_outside_vmem(n_padded)
-    return LocalEngine(op, batch_size=CHUNK)
+def _stored_entries(eng, W):
+    """``(row, column, value, near)`` of every live entry the cut
+    structure stores, read back from its arrays alone: range ``r``'s
+    staircases at ``_ell_blocks[2 r]`` (near: range-local columns) and
+    ``[2 r + 1]`` (far: global ones), a piece's position ``p`` the row
+    whose ``pos_of`` is ``p``."""
+    out = []
+    for j, (blk, pos) in enumerate(zip(eng._ell_blocks, eng._ell_pos_of)):
+        r, far = divmod(j, 2)
+        row_of = np.argsort(np.asarray(pos)) + r * W
+        for idx, cf in blk:
+            idx, cf = np.asarray(idx), np.asarray(cf)
+            t, p = np.nonzero(cf)
+            out.append((row_of[p], idx[t, p] + (0 if far else r * W),
+                        cf[t, p], np.full(t.size, not far)))
+    return [np.concatenate(c) for c in zip(*out)]
 
 
 @pytest.mark.parametrize("name", list(RINGS))
-def test_two_pass_build_with_the_table_in_hbm_matches_the_reference(
-        name, ring, reference, two_pass, table_outside_vmem):
+def test_table_cut_build_matches_the_reference(name, ring, reference,
+                                               table_outside_vmem):
     """Every row of one apply at the configuration's own contract, through
-    the two branches ``chain_28`` takes on the chip."""
+    the branch ``chain_28`` takes on the chip: the table cut into ranges,
+    one pass of the kernels a range, near entries gathered from the range
+    and far ones from whole ``x``; the counts that say so; and the stored
+    entries against the reference's own matrix, each once, near exactly
+    where its column lies in its row's range."""
     op, spec, states = ring(name)
     np.testing.assert_array_equal(op.basis.representatives, states)
     n_padded = engine.pad_to_multiple(states.size, CHUNK)
-    eng = _build(op, n_padded, table_outside_vmem)
+    R, W = table_outside_vmem(n_padded)
+    eng = LocalEngine(op, batch_size=CHUNK)
     counts = eng._ell_counts
     assert eng.mode == "ell" and eng.num_chunks > 3
-    assert (counts["build_passes"], counts["row_blocks"]) == (2, 1)
+    assert (counts["build_passes"], counts["row_blocks"],
+            counts["table_ranges"]) == (1, R, R)
     assert counts["table_bytes"] == 16 * n_padded > engine.GATHER_VMEM_BYTES
-    assert counts["levels"] > 1 and eng._ell_pos_of is not None
-    assert counts["gather_pieces"] == counts["levels"] + 1
+    assert len(eng._ell_blocks) == len(eng._ell_pos_of) == 2 * R
+    assert all(p is not None for p in eng._ell_pos_of)
+    assert counts["gather_pieces"] == counts["levels"] + 2 * R
+    slots = [sum(i.size for i, _ in blk) for blk in eng._ell_blocks]
+    assert (sum(slots[0::2]), sum(slots[1::2])) == \
+        (counts["near_slots"], counts["far_slots"])
+    assert counts["gather_slots"] == sum(slots) + 2 * n_padded
+    rows, cols, vals, near = _stored_entries(eng, W)
+    want = reference.sparse_matrix(spec, states).tocoo()
+    off = want.row != want.col
+    assert counts["live_entries"] == rows.size == int(off.sum()) \
+        == reference.count_offdiagonal(spec, states,
+                                       np.arange(states.size))
+    np.testing.assert_array_equal(near, rows // W == cols // W)
+    assert 0 < int(near.sum()) < rows.size          # both kinds occur
+    got, ref = np.lexsort((cols, rows)), np.lexsort((want.col[off],
+                                                     want.row[off]))
+    np.testing.assert_array_equal(rows[got], want.row[off][ref])
+    np.testing.assert_array_equal(cols[got], want.col[off][ref])
+    np.testing.assert_array_equal(vals[got], want.data[off][ref])
     x = np.random.default_rng(32).standard_normal(states.size)
     x /= np.linalg.norm(x)
-    want = reference.apply_rows(spec, states, x, np.arange(states.size))
-    np.testing.assert_allclose(np.asarray(eng.matvec(x)), want,
-                               atol=1e-14, rtol=1e-12)
+    np.testing.assert_allclose(
+        np.asarray(eng.matvec(x)),
+        reference.apply_rows(spec, states, x, np.arange(states.size)),
+        atol=1e-14, rtol=1e-12)
 
 
 @pytest.mark.parametrize("name", list(RINGS))
-def test_two_pass_build_makes_the_one_pass_builds_arrays(
+def test_table_cut_build_is_one_build_whatever_the_budget(
         name, ring, table_outside_vmem):
+    """Above the line ``ell_build_budget_gb`` decides nothing: the build
+    that would have gone one-pass and the one that would have gone
+    two-pass are the same pass a range, the same arrays piece for piece."""
     op, _, states = ring(name)
     n_padded = engine.pad_to_multiple(states.size, CHUNK)
-    one = _build(op, n_padded, table_outside_vmem)
+    table_outside_vmem(n_padded)
+    one = LocalEngine(op, batch_size=CHUNK)
     was = get_config().ell_build_budget_gb
     update_config(ell_build_budget_gb=1e-9)
     try:
-        two = _build(op, n_padded, table_outside_vmem)
+        two = LocalEngine(op, batch_size=CHUNK)
     finally:
         update_config(ell_build_budget_gb=was)
-    assert two._ell_counts == {**one._ell_counts, "build_passes": 2}
-    assert len(two._ell_blocks) == len(one._ell_blocks) == 1
-    assert len(two._ell_levels) == len(one._ell_levels)
+    assert two._ell_counts == one._ell_counts
+    assert one._ell_counts["build_passes"] == 1
+    assert [len(b) for b in two._ell_blocks] == \
+        [len(b) for b in one._ell_blocks]
     for (i2, c2), (i1, c1) in zip(two._ell_levels, one._ell_levels):
         np.testing.assert_array_equal(np.asarray(i2), np.asarray(i1))
         np.testing.assert_array_equal(np.asarray(c2), np.asarray(c1))
-    np.testing.assert_array_equal(np.asarray(two._ell_pos_of),
-                                  np.asarray(one._ell_pos_of))
+    for p2, p1 in zip(two._ell_pos_of, one._ell_pos_of):
+        np.testing.assert_array_equal(np.asarray(p2), np.asarray(p1))
 
 
 def _table_bytes():
@@ -210,10 +262,11 @@ def _histogram_28():
 
 
 def test_staircase_of_the_chain_28_histogram():
-    """``staircase_levels`` on the closed-form histogram, no build: 12
-    levels, 582,451,200 table slots and 40,173,568 un-permute rows for
-    582,433,600 non-zeros (fill 93.545%), one row block of whole levels
-    (the 613 MiB table cannot fit VMEM), 13 gathers an apply."""
+    """``staircase_levels`` on the closed-form histogram, no build: the 12
+    whole levels the basis had before its gather table was cut (582,451,200
+    table slots for 582,433,600 non-zeros), which the row-block rule still
+    leaves whole (the 613 MiB table cannot fit VMEM) and the table rule
+    now cuts into 12 ranges."""
     hist = _histogram_28()
     live = int(np.dot(np.arange(29), hist))
     assert live == 2 * 28 * comb(26, 13) == 582_433_600
@@ -221,21 +274,66 @@ def test_staircase_of_the_chain_28_histogram():
     assert stair and levels == LEVELS_28
     slots = sum(k * L for _, k, L in levels)
     assert slots == 582_451_200 and slots + N_PAD_28 == 622_624_768
-    assert 100.0 * live / (slots + N_PAD_28) == pytest.approx(93.545,
-                                                              abs=0.001)
     assert 16 * N_PAD_28 == 642_777_088 > engine.GATHER_VMEM_BYTES
     nb, B = gather_row_blocks(N_PAD_28, 3)
     assert (nb, B) == (1, N_PAD_28)
-    plan = block_pieces(levels, B)
-    assert len(plan) == 1 and len(plan[0]) + nb == 13
-    # 12 B a slot on the device: indices 2.33 GB, coefficients 4.66 GB
-    assert 12 * slots == 6_989_414_400
+    assert len(block_pieces(levels, B)) == 1
+    # a range is a table, a gather's rows and their indices at once: 36 B
+    # a row inside 118 MiB, so 11.7 ranges at the least
+    R, W = gather_table_ranges(N_PAD_28, 3)
+    assert (R, W) == (12, 3_348_480) and 36 * W <= engine.GATHER_VMEM_BYTES
+    assert 36 * engine.pad_to_multiple(-(-N_PAD_28 // 11), 1024) \
+        > engine.GATHER_VMEM_BYTES
+
+
+def test_staircases_of_the_chain_28_ranges():
+    """The near and far staircases of ``chain_28``'s 12 ranges, read off
+    the histograms counted independently of the engine
+    (``tests/data/chain_28_ranges.json``), no build: 84.16% of the
+    non-zeros are near; 490.3 M near slots, 92.3 M far ones and two
+    un-permute rows a padded row make 663.0 M gathered slots an apply
+    (fill 87.85%, where the whole levels' was 93.545: one more row a row
+    to put back), in 294 levels and 24 un-permutes."""
+    with open(os.path.join(ROOT, "tests", "data",
+                           "chain_28_ranges.json")) as f:
+        data = json.load(f)
+    R, W = gather_table_ranges(N_PAD_28, 3)
+    assert (data["n_states"], data["n_padded"], data["ranges"],
+            data["range_rows"]) == (N_28, N_PAD_28, R, W)
+    entries, slots, levels_n, unpermute = [0, 0], [0, 0], 0, 0
+    whole = np.zeros(29, np.int64)
+    for r in range(R):
+        rows = min(W, N_PAD_28 - r * W)
+        for part, kind in enumerate(("near", "far")):
+            hist = np.array(data[kind][r], np.int64)
+            assert hist.sum() == rows
+            entries[part] += int(np.dot(np.arange(29), hist))
+            stair, levels = staircase_levels(hist, rows)
+            assert stair and levels[0][2] <= W
+            slots[part] += sum(k * L for _, k, L in levels)
+            levels_n += len(levels)
+            unpermute += rows
+        # a range's near levels run to 22-23 columns, its far ones to 7-12
+        assert 22 <= np.flatnonzero(data["near"][r]).max() <= 23
+        assert 7 <= np.flatnonzero(data["far"][r]).max() <= 12
+    assert entries == [490_171_298, 92_262_302]
+    assert sum(entries) == 582_433_600
+    assert round(entries[0] / sum(entries), 4) == 0.8416
+    assert slots == [490_305_536, 92_332_032]
+    assert (levels_n, unpermute) == (294, 2 * N_PAD_28)
+    assert sum(slots) + unpermute == 662_984_704
+    assert 100.0 * sum(entries) / 662_984_704 == pytest.approx(87.850,
+                                                                abs=0.001)
+    # 12 B a slot on the device and two position arrays: 7.31 GB
+    assert 12 * sum(slots) + 4 * unpermute == 7_313_039_360
 
 
 def test_the_size_rule_sends_chain_28_to_the_two_pass_build():
     """1.6 x the full-width build tables (28 terms x 12 B a padded row)
     against ``ell_build_budget_gb``: 21.6 GB over 12, where the benchmark's
-    other two bases stay under it (2.9 and 5.0 GB)."""
+    other two bases stay under it (2.9 and 5.0 GB).  (Since PR 33 the
+    table rule comes first and ``chain_28`` is built a range at a time;
+    the budget rule still has the last word below the VMEM line.)"""
     budget = get_config().ell_build_budget_gb * 1e9
     full = {"chain_28": N_PAD_28 * 28 * 12,
             "chain_32_symm": 4_718_592 * 32 * 12,
@@ -298,4 +396,43 @@ def test_the_two_pass_builds_spans(ring, two_pass):
     init = obs.events("engine_init")[-1]
     assert (init["build_passes"], init["table_bytes"]) == \
         (2, 16 * eng.n_padded)
+    obs.reset_all()
+
+
+def test_the_table_cut_builds_spans(ring, table_outside_vmem):
+    """Above the line the build span holds an ``ell/fill`` and an
+    ``ell/stair_levels`` pass a range (the chunks' slabs fetched under
+    ``device_wait at=ell_fill``), and it and the ``engine_init`` event
+    carry the counts that say the cut engaged: ``table_ranges``,
+    ``near_slots``, ``far_slots`` beside ``row_blocks``, ``gather_pieces``
+    and ``table_bytes``."""
+    op, _, states = ring("ring16")
+    n_padded = engine.pad_to_multiple(states.size, CHUNK)
+    R, W = table_outside_vmem(n_padded)
+    obs.reset_all()
+    eng = LocalEngine(op, batch_size=CHUNK)
+    spans = obs.events("span")
+    (build,) = [e for e in spans
+                if e["name"] == "engine_init/build_structure"]
+    passes = [e for e in spans if e["parent_span_id"] == build["span_id"]]
+    assert [e["name"] for e in passes] == \
+        ["ell/fill", "ell/stair_levels"] * R
+    assert [e["table_range"] for e in passes] == \
+        [r for r in range(R) for _ in range(2)]
+    assert sum(e["dur_ms"] for e in passes) <= build["dur_ms"]
+    fills = {e["span_id"] for e in passes if e["name"] == "ell/fill"}
+    waits = [w for w in spans if w["name"] == "device_wait"
+             and w["parent_span_id"] in fills]
+    assert len(waits) == sum(-(-min(W, n_padded - r * W) // CHUNK)
+                             for r in range(R))      # one a chunk's slab
+    assert {w["at"] for w in waits} == {"ell_fill"}
+    counts = eng._ell_counts
+    init = obs.events("engine_init")[-1]
+    for event in (build, init):
+        assert {k: event[k] for k in counts} == counts
+    assert counts["table_ranges"] == R > 1
+    assert 0 < counts["far_slots"] < counts["near_slots"]
+    share = counts["near_slots"] / (counts["near_slots"]
+                                    + counts["far_slots"])
+    assert 0.7 < share < 0.95
     obs.reset_all()

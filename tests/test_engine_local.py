@@ -244,7 +244,9 @@ def test_staircase_accounting(rng):
         "levels": len(set(lengths)),
         "terms": 16, "widest_row": Tmax,
         "row_blocks": 1, "gather_pieces": len(set(lengths)) + 1,
-        "build_passes": 1, "table_bytes": n_pad * 16}
+        "build_passes": 1, "table_bytes": n_pad * 16,
+        "table_ranges": 1, "near_slots": 0,
+        "far_slots": int(lengths.sum())}
     # the level arrays are those columns, longest first, and nothing else
     assert [i.shape for i, _ in eng._ell_levels] == \
         [(int((lengths == L).sum()), int(L))
@@ -402,6 +404,185 @@ def test_row_blocked_apply_is_the_unblocked_one(name, batch_size, nb,
         + (width - len(cut._ell_levels) if term_loop == "unroll" else 0)
 
 
+def _cut_table_into(monkeypatch, eng, R):
+    """Steer the table rule through its input: the VMEM number under which
+    ``x`` as a gather table leaves the row-block rule no room and
+    ``eng``'s padded rows are cut into ``R`` table ranges.  Returns the
+    range length."""
+    from distributed_matvec_tpu.parallel import engine
+
+    n_pad, parts = eng.n_padded, 3 if eng.real else 6
+    row = engine.pad_to_multiple(parts, 4) * 4
+    W = engine.pad_to_multiple(-(-n_pad // R), engine.INDEX_TILE)
+    assert W * (R - 1) < n_pad, "no such cut of these rows"
+    monkeypatch.setattr(engine, "GATHER_VMEM_BYTES", W * (2 * row + 4))
+    assert engine.gather_row_blocks(n_pad, parts)[0] == 1
+    assert engine.gather_table_ranges(n_pad, parts) == (R, W)
+    return W
+
+
+# name, batch size, ranges: a plain ring with padded rows (13,000 of
+# 12,870), a fully symmetric one (rows not a tile multiple; columns far
+# from the diagonal), a complex momentum sector in pair form (eight lanes
+# a row, so half the rows a range), chunks that straddle the ranges of
+# a row's range.  (Two ranges never occur: where the table leaves the
+# row-block rule no room, 16.6 B a row pass the VMEM number, and a range
+# takes 36.)
+TABLE_CUTS = [("ring16", 1000, 3), ("ring16", 1000, 4), ("ring16", 1000, 5),
+              ("ring16", 61, 3), ("symm_ring20", None, 3),
+              ("momentum_ring18", 512, 3)]
+
+
+@pytest.mark.parametrize("name, batch_size, R", TABLE_CUTS)
+@pytest.mark.parametrize("term_loop", ["auto", "unroll"])
+def test_table_cut_apply_matches_independent_reference(
+        name, batch_size, R, term_loop, rng, pair_form, monkeypatch):
+    """Where ``x`` does not fit VMEM as a gather table (the rule's number
+    patched down to that) rows and columns are cut into 3, 4 and 5 ranges:
+    a near and a far staircase a range, every entry stored once, near
+    where its column lies in its row's range; the apply against the
+    independent reference and against the uncut engine, one vector and a
+    batch, in both forms of the term loop; no scatter."""
+    from distributed_matvec_tpu.utils.config import update_config
+
+    kw = {} if batch_size is None else {"batch_size": batch_size}
+    op, whole = _stair_engine(name, **kw)
+    W = _cut_table_into(monkeypatch, whole, R)
+    cut = LocalEngine(op, mode="ell", **kw)
+    counts, n_pad = cut._ell_counts, cut.n_padded
+    assert (counts["table_ranges"], counts["row_blocks"],
+            counts["build_passes"]) == (R, R, 1)
+    assert len(cut._ell_blocks) == len(cut._ell_pos_of) == 2 * R
+    slots = [sum(i.size for i, _ in blk) for blk in cut._ell_blocks]
+    assert (sum(slots[0::2]), sum(slots[1::2])) == \
+        (counts["near_slots"], counts["far_slots"])
+    unpermute = sum(p.size for p in cut._ell_pos_of if p is not None)
+    assert counts["gather_slots"] == sum(slots) + unpermute
+    assert counts["gather_pieces"] == len(cut._ell_levels) + sum(
+        p is not None for p in cut._ell_pos_of)
+    assert counts["levels"] == len(cut._ell_levels)
+    assert {k: counts[k] for k in ("live_entries", "terms", "table_bytes")} \
+        == {k: whole._ell_counts[k]
+            for k in ("live_entries", "terms", "table_bytes")}
+    # every entry of the uncut engine's levels, once: near ones with
+    # range-local columns inside the range, far ones outside it
+    live, near = 0, 0
+    for j, blk in enumerate(cut._ell_blocks):
+        r, far = divmod(j, 2)
+        rows = min(W, n_pad - r * W)
+        for idx, cf in blk:
+            idx, cf = np.asarray(idx), np.asarray(cf)
+            hit = cf.reshape(idx.shape + (-1,)).any(axis=-1)
+            live += int(hit.sum())
+            assert not idx[~hit].any()
+            if far:
+                assert (idx[hit] // W != r).all()
+            else:
+                assert idx.max(initial=0) < rows
+                near += int(hit.sum())
+    assert live == counts["live_entries"] and 0 < near < live
+    assert cut.ell_nbytes == sum(
+        i.nbytes + c.nbytes for i, c in cut._ell_levels) + 4 * unpermute
+    # above the line the build's inputs (states, norms, lookup) go with
+    # the build; below it they stay, as they always have
+    import jax
+
+    held = [jax.tree_util.tree_leaves([eng.memory_arrays()[k]
+                                       for k in ("lookup", "basis_rows")])
+            for eng in (cut, whole)]
+    assert (len(held[0]), len(held[1])) == (0, 4)
+    ph = cut._phase_counts(1)
+    assert ph["compute"]["gathers"] == sum(slots)
+    assert ph["accumulate"]["gathers"] == unpermute
+    n = cut.n_states
+    X = rng.random((n, 3)) - 0.5
+    if cut.pair:
+        X = X + 1j * (rng.random((n, 3)) - 0.5)
+    Y_ref = _independent_apply(op, X)
+    if cut.pair:
+        from distributed_matvec_tpu.ops import kernels as K
+
+        Xp = np.moveaxis(K.pair_from_complex(X.T), 0, 1)   # [N, k, 2]
+        back = K.complex_from_pair
+    else:
+        Xp, back = X, np.asarray
+    update_config(term_loop=term_loop)
+    try:
+        for x, y_ref in ((Xp[:, 0], Y_ref[:, 0]), (Xp, Y_ref)):
+            y = back(np.asarray(cut.matvec(x)))
+            np.testing.assert_allclose(y, y_ref, atol=1e-13, rtol=1e-12)
+            # the sum's order changes with the cut, its terms do not
+            np.testing.assert_allclose(
+                y, back(np.asarray(whole.matvec(x))), atol=1e-14,
+                rtol=1e-13)
+        prims = _apply_primitives(cut, Xp[:, 0])
+    finally:
+        update_config(term_loop="auto")
+    assert not [p for p in prims if "scatter" in p]
+    width = sum(i.shape[0] for i, _ in cut._ell_levels)
+    assert prims.count("gather") == counts["gather_pieces"] \
+        + (width - len(cut._ell_levels) if term_loop == "unroll" else 0)
+
+
+def test_table_rule_reads_the_shapes():
+    """gather_table_ranges: 1 wherever the row-block rule has room (every
+    basis the benchmark had before chain_28, every test basis), and past
+    that line the fewest ranges of which one is a table, a gather's rows
+    and their indices inside the one VMEM number."""
+    from distributed_matvec_tpu.parallel.engine import (
+        GATHER_VMEM_BYTES, gather_row_blocks, gather_table_ranges)
+
+    for rows, parts in ((4_718_592, 3), (5_242_880, 3), (2_359_296, 6),
+                        (7_000_000, 3), (7_440_000, 3), (13_000, 3), (0, 3)):
+        assert gather_table_ranges(rows, parts) == (1, rows)
+    # the first row count the row-block rule leaves whole, and the line
+    # at which the table alone fills VMEM
+    assert gather_row_blocks(7_450_000, 3)[0] == 1
+    assert gather_table_ranges(7_450_000, 3) == (3, 2_484_224)
+    assert gather_table_ranges(7_733_248, 3) == (3, 2_578_432)
+    assert gather_table_ranges(40_173_568, 3) == (12, 3_348_480)  # chain_28
+    assert gather_table_ranges(15_804_956, 3) == (5, 3_161_088)   # square_6x6
+    assert gather_table_ranges(15_859_712, 3) == (5, 3_172_352)
+    # six parts a row (pair form, a two-column batch): half the rows
+    assert gather_table_ranges(4_718_592, 6) == (3, 1_572_864)
+    assert gather_table_ranges(20_086_784, 6) == (12, 1_674_240)
+    for rows, parts in ((7_450_000, 3), (40_173_568, 3), (4_718_592, 6)):
+        R, W = gather_table_ranges(rows, parts)
+        row = 16 if parts == 3 else 32
+        assert W % 1024 == 0 and (R - 1) * W < rows <= R * W
+        assert W * (2 * row + 4) <= GATHER_VMEM_BYTES
+        assert -(-rows // (R - 1)) * (2 * row + 4) > GATHER_VMEM_BYTES
+
+
+@pytest.mark.parametrize("name, kw, blocks, digest", [
+    ("ring16", {"batch_size": 1000}, 1, "66a03d06007ee26f"),
+    ("symm_ring20", {}, 1, "6d3c1426786c0c0d"),
+    ("ring16", {"batch_size": 1000}, 3, "3bcf59f66944337b"),
+])
+def test_below_the_line_the_structure_is_the_parents(name, kw, blocks,
+                                                     digest, monkeypatch):
+    """Where the table is not cut ``structure_arrays()`` is what PR 32's
+    tree built, to the byte: SHA-256 over shape and bytes of every leaf,
+    taken from that tree (commit c23823c) for a plain ring, a symmetric
+    one and the plain ring in three row blocks."""
+    import hashlib
+
+    import jax
+
+    op, eng = _stair_engine(name, **kw)
+    if blocks > 1:
+        _cut_into(monkeypatch, eng, blocks)
+        eng = LocalEngine(op, mode="ell", **kw)
+    assert eng._ell_counts["row_blocks"] == blocks
+    assert eng._ell_counts["table_ranges"] == 1
+    h = hashlib.sha256()
+    for leaf in jax.tree_util.tree_leaves(eng.structure_arrays()):
+        a = np.asarray(leaf)
+        h.update(str(a.shape).encode())
+        h.update(a.tobytes())
+    assert h.hexdigest()[:16] == digest
+
+
 def test_equal_width_rows_keep_plain_table(rng):
     """An operator whose rows are equally wide (a transverse field on the
     full basis: every state flips at each of its n sites) reads one level
@@ -420,7 +601,9 @@ def test_equal_width_rows_keep_plain_table(rng):
                                "terms": n, "widest_row": n,
                                "row_blocks": 1, "gather_pieces": 1,
                                "build_passes": 1,
-                               "table_bytes": 16 * eng.n_padded}
+                               "table_bytes": 16 * eng.n_padded,
+                               "table_ranges": 1, "near_slots": 0,
+                               "far_slots": n * eng.n_padded}
     x = rng.random(2 ** n) - 0.5
     prims = _apply_primitives(eng, x)
     # one level, its columns scanned: one gather, and none to un-permute
@@ -616,13 +799,17 @@ def test_structure_cache_roundtrip(tmp_path, rng):
                                atol=1e-13)
 
 
-@pytest.mark.parametrize("nb", [1, 3], ids=["whole", "three_row_blocks"])
-def test_structure_cache_staircase_layout(tmp_path, rng, nb, monkeypatch):
+@pytest.mark.parametrize("nb, ranges", [(1, 1), (3, 1), (3, 3)], ids=[
+    "whole", "three_row_blocks", "three_table_ranges"])
+def test_structure_cache_staircase_layout(tmp_path, rng, nb, ranges,
+                                          monkeypatch):
     """The staircase checkpoints and restores piece for piece (level for
-    level where the rows are not cut), with its row order and counts; a
-    file in an older layout (v1: main table + tail; v2: whole levels and
-    no blocks) is refused — by fingerprint as an old build wrote it, by its
-    keys should the fingerprint ever match — and rebuilt, not misread."""
+    level where the rows are not cut; a near and a far staircase a range
+    where the table is), with its row order and counts; a file in an older
+    layout (v1: main table + tail; v2: whole levels and no blocks; v3: no
+    table ranges) is refused — by fingerprint as an old build wrote it, by
+    its keys should the fingerprint ever match — and rebuilt, not
+    misread."""
     import hashlib
 
     from distributed_matvec_tpu.io.hdf5 import (load_engine_structure,
@@ -632,17 +819,31 @@ def test_structure_cache_staircase_layout(tmp_path, rng, nb, monkeypatch):
     path = str(tmp_path / "stair.h5")
     sidecar = LocalEngine._structure_sidecar(path)
     op, eng1 = _stair_engine("symm_ring20")
-    if nb > 1:
+    if ranges > 1:
+        _cut_table_into(monkeypatch, eng1, ranges)
+    elif nb > 1:
         _cut_into(monkeypatch, eng1, nb)
     eng1 = LocalEngine(op, mode="ell", structure_cache=path)
-    assert not eng1.structure_restored and len(eng1._ell_blocks) == nb
+    assert not eng1.structure_restored
+    assert len(eng1._ell_blocks) == (nb if ranges == 1 else 2 * ranges)
+    assert (eng1._ell_counts["row_blocks"],
+            eng1._ell_counts["table_ranges"]) == (nb, ranges)
     x = rng.random(eng1.n_states) - 0.5
     y1 = np.asarray(eng1.matvec(x))
     eng2 = LocalEngine(op, mode="ell", structure_cache=path)
     assert eng2.structure_restored
     assert eng2._ell_counts == eng1._ell_counts
-    np.testing.assert_array_equal(np.asarray(eng2._ell_pos_of),
-                                  np.asarray(eng1._ell_pos_of))
+    assert eng2._ell_range_rows == eng1._ell_range_rows
+    if ranges > 1:
+        assert len(eng2._ell_pos_of) == 2 * ranges
+        for p2, p1 in zip(eng2._ell_pos_of, eng1._ell_pos_of):
+            assert (p2 is None) == (p1 is None)
+            if p1 is not None:
+                np.testing.assert_array_equal(np.asarray(p2),
+                                              np.asarray(p1))
+    else:
+        np.testing.assert_array_equal(np.asarray(eng2._ell_pos_of),
+                                      np.asarray(eng1._ell_pos_of))
     assert [len(b) for b in eng2._ell_blocks] == \
         [len(b) for b in eng1._ell_blocks]
     for (i2, c2), (i1, c1) in zip(eng2._ell_levels, eng1._ell_levels):
@@ -671,7 +872,9 @@ def test_structure_cache_staircase_layout(tmp_path, rng, nb, monkeypatch):
           "level0_idx": np.zeros((T0, eng1.n_padded), np.int32),
           "level0_coeff": np.zeros((T0, eng1.n_padded)),
           "pos_of": np.arange(eng1.n_padded, dtype=np.int32)}
-    for layout, old in (("v1", v1), ("v2", v2)):
+    v3 = dict(v2, block_pieces="1", row_blocks=1, gather_pieces=2,
+              build_passes=1, table_bytes=16 * eng1.n_padded)
+    for layout, old in (("v1", v1), ("v2", v2), ("v3", v3)):
         h = hashlib.sha256()
         hash_basis_operator(h, op)
         h.update(f"ell|False|True|{eng1.batch_size}|{eng1.n_states}"
@@ -683,7 +886,7 @@ def test_structure_cache_staircase_layout(tmp_path, rng, nb, monkeypatch):
             assert not eng3.structure_restored
             np.testing.assert_array_equal(y1, np.asarray(eng3.matvec(x)))
             # ... and the rebuild replaced the file with the new layout
-            assert "block_pieces" in load_engine_structure(
+            assert "table_ranges" in load_engine_structure(
                 sidecar, eng1._structure_fingerprint())
 
 

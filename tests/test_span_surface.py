@@ -425,22 +425,28 @@ def test_two_pass_build_pass_spans_on_one_device(clean_trace, annotations):
         build["dur_ms"], rel=0.05, abs=2.0)
 
 
-@pytest.mark.parametrize("nb", [1, 3], ids=["whole", "three_row_blocks"])
+@pytest.mark.parametrize("nb, ranges", [(1, 1), (3, 1), (3, 3)], ids=[
+    "whole", "three_row_blocks", "three_table_ranges"])
 def test_build_span_counts_how_far_the_staircase_engages(clean_trace, nb,
-                                                         monkeypatch):
+                                                         ranges, monkeypatch):
     """``gather_slots``, ``live_entries`` and ``levels`` on the build span
     and in the ``engine_init`` event, and the benchmark's reader of their
     ratio (``benchmark/metrics/gather_fill_pct.py``): 16-site ring, 12,870
     rows, one entry a domain wall.  ``row_blocks`` and ``gather_pieces``
     beside them say how the rows are cut (``row_blocks`` 1: not at all);
-    the cut moves none of the other counts."""
+    that cut moves none of the other counts.  ``table_ranges``,
+    ``near_slots`` and ``far_slots`` say whether the gather table is cut:
+    1, 0 and every table slot where it is not; where it is, a near and a
+    far staircase a range, each with its own un-permute rows."""
     import importlib.util
     from types import SimpleNamespace
 
     from distributed_matvec_tpu.parallel import engine
     from distributed_matvec_tpu.parallel.engine import LocalEngine
 
-    if nb > 1:      # the rule's VMEM number that cuts 12,870 rows in three
+    if ranges > 1:  # ... that leaves the rows whole and cuts the table
+        monkeypatch.setattr(engine, "GATHER_VMEM_BYTES", 5 * 1024 * 36)
+    elif nb > 1:    # the rule's VMEM number that cuts 12,870 rows in three
         monkeypatch.setattr(engine, "GATHER_VMEM_BYTES",
                             12_870 * 16 + 5 * 1024 * 20)
     eng = LocalEngine(build_heisenberg(16, hw=8), mode="ell")
@@ -450,20 +456,29 @@ def test_build_span_counts_how_far_the_staircase_engages(clean_trace, nb,
     assert len(build) == 1 and "ell/stair_levels" in {
         e["name"] for e in obs.events("span")
         if e["parent_span_id"] == build[0]["span_id"]}
-    counts = {k: build[0][k] for k in ("gather_slots", "live_entries",
-                                       "levels", "terms", "widest_row",
-                                       "row_blocks", "gather_pieces",
-                                       "build_passes", "table_bytes")}
+    counts = {k: build[0][k] for k in (
+        "gather_slots", "live_entries", "levels", "terms", "widest_row",
+        "row_blocks", "gather_pieces", "build_passes", "table_bytes",
+        "table_ranges", "near_slots", "far_slots")}
     assert counts == eng._ell_counts
     # one run of the kernels; ``x`` as a gather table: 16 B a padded row,
     # the number the block rule holds against the chip's VMEM
     assert (counts["build_passes"], counts["table_bytes"]) == \
         (1, 16 * eng.n_padded)
-    assert (counts["terms"], counts["widest_row"]) == (16, 16)
+    assert counts["terms"] == 16
     assert counts["live_entries"] == 109_824    # 16 bonds x 2 x C(14, 7)
-    assert (counts["gather_slots"], counts["levels"]) == (133_702, 5)
-    assert (counts["row_blocks"], counts["gather_pieces"]) == \
-        {1: (1, 6), 3: (3, 12)}[nb]
+    assert (counts["row_blocks"], counts["table_ranges"]) == (nb, ranges)
+    slots = counts["near_slots"] + counts["far_slots"]
+    if ranges == 1:
+        assert (counts["gather_slots"], counts["levels"]) == (133_702, 5)
+        assert counts["gather_pieces"] == {1: 6, 3: 12}[nb]
+        assert (counts["widest_row"], counts["near_slots"], slots) == \
+            (16, 0, 133_702 - 12_870)
+    else:           # two un-permute rows a row, most entries near
+        assert counts["gather_slots"] == slots + 2 * 12_870
+        assert counts["gather_pieces"] == counts["levels"] + 2 * ranges
+        assert counts["far_slots"] < counts["near_slots"] < 109_824 * 1.1
+        assert 16 <= counts["widest_row"] <= 2 * 16
     init = obs.events("engine_init")[-1]
     assert {k: init[k] for k in counts} == counts
 

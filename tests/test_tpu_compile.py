@@ -64,8 +64,9 @@ HISTOGRAMS = {
         (2, 1_024)]),
     "half_chain": (2_353_985, 2_359_296,
                    [(k, -(-L // 2048) * 1024) for k, L in LEVELS]),
-    # heisenberg_chain_28 (tests/test_chain_28_config.py pins the levels):
-    # its table, 613 MiB, leaves the rule no room, so the levels stay whole
+    # heisenberg_chain_28 as whole levels (tests/test_chain_28_config.py
+    # pins them): what the two-pass build packed there before the table
+    # was cut (PR 33; the apply's shapes since: ``_chain_28_ranges``)
     "chain_28": (40_116_600, 40_173_568, [
         (4, 40_117_248), (2, 40_115_200), (2, 40_057_856), (2, 39_485_440),
         (2, 36_622_336), (2, 28_893_184), (2, 17_114_112), (2, 6_807_552),
@@ -158,10 +159,59 @@ def _local_ell_engine(sh, pair, histogram="chain_32_symm", whole=False,
               for k, rows in blk)
         for blk in _pieces(histogram, pair, whole)[0])
     eng._ell_pos_of = S((n_pad,), jnp.int32)
+    eng._ell_range_rows = 0             # the gather table is not cut
     eng._diag = S((n_pad,), jnp.float64)
     eng._make_ell_matvec()
     batch = () if columns is None else (columns,)
     return eng, S((n,) + batch + ctail, jnp.float64)
+
+
+def _chain_28_ranges():
+    """``(W, staircases)`` of chain_28 with its gather table cut: the
+    range length the engine's rule gives and, range by range, near then
+    far, each staircase's ``(rows, ((columns, rows), ...))``, read off the
+    independently counted histograms in ``tests/data`` (the same the
+    engine's build reads off its own counts)."""
+    import json
+
+    from distributed_matvec_tpu.parallel.engine import (gather_table_ranges,
+                                                        staircase_levels)
+
+    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "data", "chain_28_ranges.json")) as f:
+        data = json.load(f)
+    n_pad = data["n_padded"]
+    R, W = gather_table_ranges(n_pad, 3)
+    assert (R, W) == (data["ranges"], data["range_rows"])
+    out = []
+    for r in range(R):
+        rows = min(W, n_pad - r * W)
+        for kind in ("near", "far"):
+            stair, levels = staircase_levels(np.array(data[kind][r]), rows)
+            assert stair
+            out.append((rows, tuple((k, L) for _, k, L in levels)))
+    return W, out
+
+
+def _chain_28_engine(sh):
+    """The LocalEngine shell of :func:`_local_ell_engine` at chain_28's
+    shapes above the VMEM line: two staircases a table range."""
+    from distributed_matvec_tpu.parallel.engine import LocalEngine
+
+    S = _shapes(sh)
+    n, n_pad, _ = HISTOGRAMS["chain_28"]
+    W, staircases = _chain_28_ranges()
+    eng = object.__new__(LocalEngine)
+    eng.n_states, eng.n_padded = n, n_pad
+    eng.pair, eng._dtype = False, jnp.float64
+    eng._ell_blocks = tuple(
+        tuple((S((k, L), jnp.int32), S((k, L), jnp.float64))
+              for k, L in levels) for _, levels in staircases)
+    eng._ell_pos_of = tuple(S((rows,), jnp.int32) for rows, _ in staircases)
+    eng._ell_range_rows = W
+    eng._diag = S((n_pad,), jnp.float64)
+    eng._make_ell_matvec()
+    return eng, S((n,), jnp.float64)
 
 
 def _distributed_ell_engine(topo):
@@ -231,7 +281,8 @@ def _compile(name, topo):
 
     sh = SingleDeviceSharding(topo.devices[0])
     name, _, histogram = name.partition("@")
-    eng, x = _local_ell_engine(sh, False, histogram or "chain_32_symm")
+    eng, x = _chain_28_engine(sh) if histogram == "chain_28" else \
+        _local_ell_engine(sh, False, histogram or "chain_32_symm")
     apply_fn, operands = eng.bound_matvec()
     if name == "ell_apply":
         return jax.jit(apply_fn).lower(x, operands).compile()
@@ -352,28 +403,99 @@ def test_whole_levels_gather_to_hbm(one_chip, tpu_knobs, histogram, pair,
         [rows for rows, in_vmem in results if in_vmem])
 
 
-def test_chain_28_gathers_from_a_table_in_hbm(tpu_knobs, compiled):
-    """Above 7.73 M rows ``x`` as a gather table (16 B a row) cannot fit
-    VMEM, so the rule cuts nothing: 12 whole levels and the un-permute
-    gather read a 643 MB table in HBM.  The compiler still leaves a short
-    gather's result in VMEM (the five levels of 6.8 Mrows and fewer, 2.8%
-    of the slots); the eight of 17.1 to 40.2 Mrows write to HBM.  The
-    apply's temporaries, 4.0 GB beside 7.8 GB of arguments, fit the chip:
-    the prediction of ``chain_28.apply``'s ``peak_hbm_gb`` (PERF.md §6,
-    PR 32)."""
+def _gather_operands(exe, parts=3):
+    """``(rows, table rows, table in VMEM, indices in VMEM, result in
+    VMEM, un-permute)`` of every gather fusion of the optimised HLO whose
+    result is ``f32[rows, parts]``: the placement of its two operands read
+    off the instructions that define them."""
+    text = exe.as_text()
+    layout = dict(re.findall(r"(%[\w.\-]+) = (\S+) ", text))
+    found = []
+    for line in text.splitlines():
+        m = re.search(rf"= f32\[(\d+),{parts}\](\{{[^}}]*\}}) "
+                      r"fusion\(([^)]*)\)", line)
+        if m and "kind=kCustom" in line:
+            table, index = (layout[o.strip()]
+                            for o in m.group(3).split(",")[:2])
+            found.append((int(m.group(1)),
+                          int(re.match(r"f32\[(\d+),", table).group(1)),
+                          "S(1)" in table, "S(1)" in index,
+                          "S(1)" in m.group(2), "apply/unpermute" in line))
+    return found
+
+
+def test_chain_28_gathers_its_near_entries_from_vmem(tpu_knobs, compiled):
+    """Above 7.44 M rows ``x`` cannot be a gather table in VMEM, so the
+    table is cut (PR 33): 12 ranges of 3,348,480 rows at chain_28, each a
+    near staircase gathered from its own range of ``x`` and a far one
+    gathered from whole ``x``.  In the optimised HLO for a described v5e
+    every one of the 318 gathers writes to VMEM; the 215 near gathers and
+    the 24 that put a range's sums back in range order read a table in
+    VMEM (a range of ``x``, an accumulator of a range's rows); the 79 far
+    gathers read the 642 MB table in HBM, named as such.  Indices are in
+    VMEM too, but for the near and un-permute gathers of 3.22 Mrows and
+    more, which stream them from HBM as chain_32_symm's 2,359,296-row
+    pieces do (4.317 ns a slot there: PERF.md §5).  The apply's
+    temporaries, 2.2 GB beside 8.1 GB of arguments, fit the chip."""
     exe = compiled("ell_apply@chain_28")
-    assert _fits(exe, "ell apply at chain_28") < 12.5e9
-    assert 3.5e9 < exe.memory_analysis().temp_size_in_bytes < 4.5e9
-    _gathers_the_staircase(exe, "chain_28")
-    blocks, unpermute = _pieces("chain_28")
-    assert len(blocks) == 1 and unpermute == [40_173_568]
-    results = _gather_results(exe)
-    assert len(results) == 13
-    in_hbm = sorted(rows for rows, in_vmem in results if not in_vmem)
-    assert in_hbm == [17_114_112, 28_893_184, 36_622_336, 39_485_440,
-                      40_057_856, 40_115_200, 40_117_248, 40_173_568]
-    # the table itself: no f32[n, 3] of the full length is placed in VMEM
+    assert _fits(exe, "ell apply at chain_28") < 11.0e9
+    assert 1.8e9 < exe.memory_analysis().temp_size_in_bytes < 2.6e9
+    assert "scatter" not in exe.as_text()
+    W, staircases = _chain_28_ranges()
+    n = HISTOGRAMS["chain_28"][0]
+    gathers = _gather_operands(exe)
+    want = sorted([L for _, levels in staircases for _, L in levels]
+                  + [rows for rows, _ in staircases])
+    assert len(want) == 294 + 24
+    assert sorted(g[0] for g in gathers) == want
+    assert all(result for *_, result, _ in gathers)
+    near = [g for g in gathers if g[1] <= W]
+    far = [g for g in gathers if g[1] > W]
+    assert sorted(g[0] for g in far) == sorted(
+        L for _, levels in staircases[1::2] for _, L in levels)
+    assert len(near) == 215 + 24 and len(far) == 79
+    assert sum(g[5] for g in gathers) == 24 == sum(g[5] for g in near)
+    # the near tables: a range of x (the last one ends with the states),
+    # or the accumulator of a range's rows; the far one: whole x, in HBM
+    assert {g[1] for g in near} <= {W, n - 11 * W, 40_173_568 - 11 * W}
+    assert all(table for _, _, table, *_ in near)
+    assert {(g[1], g[2]) for g in far} == {(n, False)}
     assert not re.search(r"f32\[40\d{6},3\]\{[^}]*S\(1\)", exe.as_text())
+    streamed = [g for g in gathers if not g[3]]
+    assert len(streamed) == 82 and min(g[0] for g in streamed) > 3_220_000
+    assert all(g[1] <= W for g in streamed)
+    assert max(g[0] for g in near if g[3]) < 3_220_000
+
+
+def test_range_build_chunk_compiles_at_chain_28(one_chip):
+    """One ``ell_range_chunk`` step of the build above the VMEM line at
+    chain_28's shapes (28 terms, no group, chunks of 65,536 rows): the
+    kernels, the lookup and the near / far / dead pack of a chunk's rows,
+    whose slabs go to the host.  It holds no range-wide table: under a
+    quarter of a GB of temporaries beside its arguments."""
+    from functools import partial
+
+    from distributed_matvec_tpu.models.yaml_io import load_config_from_yaml
+    from distributed_matvec_tpu.ops import kernels as K
+    from distributed_matvec_tpu.parallel.engine import _range_chunk
+
+    S = _shapes(one_chip)
+    n = HISTOGRAMS["chain_28"][0]
+    op = load_config_from_yaml(
+        FULL_YAML.replace("chain_32_symm", "chain_28")).hamiltonian
+    tables = jax.tree_util.tree_map(
+        lambda a: S(a.shape, a.dtype), K.device_tables(op, pair=False))
+    exe = jax.jit(partial(_range_chunk, shift=LK_SHIFT, probes=LK_PROBES,
+                          is_pair=False)).lower(
+        tables, S((n, 2), jnp.uint32), S(((1 << 20) + 1,), jnp.int32),
+        S((CHUNK,), jnp.uint64), S((CHUNK,), jnp.float64),
+        S((), jnp.int32), S((), jnp.int32)).compile()
+    _fits(exe, "ell_range_chunk at chain_28")
+    m = exe.memory_analysis()
+    assert m.temp_size_in_bytes < 0.25e9
+    # the slabs: 28 x 65,536 indices, coefficients and two counts a row
+    assert 28 * CHUNK * 12 + 2 * CHUNK * 4 <= m.output_size_in_bytes \
+        < 1.2 * (28 * CHUNK * 12 + 2 * CHUNK * 4)
 
 
 def test_pair_form_blocks_gather_to_vmem(one_chip, tpu_knobs):
@@ -435,7 +557,9 @@ def test_structure_build_chunk_compiles(one_chip):
 
 def test_two_pass_build_chunks_compile_at_chain_28(one_chip):
     """``count_row_nnz`` and ``ell_lowmem_pack`` steps of the two-pass
-    build at chain_28's shapes (28 terms, no group, 613 chunks): the pack
+    build at the shapes of chain_28's whole levels (28 terms, no group, 613
+    chunks; what that basis took until its table was cut, and what a basis
+    under the VMEM line with tables over the build budget takes): the pack
     step takes the 7.0 GB of chunk-padded level buffers donated and writes
     them in place (its output aliases them), with under 2 GB of
     temporaries (the f64 emulation splits the longest coefficient buffer

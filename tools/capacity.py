@@ -98,10 +98,13 @@ def load_structure(path: str) -> dict:
             raise ValueError(f"{path}: no idx table in the sidecar")
         if level_keys:
             # LocalEngine ell: levels [k, L] (cut into a piece a row block
-            # where the engine cut them, under the same names) and, where
-            # the rows are ordered by width, ``pos_of`` over the padded rows
-            # (else the one level is that long); T0 is the mean width
-            n_pad = int(g["pos_of"].shape[0]) if "pos_of" in g \
+            # where the engine cut them, or into a near and a far staircase
+            # a table range, under the same names); the padded rows are the
+            # file's ``n_padded`` (layout v4), else ``pos_of``'s where the
+            # rows are ordered by width, else the one level's; T0 is the
+            # mean width
+            n_pad = int(g.attrs["n_padded"]) if "n_padded" in g.attrs \
+                else int(g["pos_of"].shape[0]) if "pos_of" in g \
                 else max(int(g[k].shape[-1]) for k in level_keys)
             slots = sum(int(g[k].size) for k in level_keys)
             T0 = -(-slots // max(n_pad, 1))
