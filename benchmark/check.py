@@ -4,8 +4,14 @@ What the timed path produced in the window — the applies' results, the
 solves' Ritz pairs — is compared with the configuration's plain reference
 (``benchmark/references/<name>.py``), which imports nothing of the program
 and is given nothing the program made: it enumerates the basis itself from
-the YAML, applies H to sampled rows from the definition, and knows the
-ring's ground energy from the Bethe ansatz.  Every number compared has a
+the YAML, applies H to sampled rows from the definition, and has a ground
+energy of its own (the ring's from the Bethe ansatz).  A reference is a
+module with ``Spec(path)``, ``enumerate_representatives(spec)``,
+``apply_rows(spec, reps, x, rows, dtype)``, ``count_offdiagonal(spec, reps,
+rows)`` and ``ground_energy(spec)``.  Where the configuration states a
+complex sector (``work.is_complex``) the vectors compared are complex128 on
+the host, and everything here is complex arithmetic: ``|.|`` is the modulus
+and a squared norm is ``sum |r|^2``.  Every number compared has a
 limit of its own; a run is correct when each number is at or under its
 limit (a NaN is over it).  Limits come from the traffic file, and those the
 configuration states itself (the apply contract, the solver's tolerance)
@@ -17,6 +23,8 @@ import os
 import sys
 
 import numpy as np
+
+from . import work
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -30,7 +38,7 @@ def load_reference(config):
         "benchmark_reference_" + name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod, mod.RingSpec(os.path.join(ROOT, config["model"]))
+    return mod, mod.Spec(os.path.join(ROOT, config["model"]))
 
 
 def sample_rows(seed, n_states, count):
@@ -54,11 +62,29 @@ class Reference:
         """Other rows for another seed; the basis stays."""
         self.rows = sample_rows(seed, self.reps.size, self.rows.size)
 
-    def apply_rows(self, x, dtype=np.float64):
+    def apply_rows(self, x, dtype=None):
+        """(H x)[rows] in ``dtype`` arithmetic; by default in the precision
+        the configuration states."""
+        if dtype is None:
+            dtype = _precisions(self.config)[0]
         return self.mod.apply_rows(self.spec, self.reps, x, self.rows, dtype)
 
     def e0(self):
-        return self.mod.bethe_e0(self.spec.n)
+        """The ground energy a Ritz value is compared with: the
+        configuration's own ``ground_energy`` where its file brings one
+        (stored beside how it was computed, as a reference that has no
+        closed form asks), else the reference's."""
+        stored = self.config.get("ground_energy")
+        if stored is not None:
+            return float(stored)
+        return self.mod.ground_energy(self.spec)
+
+
+def _precisions(config):
+    """(what the configuration states, the nearest precision below it)."""
+    if work.is_complex(config):
+        return np.complex128, np.complex64
+    return np.float64, np.float32
 
 
 def compare_apply(ref, x, answers):
@@ -71,6 +97,7 @@ def compare_apply(ref, x, answers):
         out["apply_err_over_tol"] = float("nan")
         return out
     want = ref.apply_rows(x)
+    # np.abs is the modulus: of complex numbers where the sector is complex
     tol = g["apply_atol"] + g["apply_rtol"] * np.abs(want)
     out["apply_err_over_tol"] = float(max(
         np.max(np.abs(np.asarray(y)[ref.rows] - want) / tol)
@@ -96,9 +123,10 @@ def compare_eigenpairs(ref, params, solves):
         theta, v = float(s["eigenvalue"]), np.asarray(s["vector"])
         scale = tol * max(1.0, abs(theta))
         # ||H v - theta v|| from the sampled rows: an unbiased estimate of
-        # the squared norm, by the reference's H
+        # the squared norm sum |r|^2, by the reference's H; theta is real
         r = ref.apply_rows(v) - theta * v[ref.rows]
-        est = np.sqrt(n / ref.rows.size * float(np.sum(r * r)))
+        est = np.sqrt(n / ref.rows.size
+                      * float(np.sum((r * np.conj(r)).real)))
         got = {"claimed_residual_over_tol": float(s["residual"]) / scale,
                "residual_over_tol": float(est / scale),
                "e0_rel_err": abs(theta - e0) / abs(e0),
@@ -112,21 +140,24 @@ def compare_eigenpairs(ref, params, solves):
 def control_apply(ref, x):
     """The control of the ``apply_rows`` check: the reference put in the
     program's place and computed in float32, the nearest precision below
-    the float64 the configuration states.  Only the sampled rows are
-    filled; the comparison reads no others."""
-    y = np.zeros(ref.reps.size)
-    y[ref.rows] = ref.apply_rows(x, np.float32)
+    the float64 the configuration states (complex64 below complex128).
+    Only the sampled rows are filled; the comparison reads no others."""
+    stated, below = _precisions(ref.config)
+    y = np.zeros(ref.reps.size, stated)
+    y[ref.rows] = ref.apply_rows(x, below)
     return y
 
 
-def control_eigenpairs(solves):
+def control_eigenpairs(ref, solves):
     """The control of the ``eigenpair`` check: each Ritz pair rounded to
-    float32.  A solver that computed in float32 could at best return the
-    float32 number nearest to each exact component, so what this reads is
-    the least that any float32 solve could read."""
+    float32 (the vector of a complex sector to complex64).  A solver that
+    computed in float32 could at best return the float32 number nearest to
+    each exact component, so what this reads is the least that any float32
+    solve could read."""
+    stated, below = _precisions(ref.config)
     return [dict(s, eigenvalue=float(np.float32(s["eigenvalue"])),
-                 vector=np.asarray(s["vector"]).astype(np.float32)
-                 .astype(np.float64)) for s in solves]
+                 vector=np.asarray(s["vector"]).astype(below)
+                 .astype(stated)) for s in solves]
 
 
 def judge(numbers, limits):

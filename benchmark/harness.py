@@ -159,6 +159,7 @@ def run_cell(bench, workload, seed, seconds, trace, t_start,
     mark = compiles.mark()
     setup_s = time.perf_counter() - t_start
 
+    system.open_window()
     # ---- the measured window -------------------------------------------
     try:
         window = mix.window(system, seconds, annotator(trace))
@@ -170,6 +171,8 @@ def run_cell(bench, workload, seed, seconds, trace, t_start,
     # ---------------------------------------------------------------------
 
     window_compiles = compiles.since(mark)
+    events = system.close_window()
+    engine_counts = system.engine_counts()
     device["memory_peak_bytes"] = system.memory_peak_bytes()
     answers = mix.collect(system)
     system.close()
@@ -195,7 +198,8 @@ def run_cell(bench, workload, seed, seconds, trace, t_start,
         cell=cell, config=config, traffic=mix.params, window=window,
         setup_s=setup_s, spans=spans.seconds, timers=timers,
         setup_compiles=setup_compiles, window_compiles=window_compiles,
-        device=device, trace=summary, chips=int(cell["chips"]),
+        events=events, device=device, trace=summary,
+        chips=int(cell["chips"]),
         peaks=peaks.peaks_for(dev.device_kind) if trace else None,
         work=work)
     metrics = {}
@@ -210,6 +214,7 @@ def run_cell(bench, workload, seed, seconds, trace, t_start,
         result["breakdown"] = summary.breakdown()
     result["window"] = dict(window, setup_compiles=setup_compiles,
                             window_compiles=window_compiles,
-                            spans=spans.seconds, timers=timers)
+                            spans=spans.seconds, timers=timers,
+                            engine=engine_counts)
     result["checks"] = table
     return result

@@ -11,7 +11,7 @@ from benchmark import program_spans
 
 
 def read(run):
-    build = program_spans.build_span(run, program_spans.span_events())
+    build = program_spans.build_span(run)
     if not build or not build.get("gather_slots"):
         return None
     return 100.0 * build.get("live_entries", 0) / build["gather_slots"]

@@ -13,7 +13,7 @@ PROGRAM = r"jit_apply_fn"
 
 
 def read(run):
-    build = program_spans.build_span(run, program_spans.span_events())
+    build = program_spans.build_span(run)
     if not build or not build.get("gather_slots"):
         return None
     seconds, runs = run.trace.fullest.module_runs(PROGRAM)

@@ -6,16 +6,22 @@ Two sources, both written by ``distributed_matvec_tpu/obs/trace.py::span``:
   ``TraceAnnotation`` of its name, so it lies on the clock of the device's
   operations.  Times come from here: the idle time of the fullest device
   (``run.trace.fullest.busy``) under the spans of a name.
-* the program's in-memory event store (``obs.events.events("span")``): one
-  event a closed span, with its parent, its monotonic duration and the counts
-  the program added while it was open.  Counts come from here, and the times
-  of set-up, which runs before the profiler starts.
+* the program's own events of this run (``run.events``): what
+  ``benchmark/system.py`` copied out of the program's in-memory ring right
+  after the engine was built (``build``) and right after the window
+  (``window``).  One ``span`` event a closed span, with its parent, its
+  monotonic duration and the counts the program added while it was open.
+  Counts come from here, and the times of set-up, which runs before the
+  profiler starts.  Nothing here imports the program: what another run of
+  the same process left in its ring is not in the snapshot (a run that was
+  handed no events at all gets the whole ring, through ``system.py``).
 
 Every function returns ``None`` (or an empty list) where it finds nothing: a
 program without these spans, or a CPU rehearsal whose trace has no device.
-Where the store shows that what the reader needs may have been there and is
-gone (it keeps the newest 65,536 events of every kind), or holds a build that
-is not this run's, the reader raises: a wrong number is worse than none.
+Where the snapshot says that the ring let some of this run's events go (it
+keeps the newest 65,536) and the reader does not find what it needs, or the
+snapshot holds more than one build, the reader raises: a wrong number is
+worse than none.
 """
 
 import re
@@ -73,39 +79,36 @@ def dispatch_idle(trace):
     return total / 1e9
 
 
-def program_events():
-    """The program's in-memory event store, oldest first; [] for a program
-    that keeps none."""
-    try:
-        from distributed_matvec_tpu.obs.events import events
-    except ImportError:
-        return []
-    return events()
+def run_events(run):
+    """``run.events``; for a run that was handed none, the whole of the
+    program's ring through ``benchmark/system.py`` (which alone imports the
+    program)."""
+    events = getattr(run, "events", None)
+    if events is None:
+        from .system import whole_ring
+
+        events = whole_ring()
+    return events
 
 
-def span_events():
-    """The program's closed spans, oldest first."""
-    return [e for e in program_events() if e.get("kind") == "span"]
-
-
-def dropped_events():
-    """How many of its oldest events the store has let go (the first kept
-    event's ``seq``; the program counts them from 0)."""
-    kept = program_events()
-    return int(kept[0].get("seq", 0)) if kept else 0
+def span_events(run, part):
+    """The program's closed spans of this run's ``build`` or ``window``,
+    oldest first."""
+    return [e for e in run_events(run)[part] if e.get("kind") == "span"]
 
 
 def window_solves(run):
     """The root ``lanczos`` spans of the window's solves: the last
-    ``run.window["solves"]`` of the store.  ``None`` where there are fewer,
-    or where they carry no counts."""
-    solves = [e for e in span_events()
+    ``run.window["solves"]`` of the window's events.  ``None`` where there
+    are fewer, or where they carry no counts."""
+    solves = [e for e in span_events(run, "window")
               if e.get("name") == "lanczos" and e.get("cat") == "solve"]
     n = int(run.window.get("solves") or 0)
-    if n and len(solves) < n and dropped_events():
+    if n and len(solves) < n and run_events(run)["lost"]:
         raise RuntimeError(
-            f"the program's span store holds {len(solves)} of the window's "
-            f"{n} solves and has dropped {dropped_events()} older events")
+            f"the snapshot of the program's events holds {len(solves)} of "
+            f"the window's {n} solves, and the program's ring has dropped "
+            "events of this run")
     if not n or len(solves) < n:
         return None
     solves = solves[-n:]
@@ -114,37 +117,32 @@ def window_solves(run):
     return solves
 
 
-def build_span(run, spans):
+def build_span(run):
     """The span of the build that this run's set-up made: the one of the
-    engine's name whose duration is what the engine's own timer read at
-    set-up (``run.timers``; both bracket the same ``with``).  ``None``
-    for a program that opens no such span."""
+    engine's name among the events emitted while the engine was built.
+    ``None`` for a program that opens no such span."""
     name = BUILDS.get(run.config["engine"]["kind"])
-    builds = [e for e in spans if e.get("name") == name]
+    builds = [e for e in span_events(run, "build") if e.get("name") == name]
     if not builds:
-        if dropped_events():
+        if run_events(run)["lost"]:
             raise RuntimeError(
-                f"no {name} span in the program's span store, which has "
-                f"dropped {dropped_events()} older events: this run's "
-                "build may have been among them")
+                f"no {name} span among this run's events, and the "
+                "program's ring has dropped some of them: the build may "
+                "have been among those")
         return None
-    timed_ms = 1e3 * run.timers["structure_build_s"]
-    mine = [e for e in builds
-            if abs(e["dur_ms"] - timed_ms) <= max(0.05 * timed_ms, 2.0)]
-    if len(mine) != 1:
+    if len(builds) != 1:
         raise RuntimeError(
             f"{len(builds)} {name} spans of "
-            f"{[e['dur_ms'] for e in builds]} ms, and the engine's timer "
-            f"read {timed_ms:.1f} ms at set-up: none, or more than one, is "
-            "this run's build")
-    return mine[0]
+            f"{[e['dur_ms'] for e in builds]} ms were emitted while this "
+            "run's engine was built: which is the build?")
+    return builds[0]
 
 
 def build_host_seconds(run):
     """This run's structure or plan build less the ``device_wait`` spans
     under it: the span's own time and the passes' own."""
-    spans = span_events()
-    build = build_span(run, spans)
+    spans = span_events(run, "build")
+    build = build_span(run)
     if build is None:
         return None
     parent = {e["span_id"]: e.get("parent_span_id") for e in spans

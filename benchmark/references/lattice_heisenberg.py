@@ -16,16 +16,14 @@ parallel and -1 where they are not, and couples an antiparallel pair to the
 state with both flipped with amplitude 2.
 
 There is no Bethe ansatz off the ring, so the ground energy is this file's
-own: ARPACK on its own sparse H (:func:`ground_energy`).  At the benchmark's
-size that is minutes of host time, so the value is computed once, kept in
-:data:`STORED_E0` beside the command that reproduces it, and looked up by
-the specification's digest; a specification without a stored value is
-solved on the fly.
+own: ARPACK on its own sparse H (:func:`solve_ground_energy`).  At the
+benchmark's size that is minutes of host time, so the value is computed
+once, kept in :data:`STORED_E0` beside the command that reproduces it, and
+looked up by the specification's digest (:func:`ground_energy`); a
+specification without a stored value is solved on the fly.
 
-``benchmark/check.py`` calls its reference by the ring's names, so this
-file answers to them: ``RingSpec`` is :class:`LatticeSpec`, and
-``bethe_e0(n)`` is the ground energy of the specification last read for
-``n`` sites.
+``benchmark/check.py`` asks every reference for ``Spec(path)`` and
+``ground_energy(spec)``: ``Spec`` is :class:`LatticeSpec`.
 
     python3 benchmark/references/lattice_heisenberg.py <model.yaml>
 
@@ -41,7 +39,7 @@ import yaml
 
 _XX, _YY, _ZZ = "σˣ₀ σˣ₁", "σʸ₀ σʸ₁", "σᶻ₀ σᶻ₁"
 
-#: Ground energies computed once by :func:`ground_energy`, by
+#: Ground energies computed once by :func:`solve_ground_energy`, by
 #: ``LatticeSpec.digest``.
 STORED_E0 = {
     # 5x5 torus, hamming weight 13, 5,200,300 states: the line printed by
@@ -50,10 +48,6 @@ STORED_E0 = {
     # (PR 28, CPU, 115 s: CSR build 47 s, then ARPACK)
     "76702fbdccc5271f": -60.143081768240215,
 }
-
-#: The specification last read for each number of sites: ``check.py`` hands
-#: ``bethe_e0`` the number of sites and nothing else.
-_LAST_SPEC = {}
 
 
 class LatticeSpec:
@@ -87,7 +81,6 @@ class LatticeSpec:
                 raise NotImplementedError(f"bond {b}")
         self.bonds = lists[0]
         self.group_order = 1
-        _LAST_SPEC[n] = self
 
     @property
     def digest(self):
@@ -97,7 +90,7 @@ class LatticeSpec:
         return hashlib.sha256(repr(what).encode()).hexdigest()[:16]
 
 
-RingSpec = LatticeSpec      # the name ``benchmark/check.py`` asks for
+Spec = LatticeSpec  # the name ``benchmark/check.py`` asks every reference for
 
 
 def enumerate_representatives(spec):
@@ -191,7 +184,7 @@ def sparse_matrix(spec, reps):
     return csr_matrix((vals[live], cols[live], ptr), shape=(n, n))
 
 
-def ground_energy(spec, tol=1e-13):
+def solve_ground_energy(spec, tol=1e-13):
     """Lowest eigenvalue of H on the sector: ARPACK (``eigsh``, smallest
     algebraic) on this file's own sparse matrix; a dense ``eigvalsh`` below
     a few hundred states, where ARPACK has nothing to iterate on."""
@@ -207,15 +200,13 @@ def ground_energy(spec, tol=1e-13):
     return float(vals[0])
 
 
-def bethe_e0(n):
-    """The ground energy ``check.py`` compares the Ritz value with: of the
-    specification last read for ``n`` sites (the name is the ring
-    reference's; nothing here is a Bethe ansatz).  The stored value where
-    there is one, else :func:`ground_energy`, once."""
-    spec = _LAST_SPEC[n]
+def ground_energy(spec):
+    """The ground energy ``check.py`` compares the Ritz value with: the
+    stored value where there is one, else :func:`solve_ground_energy`,
+    once."""
     stored = STORED_E0.get(spec.digest)
     if stored is None:
-        stored = STORED_E0[spec.digest] = ground_energy(spec)
+        stored = STORED_E0[spec.digest] = solve_ground_energy(spec)
     return stored
 
 
@@ -223,4 +214,4 @@ if __name__ == "__main__":
     _spec = LatticeSpec(sys.argv[1])
     print(f"digest {_spec.digest}  n {_spec.n}  hamming_weight {_spec.hw}  "
           f"bonds {len(_spec.bonds)}  states {comb(_spec.n, _spec.hw)}")
-    print(f"ground_energy {ground_energy(_spec)!r}")
+    print(f"ground_energy {solve_ground_energy(_spec)!r}")

@@ -17,6 +17,12 @@ os.environ["XLA_FLAGS"] = (
     + " --xla_cpu_collective_call_terminate_timeout_seconds=1200")
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["DMT_ARTIFACT_CACHE"] = "off"
+# as ``benchmark/system.py::start`` sets it, but before anything imports
+# JAX: JAX reads the variable once, at import, and a test process imports
+# JAX before a rehearsal's ``start``.  Left at JAX's default of 1 s, a toy
+# program compiled during a warm-up is not in the persistent cache when the
+# window asks for it again, and ``window_compiles.compiled`` reads 1
+os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")
 
 import pytest  # noqa: E402
 
@@ -45,6 +51,39 @@ def ring_yaml(path, n_sites, symmetric=True):
     with open(path, "w", encoding="utf-8") as f:
         f.writelines(lines)
     return str(path)
+
+
+def momentum_ring_yaml(path, n_sites, k, inversion=None):
+    """A periodic Heisenberg chain at half filling in the translation
+    sector ``k`` (no reflection), with the spin flip's character
+    ``inversion`` (+1, -1, or ``None`` for no spin flip)."""
+    bonds = [[i, (i + 1) % n_sites] for i in range(n_sites)]
+    lines = [f"basis:\n  number_spins: {n_sites}\n"
+             f"  hamming_weight: {n_sites // 2}\n"]
+    if inversion is not None:
+        lines.append(f"  spin_inversion: {inversion}\n")
+    lines.append("  symmetries:\n"
+                 f"    - {{permutation: {[*range(1, n_sites), 0]}, "
+                 f"sector: {k}}}\n")
+    lines.append("hamiltonian:\n  name: Heisenberg\n  terms:\n")
+    for axis in "ˣʸᶻ":
+        lines.append(f"    - {{expression: \"σ{axis}₀ σ{axis}₁\", "
+                     f"sites: {bonds}}}\n")
+    with open(path, "w", encoding="utf-8") as f:
+        f.writelines(lines)
+    return str(path)
+
+
+def load_ring_reference():
+    """``benchmark/references/ring_heisenberg.py`` as a module of its own,
+    as ``benchmark/check.py`` loads it."""
+    import importlib.util
+
+    path = os.path.join(ROOT, "benchmark", "references", "ring_heisenberg.py")
+    spec = importlib.util.spec_from_file_location("ring_ref", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 @pytest.fixture

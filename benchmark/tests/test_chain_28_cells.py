@@ -5,7 +5,11 @@ states, the two-pass build forced as the full size's rule decides it), and
 ``chain_32_symm.ground_state_restart`` on the symmetric ring (257 states)
 under a cap low enough that every solve restarts.  A sound run reads
 ``correct`` true, the float32 controls and a Hamiltonian with bonds left out
-read false.  No number read here is a device metric."""
+read false.  The two metrics that took the place of PR 32's pass metrics
+(``build_fill_pass_s``, ``build_levels_pass_s``, PR 34) are read off toy
+builds of all three kinds: one pass below the table-cut line, one a table
+range above it, none in the two-pass build.  No number read here is a device
+metric."""
 
 import json
 import os
@@ -22,18 +26,6 @@ CELLS = ["chain_28.apply", "chain_32_symm.ground_state_restart"]
 NO_CHECK = dict(chip_check=lambda devices, chips: None)
 RING = [[i, (i + 1) % 16] for i in range(16)]
 TOY_CAP = 12
-
-
-@pytest.fixture(autouse=True)
-def leave_no_toy_builds():
-    """The program's event store is the process's: the build readers take
-    the one build whose duration the engine's timer read, and refuse where
-    several toy builds of a few milliseconds qualify (``PERF.md`` §7).
-    This file's dozen builds do not stay for the tests that follow."""
-    yield
-    from distributed_matvec_tpu.obs.events import reset
-
-    reset()
 
 
 @pytest.fixture
@@ -130,8 +122,41 @@ def test_the_restart_traffic_is_the_ground_state_traffic_under_a_cap_of_48():
     base, restart = (traffic.load(n) for n in ("ground_state",
                                                "ground_state_restart"))
     assert base["max_basis_size"] == 96
-    assert restart == dict(base, max_basis_size=48)
+    assert restart == dict(base, max_basis_size=48, warm_restart=True)
     assert restart["min_restart_size"] is None
+    assert "warm_restart" not in base
+
+
+def test_the_warm_up_builds_the_restart_program_the_solver_builds(
+        toy_cells, toy_system, monkeypatch):
+    """Under the restart traffic every solve restarts and the warm-up's one
+    block never does: ``warm_epilogue`` compiles the restart's program for
+    the sizes the solver works out itself, so that a cold compile cache
+    compiles it in set-up and not inside the first window (PR 34: the chip
+    read ``jit(restart)`` compiled in the window of a machine's first run).
+    Here: what the warm-up hands ``_make_restart`` is what ``lanczos`` hands
+    it in the window, and the other ``ground_state`` traffic warms none."""
+    import importlib
+
+    module = importlib.import_module("distributed_matvec_tpu.solve.lanczos")
+    real, asked = module._make_restart, []
+
+    def recording(mcap, shape, dtype, keep):
+        asked.append((mcap, tuple(shape), str(dtype), keep))
+        return real(mcap, shape, dtype, keep)
+
+    monkeypatch.setattr(module, "_make_restart", recording)
+    bench, _ = toy_cells
+    res = _run(bench, toy_system, "chain_32_symm.ground_state_restart")
+    assert res["correct"] is True and res["window"]["restarts"] >= 1
+    # the warm-up's block (lanczos builds its restart closure every call),
+    # then warm_epilogue, then one a solve of the window
+    assert len(asked) == 2 + res["window"]["solves"]
+    assert len(set(asked)) == 1 and asked[0][0] == TOY_CAP
+    assert asked[0][1:3] == ((257,), "float64")
+    del asked[:]
+    res = _run(bench, toy_system, "chain_32_symm.ground_state")
+    assert len(asked) == 1 + res["window"]["solves"]    # no warm_epilogue's
 
 
 @pytest.mark.parametrize("workload", CELLS)
@@ -151,7 +176,7 @@ def test_the_new_cells_report_the_metrics_that_reach_them(workload):
         assert layer == shared | {
             "compilations_in_window.apply", "apply_device_ms",
             "apply_roofline", "device_idle_pct.apply", "gather_fill_pct",
-            "gather_ns_per_slot", "build_count_pass_s", "build_pack_pass_s"}
+            "gather_ns_per_slot", "build_fill_pass_s", "build_levels_pass_s"}
     else:
         assert cell["config"] == "chain_32_symm"
         assert layer == shared | {
@@ -161,12 +186,16 @@ def test_the_new_cells_report_the_metrics_that_reach_them(workload):
             "applies_per_iteration", "block_programs_built.solve"}
     for name in layer:
         assert callable(harness.load_reader(name))
-    # the two pass metrics are chain_28.apply's alone
-    for name in ("build_count_pass_s", "build_pack_pass_s"):
+    # the two pass metrics are the three apply cells'; the two they took
+    # the place of are gone with the build that made their passes
+    for name in ("build_fill_pass_s", "build_levels_pass_s"):
         entry = harness.find(bench["per_layer"], name, "metric")
-        assert entry["workloads"] == ["chain_28.apply"]
+        assert entry["workloads"] == [
+            "chain_32_symm.apply", "square_5x5.apply", "chain_28.apply"]
         assert (entry["layer"], entry["moves"], entry["source"]) == \
             ("structure build", "setup_s", "program_span")
+    assert not {"build_count_pass_s", "build_pack_pass_s"} & {
+        m["name"] for m in bench["per_layer"]}
 
 
 def test_the_configuration_states_upstreams_size_and_the_roofline_bytes():
@@ -234,56 +263,108 @@ def test_bonds_left_out(toy_cells, toy_system, workload, over):
     assert over <= _over(res), res["checks"]
 
 
-def test_the_pass_readers_read_this_runs_two_pass_build(toy_cells,
-                                                        toy_system, two_pass):
-    """After a two-pass build the readers give the two passes' seconds,
-    inside the build's; after a one-pass build, and on a program whose
-    build opens no such spans (the parent commit's), nothing."""
-    from distributed_matvec_tpu.obs.events import reset
+@pytest.fixture
+def table_outside_vmem(monkeypatch):
+    """The rules' VMEM number a range of 1,024 rows wide: ``x`` as a gather
+    table does not fit, the row-block rule has no room, and the table is
+    cut (steered through the rules' input, as ``tests/
+    test_chain_28_config.py`` does, not through an option)."""
+    from distributed_matvec_tpu.parallel import engine
+
+    monkeypatch.setattr(engine, "GATHER_VMEM_BYTES", 36 * 1024)
+
+
+def _built(toy_system, config):
+    """A run as far as the readers need it: the engine built, this run's
+    events handed over."""
+    system = toy_system(config)
+    system.start()
+    system.enumerate()
+    system.build_engine()
+    system.open_window()
+    return SimpleNamespace(config=config, timers=system.timers(),
+                           events=system.close_window(),
+                           counts=system.engine_counts())
+
+
+def test_the_pass_readers_sum_a_pass_a_table_range(toy_cells, toy_system,
+                                                   table_outside_vmem):
+    """Above the table-cut line the build makes an ``ell/fill`` and an
+    ``ell/stair_levels`` pass a range (twelve at ``chain_28``): each reader
+    gives the sum of its passes, inside the build's seconds."""
+    bench, _ = toy_cells
+    config = harness.load_config(bench, "chain_28")
+    run = _built(toy_system, config)
+    ranges = run.counts["table_ranges"]
+    assert ranges == run.counts["row_blocks"] > 1
+    assert 0 < run.counts["far_slots"] < run.counts["near_slots"]
+    build = program_spans.build_span(run)
+    passes = {name: [e["dur_ms"] for e in run.events["build"]
+                     if e.get("name") == name
+                     and e.get("parent_span_id") == build["span_id"]]
+              for name in ("ell/fill", "ell/stair_levels")}
+    assert [len(p) for p in passes.values()] == [ranges, ranges]
+    fill = harness.load_reader("build_fill_pass_s")(run)
+    levels = harness.load_reader("build_levels_pass_s")(run)
+    assert fill == pytest.approx(sum(passes["ell/fill"]) / 1e3)
+    assert levels == pytest.approx(sum(passes["ell/stair_levels"]) / 1e3)
+    assert fill > max(passes["ell/fill"]) / 1e3
+    assert 0 < fill + levels <= run.timers["structure_build_s"]
+
+
+def test_the_pass_readers_read_the_one_pass_and_not_the_two_pass_build(
+        toy_cells, toy_system, two_pass):
+    """Below the line the one-pass build makes one pass of each name; the
+    two-pass build (no cell takes it since PR 33) makes neither, and the
+    readers say nothing there.  Every build of this process is in the
+    program's ring; each run reads its own."""
+    from distributed_matvec_tpu.utils.config import update_config
 
     bench, _ = toy_cells
     config = harness.load_config(bench, "chain_28")
-    count = harness.load_reader("build_count_pass_s")
-    pack = harness.load_reader("build_pack_pass_s")
+    fill = harness.load_reader("build_fill_pass_s")
+    levels = harness.load_reader("build_levels_pass_s")
 
-    def built():
-        reset()         # one build in the store: toy builds last alike
-        system = toy_system(config)
-        system.start()
-        system.enumerate()
-        system.build_engine()
-        return SimpleNamespace(config=config, timers=system.timers())
+    run = _built(toy_system, config)            # the two-pass build
+    assert run.counts["build_passes"] == 2
+    assert fill(run) is None and levels(run) is None
+    assert build_passes.pass_seconds(run, "ell/count_rows") > 0
+    assert build_passes.pass_seconds(run, "ell/pack") > 0
 
-    run = built()
-    a, b = count(run), pack(run)
-    assert a > 0 and b > 0 and a + b <= run.timers["structure_build_s"]
-    assert build_passes.pass_seconds(run, "ell/fill") is None
-
-    from distributed_matvec_tpu.utils.config import update_config
     update_config(ell_build_budget_gb=two_pass)     # the rule as it stands
-    run = built()
-    assert count(run) is None and pack(run) is None
-    assert build_passes.pass_seconds(run, "ell/fill") > 0
+    run = _built(toy_system, config)
+    assert run.counts["build_passes"] == 1
+    assert run.counts["table_ranges"] == 1
+    a, b = fill(run), levels(run)
+    assert a > 0 and b > 0 and a + b <= run.timers["structure_build_s"]
+    build = program_spans.build_span(run)
+    assert [e["name"] for e in run.events["build"]
+            if e.get("parent_span_id") == build["span_id"]
+            and e["name"] in ("ell/fill", "ell/stair_levels")] == \
+        ["ell/fill", "ell/stair_levels"]
 
 
-def test_the_pass_readers_read_nothing_without_their_spans(monkeypatch):
-    run = SimpleNamespace(config={"engine": {"kind": "local"}},
-                          timers={"structure_build_s": 1.0})
-    build = {"name": "engine_init/build_structure", "dur_ms": 1000.0,
-             "span_id": "b"}
-    other = {"name": "ell/count_rows", "dur_ms": 400.0, "span_id": "c",
-             "parent_span_id": "another build"}
-    for name in ("build_count_pass_s", "build_pack_pass_s"):
+def test_the_pass_readers_read_nothing_without_their_spans():
+    build = {"kind": "span", "name": "engine_init/build_structure",
+             "dur_ms": 1000.0, "span_id": "b"}
+    other = {"kind": "span", "name": "ell/fill", "dur_ms": 400.0,
+             "span_id": "c", "parent_span_id": "another build"}
+
+    def run(*events):
+        return SimpleNamespace(
+            config={"engine": {"kind": "local"}},
+            events={"build": [dict(e) for e in events], "window": [],
+                    "lost": False})
+
+    for name in ("build_fill_pass_s", "build_levels_pass_s"):
         read = harness.load_reader(name)
-        # no event store, no build span, a build span without passes, and
-        # a pass of another build
-        for spans in ([], [dict(other)], [dict(build)],
-                      [dict(build), dict(other)]):
-            monkeypatch.setattr(program_spans, "span_events", lambda: spans)
-            assert read(run) is None
+        # no events, no build span, a build span without passes, and a
+        # pass of another build
+        for events in ((), (other,), (build,), (build, other)):
+            assert read(run(*events)) is None
     mine = dict(other, parent_span_id="b")
-    monkeypatch.setattr(program_spans, "span_events",
-                        lambda: [dict(build), mine])
-    assert harness.load_reader("build_count_pass_s")(run) == \
-        pytest.approx(0.4)
-    assert harness.load_reader("build_pack_pass_s")(run) is None
+    second = dict(mine, span_id="d", dur_ms=100.0)
+    assert harness.load_reader("build_fill_pass_s")(
+        run(build, mine, second)) == pytest.approx(0.5)
+    assert harness.load_reader("build_levels_pass_s")(
+        run(build, mine, second)) is None

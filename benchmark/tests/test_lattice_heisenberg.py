@@ -105,9 +105,12 @@ def test_sparse_matrix_and_ground_energy_against_dense(case, ref):
     np.testing.assert_array_equal(
         ref.sparse_matrix(spec, states).toarray(), dense)
     want = float(np.linalg.eigvalsh(dense)[0])
+    assert ref.solve_ground_energy(spec) == pytest.approx(want, rel=1e-12)
+    # the name check.py calls, given the specification: solved once, then
+    # kept by the specification's digest
+    assert ref.Spec is ref.LatticeSpec
     assert ref.ground_energy(spec) == pytest.approx(want, rel=1e-12)
-    # the name check.py calls, given the number of sites alone
-    assert ref.bethe_e0(spec.n) == pytest.approx(want, rel=1e-12)
+    assert ref.STORED_E0[spec.digest] == ref.ground_energy(spec)
 
 
 def test_count_offdiagonal_against_the_closed_form(case, ref):
@@ -181,5 +184,5 @@ def test_the_benchmarks_configuration_is_what_the_reference_counts(ref):
     assert config["reduced"] == [] and len(config["assumed"]) == 2
     assert spec.digest in mod.STORED_E0
     # the stored value is what the run compares with, without a solve
-    assert mod.bethe_e0(25) == mod.STORED_E0[spec.digest] == \
+    assert mod.ground_energy(spec) == mod.STORED_E0[spec.digest] == \
         pytest.approx(-60.14308176824, abs=1e-10)

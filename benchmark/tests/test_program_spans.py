@@ -1,5 +1,7 @@
 """The readers of the program's own spans (PR 26), on a synthetic trace and
-a synthetic span store, and once against the program itself at a toy size:
+a synthetic copy of a run's events (``run.events``, which
+``benchmark/system.py`` hands over since PR 34), and once against the
+program itself at a toy size:
 the names the readers go by are the names the program opens, and what the
 program puts on the profiler's host line is what labels an idle gap best.
 Times are made up; nothing here is a device number."""
@@ -50,10 +52,13 @@ def summary(host=HOST, ops=OPS, modules=MODULES):
     return T.TraceSummary(lo, hi, [dev], host, marks=[(lo, hi)])
 
 
-def run_with(trace=None, engine="distributed", build_s=1.0, **window):
+def run_with(trace=None, engine="distributed", build=(), events=(),
+             lost=False, **window):
+    """A run whose set-up emitted ``build`` and whose window ``events``."""
     return SimpleNamespace(trace=trace, window=window,
                            config={"engine": {"kind": engine}},
-                           timers={"structure_build_s": build_s})
+                           events={"build": list(build),
+                                   "window": list(events), "lost": lost})
 
 
 def reader(name):
@@ -206,19 +211,32 @@ def test_the_program_mirrors_the_names_the_readers_go_by(monkeypatch,
     system.start()
     system.enumerate()
     system.build_engine()
+    system.open_window()
     system.solve({"k": 1, "tol": 1e-8, "max_iters": 64,
                   "eigenvectors": True})
+    events = system.close_window()
     assert {P.DISPATCH, P.WAIT, "lanczos/start", "lanczos/check",
             "lanczos/epilogue", "apply", "ell/fill", P.DEVICE_WAIT} \
         <= set(opened)
     assert set(P.CHECKS) - set(opened) == {"lanczos/restart"}
     assert not {"lanczos", "iteration", *P.BUILDS.values()} & set(opened)
-    solves = P.window_solves(run_with(solves=1))
+    run = run_with(engine="local", build=events["build"],
+                   events=events["window"], solves=1)
+    solves = P.window_solves(run)
     assert solves and solves[0]["steps_counted"] >= 16
-    # the build is found by what system.py read off the engine's timer
-    run = run_with(engine="local",
-                   build_s=system.timers()["structure_build_s"])
-    assert 0 < P.build_host_seconds(run) <= run.timers["structure_build_s"]
+    # this run's build and nothing older: what the process's ring held
+    # before build_engine is not in the snapshot
+    assert not events["lost"]
+    assert [e["kind"] for e in events["build"]].count("engine_init") == 1
+    assert not [e for e in events["window"]
+                if e.get("name") == P.BUILDS["local"]]
+    assert 0 < P.build_host_seconds(run) \
+        <= system.timers()["structure_build_s"] * 1.05 + 0.002
+    counts = system.engine_counts()
+    assert counts["pair"] is False and counts["n_states"] == 35
+    assert {"table_ranges", "near_slots", "far_slots", "row_blocks",
+            "scanned_columns"} <= set(counts)
+    assert not {"seq", "ts", "kind", "span_id"} & set(counts)
 
 
 # ---------------------------------------------------------------------------
@@ -240,29 +258,31 @@ OTHER = {"kind": "span", "name": "lanczos_block", "cat": "solve",
          "span_id": "9", "parent_span_id": None, "dur_ms": 1.0}
 
 
-def test_a_window_of_two_solves_after_a_warm_up_solve(monkeypatch):
+def test_a_window_of_two_solves_after_a_warm_up_solve():
     store = [WARM_UP, OTHER, FIRST, {"kind": "span", "name": "apply",
                                      "cat": "apply", "span_id": "7"}, SECOND]
-    monkeypatch.setattr(P, "program_events", lambda: store)
-    run = run_with(solves=2, iterations=144)
+    run = run_with(events=store, solves=2, iterations=144)
     assert P.window_solves(run) == [FIRST, SECOND]
     assert reader("applies_per_iteration")(run) == pytest.approx(
         (80 + 1 + 96 + 1) / 144)
     assert reader("block_programs_built.solve")(run) == 2.5
-    one = run_with(solves=1, iterations=80)
+    one = run_with(events=store, solves=1, iterations=80)
     assert reader("applies_per_iteration")(one) == pytest.approx(97 / 80)
+    # a solve of the set-up (the warm-up's) is not the window's
+    setup = run_with(build=store, events=[SECOND], solves=2, iterations=144)
+    assert P.window_solves(setup) is None
 
 
 @pytest.mark.parametrize("store", [
     [], [WARM_UP],                                  # fewer than the window's
     [solve_span("1"), solve_span("2")],             # the parent: no counts
 ], ids=["empty", "too_few", "no_counts"])
-def test_count_readers_find_nothing(monkeypatch, store):
-    monkeypatch.setattr(P, "program_events", lambda: store)
-    run = run_with(solves=2, iterations=128)
+def test_count_readers_find_nothing(store):
+    run = run_with(events=store, solves=2, iterations=128)
     assert reader("applies_per_iteration")(run) is None
     assert reader("block_programs_built.solve")(run) is None
-    assert reader("applies_per_iteration")(run_with(iterations=16)) is None
+    assert reader("applies_per_iteration")(
+        run_with(events=store, iterations=16)) is None
 
 
 def phase(sid, parent, name, dur_ms, seq=0):
@@ -270,65 +290,106 @@ def phase(sid, parent, name, dur_ms, seq=0):
             "parent_span_id": parent, "dur_ms": dur_ms, "seq": seq}
 
 
-BUILD_STORE = [phase("1", None, "engine_init/build_plan", 900.0),  # another
-               phase("a", None, "device_wait", 5.0),     # not under a build
+BUILD_STORE = [phase("a", None, "device_wait", 5.0),     # not under a build
                phase("c", "b", "device_wait", 100.0),
                phase("b", "2", "plan/count", 400.0),
                phase("e", "d", "device_wait", 50.0),
                phase("f", "d", "compile/dist_gather_chunk", 70.0),
                phase("d", "2", "plan/pack", 500.0),
                phase("2", None, "engine_init/build_plan", 1000.0)]
+ANOTHER = phase("1", None, "engine_init/build_plan", 900.0)
 
 
-def test_the_builds_host_time_is_its_span_less_the_device_waits_under_it(
-        monkeypatch):
-    """This run's build is the one the engine's timer read at set-up
-    (1.0 s), not the last of the store and not another engine's."""
-    monkeypatch.setattr(P, "program_events", lambda: BUILD_STORE)
+def test_the_builds_host_time_is_its_span_less_the_device_waits_under_it():
+    """This run's build is the one emitted while its engine was built."""
     read = reader("structure_build_host_s")
-    assert read(run_with(build_s=1.0)) == pytest.approx(0.850)
-    assert read(run_with(build_s=0.9)) == pytest.approx(0.900)
+    assert read(run_with(build=BUILD_STORE)) == pytest.approx(0.850)
+    assert read(run_with(build=[ANOTHER])) == pytest.approx(0.900)
+    # a build span among the window's events is not the set-up's
+    assert read(run_with(events=BUILD_STORE)) is None
     # a program without the span (the parent commit): nothing, no error
-    assert read(run_with(engine="local")) is None
-    monkeypatch.setattr(P, "program_events", lambda: BUILD_STORE[1:2])
-    assert read(run_with()) is None
+    assert read(run_with(build=BUILD_STORE, engine="local")) is None
+    assert read(run_with(build=BUILD_STORE[:1])) is None
 
 
-@pytest.mark.parametrize("store, build_s, message", [
-    (BUILD_STORE, 2.0, "none, or more than one"),     # no build so long
-    (BUILD_STORE + [phase("3", None, "engine_init/build_plan", 1001.0)],
-     1.0, "none, or more than one"),                  # two candidates
-    ([phase("a", None, "device_wait", 5.0, seq=70000)], 1.0,
-     "dropped 70000 older events"),                   # the ring overflowed
-], ids=["no_match", "two_match", "overflowed"])
-def test_a_build_that_is_not_this_runs_is_an_error(monkeypatch, store,
-                                                   build_s, message):
-    monkeypatch.setattr(P, "program_events", lambda: store)
+@pytest.mark.parametrize("build, lost, message", [
+    (BUILD_STORE + [ANOTHER], False, "which is the build"),   # two builds
+    (BUILD_STORE[:1], True, "has dropped some of them"),  # the ring overflowed
+], ids=["two_builds", "overflowed"])
+def test_a_build_that_cannot_be_told_is_an_error(build, lost, message):
     with pytest.raises(RuntimeError, match=message):
-        reader("structure_build_host_s")(run_with(build_s=build_s))
+        reader("structure_build_host_s")(run_with(build=build, lost=lost))
 
 
-def test_solves_lost_from_an_overflowed_store_are_an_error(monkeypatch):
+def test_solves_lost_from_an_overflowed_ring_are_an_error():
     store = [dict(FIRST, seq=66000)]
-    monkeypatch.setattr(P, "program_events", lambda: store)
-    assert P.window_solves(run_with(solves=1)) == store
+    assert P.window_solves(run_with(events=store, lost=True, solves=1)) \
+        == store
     with pytest.raises(RuntimeError, match="1 of the window's 2 solves"):
-        reader("applies_per_iteration")(run_with(solves=2, iterations=144))
+        reader("applies_per_iteration")(
+            run_with(events=store, lost=True, solves=2, iterations=144))
+    assert reader("applies_per_iteration")(
+        run_with(events=store, solves=2, iterations=144)) is None
+
+
+def test_the_snapshot_holds_what_came_after_its_mark(monkeypatch):
+    """``benchmark/system.py`` copies out of the program's ring what was
+    emitted since its mark: by the identity of the marked event, so a ring
+    that was cleared in between (tests do) or one that has let the mark go
+    is told apart from one that holds it."""
+    from benchmark import system as system_module
+
+    ring = [{"seq": i, "kind": "span"} for i in range(5)]
+    monkeypatch.setattr(system_module, "program_ring", lambda: list(ring))
+    system = system_module.System({"engine": {"kind": "local"}})
+    system.open_window()
+    assert system.close_window()["window"] == []
+    ring += [{"seq": 5, "kind": "span"}, {"seq": 6, "kind": "engine_init"}]
+    assert [e["seq"] for e in system.close_window()["window"]] == [5, 6]
+    assert system.close_window()["lost"] is False
+    del ring[:6]                    # the mark has left the ring
+    events = system.close_window()
+    assert [e["seq"] for e in events["window"]] == [6] and events["lost"]
+    # an empty ring at the mark: everything after it is the run's
+    kept, ring[:] = list(ring), []
+    fresh = system_module.System({"engine": {"kind": "local"}})
+    fresh.open_window()
+    ring[:] = kept
+    assert fresh.close_window() == {"build": [], "window": kept,
+                                    "lost": False}
+
+
+def test_a_run_that_was_handed_no_events_reads_the_whole_ring(monkeypatch):
+    """A reader called outside the harness (``tests/test_span_surface.py``
+    calls ``gather_fill_pct`` with the engine it has just built): the whole
+    ring, through ``benchmark/system.py``."""
+    from benchmark import system as system_module
+
+    build = dict(phase("2", None, "engine_init/build_plan", 1000.0, seq=3),
+                 gather_slots=200, live_entries=150)
+    monkeypatch.setattr(system_module, "program_ring", lambda: [build])
+    run = SimpleNamespace(config={"engine": {"kind": "distributed"}})
+    assert P.run_events(run) == {"build": [build], "window": [build],
+                                 "lost": True}
+    assert reader("gather_fill_pct")(run) == 75.0
+    assert P.run_events(run_with(build=[build]))["lost"] is False
 
 
 def test_the_new_entries_name_their_cells():
+    """PR 26's five entries, by name: wherever later PRs put theirs."""
     bench = harness.load_benchmark()
-    added = {m["name"]: m for m in bench["per_layer"][-5:]}
-    assert list(added) == [
-        "solver_dispatch_idle_ms", "solver_check_idle_ms",
-        "applies_per_iteration", "block_programs_built.solve",
-        "structure_build_host_s"]
-    solves = ["chain_32_symm.ground_state", "chain_32_symm_x4.ground_state"]
-    for name, m in added.items():
+    solves = [c["name"] for c in bench["workloads"]
+              if c["traffic"].startswith("ground_state")]
+    assert solves[:2] == ["chain_32_symm.ground_state",
+                          "chain_32_symm_x4.ground_state"]
+    for name in ("solver_dispatch_idle_ms", "solver_check_idle_ms",
+                 "applies_per_iteration", "block_programs_built.solve",
+                 "structure_build_host_s"):
+        m = harness.find(bench["per_layer"], name, "metric")
         assert callable(reader(name))
         # the one-chip builds are device-bound: the span less its waits is
         # no host time there (PERF.md section 5), so the metric is not listed
-        want = solves[1:] if name == "structure_build_host_s" else solves
+        want = solves[1:2] if name == "structure_build_host_s" else solves
         assert m["workloads"] == want
         assert m["source"] in ("program_span", "program_counter")
 

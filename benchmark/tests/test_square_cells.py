@@ -82,7 +82,7 @@ def test_the_new_cells_report_the_metrics_that_reach_them(workload):
         assert layer == shared | {
             "compilations_in_window.apply", "apply_device_ms",
             "apply_roofline", "device_idle_pct.apply", "gather_fill_pct",
-            "gather_ns_per_slot"}
+            "gather_ns_per_slot", "build_fill_pass_s", "build_levels_pass_s"}
     else:
         assert layer == shared | {
             "compilations_in_window.solve", "iter_device_ms",
@@ -145,21 +145,19 @@ def test_gather_ns_per_slot_reads_nothing_without_counts_or_a_trace():
     parent commit's), and on one whose trace holds no apply."""
     from types import SimpleNamespace
 
-    from benchmark import program_spans
-
     read = harness.load_reader("gather_ns_per_slot")
     fullest = SimpleNamespace(module_runs=lambda pattern: (4.0, 8))
-    run = SimpleNamespace(config={"engine": {"kind": "local"}},
-                          timers={"structure_build_s": 1.0},
-                          trace=SimpleNamespace(fullest=fullest))
-    build = {"name": "engine_init/build_structure", "dur_ms": 1000.0}
-    real = program_spans.span_events
-    try:
-        program_spans.span_events = lambda: [dict(build)]
-        assert read(run) is None
-        program_spans.span_events = lambda: [dict(build, gather_slots=10**8)]
-        assert read(run) == pytest.approx(5.0)       # 0.5 s over 1e8 slots
-        fullest.module_runs = lambda pattern: (0.0, 0)
-        assert read(run) is None
-    finally:
-        program_spans.span_events = real
+    build = {"kind": "span", "name": "engine_init/build_structure",
+             "dur_ms": 1000.0}
+
+    def run(build):
+        return SimpleNamespace(
+            config={"engine": {"kind": "local"}},
+            events={"build": [build], "window": [], "lost": False},
+            trace=SimpleNamespace(fullest=fullest))
+
+    assert read(run(build)) is None
+    counted = dict(build, gather_slots=10**8)
+    assert read(run(counted)) == pytest.approx(5.0)  # 0.5 s over 1e8 slots
+    fullest.module_runs = lambda pattern: (0.0, 0)
+    assert read(run(counted)) is None

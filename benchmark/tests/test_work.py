@@ -1,22 +1,14 @@
 """The work counts against brute force on a 16-site ring, the reference
 against a dense construction, and the peaks table."""
 
-import importlib.util
+import json
 import os
 
 import numpy as np
 import pytest
 
-from benchmark import peaks, work
-from conftest import ROOT, ring_yaml
-
-
-def _reference():
-    path = os.path.join(ROOT, "benchmark", "references", "ring_heisenberg.py")
-    spec = importlib.util.spec_from_file_location("ring_ref", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+from benchmark import harness, peaks, work
+from conftest import ROOT, load_ring_reference as _reference, ring_yaml
 
 
 def _dense_reduced(n, symmetric):
@@ -57,7 +49,7 @@ def _dense_reduced(n, symmetric):
 @pytest.mark.parametrize("n, symmetric", [(16, True), (12, True), (10, False)])
 def test_reference_against_brute_force(tmp_path, n, symmetric):
     ref = _reference()
-    spec = ref.RingSpec(ring_yaml(tmp_path / "r.yaml", n, symmetric))
+    spec = ref.Spec(ring_yaml(tmp_path / "r.yaml", n, symmetric))
     reps = ref.enumerate_representatives(spec)
     want_reps, dense = _dense_reduced(n, symmetric)
     assert np.array_equal(reps, want_reps)
@@ -75,7 +67,8 @@ def test_reference_against_brute_force(tmp_path, n, symmetric):
 
 def test_toy_offdiag_count_matches_the_test_suite_fixture(tmp_path):
     ref = _reference()
-    spec = ref.RingSpec(ring_yaml(tmp_path / "r.yaml", 16))
+    spec = ref.Spec(ring_yaml(tmp_path / "r.yaml", 16))
+    assert ref.Spec is ref.RingSpec and not spec.complex
     reps = ref.enumerate_representatives(spec)
     assert reps.size == 257
     assert ref.count_offdiagonal(spec, reps, np.arange(257)) == 1774
@@ -89,14 +82,44 @@ def test_bethe_energy_matches_the_repo_anchors():
         assert ref.bethe_e0(n) / 4 == pytest.approx(e0_over_4, abs=2e-10)
 
 
+def test_ground_energy_is_asked_for_by_specification(tmp_path):
+    """``check.py`` hands the reference its specification: the Bethe value
+    where the fully symmetric sector holds the ground state (a multiple of
+    four sites), and a refusal where it does not."""
+    ref = _reference()
+    for n in (12, 16):
+        spec = ref.Spec(ring_yaml(tmp_path / f"r{n}.yaml", n))
+        assert ref.ground_energy(spec) == ref.bethe_e0(n)
+    plain = ref.Spec(ring_yaml(tmp_path / "p.yaml", 10, symmetric=False))
+    assert ref.ground_energy(plain) == ref.bethe_e0(10)
+    with pytest.raises(NotImplementedError, match="momentum pi"):
+        ref.ground_energy(ref.Spec(ring_yaml(tmp_path / "r10.yaml", 10)))
+
+
 def test_reference_refuses_what_it_does_not_cover(tmp_path):
     ref = _reference()
     path = ring_yaml(tmp_path / "r.yaml", 12)
     text = open(path, encoding="utf-8").read().replace("sector: 0}",
                                                        "sector: 1}", 1)
     open(path, "w", encoding="utf-8").write(text)
-    with pytest.raises(NotImplementedError):
-        ref.RingSpec(path)
+    # k = 1 beside the reflection, which maps it to -k
+    with pytest.raises(NotImplementedError, match="maps k to -k"):
+        ref.Spec(path)
+    open(path, "w", encoding="utf-8").write(
+        text.replace("sector: 1}", "sector: 6}", 1))
+    with pytest.raises(NotImplementedError, match="sector 6 of 12"):
+        ref.Spec(path)          # k = n / 2: a real character this file lacks
+    open(path, "w", encoding="utf-8").write(
+        text.replace("sector: 1}", "sector: 0}", 1)
+        .replace("sector: 0}\n", "sector: 1}\n").replace(
+            "sector: 1}\n", "sector: 0}\n", 1))
+    with pytest.raises(NotImplementedError, match="odd reflection"):
+        ref.Spec(path)
+    open(path, "w", encoding="utf-8").write(
+        text.replace("sector: 1}", "sector: 0}", 1)
+        .replace("spin_inversion: 1", "spin_inversion: -1"))
+    with pytest.raises(NotImplementedError, match="-1 in a real sector"):
+        ref.Spec(path)
 
 
 def test_work_counts():
@@ -105,14 +128,45 @@ def test_work_counts():
     assert work.apply_bytes(config) == nnz * 12 + 258 * 4 + 2 * 257 * 8
     assert work.iteration_bytes(config) == \
         work.apply_bytes(config) + 4 * 257 * 8
+    # "real" says what saying nothing says; a complex value is 16 bytes,
+    # an index what it was
+    assert work.apply_bytes(dict(config, sector="real")) == \
+        work.apply_bytes(config)
+    cplx = dict(config, sector="complex")
+    assert work.is_complex(cplx) and not work.is_complex(config)
+    assert work.apply_bytes(cplx) == nnz * 20 + 258 * 4 + 2 * 257 * 16
+    assert work.iteration_bytes(cplx) == \
+        work.apply_bytes(cplx) + 4 * 257 * 16
+    with pytest.raises(ValueError, match="sector 'imaginary'"):
+        work.apply_bytes(dict(config, sector="imaginary"))
     v5e = peaks.peaks_for("TPU v5 lite")
     assert work.least_seconds(819e9, v5e, 1) == pytest.approx(1.0)
     assert work.least_seconds(819e9, v5e, 4) == pytest.approx(0.25)
 
 
-def test_committed_config_states_its_sizes():
-    import json
+#: ``work.apply_bytes`` and ``work.iteration_bytes`` of the four committed
+#: configurations as the parent of PR 34 computes them (8 B a value): no
+#: roofline numerator of a cell moved when the value's bytes became the
+#: configuration's to state
+PARENT_BYTES = {
+    "chain_32_symm": (1_081_536_100, 1_232_191_108),
+    "chain_32_symm_x4": (1_081_536_100, 1_232_191_108),
+    "square_5x5": (1_788_903_204, 1_955_312_804),
+    "chain_28": (8_272_934_404, 9_556_665_604),
+}
 
+
+def test_the_committed_configurations_keep_the_parents_bytes():
+    bench = harness.load_benchmark()
+    assert {c["name"] for c in bench["configs"]} == set(PARENT_BYTES)
+    for name, (apply_b, iteration_b) in PARENT_BYTES.items():
+        config = harness.load_config(bench, name)
+        assert "sector" not in config and not work.is_complex(config)
+        assert work.apply_bytes(config) == apply_b, name
+        assert work.iteration_bytes(config) == iteration_b, name
+
+
+def test_committed_config_states_its_sizes():
     for name in ("chain_32_symm", "chain_32_symm_x4"):
         with open(os.path.join(ROOT, "benchmark", "configs",
                                name + ".json")) as f:

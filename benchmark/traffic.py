@@ -6,6 +6,11 @@ caller, who waits for each answer before asking for the next):
 
 ``apply``         ``y = eng.matvec(x)`` on one unit-norm standard-normal
                   ``x`` made from the seed, waited for, again and again.
+                  Where the configuration states a complex sector,
+                  ``x = (a + i b) / ||a + i b||`` with ``a`` the real
+                  sector's draw and ``b`` from a stream of its own; it
+                  crosses to the device once, before the window, in the
+                  engine's (re, im) layout.
 ``ground_state``  whole ground-state solves, one after the other.  A solve
                   is one request: the one in flight when ``--seconds`` have
                   passed runs to its end and is counted and checked like
@@ -30,7 +35,7 @@ import time
 
 import numpy as np
 
-from . import check
+from . import check, work
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -65,6 +70,9 @@ class Apply(_Mix):
         """The window's input, from the seed."""
         rng = np.random.default_rng(_seed_sequence(self.seed, 0))
         x = rng.standard_normal(n_states)
+        if work.is_complex(system.config):
+            rng = np.random.default_rng(_seed_sequence(self.seed, 1))
+            x = x + 1j * rng.standard_normal(n_states)
         self.x = x / np.linalg.norm(x)
         self.xd = system.to_device(self.x)
 
@@ -162,7 +170,7 @@ class GroundState(_Mix):
 
     def control(self, ref, collected):
         """The control's answers in the place of the program's."""
-        return check.control_eigenpairs(collected)
+        return check.control_eigenpairs(ref, collected)
 
 
 KINDS = {"apply": Apply, "ground_state": GroundState}
