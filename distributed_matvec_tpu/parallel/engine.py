@@ -553,6 +553,32 @@ def choose_ell_split(hist: np.ndarray, n_rows: int, T: int,
     return T0, S, Tmax
 
 
+def gather_table_counts(eng) -> Dict[str, int]:
+    """Four counts that stand beside an ell engine's ``_ell_counts`` on its
+    build span and in its ``engine_init`` event, and say which gather is
+    which in a device trace of the apply.  They are derived from what the
+    engine holds after either build and after a restore, and are no part of
+    ``_ell_counts`` (that dict is the structure artifact's: no key is added
+    there).  ``range_rows``: the rows of one table range, the table of
+    every near gather and of the gathers that put a range's sums back in
+    range order (0 where the gather table is not cut); ``table_rows``: the
+    padded rows, what whole ``x`` is at most as a gather table (the far
+    gathers'; every gather's where the table is not cut);
+    ``unpermute_slots``: the rows of the gathers that put the sums back in
+    basis order, ``gather_slots`` less the table slots; ``row_bytes``: one
+    gathered row in the chip's layout (:func:`gather_row_bytes`: 16, or 32
+    for a pair-form vector).  ``{}`` for an engine without the counts."""
+    counts = getattr(eng, "_ell_counts", None)
+    if not counts:
+        return {}
+    return {"range_rows": int(eng._ell_range_rows),
+            "table_rows": int(eng.n_padded),
+            "unpermute_slots": int(counts["gather_slots"]
+                                   - counts["near_slots"]
+                                   - counts["far_slots"]),
+            "row_bytes": gather_row_bytes(3 if eng.real else 6)}
+
+
 def emit_engine_init(eng, engine_kind: str, init_s: Optional[float] = None
                      ) -> None:
     """One ``engine_init`` telemetry event carrying the construction split
@@ -578,6 +604,7 @@ def emit_engine_init(eng, engine_kind: str, init_s: Optional[float] = None
          transfer_s=round(t.scope_total("transfer"), 6),
          diag_s=round(t.scope_total("diag"), 6),
          **getattr(eng, "_ell_counts", {}),
+         **gather_table_counts(eng),
          **getattr(eng, "_ell_form", {}),
          **({} if init_s is None else {"init_s": round(init_s, 6)}))
 
@@ -1048,7 +1075,8 @@ class LocalEngine:
                     except Exception as e:
                         oom_reraise(e, engine="local", mode=mode,
                                     phase="init", n_states=int(n))
-                    build_span.add(**self._ell_counts)
+                    build_span.add(**self._ell_counts,
+                                   **gather_table_counts(self))
                 self._save_structure(structure_cache, soft=soft_save)
             self._matvec = self._make_ell_matvec()
             self._checked = True                  # validated at build time

@@ -166,10 +166,12 @@ def _local_ell_engine(sh, pair, histogram="chain_32_symm", whole=False,
     return eng, S((n,) + batch + ctail, jnp.float64)
 
 
-def _chain_28_ranges():
-    """``(W, staircases)`` of chain_28 with its gather table cut: the
-    range length the engine's rule gives and, range by range, near then
-    far, each staircase's ``(rows, ((columns, rows), ...))``, read off the
+def _table_ranges(config, parts):
+    """``(n, n_pad, W, staircases)`` of a configuration whose gather table
+    is cut (``chain_28``; ``chain_32_k1``, whose pair-form rows of six
+    ``parts`` halve a range): the states, the padded rows, the range length
+    the engine's rule gives and, range by range, near then far, each
+    staircase's ``(rows, ((columns, rows), ...))``, read off the
     independently counted histograms in ``tests/data`` (the same the
     engine's build reads off its own counts)."""
     import json
@@ -178,10 +180,10 @@ def _chain_28_ranges():
                                                         staircase_levels)
 
     with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                           "data", "chain_28_ranges.json")) as f:
+                           "data", config + "_ranges.json")) as f:
         data = json.load(f)
     n_pad = data["n_padded"]
-    R, W = gather_table_ranges(n_pad, 3)
+    R, W = gather_table_ranges(n_pad, parts)
     assert (R, W) == (data["ranges"], data["range_rows"])
     out = []
     for r in range(R):
@@ -190,28 +192,34 @@ def _chain_28_ranges():
             stair, levels = staircase_levels(np.array(data[kind][r]), rows)
             assert stair
             out.append((rows, tuple((k, L) for _, k, L in levels)))
-    return W, out
+    return data["n_states"], n_pad, W, out
 
 
-def _chain_28_engine(sh):
-    """The LocalEngine shell of :func:`_local_ell_engine` at chain_28's
-    shapes above the VMEM line: two staircases a table range."""
+def _chain_28_ranges():
+    """``(W, staircases)`` of chain_28 with its gather table cut."""
+    return _table_ranges("chain_28", 3)[2:]
+
+
+def _range_engine(sh, config, pair=False):
+    """The LocalEngine shell of :func:`_local_ell_engine` at the shapes of
+    a configuration above the VMEM line: two staircases a table range (in
+    pair form where ``pair``)."""
     from distributed_matvec_tpu.parallel.engine import LocalEngine
 
     S = _shapes(sh)
-    n, n_pad, _ = HISTOGRAMS["chain_28"]
-    W, staircases = _chain_28_ranges()
+    n, n_pad, W, staircases = _table_ranges(config, 6 if pair else 3)
+    ctail = (2,) if pair else ()
     eng = object.__new__(LocalEngine)
     eng.n_states, eng.n_padded = n, n_pad
-    eng.pair, eng._dtype = False, jnp.float64
+    eng.pair, eng._dtype = pair, jnp.float64
     eng._ell_blocks = tuple(
-        tuple((S((k, L), jnp.int32), S((k, L), jnp.float64))
+        tuple((S((k, L), jnp.int32), S((k, L) + ctail, jnp.float64))
               for k, L in levels) for _, levels in staircases)
     eng._ell_pos_of = tuple(S((rows,), jnp.int32) for rows, _ in staircases)
     eng._ell_range_rows = W
     eng._diag = S((n_pad,), jnp.float64)
     eng._make_ell_matvec()
-    return eng, S((n,), jnp.float64)
+    return eng, S((n,) + ctail, jnp.float64)
 
 
 def _distributed_ell_engine(topo):
@@ -281,7 +289,7 @@ def _compile(name, topo):
 
     sh = SingleDeviceSharding(topo.devices[0])
     name, _, histogram = name.partition("@")
-    eng, x = _chain_28_engine(sh) if histogram == "chain_28" else \
+    eng, x = _range_engine(sh, "chain_28") if histogram == "chain_28" else \
         _local_ell_engine(sh, False, histogram or "chain_32_symm")
     apply_fn, operands = eng.bound_matvec()
     if name == "ell_apply":
@@ -465,6 +473,55 @@ def test_chain_28_gathers_its_near_entries_from_vmem(tpu_knobs, compiled):
     assert len(streamed) == 82 and min(g[0] for g in streamed) > 3_220_000
     assert all(g[1] <= W for g in streamed)
     assert max(g[0] for g in near if g[3]) < 3_220_000
+
+
+@pytest.mark.slow
+def test_chain_32_k1_leaves_its_longest_near_tables_in_hbm(one_chip,
+                                                           tpu_knobs):
+    """A finding, read before the chip run and mended nowhere (PR 35): what
+    the compiler does today with a pair-form range.  chain_32_k1's table is
+    cut into 6 ranges of 1,572,864 rows of 32 B, which the rule counts as a
+    table, a gather's rows and their indices at once inside 118 MiB (68 B a
+    row, 107 MB).  In the optimised HLO for a described v5e all 163 gathers
+    write to VMEM and read their indices there, the 12 that put a range's
+    sums back in range order (full length, the accumulator as table) read
+    their table there too, and the far ones read whole ``x`` in HBM, as at
+    chain_28.  But the six longest near levels, the first level of every
+    range (1,572,864 rows, 1,526,784 in the last), **read their table from
+    HBM**: a range of ``x`` as long as the gather's result does not get a
+    place in VMEM beside it, where every shorter near level's does.  They
+    are 31 + 7 of an apply's gathers and 59.4 M of its 146.4 M near and
+    un-permute rows, and the trace reads them at 12-15 ns a row for 4.2
+    (PERF.md §5).  Marked slow: the compile takes five minutes on the CPU,
+    twice chain_28's."""
+    eng, x = _range_engine(one_chip, "chain_32_k1", pair=True)
+    apply_fn, operands = eng.bound_matvec()
+    exe = jax.jit(apply_fn).lower(x, operands).compile()
+    assert _fits(exe, "ell apply at chain_32_k1") < 6.0e9
+    assert "scatter" not in exe.as_text()
+    n, _, W, staircases = _table_ranges("chain_32_k1", 6)
+    gathers = _gather_operands(exe, parts=6)
+    want = sorted([L for _, levels in staircases for _, L in levels]
+                  + [rows for rows, _ in staircases])
+    assert len(want) == 151 + 12
+    assert sorted(g[0] for g in gathers) == want
+    # every result and every index array in VMEM, the longest too
+    assert all(result and index for _, _, _, index, result, _ in gathers)
+    unpermute = [g for g in gathers if g[5]]
+    near = [g for g in gathers if g[1] <= W and not g[5]]
+    far = [g for g in gathers if g[1] > W]
+    assert (len(unpermute), len(near), len(far)) == (12, 99, 52)
+    assert {g[:3] for g in unpermute} == {(W, W, True)}
+    assert {(g[1], g[2]) for g in far} == {(n, False)}
+    # the near tables: a range of x, the last one ending with the states
+    assert {g[1] for g in near} == {W, n - 5 * W}
+    in_hbm = sorted(g[:2] for g in near if not g[2])
+    assert in_hbm == [(1_526_784, n - 5 * W)] + [(W, W)] * 5
+    assert max(g[0] for g in near if g[2]) == 1_571_840
+    # an apply runs a level's gather once a column: 31 + 7 gathers
+    first = [levels[0] for _, levels in staircases[0::2]]
+    assert sorted(first) == [(6, W)] * 4 + [(7, 1_526_784), (7, W)]
+    assert sum(k * L for k, L in first) == 59_446_272
 
 
 def test_range_build_chunk_compiles_at_chain_28(one_chip):
