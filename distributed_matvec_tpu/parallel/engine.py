@@ -329,7 +329,13 @@ def gather_table_ranges(n_rows: int, parts: int):
     ``W`` gathered rows and their indices: the near levels' gathers and
     the two that put a range's sums back in basis order, whose table is
     the accumulator), so no piece is cut inside a range and ``R`` is the
-    fewest ranges that allow it: 12 of 3,348,480 rows at chain_28.
+    fewest ranges that allow it: 12 of 3,348,480 rows at chain_28, 6 of
+    1,572,864 pair rows at chain_32_k1.  The compiler follows the count
+    for every gather it places by itself, which is why the apply gathers a
+    cut table's columns one by one (:func:`ell_term_loop`): the table of a
+    gather inside a ``lax.scan`` it left in HBM at chain_32_k1 however
+    short the ranges (8 of 1,179,648 rows, 82 MB by this count, too:
+    PERF.md §6, PR 36).
     """
     row = gather_row_bytes(parts)
     if n_rows * (2 * row + 4) <= GATHER_VMEM_BYTES \
@@ -841,33 +847,52 @@ def unroll_terms_ok(width: int, rows: int, x_shape=()) -> bool:
     return width <= 64 and width * rows * vec_width * 20 <= 2_000_000_000
 
 
-def ell_term_loop(levels):
+def ell_term_loop(levels, cut: bool = False):
     """``(unroll, counts)`` for an apply of ``LocalEngine``'s ELL ``levels``
     (``(idx, coeff)`` a level; where the rows are cut into blocks, the
-    first block's pieces, which every level reaches): a ``lax.scan`` over
-    each level's columns, unless the ``term_loop`` test hook says
-    ``unroll``.  ``counts`` says which form the columns take:
-    ``unrolled_columns`` and ``scanned_columns``, one of them 0.
+    first block's pieces, which every level reaches; where the gather table
+    is ``cut`` into ranges, the widest range's: :func:`widest_pieces`).
+    Under the VMEM line a ``lax.scan`` over each level's columns; above it,
+    where the table is cut, one gather a column in straight-line code.  The
+    ``term_loop`` test hook overrides either (``unroll``, ``scan``).
+    ``counts`` says which form the columns take: ``unrolled_columns`` and
+    ``scanned_columns``, one of them 0.
 
-    Both forms ran on a v5e at the benchmark's two Hamiltonians (my chip
-    runs, PR 28; PERF.md §6).  On the device they are the same gathers at
-    the same times; a coefficient row reaches its multiply by a dynamic
-    slice a step where the unrolled form cuts one static slice a level,
-    and the scan's is the cheaper: ``apply_device_ms`` 879.17 scanned
-    against 880.86 unrolled at square_5x5 (40 columns in 14 levels), 509.52
-    against 511.09 at chain_32_symm (26 in 12).  A level is one traced
-    gather where unrolled it is one a column, so a solver's block programs
-    build faster: the device idles 0.47 s under the dispatch that builds
-    the Lanczos window program against 1.84 s, and a whole chain_32_symm
-    solve reads ``lanczos_iter_ms`` 670.3 against 700.7 (-4.3%), square_5x5
-    1,086.9 against 1,120.1.  The scan form also needs the less memory
+    *Under the line* both forms ran on a v5e at the benchmark's two
+    Hamiltonians (my chip runs, PR 28; PERF.md §6).  On the device they are
+    the same gathers at the same times; a coefficient row reaches its
+    multiply by a dynamic slice a step where the unrolled form cuts one
+    static slice a level, and the scan's is the cheaper:
+    ``apply_device_ms`` 879.17 scanned against 880.86 unrolled at
+    square_5x5 (40 columns in 14 levels), 509.52 against 511.09 at
+    chain_32_symm (26 in 12).  A level is one traced gather where unrolled
+    it is one a column, so a solver's block programs build faster: the
+    device idles 0.47 s under the dispatch that builds the Lanczos window
+    program against 1.84 s, and a whole chain_32_symm solve reads
+    ``lanczos_iter_ms`` 670.3 against 700.7 (-4.3%), square_5x5 1,086.9
+    against 1,120.1.  The scan form also needs the less memory
     (``peak_hbm_gb`` 9.4106 against 9.4222 in the chain's solve).  So there
-    is nothing for an estimate to trade off on this chip.
+    is nothing for an estimate to trade off there.
+
+    *Above the line* there is (PR 36; PERF.md §6).  A range's first near
+    level is its only long level with more than one column (6 or 7 at
+    chain_32_k1, 4 or 5 at chain_28), so the only scan that runs more than
+    one step, and a table that reaches its gather as an element of a loop's
+    tuple is one the compiler may leave in HBM: at chain_32_k1 those six
+    tables, a range of ``x`` each, were (38 column gathers an apply at the
+    far rate, 15 ns a row for 3.3, half of the apply).  Unrolled, every
+    column is a gather the compiler places by itself, as it places the
+    one-column levels: table, indices and result in VMEM.  chain_28's
+    loops had their table in VMEM already and streamed their indices; its
+    columns now do what its one-column levels do.  Every other level above
+    the line is one column long (or a staircase's bottom of 1,024 rows),
+    so nothing else changes form.
     """
     from ..utils.config import get_config
 
     width = sum(idx.shape[0] for idx, _ in levels)
-    unroll = get_config().term_loop == "unroll" and width <= 64
+    form = get_config().term_loop
+    unroll = (form != "scan") if cut else (form == "unroll" and width <= 64)
     return unroll, {"unrolled_columns": width if unroll else 0,
                     "scanned_columns": 0 if unroll else width}
 
@@ -1458,6 +1483,11 @@ class LocalEngine:
         which fits VMEM too.  ``_ell_blocks`` holds the staircases range
         by range, near then far, and ``_ell_pos_of`` their positions
         (``None`` where rows of near-equal width keep the plain table).
+        The apply gathers every column of these levels in straight-line
+        code, not under ``lax.scan`` (:func:`ell_term_loop`): a range's
+        first near level, its only long level with more than one column,
+        would else reach its gathers as a loop's operand, which the
+        compiler may leave in HBM.
 
         One pass of the kernels, a range at a time (``ell_build_budget_gb``
         has no say here): the device runs the kernels and packs each
@@ -1893,7 +1923,8 @@ class LocalEngine:
             # operation is added, moved or split for them
             with jax.named_scope("apply/split"):
                 gx = prep_gather(x, dtype, use_sg)
-            unroll, form = ell_term_loop(widest_pieces(blocks, W > 0))
+            unroll, form = ell_term_loop(widest_pieces(blocks, W > 0),
+                                         cut=W > 0)
             # on the span this trace runs under (``apply``, or the solver's
             # ``lanczos/dispatch``): which form the program it builds takes
             obs_trace.current_span().add(**form)
@@ -1975,7 +2006,7 @@ class LocalEngine:
         self._operands = (self._ell_blocks, self._ell_pos_of, self._diag)
         #: the form the apply takes (``engine_init`` event)
         self._ell_form = ell_term_loop(
-            widest_pieces(self._ell_blocks, W > 0))[1]
+            widest_pieces(self._ell_blocks, W > 0), cut=W > 0)[1]
         _mv = jax.jit(apply_fn)
         return lambda x: _mv(x, self._operands)
 

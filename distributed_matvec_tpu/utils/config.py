@@ -179,7 +179,10 @@ class RuntimeConfig:
     term_loop: str = "auto"                # ELL/compact per-term loop form
     #   (a test hook): auto (LocalEngine's ELL levels: lax.scan, the form
     #   that is no slower on the chip and builds faster into a solver's
-    #   programs — engine.ell_term_loop; compact mode and DistributedEngine:
+    #   programs, and, where the gather table is cut into ranges, above the
+    #   VMEM line, one gather a column, the form whose tables the compiler
+    #   places in VMEM — engine.ell_term_loop; compact mode and
+    #   DistributedEngine:
     #   unrolled until the estimated gather scratch would exceed ~2 GB, then
     #   lax.scan — engine.unroll_terms_ok) | scan (force the serialized
     #   form everywhere) | unroll (force one gather a column wherever the

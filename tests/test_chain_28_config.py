@@ -26,7 +26,7 @@ from distributed_matvec_tpu.models.yaml_io import load_config_from_yaml
 from distributed_matvec_tpu.parallel import engine
 from distributed_matvec_tpu.parallel.engine import (
     LocalEngine, block_pieces, gather_row_blocks, gather_table_ranges,
-    staircase_levels)
+    staircase_levels, widest_pieces)
 from distributed_matvec_tpu.utils.config import get_config, update_config
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -435,4 +435,62 @@ def test_the_table_cut_builds_spans(ring, table_outside_vmem):
     share = counts["near_slots"] / (counts["near_slots"]
                                     + counts["far_slots"])
     assert 0.7 < share < 0.95
+    obs.reset_all()
+
+
+FORMS = ("auto", "scan", "unroll")
+
+
+def _applies_in_every_form(op, xs, **kw):
+    """``{form: (the engine_init event's two column counts, one apply of
+    each of xs)}``: a ``LocalEngine`` built and its apply traced under each
+    value of the ``term_loop`` hook."""
+    out = {}
+    try:
+        for form in FORMS:
+            update_config(term_loop=form)
+            eng = LocalEngine(op, **kw)
+            init = obs.events("engine_init")[-1]
+            out[form] = ((init["unrolled_columns"], init["scanned_columns"]),
+                         [np.asarray(eng.matvec(x)) for x in xs])
+    finally:
+        update_config(term_loop="auto")
+    return out, eng
+
+
+@pytest.mark.parametrize("name", list(RINGS))
+def test_cut_apply_is_the_same_bits_in_every_form_of_the_term_loop(
+        name, ring, table_outside_vmem):
+    """Where the table is cut every column is a gather of its own (PR 36:
+    the form whose tables the chip's compiler places in VMEM), and the
+    ``term_loop`` hook can still ask for the scan: the apply of a real
+    vector is the same bits in all three forms (a row's columns are added
+    in the same order, column 0 first), and the ``engine_init`` event says
+    which form ran.  A two-column batch agrees to the last bit or two: in
+    straight-line code XLA's CPU backend contracts a batch's multiply-adds
+    where under the scan it does not (as ``test_engine_local.py`` records
+    of two unrolled programs).  Where the table is not cut ``auto`` is the
+    scan, as since PR 28."""
+    op, _, states = ring(name)
+    rng = np.random.default_rng(36)
+    xs = [rng.standard_normal(states.size),
+          rng.standard_normal((states.size, 2))]
+    whole, eng = _applies_in_every_form(op, xs, batch_size=CHUNK)
+    wide = sum(i.shape[0] for i, _ in eng._ell_levels)
+    assert eng._ell_counts["table_ranges"] == 1
+    assert [whole[f][0] for f in FORMS] == [(0, wide), (0, wide), (wide, 0)]
+    table_outside_vmem(engine.pad_to_multiple(states.size, CHUNK))
+    cut, eng = _applies_in_every_form(op, xs, batch_size=CHUNK)
+    assert eng._ell_counts["table_ranges"] == 3
+    wide = sum(i.shape[0] for i, _ in widest_pieces(eng._ell_blocks, True))
+    assert 0 < wide <= 2 * eng._ell_counts["widest_row"]
+    assert [cut[f][0] for f in FORMS] == [(wide, 0), (0, wide), (wide, 0)]
+    # the same form twice is the same program; the other form of the cut
+    # apply is the same bits for the vector
+    for y, y_auto in zip(cut["unroll"][1] + whole["scan"][1],
+                         cut["auto"][1] + whole["auto"][1]):
+        np.testing.assert_array_equal(y, y_auto)
+    (y, Y), (y_auto, Y_auto) = cut["scan"][1], cut["auto"][1]
+    np.testing.assert_array_equal(y, y_auto)
+    np.testing.assert_allclose(Y, Y_auto, atol=1e-14, rtol=1e-13)
     obs.reset_all()

@@ -24,6 +24,7 @@ import json
 import os
 import re
 from math import comb
+from types import SimpleNamespace
 
 import numpy as np
 import pytest
@@ -33,8 +34,9 @@ from distributed_matvec_tpu.models.yaml_io import load_config_from_yaml
 from distributed_matvec_tpu.ops import kernels as K
 from distributed_matvec_tpu.parallel import engine
 from distributed_matvec_tpu.parallel.engine import (
-    LocalEngine, gather_row_blocks, gather_row_bytes, gather_table_counts,
-    gather_table_ranges, staircase_levels)
+    LocalEngine, ell_term_loop, gather_row_blocks, gather_row_bytes,
+    gather_table_counts, gather_table_ranges, staircase_levels,
+    widest_pieces)
 from distributed_matvec_tpu.utils.config import get_config, update_config
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -378,3 +380,86 @@ def test_the_chain_32_k1_yaml_describes_the_sector():
     at = "hamiltonian:"
     assert text[text.index(at):] == symm[symm.index(at):]
     assert re.search(r"sector: 1\n", text) and "sector: 0" not in text
+
+
+FORMS = ("auto", "scan", "unroll")
+
+
+@pytest.mark.parametrize("n", list(RINGS))
+def test_cut_pair_form_apply_is_the_same_bits_in_every_term_loop_form(
+        n, ring, pair_form, pair_table_outside_vmem):
+    """The pair-form apply with its table cut, one ``[N, 2]`` vector and a
+    two-column batch ``[N, 2, 2]``, under each value of the ``term_loop``
+    hook: the same apply to the last bit or two (one gather a column in
+    straight-line code, which ``auto`` is where the table is cut since PR
+    36, adds a row's columns in the scan's order; XLA's CPU backend
+    contracts ``cmul_pair``'s multiply-adds otherwise there than under the
+    scan, 10 of 9,174 values an ulp apart at 20 sites: a real vector's
+    apply is the same bits, ``test_chain_28_config.py``), ``auto`` and
+    ``unroll`` the same bits, and the ``engine_init`` event says which ran:
+    ``unrolled_columns`` the widest range's columns and ``scanned_columns``
+    0, the reverse under ``scan`` and, under ``auto``, wherever the table
+    is not cut (``test_the_span_counts_below_the_line``'s engine)."""
+    op, _, reps = ring(n)
+    chunk, tile, R = RINGS[n]
+    rng = np.random.default_rng(36 + n)
+    xs = [rng.standard_normal((reps.size, 2)),
+          rng.standard_normal((reps.size, 2, 2))]
+    LocalEngine(op, batch_size=chunk)   # not cut: the scan, as since PR 28
+    init = obs.events("engine_init")[-1]
+    assert init["unrolled_columns"] == 0 < init["scanned_columns"]
+    pair_table_outside_vmem(engine.pad_to_multiple(reps.size, chunk), tile, R)
+    said, got = {}, {}
+    try:
+        for form in FORMS:
+            update_config(term_loop=form)
+            eng = LocalEngine(op, batch_size=chunk)
+            init = obs.events("engine_init")[-1]
+            assert eng.pair and init["table_ranges"] == R
+            said[form] = (init["unrolled_columns"], init["scanned_columns"])
+            got[form] = [np.asarray(eng.matvec(x)) for x in xs]
+    finally:
+        update_config(term_loop="auto")
+    wide = sum(i.shape[0] for i, _ in widest_pieces(eng._ell_blocks, True))
+    assert wide > 0
+    assert [said[f] for f in FORMS] == [(wide, 0), (0, wide), (wide, 0)]
+    assert [y.shape for y in got["auto"]] == [x.shape for x in xs]
+    for y, y_scan, y_auto in zip(got["unroll"], got["scan"], got["auto"]):
+        np.testing.assert_array_equal(y, y_auto)
+        np.testing.assert_allclose(y_scan, y_auto, atol=1e-14, rtol=1e-13)
+    obs.reset_all()
+
+
+@pytest.mark.parametrize("form, unrolled", [("auto", True), ("scan", False),
+                                            ("unroll", True)])
+@pytest.mark.parametrize("cell, parts, width", [("chain_32_k1", 6, 42),
+                                                ("chain_28", 3, 35)])
+def test_term_loop_form_above_the_line(cell, parts, width, form, unrolled):
+    """The form of the term loop at the two cells above the VMEM line, from
+    the shapes of their ranges' staircases alone
+    (``tests/data/<cell>_ranges.json``): one gather a column under
+    ``auto``, the form whose tables the compiler places in VMEM (PR 36:
+    PERF.md §6), the scan only under the hook; the counts are the widest
+    range's columns, near and far, 42 and 35."""
+    with open(os.path.join(ROOT, "tests", "data", cell + "_ranges.json"),
+              encoding="utf-8") as f:
+        data = json.load(f)
+    R, W = gather_table_ranges(data["n_padded"], parts)
+    blocks = []
+    for r in range(R):
+        rows = min(W, data["n_padded"] - r * W)
+        for kind in ("near", "far"):
+            _, levels = staircase_levels(np.array(data[kind][r]), rows)
+            blocks.append(tuple((SimpleNamespace(shape=(k, L)), None)
+                                for _, k, L in levels))
+    update_config(term_loop=form)
+    try:
+        unroll, counts = ell_term_loop(widest_pieces(blocks, True), cut=True)
+        below = ell_term_loop(widest_pieces(blocks, True))
+    finally:
+        update_config(term_loop="auto")
+    assert unroll is unrolled
+    assert counts == {"unrolled_columns": width if unrolled else 0,
+                      "scanned_columns": 0 if unrolled else width}
+    # the same shapes under the line: the scan unless the hook says unroll
+    assert below[0] is (form == "unroll")

@@ -434,7 +434,7 @@ TABLE_CUTS = [("ring16", 1000, 3), ("ring16", 1000, 4), ("ring16", 1000, 5),
 
 
 @pytest.mark.parametrize("name, batch_size, R", TABLE_CUTS)
-@pytest.mark.parametrize("term_loop", ["auto", "unroll"])
+@pytest.mark.parametrize("term_loop", ["auto", "scan"])
 def test_table_cut_apply_matches_independent_reference(
         name, batch_size, R, term_loop, rng, pair_form, monkeypatch):
     """Where ``x`` does not fit VMEM as a gather table (the rule's number
@@ -442,7 +442,9 @@ def test_table_cut_apply_matches_independent_reference(
     a near and a far staircase a range, every entry stored once, near
     where its column lies in its row's range; the apply against the
     independent reference and against the uncut engine, one vector and a
-    batch, in both forms of the term loop; no scatter."""
+    batch, in both forms of the term loop (where the table is cut ``auto``
+    is one gather a column, PR 36; the hook asks for the scan); no
+    scatter."""
     from distributed_matvec_tpu.utils.config import update_config
 
     kw = {} if batch_size is None else {"batch_size": batch_size}
@@ -521,7 +523,9 @@ def test_table_cut_apply_matches_independent_reference(
     assert not [p for p in prims if "scatter" in p]
     width = sum(i.shape[0] for i, _ in cut._ell_levels)
     assert prims.count("gather") == counts["gather_pieces"] \
-        + (width - len(cut._ell_levels) if term_loop == "unroll" else 0)
+        + (width - len(cut._ell_levels) if term_loop == "auto" else 0)
+    assert prims.count("scan") == (0 if term_loop == "auto"
+                                   else len(cut._ell_levels))
 
 
 def test_table_rule_reads_the_shapes():

@@ -200,14 +200,20 @@ def _chain_28_ranges():
     return _table_ranges("chain_28", 3)[2:]
 
 
-def _range_engine(sh, config, pair=False):
+def _range_engine(sh, config, pair=False, keep=None):
     """The LocalEngine shell of :func:`_local_ell_engine` at the shapes of
     a configuration above the VMEM line: two staircases a table range (in
-    pair form where ``pair``)."""
+    pair form where ``pair``).  ``keep`` empties every other range (no
+    level, no un-permute; ``x`` keeps its length, so the far gathers'
+    table is what it is in the whole program): the kept ranges' gathers at
+    a fraction of the compile."""
     from distributed_matvec_tpu.parallel.engine import LocalEngine
 
     S = _shapes(sh)
     n, n_pad, W, staircases = _table_ranges(config, 6 if pair else 3)
+    if keep is not None:
+        staircases = [st if j // 2 in keep else (st[0], ())
+                      for j, st in enumerate(staircases)]
     ctail = (2,) if pair else ()
     eng = object.__new__(LocalEngine)
     eng.n_states, eng.n_padded = n, n_pad
@@ -215,7 +221,8 @@ def _range_engine(sh, config, pair=False):
     eng._ell_blocks = tuple(
         tuple((S((k, L), jnp.int32), S((k, L) + ctail, jnp.float64))
               for k, L in levels) for _, levels in staircases)
-    eng._ell_pos_of = tuple(S((rows,), jnp.int32) for rows, _ in staircases)
+    eng._ell_pos_of = tuple(S((rows,), jnp.int32) if levels else None
+                            for rows, levels in staircases)
     eng._ell_range_rows = W
     eng._diag = S((n_pad,), jnp.float64)
     eng._make_ell_matvec()
@@ -436,32 +443,36 @@ def test_chain_28_gathers_its_near_entries_from_vmem(tpu_knobs, compiled):
     """Above 7.44 M rows ``x`` cannot be a gather table in VMEM, so the
     table is cut (PR 33): 12 ranges of 3,348,480 rows at chain_28, each a
     near staircase gathered from its own range of ``x`` and a far one
-    gathered from whole ``x``.  In the optimised HLO for a described v5e
-    every one of the 318 gathers writes to VMEM; the 215 near gathers and
-    the 24 that put a range's sums back in range order read a table in
-    VMEM (a range of ``x``, an accumulator of a range's rows); the 79 far
-    gathers read the 642 MB table in HBM, named as such.  Indices are in
-    VMEM too, but for the near and un-permute gathers of 3.22 Mrows and
-    more, which stream them from HBM as chain_32_symm's 2,359,296-row
-    pieces do (4.317 ns a slot there: PERF.md §5).  The apply's
-    temporaries, 2.2 GB beside 8.1 GB of arguments, fit the chip."""
+    gathered from whole ``x``, every column a gather of its own (PR 36: no
+    loop over a level's columns is left, 318 before; the three ``while``
+    the program keeps are the compiler's own, over the split parts of
+    whole ``x``).  In the optimised HLO for a described v5e every one of
+    the 414 gathers writes to VMEM; the 274 near gathers and the 24 that
+    put a range's sums back in range order read a table in VMEM (a range
+    of ``x``, an accumulator of a range's rows); the 116 far gathers read
+    the 642 MB table in HBM, named as such.  Indices are in VMEM too, but
+    for the near and un-permute gathers of 3.23 Mrows and more, which
+    stream them from HBM as chain_32_symm's 2,359,296-row pieces do (4.317
+    ns a slot there: PERF.md §5).  The apply's temporaries, 1.9 GB beside
+    8.1 GB of arguments, fit the chip."""
     exe = compiled("ell_apply@chain_28")
     assert _fits(exe, "ell apply at chain_28") < 11.0e9
-    assert 1.8e9 < exe.memory_analysis().temp_size_in_bytes < 2.6e9
+    assert 1.6e9 < exe.memory_analysis().temp_size_in_bytes < 2.4e9
     assert "scatter" not in exe.as_text()
     W, staircases = _chain_28_ranges()
     n = HISTOGRAMS["chain_28"][0]
     gathers = _gather_operands(exe)
-    want = sorted([L for _, levels in staircases for _, L in levels]
-                  + [rows for rows, _ in staircases])
-    assert len(want) == 294 + 24
+    want = sorted([L for _, levels in staircases for k, L in levels
+                   for _ in range(k)] + [rows for rows, _ in staircases])
+    assert len(want) == 390 + 24
     assert sorted(g[0] for g in gathers) == want
     assert all(result for *_, result, _ in gathers)
     near = [g for g in gathers if g[1] <= W]
     far = [g for g in gathers if g[1] > W]
     assert sorted(g[0] for g in far) == sorted(
-        L for _, levels in staircases[1::2] for _, L in levels)
-    assert len(near) == 215 + 24 and len(far) == 79
+        L for _, levels in staircases[1::2] for k, L in levels
+        for _ in range(k))
+    assert len(near) == 274 + 24 and len(far) == 116
     assert sum(g[5] for g in gathers) == 24 == sum(g[5] for g in near)
     # the near tables: a range of x (the last one ends with the states),
     # or the accumulator of a range's rows; the far one: whole x, in HBM
@@ -469,59 +480,118 @@ def test_chain_28_gathers_its_near_entries_from_vmem(tpu_knobs, compiled):
     assert all(table for _, _, table, *_ in near)
     assert {(g[1], g[2]) for g in far} == {(n, False)}
     assert not re.search(r"f32\[40\d{6},3\]\{[^}]*S\(1\)", exe.as_text())
+    # one gather a column (a loop would be one a level: 294 + 24), and the
+    # first near levels' 49 columns stream their indices one by one, as
+    # the one-column levels beside them do (82 streamed before: 12 + 46)
     streamed = [g for g in gathers if not g[3]]
-    assert len(streamed) == 82 and min(g[0] for g in streamed) > 3_220_000
+    assert len(streamed) == 119 and min(g[0] for g in streamed) > 3_220_000
     assert all(g[1] <= W for g in streamed)
+    assert sum(g[5] for g in streamed) == 24
     assert max(g[0] for g in near if g[3]) < 3_220_000
 
 
-@pytest.mark.slow
-def test_chain_32_k1_leaves_its_longest_near_tables_in_hbm(one_chip,
-                                                           tpu_knobs):
-    """A finding, read before the chip run and mended nowhere (PR 35): what
-    the compiler does today with a pair-form range.  chain_32_k1's table is
-    cut into 6 ranges of 1,572,864 rows of 32 B, which the rule counts as a
-    table, a gather's rows and their indices at once inside 118 MiB (68 B a
-    row, 107 MB).  In the optimised HLO for a described v5e all 163 gathers
-    write to VMEM and read their indices there, the 12 that put a range's
-    sums back in range order (full length, the accumulator as table) read
-    their table there too, and the far ones read whole ``x`` in HBM, as at
-    chain_28.  But the six longest near levels, the first level of every
-    range (1,572,864 rows, 1,526,784 in the last), **read their table from
-    HBM**: a range of ``x`` as long as the gather's result does not get a
-    place in VMEM beside it, where every shorter near level's does.  They
-    are 31 + 7 of an apply's gathers and 59.4 M of its 146.4 M near and
-    un-permute rows, and the trace reads them at 12-15 ns a row for 4.2
-    (PERF.md §5).  Marked slow: the compile takes five minutes on the CPU,
-    twice chain_28's."""
-    eng, x = _range_engine(one_chip, "chain_32_k1", pair=True)
-    apply_fn, operands = eng.bound_matvec()
-    exe = jax.jit(apply_fn).lower(x, operands).compile()
-    assert _fits(exe, "ell apply at chain_32_k1") < 6.0e9
+def _chain_32_k1_placement(exe, keep=None):
+    """``(un-permutes, near, far, W, first)`` of a chain_32_k1 apply's
+    gathers (:func:`_gather_operands`; every range, or those of ``keep``),
+    checked for what holds in either form of the term loop: nothing
+    scattered, every result and every index array in VMEM, the un-permutes
+    (full length, the accumulator as table) with their table there too,
+    the far ones reading whole ``x`` in HBM, the near tables a range of
+    ``x``.  ``first``: each kept range's first near level, the only long
+    level with more than one column."""
     assert "scatter" not in exe.as_text()
-    n, _, W, staircases = _table_ranges("chain_32_k1", 6)
+    n, n_pad, W, staircases = _table_ranges("chain_32_k1", 6)
+    ranges = range(6) if keep is None else keep
     gathers = _gather_operands(exe, parts=6)
-    want = sorted([L for _, levels in staircases for _, L in levels]
-                  + [rows for rows, _ in staircases])
-    assert len(want) == 151 + 12
-    assert sorted(g[0] for g in gathers) == want
-    # every result and every index array in VMEM, the longest too
     assert all(result and index for _, _, _, index, result, _ in gathers)
     unpermute = [g for g in gathers if g[5]]
     near = [g for g in gathers if g[1] <= W and not g[5]]
     far = [g for g in gathers if g[1] > W]
-    assert (len(unpermute), len(near), len(far)) == (12, 99, 52)
     assert {g[:3] for g in unpermute} == {(W, W, True)}
+    assert len(unpermute) == 2 * len(ranges)
     assert {(g[1], g[2]) for g in far} == {(n, False)}
-    # the near tables: a range of x, the last one ending with the states
-    assert {g[1] for g in near} == {W, n - 5 * W}
-    in_hbm = sorted(g[:2] for g in near if not g[2])
-    assert in_hbm == [(1_526_784, n - 5 * W)] + [(W, W)] * 5
-    assert max(g[0] for g in near if g[2]) == 1_571_840
-    # an apply runs a level's gather once a column: 31 + 7 gathers
-    first = [levels[0] for _, levels in staircases[0::2]]
+    assert {g[1] for g in near} == {W} | ({W - (n_pad - n)} if 5 in ranges
+                                          else set())
+    return (unpermute, near, far, W,
+            [staircases[2 * r][1][0] for r in ranges])
+
+
+@pytest.mark.parametrize("form", [
+    "auto", pytest.param("scan", marks=pytest.mark.slow)])
+def test_chain_32_k1_first_near_levels_in_two_ranges(one_chip, tpu_knobs,
+                                                     form):
+    """The mend of PR 36 and the finding of PR 35 on a shell of two of
+    chain_32_k1's six ranges (ranges 0 and 1, the other four emptied; 100 s
+    a compile where the whole apply takes five minutes, so tier-1 guards
+    the placement).  A range's first near level, ``(6, 1,572,864)``, is its
+    only long level with more than one column.  Unrolled (``auto`` where
+    the table is cut) there is no ``while`` and each of the twelve columns
+    is a gather whose table, indices and result are all in ``S(1)``, as
+    every other near gather's and the four un-permutes' are: 66 gathers, no
+    table in HBM but whole ``x``.  Under ``lax.scan`` (the ``term_loop``
+    hook; what every engine ran until PR 36; marked slow, the test above
+    can fail) the level's table, a range of ``x``, reaches the gather as an
+    element of the loop's tuple and stays in HBM: 50 gathers, the two first
+    levels' tables in HBM (and in this shell one shorter level's, 988,160
+    rows), every other near table in VMEM."""
+    update_config(term_loop=form)
+    try:
+        eng, x = _range_engine(one_chip, "chain_32_k1", pair=True,
+                               keep=(0, 1))
+        apply_fn, operands = eng.bound_matvec()
+        exe = jax.jit(apply_fn).lower(x, operands).compile()
+    finally:
+        update_config(term_loop="auto")
+    _, near, far, W, first = _chain_32_k1_placement(exe, (0, 1))
+    assert first == [(6, W)] * 2
+    in_hbm = [g[:2] for g in near if not g[2]]
+    if form == "scan":
+        assert " while(" in exe.as_text()
+        assert (len(near), len(far)) == (31, 15)
+        assert sorted(in_hbm)[-2:] == [(W, W)] * 2
+        assert set(in_hbm) <= {(W, W), (988_160, W)}
+        assert max(g[0] for g in near if g[2]) == 1_571_840
+    else:
+        assert " while(" not in exe.as_text()
+        assert (len(near), len(far)) == (42, 20)
+        assert in_hbm == []
+        assert sum(g[0] == W for g in near) == 12
+
+
+@pytest.mark.slow
+def test_chain_32_k1_gathers_its_near_entries_from_vmem(one_chip, tpu_knobs):
+    """chain_32_k1's table is cut into 6 ranges of 1,572,864 rows of 32 B,
+    which the rule counts as a table, a gather's rows and their indices at
+    once inside 118 MiB (68 B a row, 107 MB).  Until PR 36 the first near
+    level of every range, the only levels with more than one column, ran
+    under ``lax.scan`` and read its table from HBM (38 column gathers an
+    apply, 59.4 M of its 146.4 M near and un-permute rows, at 12-15 ns a
+    row for 3.3: PERF.md §6); every column is now a gather of its own.  In
+    the optimised HLO for a described v5e there is no ``while``, all 216
+    gathers write to VMEM and read their indices there, the 137 near ones
+    and the 12 that put a range's sums back in range order read their table
+    there too, and the 67 far ones read whole ``x`` in HBM, as at chain_28.
+    Marked slow: the compile takes five minutes on the CPU, twice
+    chain_28's; two ranges of it are compiled in tier-1
+    (``test_chain_32_k1_first_near_levels_in_two_ranges``)."""
+    eng, x = _range_engine(one_chip, "chain_32_k1", pair=True)
+    apply_fn, operands = eng.bound_matvec()
+    exe = jax.jit(apply_fn).lower(x, operands).compile()
+    assert _fits(exe, "ell apply at chain_32_k1") < 6.0e9
+    assert 0.9e9 < exe.memory_analysis().temp_size_in_bytes < 1.4e9
+    assert " while(" not in exe.as_text()
+    unpermute, near, far, W, first = _chain_32_k1_placement(exe)
+    _, _, _, staircases = _table_ranges("chain_32_k1", 6)
+    want = sorted([L for _, levels in staircases for k, L in levels
+                   for _ in range(k)] + [rows for rows, _ in staircases])
+    assert len(want) == 204 + 12
+    assert sorted(g[0] for g in unpermute + near + far) == want
+    assert (len(unpermute), len(near), len(far)) == (12, 137, 67)
+    # no near table is left in HBM, the 38 first-level columns' least
+    assert all(table for _, _, table, *_ in near)
     assert sorted(first) == [(6, W)] * 4 + [(7, 1_526_784), (7, W)]
     assert sum(k * L for k, L in first) == 59_446_272
+    assert sum(g[0] in (W, 1_526_784) for g in near) == 38
 
 
 def test_range_build_chunk_compiles_at_chain_28(one_chip):
