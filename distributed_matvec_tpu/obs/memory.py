@@ -14,14 +14,22 @@ Four producers report through here:
   buffers) under ``/``-separated attribution paths; the tree rolls totals
   up per component and is emitted as ``memory_ledger`` events.  Entries are
   *live*: :meth:`Handle.release` (or the owner being garbage-collected,
-  via ``weakref.finalize``) removes them.
+  via ``weakref.finalize``) removes them.  An entry made from arrays
+  (:func:`track_tree`) also records the bytes EACH device holds
+  (``per_device``, from the arrays' shardings), beside the global total: a
+  quarter each for a buffer sharded over four chips.
 * **Watermark sampler** (:func:`sample_watermark` / :func:`watermark_due`):
-  polls ``device.memory_stats()`` around engine init, plan uploads, and
-  every ``memory_every``-th apply, publishing ``hbm_bytes_in_use`` /
-  ``hbm_peak_bytes`` gauges and ``memory_watermark`` events.  Backends
-  without stats (the CPU client returns ``None``) soft-fail once and stay
-  silent — the ledger and executable analysis remain the advisory sources
-  there.
+  polls ``device.memory_stats()`` around engine init, at the end of every
+  build pass, at the solver's block boundaries, and every
+  ``memory_every``-th apply, publishing ``hbm_bytes_in_use`` /
+  ``hbm_peak_bytes`` gauges and ``memory_watermark`` events.  A sample
+  names the fullest device's own row (``fullest``), what the ledger holds
+  on that device (``ledger_bytes``, ``ledger``) and whether the device
+  had been waited for (``synced``), so that one event says how much is
+  resident and owned by nobody; its envelope's ``span_id`` names the span
+  it was taken in.  Backends without stats (the CPU client returns
+  ``None``) soft-fail once and stay silent — the ledger and executable
+  analysis remain the advisory sources there.
 * **Compiled-executable analysis** (:func:`record_executable_analysis`):
   captures ``compiled.memory_analysis()`` (argument / output / temp /
   generated-code bytes) for every AOT-cached executable at compile time,
@@ -63,6 +71,7 @@ __all__ = [
     "ledger_entries",
     "ledger_tree",
     "ledger_total",
+    "ledger_on",
     "host_rss_bytes",
     "emit_ledger",
     "next_instance",
@@ -123,6 +132,8 @@ class Handle:
             ent = _ledger.get(path)
             if ent is not None:
                 ent["bytes"] = int(nbytes)
+                # the split by device was of the old size
+                ent.pop("per_device", None)
 
     def release(self) -> None:
         # GC-safe by construction: NO lock here (see ``_released``)
@@ -160,16 +171,22 @@ def next_instance(kind: str) -> str:
 
 
 def track(path: str, nbytes: int, device: str = "",
-          handle: Optional[Handle] = None, **meta) -> Handle:
+          handle: Optional[Handle] = None,
+          per_device: Optional[Dict[str, int]] = None, **meta) -> Handle:
     """Register one named allocation under a ``/``-separated attribution
     path (``engine/local:0/structure/idx``).  Re-tracking an existing path
     replaces it (a rebuilt table supersedes the old entry).  Returns the
     handle owning the registration (pass ``handle=`` to accumulate several
-    paths under one owner)."""
+    paths under one owner).  ``per_device`` is the split of ``nbytes`` by
+    device (``{"tpu:0": ...}``, the names of a watermark's ``devices``
+    rows); an entry without one counts in full on every device
+    (:func:`ledger_on`)."""
     if not obs_enabled():
         return NULL_HANDLE
     h = handle if handle is not None else Handle()
     ent = {"bytes": int(nbytes), "device": str(device)}
+    if per_device is not None:
+        ent["per_device"] = {str(d): int(b) for d, b in per_device.items()}
     for k, v in meta.items():
         ent[k] = v
     with _lock:
@@ -180,20 +197,53 @@ def track(path: str, nbytes: int, device: str = "",
     return h
 
 
+def _device_name(dev) -> str:
+    """A device as the ledger's ``per_device`` and a watermark's
+    ``devices`` rows name it: the two are joined on it."""
+    return f"{dev.platform}:{dev.id}"
+
+
+def _tree_bytes(tree):
+    """``(total, per_device)`` of a pytree of arrays: the summed global
+    ``nbytes`` and what each local device holds of them, by the arrays'
+    own shardings: a shard's shape on every device the sharding names (a
+    replicated array counts in full on each).  Read off the sharding and
+    not off the shards' buffers, which would materialise an array object a
+    shard.  ``per_device`` is None where a leaf is no device array: the
+    split is then not known."""
+    import math
+
+    import jax
+
+    total, per, known = 0, {}, True
+    for leaf in jax.tree_util.tree_leaves(tree):
+        total += int(getattr(leaf, "nbytes", 0))
+        sharding = getattr(leaf, "sharding", None)
+        if sharding is None:
+            known = False
+            continue
+        held = math.prod(sharding.shard_shape(leaf.shape)) \
+            * leaf.dtype.itemsize
+        for dev in sharding.addressable_devices:
+            name = _device_name(dev)
+            per[name] = per.get(name, 0) + int(held)
+    return total, (per if known else None)
+
+
 def track_tree(path: str, tree, device: str = "",
                handle: Optional[Handle] = None, **meta) -> Handle:
-    """Register the summed ``nbytes`` of a pytree of arrays under one
-    path (the engines' table bundles are pytrees)."""
+    """Register a pytree of arrays under one path (the engines' table
+    bundles are pytrees): the summed ``nbytes``, and the bytes each device
+    holds of it (``per_device``; all of them on the one device of a
+    one-chip run)."""
     if not obs_enabled():
         return NULL_HANDLE
     try:
-        import jax
-
-        total = sum(int(getattr(leaf, "nbytes", 0))
-                    for leaf in jax.tree_util.tree_leaves(tree))
+        total, per = _tree_bytes(tree)
     except Exception:
-        total = int(getattr(tree, "nbytes", 0))
-    return track(path, total, device=device, handle=handle, **meta)
+        total, per = int(getattr(tree, "nbytes", 0)), None
+    return track(path, total, device=device, handle=handle, per_device=per,
+                 **meta)
 
 
 def ledger_entries() -> Dict[str, dict]:
@@ -235,6 +285,24 @@ def ledger_total(prefix: Optional[str] = None,
             continue
         total += ent["bytes"]
     return total
+
+
+def ledger_on(device_name: str) -> Dict[str, int]:
+    """What the live ledger holds on one device (a name as in a
+    watermark's ``devices`` rows), by the first part of the path
+    (``engine``, ``solver``, ``plan``, ...).  Host-RAM entries
+    (``device="host"``) are left out; an entry that recorded no split by
+    device counts in full, which is what it holds on the one device of a
+    one-chip run."""
+    out: Dict[str, int] = {}
+    for path, ent in ledger_entries().items():
+        if ent.get("device") == "host":
+            continue
+        per = ent.get("per_device")
+        held = ent["bytes"] if per is None else per.get(device_name, 0)
+        root = path.split("/", 1)[0]
+        out[root] = out.get(root, 0) + int(held)
+    return out
 
 
 def host_rss_bytes() -> int:
@@ -286,7 +354,7 @@ def _device_stats() -> Optional[List[dict]]:
             if not st:
                 continue
             rows.append({
-                "device": f"{d.platform}:{d.id}",
+                "device": _device_name(d),
                 "bytes_in_use": int(st.get("bytes_in_use", 0)),
                 "peak_bytes_in_use": int(st.get("peak_bytes_in_use", 0)),
                 "bytes_limit": int(st.get("bytes_limit", 0)),
@@ -305,22 +373,43 @@ def _device_stats() -> Optional[List[dict]]:
     return rows
 
 
-def sample_watermark(tag: str, **fields) -> Optional[dict]:
+def sample_watermark(tag: str, synced: bool = False, wait_for=None,
+                     **fields) -> Optional[dict]:
     """Poll device memory and publish one ``memory_watermark`` event plus
     the ``hbm_bytes_in_use`` / ``hbm_peak_bytes`` gauges.  Returns the
     sample dict, or None when the layer is off or the backend has no
-    stats (soft-fail: never raises)."""
+    stats (soft-fail: never raises).
+
+    ``bytes_in_use`` is the SUM over the local devices and ``peak_bytes``
+    the MAXIMUM, so the two cannot be subtracted on a mesh: ``fullest`` is
+    the one row of the device with the largest ``peak_bytes_in_use`` (the
+    device a harness's peak is read on), ``ledger`` what the ledger holds
+    on that device by first path part (:func:`ledger_on`) and
+    ``ledger_bytes`` its sum.  ``synced`` says that the caller has just
+    waited for the device in the span the sample is taken in, so that
+    ``bytes_in_use`` is what is resident with nothing in flight; with
+    ``wait_for`` (arrays) the sampler waits for them itself, after it has
+    seen that the backend has stats, and the sample is synced."""
     global _last_watermark
     if not obs_enabled():
         return None
     rows = _device_stats()
     if rows is None:
         return None
+    if wait_for is not None:
+        import jax
+
+        jax.block_until_ready(wait_for)
+        rows, synced = _device_stats() or rows, True
     in_use = sum(r["bytes_in_use"] for r in rows)
     peak = max(r["peak_bytes_in_use"] for r in rows)
     limit = sum(r["bytes_limit"] for r in rows)
+    fullest = max(rows, key=lambda r: r["peak_bytes_in_use"])
+    held = ledger_on(fullest["device"])
     sample = {"tag": str(tag), "bytes_in_use": in_use,
-              "peak_bytes": peak, "bytes_limit": limit, "devices": rows}
+              "peak_bytes": peak, "bytes_limit": limit, "devices": rows,
+              "fullest": dict(fullest), "ledger_bytes": sum(held.values()),
+              "ledger": held, "synced": bool(synced)}
     gauge("hbm_bytes_in_use").set(in_use)
     gauge("hbm_peak_bytes").set(peak)
     with _wm_lock:

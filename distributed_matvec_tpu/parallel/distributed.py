@@ -1057,6 +1057,8 @@ class DistributedEngine:
                     log_debug(f"plan pass1 shard {d}: rows {e}/{M}")
                 for p in range(D):
                     fold_unique(pend[d][p])
+            # every chunk's results were fetched
+            obs_memory.sample_watermark("plan/count", synced=True)
 
         # -- pass 1b: resolve unique targets against each peer's rows (one
         #    peer resident at a time) ------------------------------------
@@ -1088,6 +1090,7 @@ class DistributedEngine:
                     pend[d][p] = []
                 del peer
             del pend
+            obs_memory.sample_watermark("plan/resolve")
 
         # -- query lists: the sector check agreed, the split chosen, what
         #    each peer reads from each shard --------------------------------
@@ -1149,6 +1152,7 @@ class DistributedEngine:
                             qin_rows[d][q] = buf[d]
             qin_shards = [qin_rows.get(d) for d in range(D)]
             self._qin = self._assemble_sharded(qin_shards)
+            obs_memory.sample_watermark("plan/queries")
 
         with obs_trace.span("plan/pack", kind="phase"):
             W = self._c_W if compact else 0.0
@@ -1301,8 +1305,13 @@ class DistributedEngine:
                     self._ell_tail = (self._assemble_sharded(trow_shards),
                                       self._assemble_sharded(tidx_shards),
                                       self._assemble_sharded(tcf_shards))
+            # the shards' uploads are not waited for
+            obs_memory.sample_watermark("plan/pack")
         _mem_h.release()           # stream staging gone; tables resident
-        obs_memory.sample_watermark("plan_upload/distributed")
+        # the build's close (this runs under ``engine_init/build_plan``):
+        # the tables resident and waited for
+        obs_memory.sample_watermark("plan_upload/distributed",
+                                    wait_for=self.structure_arrays())
 
     def _finish_compact_aux(self, n_all_dev) -> None:
         """Derived compact-mode device arrays (recomputed on cache restore).

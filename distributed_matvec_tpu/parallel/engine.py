@@ -677,7 +677,11 @@ def register_engine_memory(eng, engine_kind: str) -> None:
         if getattr(eng, "plan_bytes_raw", None):
             ctx["plan_bytes_raw"] = int(eng.plan_bytes_raw)
     obs_memory.emit_ledger(f"engine_init/{engine_kind}", **ctx)
-    obs_memory.sample_watermark(f"engine_init/{engine_kind}")
+    # the built engine: its arrays are in the ledger, the build's
+    # temporaries are gone, and the device is waited for, so that resident
+    # bytes can be held against the ledger's
+    obs_memory.sample_watermark(f"engine_init/{engine_kind}",
+                                wait_for=eng.memory_arrays())
 
 
 def analyze_bound_apply(eng, engine_kind: str, x):
@@ -1102,6 +1106,9 @@ class LocalEngine:
                                     phase="init", n_states=int(n))
                     build_span.add(**self._ell_counts,
                                    **gather_table_counts(self))
+                    obs_memory.sample_watermark(
+                        "engine_init/build_structure",
+                        wait_for=self._ell_blocks)
                 self._save_structure(structure_cache, soft=soft_save)
             self._matvec = self._make_ell_matvec()
             self._checked = True                  # validated at build time
@@ -1353,6 +1360,7 @@ class LocalEngine:
                         norms_c[ci], jnp.int32(ci * b))
             with obs_trace.span("device_wait", kind="phase", at="ell_fill"):
                 bad = int(bad)
+            obs_memory.sample_watermark("ell/fill", synced=True)
         if bad:
             raise RuntimeError(
                 f"{bad} generated matrix elements map outside the basis "
@@ -1428,6 +1436,7 @@ class LocalEngine:
                                 at="ell_count"):
                 hist = np.asarray(hist)
             stair, levels, plan = self._plan_levels(hist, passes=1)
+            obs_memory.sample_watermark("ell/count", synced=True)
         self._ell_pos_of = None
         if not stair:
             Tmax = levels[0][1]
@@ -1465,6 +1474,8 @@ class LocalEngine:
             self._ell_blocks = tuple(
                 tuple(zip(ib, cb)) for ib, cb in
                 zip(idx_blocks, cut("ell_stair_coeff", coeff_buf)))
+            # the coefficient pieces' programs are still in flight
+            obs_memory.sample_watermark("ell/stair_levels")
 
     def _build_ell_ranges(self, R: int, W: int) -> None:
         """The structure where ``x`` is too long to be a gather table in
@@ -1551,6 +1562,8 @@ class LocalEngine:
                             bad += fetch(*queue.pop(0))
                     bad += sum(fetch(*item) for item in queue)
                     del queue, alphas_c, norms_c
+                    obs_memory.sample_watermark("ell/fill", synced=True,
+                                                table_range=r)
                 if bad:
                     raise RuntimeError(
                         f"{bad} generated matrix elements map outside the "
@@ -1577,6 +1590,9 @@ class LocalEngine:
                         del pieces
                     widest = max(widest, width)
                     del tabs, cnt
+                    # the pieces' uploads are not waited for
+                    obs_memory.sample_watermark("ell/stair_levels",
+                                                table_range=r)
         self._ell_blocks = tuple(blocks)
         self._ell_pos_of = tuple(pos_of)
         self._ell_range_rows = W
@@ -1622,6 +1638,7 @@ class LocalEngine:
             with obs_trace.span("device_wait", kind="phase",
                                 at="ell_count_rows"):
                 bad = int(bad)
+            obs_memory.sample_watermark("ell/count_rows", synced=True)
         if bad:
             raise RuntimeError(
                 f"{bad} generated matrix elements map outside the basis "
@@ -1697,6 +1714,8 @@ class LocalEngine:
                 alphas, norms = alphas[row_of], norms[row_of]
                 del row_of
             del nnz
+            # the sort and the two gathers may still run
+            obs_memory.sample_watermark("ell/row_order")
         alphas_c, norms_c = alphas.reshape(C, b), norms.reshape(C, b)
 
         with obs_trace.span("ell/pack", kind="phase"):
@@ -1720,6 +1739,7 @@ class LocalEngine:
                                   jnp.int32(ci * b))
             with obs_trace.span("device_wait", kind="phase", at="ell_pack"):
                 jax.block_until_ready(bufs)
+            obs_memory.sample_watermark("ell/pack", synced=True)
         del alphas, norms, alphas_c, norms_c
 
         def cut(tab, cuts):
@@ -1744,6 +1764,8 @@ class LocalEngine:
                 tuple((tabs[li][0][r0, rows], tabs[li][1][r0, rows])
                       for li, r0, rows in blk)
                 for blk in plan)
+            # a level cut into pieces was waited for; one kept whole was not
+            obs_memory.sample_watermark("ell/cut")
 
     def _build_compact(self) -> None:
         """4-bytes-per-entry structure for real sectors with one off-diagonal

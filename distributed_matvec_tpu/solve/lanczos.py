@@ -1678,6 +1678,23 @@ def _lanczos_impl(
         V = V.at[0].set((v / nrm.astype(dtype)).astype(dtype))
         alph_d = jnp.zeros(mcap, jnp.float64)
         bet_d = jnp.zeros(mcap, jnp.float64)
+        # the Krylov buffer is the solver's whole device footprint —
+        # register it in the memory ledger for the solve's lifetime, with
+        # what each device holds of it (released at normal completion; a
+        # failed solve keeps the entry live, which is what an OOM forensics
+        # report should show)
+        mem_h = obs_memory.NULL_HANDLE
+        if obs_enabled():
+            mem_h = obs_memory.track_tree(
+                f"solver/{obs_memory.next_instance('lanczos')}/krylov_basis",
+                (V, alph_d, bet_d), rows=int(_buffer_rows(mcap)))
+        # the buffer allocated, before any block program.  Not waited for:
+        # the probe apply is still in flight, and a wait here would hold
+        # the host's build of the block programs back behind it (0.45 s a
+        # solve at chain_32_symm: PERF.md, PR 37).  The allocator counts a
+        # buffer when its program is dispatched, so the peak is this
+        # span's all the same
+        obs_memory.sample_watermark("lanczos/start")
 
     # Block programs compiled lazily: ONE full-sweep runner (dynamic step
     # count) and, in selective mode, a window runner per distinct block
@@ -1713,17 +1730,6 @@ def _lanczos_impl(
                                  omega_tr.state, operands)
 
     restart_fn = _make_restart(mcap, shape, dtype, l_restart)
-
-    # the Krylov buffer is the solver's whole device footprint — register
-    # it in the memory ledger for the solve's lifetime (released at normal
-    # completion; a failed solve keeps the entry live, which is what an
-    # OOM forensics report should show)
-    mem_h = obs_memory.NULL_HANDLE
-    if obs_enabled():
-        mem_h = obs_memory.track(
-            f"solver/{obs_memory.next_instance('lanczos')}/krylov_basis",
-            int(V.nbytes) + int(alph_d.nbytes) + int(bet_d.nbytes),
-            rows=int(_buffer_rows(mcap)))
 
     lock_theta = np.zeros(0)
     lock_sigma = np.zeros(0)
@@ -1896,6 +1902,8 @@ def _lanczos_impl(
                 m = l
                 pending_full = True
                 n_restarts += 1
+                # the restart's program is in flight
+                obs_memory.sample_watermark("lanczos/restart")
         nsteps = min(check_every, mcap - m, max_iters - total_iters)
         # tiny remainder stubs (< half a block) reuse the prewarmed
         # dynamic-step full runner: a fresh window program would spend
@@ -1925,6 +1933,9 @@ def _lanczos_impl(
                 operands)
             with obs_trace.span("lanczos/wait", kind="phase"):
                 jax.block_until_ready(V)   # one collective program in flight
+                # what stays on the chip between block programs, and how
+                # far the one just run pushed the peak
+                obs_memory.sample_watermark("lanczos/wait", synced=True)
             block.enter_context(
                 obs_trace.span("lanczos/check", kind="phase"))
             # what the program reports it ran, the crossing step included
@@ -2052,6 +2063,8 @@ def _lanczos_impl(
                 e = E[i]
                 enrm = jnp.sqrt(jnp.real(_vdot(e, e)))
                 evecs.append((e / enrm.astype(dtype)).reshape(shape))
+            # the combination and the norms are in flight
+            obs_memory.sample_watermark("lanczos/epilogue")
     obs_emit("solver_end", solver="lanczos", iters=int(total_iters),
              converged=bool(converged),
              eigenvalues=[float(t) for t in np.atleast_1d(theta)[:kk]]

@@ -145,6 +145,268 @@ def test_watermark_event_shape_with_fake_stats(clean_obs, monkeypatch):
 
 
 # ---------------------------------------------------------------------------
+# the sample's layers (PR 37): the fullest device's own row, the ledger on
+# that device, ``synced``, the open span; samples where the work happens
+
+
+class FakeAllocator:
+    """``_device_stats`` for the CPU backend, which reports none: a
+    device's ``bytes_in_use`` is what ``jax.live_arrays()`` holds on it
+    (each buffer once), its peak the highest reading so far."""
+
+    def __init__(self, n_devices=1):
+        import jax
+
+        self.devices = jax.local_devices()[:n_devices]
+        self.peak = {}
+
+    def __call__(self):
+        import jax
+
+        use = {f"{d.platform}:{d.id}": 0 for d in self.devices}
+        seen = set()
+        for arr in jax.live_arrays():
+            for sh in arr.addressable_shards:
+                name = f"{sh.device.platform}:{sh.device.id}"
+                key = (name, sh.data.unsafe_buffer_pointer())
+                if name in use and key not in seen:
+                    seen.add(key)
+                    use[name] += int(sh.data.nbytes)
+        rows = []
+        for name, b in use.items():
+            self.peak[name] = max(self.peak.get(name, 0), b)
+            rows.append({"device": name, "bytes_in_use": b,
+                         "peak_bytes_in_use": self.peak[name],
+                         "bytes_limit": 16 * 10 ** 9})
+        return rows
+
+
+def _samples_by_span():
+    """{span name: [memory_watermark events taken in a span of that name]},
+    joined through the envelope's ``span_id`` as a reader does."""
+    names = {e["span_id"]: e["name"] for e in obs.events("span")}
+    out = {}
+    for e in obs.events("memory_watermark"):
+        out.setdefault(names.get(e.get("span_id")), []).append(e)
+    return out
+
+
+def test_watermark_sample_layers(clean_obs, monkeypatch):
+    """One event says how much is resident on the fullest device, how much
+    of that the ledger owns there, whether the device was waited for, and
+    in which span it was taken."""
+    rows = [{"device": "tpu:0", "bytes_in_use": 700, "peak_bytes_in_use": 900,
+             "bytes_limit": 1000},
+            {"device": "tpu:1", "bytes_in_use": 800, "peak_bytes_in_use": 950,
+             "bytes_limit": 1000}]
+    monkeypatch.setattr(obs_mem, "_device_stats", lambda: rows)
+    obs_mem.track("engine/x:0/tables", 400, device="device",
+                  per_device={"tpu:0": 300, "tpu:1": 100})
+    obs_mem.track("solver/lanczos:0/krylov_basis", 800,
+                  per_device={"tpu:0": 400, "tpu:1": 400})
+    obs_mem.track("plan/p:0/staging", 50)          # no split: whole on each
+    obs_mem.track("plan/s:0/host", 10 ** 6, device="host")
+    assert obs_mem.ledger_on("tpu:0") == {"engine": 300, "solver": 400,
+                                          "plan": 50}
+    with obs.span("lanczos/wait", kind="phase") as sp:
+        s = obs_mem.sample_watermark("lanczos/wait", synced=True)
+    ev = obs.events("memory_watermark")[-1]
+    # the fields obs_report reads stay: the sum beside the maximum
+    assert ev["bytes_in_use"] == 1500 and ev["peak_bytes"] == 950
+    assert ev["fullest"] == rows[1] and s["fullest"] == rows[1]
+    assert ev["ledger"] == {"engine": 100, "solver": 400, "plan": 50}
+    assert ev["ledger_bytes"] == 550 and ev["synced"] is True
+    assert ev["fullest"]["bytes_in_use"] - ev["ledger_bytes"] == 250
+    assert ev["span_id"] == sp.sid
+    # not synced unless the caller says so; wait_for waits, then says so
+    assert obs_mem.sample_watermark("apply/local")["synced"] is False
+    import jax.numpy as jnp
+    assert obs_mem.sample_watermark(
+        "lanczos/start", wait_for=jnp.zeros(4))["synced"] is True
+    # a re-pointed entry forgets the split of its old size
+    h = obs_mem.track("solver/b:0/block", 100, per_device={"tpu:1": 25})
+    assert obs_mem.ledger_on("tpu:1")["solver"] == 425
+    h.set("solver/b:0/block", 200)
+    assert obs_mem.ledger_on("tpu:1")["solver"] == 600
+
+
+def test_track_tree_records_what_each_device_holds(clean_obs):
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    devs = jax.local_devices()[:4]
+    mesh = Mesh(np.array(devs), ("shards",))
+    sharded = jax.device_put(jnp.zeros((6, 4, 32)),
+                             NamedSharding(mesh, P(None, "shards")))
+    whole = jax.device_put(jnp.zeros(8), NamedSharding(mesh, P()))
+    one = jnp.zeros(5, jnp.int32)
+    obs_mem.track_tree("solver/t:0/krylov_basis", (sharded, whole, one))
+    ent = obs_mem.ledger_entries()["solver/t:0/krylov_basis"]
+    assert ent["bytes"] == 6 * 4 * 32 * 8 + 64 + 20      # global, as before
+    names = [f"{d.platform}:{d.id}" for d in devs]
+    first = f"{one.devices().pop().platform}:{one.devices().pop().id}"
+    for name in names:
+        # a quarter of the sharded buffer, the whole of the replicated one
+        assert ent["per_device"][name] == 6 * 32 * 8 + 64 \
+            + (20 if name == first else 0)
+    # a host array among the leaves: the split is not known
+    obs_mem.track_tree("x/t", (np.zeros(3), one))
+    assert "per_device" not in obs_mem.ledger_entries()["x/t"]
+
+
+def test_sharded_krylov_buffer_is_a_quarter_a_device(clean_obs, monkeypatch):
+    """On four devices the solver's entry counts what ONE device holds of
+    the sharded buffer, so that resident less ledger is not negative."""
+    from distributed_matvec_tpu.parallel.distributed import DistributedEngine
+    from distributed_matvec_tpu.solve import lanczos
+
+    monkeypatch.setattr(obs_mem, "_device_stats", FakeAllocator(4))
+    op = build_heisenberg(12, 6, None, ())
+    eng = DistributedEngine(op, n_devices=4, batch_size=64)
+    init = [e for e in obs.events("memory_watermark")
+            if e["tag"] == "engine_init/distributed"][-1]
+    assert init["synced"] and init["ledger"]["engine"] > 0
+    assert init["ledger"]["engine"] < obs_mem.ledger_total("engine")
+    assert init["fullest"]["bytes_in_use"] >= init["ledger_bytes"]
+    lanczos(eng.matvec, k=1, v0=eng.random_hashed(seed=42), tol=1e-10,
+            max_iters=64, max_basis_size=32, compute_eigenvectors=True)
+    waits = _samples_by_span()["lanczos/wait"]
+    rows = -(-(32 + 1) // 8) * 8                       # _buffer_rows(32)
+    quarter = rows * eng.shard_size * 8
+    assert waits and all(e["synced"] for e in waits)
+    for e in waits:
+        # V's quarter (alph and bet, 2 x 256 B, sit on one device)
+        assert quarter <= e["ledger"]["solver"] <= quarter + 512
+        assert e["fullest"]["bytes_in_use"] - e["ledger_bytes"] >= 0
+        assert e["fullest"]["bytes_in_use"] < 4 * quarter
+
+
+def test_lanczos_solve_samples(clean_obs, monkeypatch):
+    """One synced sample under every ``lanczos/wait``, one (not synced:
+    their programs are in flight) under ``lanczos/start`` (the buffer
+    allocated), ``lanczos/restart`` and ``lanczos/epilogue``; the solver's
+    entry in each of them."""
+    from distributed_matvec_tpu.parallel.engine import LocalEngine
+    from distributed_matvec_tpu.solve import lanczos
+
+    monkeypatch.setattr(obs_mem, "_device_stats", FakeAllocator())
+    op = build_heisenberg(16, 8, None, ())
+    eng = LocalEngine(op, mode="ell")
+    eng.matvec(np.ones(op.basis.number_states))     # apply 0: the cadence's
+    obs.reset()                      # events only; the ledger keeps the engine
+    res = lanczos(eng.matvec, op.basis.number_states, k=1, tol=1e-10,
+                  max_iters=200, max_basis_size=32, compute_eigenvectors=True)
+    assert res.converged and res.restarts > 0
+    spans = {}
+    for e in obs.events("span"):
+        spans.setdefault(e["name"], []).append(e["span_id"])
+    by = _samples_by_span()
+    assert sorted(e["span_id"] for e in by["lanczos/wait"]) \
+        == sorted(spans["lanczos/wait"])
+    assert all(e["synced"] for e in by["lanczos/wait"])
+    # the probe apply is in flight there: nothing waits for it
+    assert [e["synced"] for e in by["lanczos/start"]] == [False]
+    assert len(by["lanczos/epilogue"]) == 1
+    assert sorted(e["span_id"] for e in by["lanczos/restart"]) \
+        == sorted(spans["lanczos/restart"])
+    nbytes = 40 * op.basis.number_states * 8           # _buffer_rows(32)
+    for e in by["lanczos/start"] + by["lanczos/wait"] + by["lanczos/restart"]:
+        assert nbytes <= e["ledger"]["solver"] <= nbytes + 512
+        assert e["ledger"]["engine"] == obs_mem.ledger_total("engine")
+        assert e["fullest"]["bytes_in_use"] >= e["ledger_bytes"]
+    # no sample anywhere else in the solve (the probe apply is number 1 of
+    # this engine: the eager cadence stays what it was)
+    assert set(by) == {"lanczos/start", "lanczos/wait", "lanczos/restart",
+                       "lanczos/epilogue"}
+
+
+@pytest.mark.parametrize("build", ["one_pass", "ranges", "two_pass",
+                                   "plan"])
+def test_build_samples_every_pass(clean_obs, monkeypatch, build):
+    """A build takes one sample under every pass it runs and a synced one
+    when it closes; the built engine's own sample waits for the device."""
+    from distributed_matvec_tpu.parallel import engine as E
+    from distributed_matvec_tpu.utils.config import get_config, update_config
+
+    monkeypatch.setattr(obs_mem, "_device_stats", FakeAllocator(
+        2 if build == "plan" else 1))
+    # 12,870 rows of unequal width: every build cuts a staircase
+    op = build_heisenberg(16, 8, None, ())
+    op.basis.build()
+    budget = get_config().ell_build_budget_gb
+    try:
+        if build == "plan":
+            from distributed_matvec_tpu.parallel.distributed import (
+                DistributedEngine)
+
+            DistributedEngine(op, n_devices=2, batch_size=1024)
+            passes = {"plan/count", "plan/resolve", "plan/queries",
+                      "plan/pack"}
+            close, kind = "engine_init/build_plan", "distributed"
+        else:
+            if build == "ranges":
+                n_pad = E.pad_to_multiple(op.basis.number_states, 1024)
+                monkeypatch.setattr(E, "GATHER_VMEM_BYTES", 16 * n_pad - 16)
+                assert E.gather_table_ranges(n_pad, 3)[0] > 1
+            elif build == "two_pass":
+                update_config(ell_build_budget_gb=1e-9)
+            E.LocalEngine(op, mode="ell", batch_size=1024)
+            passes = {"one_pass": {"ell/fill", "ell/count",
+                                   "ell/stair_levels"},
+                      "ranges": {"ell/fill", "ell/stair_levels"},
+                      "two_pass": {"ell/count_rows", "ell/row_order",
+                                   "ell/pack", "ell/cut"}}[build]
+            close, kind = "engine_init/build_structure", "local"
+    finally:
+        update_config(ell_build_budget_gb=budget)
+    ran = {}
+    for e in obs.events("span"):
+        if e["name"].startswith(("ell/", "plan/")):
+            ran.setdefault(e["name"], []).append(e["span_id"])
+    assert set(ran) == passes
+    by = _samples_by_span()
+    for name, ids in ran.items():
+        assert sorted(e["span_id"] for e in by[name]) == sorted(ids), name
+    assert [e["synced"] for e in by[close]] == [True]
+    built = [e for e in obs.events("memory_watermark")
+             if e["tag"] == f"engine_init/{kind}"]
+    assert len(built) == 1 and built[0]["synced"]
+    # all of the engine on one chip, a shard's share of it on a mesh
+    held, total = built[0]["ledger"]["engine"], obs_mem.ledger_total("engine")
+    assert held == total if kind == "local" else total / 2 <= held < total
+    assert built[0]["fullest"]["bytes_in_use"] >= built[0]["ledger_bytes"]
+    # the passes that end in a wait say so
+    for name in ("ell/fill", "ell/count", "ell/count_rows", "ell/pack",
+                 "plan/count"):
+        assert all(e["synced"] for e in by.get(name, []))
+
+
+def test_samples_disabled_noop(clean_obs, monkeypatch):
+    """``DMT_OBS=off``: a build and a solve take no sample and wait for
+    nothing on the sampler's account; ``span`` is the shared null context."""
+    from distributed_matvec_tpu.obs import trace as obs_trace
+    from distributed_matvec_tpu.parallel.engine import LocalEngine
+    from distributed_matvec_tpu.solve import lanczos
+
+    monkeypatch.setenv("DMT_OBS", "off")
+    obs.reset_all()
+
+    def explode(*a, **k):
+        raise AssertionError("memory layer touched while disabled")
+
+    monkeypatch.setattr(obs_mem, "_device_stats", explode)
+    monkeypatch.setattr(obs_mem, "ledger_on", explode)
+    assert obs.span("lanczos/wait", kind="phase") is obs_trace._NULL_CM
+    assert obs_mem.sample_watermark("x", wait_for=object()) is None
+    op = build_heisenberg(10, 5, None, ())
+    eng = LocalEngine(op, mode="ell")
+    lanczos(eng.matvec, op.basis.number_states, k=1, max_iters=32,
+            tol=1e-10, compute_eigenvectors=True)
+    assert obs.events() == [] and obs_mem.ledger_total() == 0
+
+
+# ---------------------------------------------------------------------------
 # ell_nbytes parity: reported totals == summed nbytes of live table leaves
 # for EVERY engine mode (the hand-maintained totals this PR derives from
 # structure_arrays(); these tests hand-enumerate the expected leaves so a
@@ -583,6 +845,41 @@ def test_obs_report_summarize_memory_section(clean_obs, tmp_path,
     rep.print_summary(s)                 # renderer must not throw
     # report --memory renders the same digest
     assert rep.main(["report", str(run), "--memory"]) == 0
+
+
+def test_obs_report_memory_digest_reads_the_fullest_device(clean_obs,
+                                                           tmp_path,
+                                                           monkeypatch,
+                                                           capsys):
+    """Where a sample names its fullest device the digest and the watch
+    frame show that device's in-use beside its peak, not the sum over
+    devices beside the maximum."""
+    rep = _load_tool("obs_report")
+    run = tmp_path / "run"
+    monkeypatch.setenv("DMT_OBS_DIR", str(run))
+    rows = [{"device": "tpu:0", "bytes_in_use": 3000,
+             "peak_bytes_in_use": 5000, "bytes_limit": 16000},
+            {"device": "tpu:1", "bytes_in_use": 2500,
+             "peak_bytes_in_use": 9000, "bytes_limit": 16000}]
+    monkeypatch.setattr(obs_mem, "_device_stats", lambda: rows)
+    obs_mem.track("solver/lanczos:0/krylov_basis", 4000,
+                  per_device={"tpu:0": 2000, "tpu:1": 2000})
+    obs_mem.sample_watermark("lanczos/wait", synced=True)
+    obs.flush()
+    events = rep.load_events(str(run))
+    obs.reset()
+    mem = rep.run_summary(events)["memory"]
+    assert mem["peak_hbm_bytes"][0] == 9000
+    assert mem["fullest"][0]["device"] == "tpu:1"
+    assert mem["fullest"][0]["bytes_in_use"] == 2500
+    assert mem["fullest"][0]["ledger_bytes"] == 2000
+    rep.print_memory_section(mem)
+    out = capsys.readouterr().out
+    assert "fullest device tpu:1 at lanczos/wait" in out
+    assert "work in flight" not in out
+    state = rep.watch_state(events)
+    assert state["per_rank"][0]["hbm"] == 2500          # not the sum, 5500
+    assert state["per_rank"][0]["hbm_peak"] == 9000
 
 
 def test_obs_report_rank_table_peak_hbm_column(tmp_path):

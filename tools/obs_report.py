@@ -365,10 +365,14 @@ def _cache_rates(snap: dict) -> dict:
 def memory_summary(events: List[dict], top_n: int = 8) -> dict:
     """Memory observability digest of one run: the LAST ``memory_ledger``
     snapshot per rank (top-N allocations by bytes), max watermark peak per
-    rank, executable analyses (one per compiled specialization, last
-    wins), and any OOM ``memory_report`` events."""
+    rank, the fullest device's own row of each rank's last watermark with
+    what the ledger held on it (``fullest``: in-use and peak of ONE device,
+    which can be subtracted; a sample without the field gives none),
+    executable analyses (one per compiled specialization, last wins), and
+    any OOM ``memory_report`` events."""
     ledgers: Dict[int, dict] = {}
     peaks: Dict[int, int] = {}
+    fullest: Dict[int, dict] = {}
     analyses: Dict[str, dict] = {}
     ooms = []
     for ev in events:
@@ -378,6 +382,11 @@ def memory_summary(events: List[dict], top_n: int = 8) -> dict:
         elif kind == "memory_watermark":
             r = _rank_of(ev)
             peaks[r] = max(peaks.get(r, 0), int(ev.get("peak_bytes") or 0))
+            if ev.get("fullest"):
+                fullest[r] = dict(ev["fullest"], tag=ev.get("tag"),
+                                  synced=bool(ev.get("synced")),
+                                  ledger_bytes=int(ev.get("ledger_bytes")
+                                                   or 0))
         elif kind == "memory_analysis":
             analyses[str(ev.get("key") or ev.get("program"))] = {
                 k: ev.get(k) for k in
@@ -402,6 +411,7 @@ def memory_summary(events: List[dict], top_n: int = 8) -> dict:
                         "table_bytes") if k in ev}
     return {"ledger_total_bytes": totals, "top_allocations": top,
             "ledger_context": contexts, "peak_hbm_bytes": peaks,
+            "fullest": fullest,
             "executables": analyses, "oom_events": ooms}
 
 
@@ -777,6 +787,14 @@ def print_memory_section(mem: dict) -> None:
         print(f"  rank {r}: ledger {_fmt_bytes(totals.get(r))} resident, "
               f"peak HBM {_fmt_bytes(peaks.get(r))}"
               + (f"  ({note})" if note else ""))
+        full = (mem.get("fullest") or {}).get(r)
+        if full:
+            print(f"    fullest device {full.get('device')} at "
+                  f"{full.get('tag')}: "
+                  f"{_fmt_bytes(full.get('bytes_in_use'))} in use"
+                  f"{'' if full.get('synced') else ' (work in flight)'}, "
+                  f"{_fmt_bytes(full.get('ledger_bytes'))} of it in the "
+                  f"ledger, peak {_fmt_bytes(full.get('peak_bytes_in_use'))}")
     for r, rows in sorted((mem.get("top_allocations") or {}).items()):
         print(f"  top allocations (rank {r}):")
         for row in rows:
@@ -1444,7 +1462,10 @@ def watch_state(events, window_s: float = _WATCH_WINDOW_S,
             health["stalls"] += 1
         elif kind == "memory_watermark":
             row = per_rank[r]
-            if ev.get("bytes_in_use") is not None:
+            if ev.get("fullest"):
+                # one device's in-use beside that device's peak
+                row["hbm"] = int(ev["fullest"]["bytes_in_use"])
+            elif ev.get("bytes_in_use") is not None:
                 row["hbm"] = int(ev["bytes_in_use"])
             if ev.get("peak_bytes") is not None:
                 row["hbm_peak"] = max(row["hbm_peak"] or 0,
