@@ -7,7 +7,8 @@ from .evolve import EvolveResult, krylov_evolve  # noqa: F401
 from .kpm import (KPMResult, exact_moments, jackson_kernel,  # noqa: F401
                   kpm_dos, kpm_moments, kpm_spectral_function,
                   lorentz_kernel, reconstruct_dos, spectral_bounds)
-from .lanczos import LanczosResult, lanczos, lanczos_block  # noqa: F401
+from .lanczos import (LanczosResult, krylov_buffer, lanczos,  # noqa: F401
+                      lanczos_block)
 from .lobpcg import lobpcg  # noqa: F401
 
 # module aliases so the refusal-message pointers ("solve.kpm",

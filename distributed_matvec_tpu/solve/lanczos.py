@@ -12,6 +12,13 @@ native C/Fortran library we don't vendor; the TPU-native replacement is a
   program (``lax.fori_loop``) with donated buffers — the host only syncs the
   small (α, β) arrays every ``check_every`` steps for the convergence test,
   so the device never waits on a per-iteration host round-trip.
+* The buffer is born once, as the output of one program
+  (:func:`krylov_buffer`: zeros with the normalised start vector in row 0),
+  on the start vector's device or sharded over its mesh as the vector is,
+  and from then on only ever donated: to the block programs, to the thick
+  restart, to the setter that writes a checkpoint's rows.  Nothing in this
+  module updates an array of the buffer's shape eagerly, so no second
+  array of its size is ever live between programs.
 * Memory is bounded by **thick restarting** (the TRLan scheme): when the
   basis hits ``max_basis_size`` (the analog of the reference's
   ``kMaxBasisSize``, Diagonalize.chpl:169), the ``min_restart_size`` lowest
@@ -32,12 +39,13 @@ from __future__ import annotations
 
 import contextlib
 from dataclasses import dataclass
-from functools import partial
+from functools import lru_cache, partial
 from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 from scipy.linalg import eigh
 
 from ..obs import health as obs_health
@@ -46,7 +54,7 @@ from ..obs import trace as obs_trace
 from ..obs.events import emit as obs_emit, flush as obs_flush, obs_enabled
 from ..utils import faults, preempt
 
-__all__ = ["LanczosResult", "lanczos", "lanczos_block"]
+__all__ = ["LanczosResult", "krylov_buffer", "lanczos", "lanczos_block"]
 
 
 def _emit_trace(solver: str, it: int, m: int, theta, res,
@@ -970,6 +978,61 @@ def _make_restart(mcap, shape, dtype, l):
     return restart
 
 
+@lru_cache(maxsize=16)
+def _make_buffer_programs(nrows, shape, dtype, sharding):
+    """The two programs through which a Krylov buffer ``[nrows, *shape]``
+    comes to hold rows it did not compute: ``make(row)``, the buffer zero
+    but for ``row`` in row 0, and ``set_row(V, i, row)``, which writes row
+    ``i`` of a donated buffer (a checkpoint's restore).  Inside one program
+    the zero fill and the row write are one output buffer, and a donated
+    buffer is updated in place, so neither ever holds a second array of the
+    buffer's size: an eager ``jnp.zeros(...).at[0].set(row)`` donates
+    nothing and held two (9.42 GB where 5.37 are resident at chain_32_symm:
+    PERF.md, PR 37).
+
+    ``sharding`` is the rows' ``NamedSharding`` (the hashed ``[D, M(, 2)]``
+    vectors of ``DistributedEngine``) or None.  Over a mesh both programs
+    put their output out with the row's spec behind an unsharded row axis,
+    so every chip allocates its own share from birth; an uncommitted
+    ``jnp.zeros`` landed whole on device 0.  One pair per (rows, shape,
+    dtype, sharding), kept between solves so that nothing is re-traced; a
+    long-lived process that solves in many vector spaces keeps the last
+    sixteen."""
+    out = {} if sharding is None else {"out_shardings": NamedSharding(
+        sharding.mesh, PartitionSpec(None, *sharding.spec))}
+
+    @partial(jax.jit, **out)
+    def make(row):
+        return jnp.zeros((nrows,) + shape, dtype).at[0].set(row)
+
+    @partial(jax.jit, donate_argnums=(0,), **out)
+    def set_row(V, i, row):
+        return jax.lax.dynamic_update_index_in_dim(
+            V, row.astype(dtype), i, axis=0)
+
+    return make, set_row
+
+
+def _buffer_programs(mcap: int, row):
+    """:func:`_make_buffer_programs` for the buffer whose rows are laid out
+    as ``row`` is: what selects the sharded form is the sharding the row
+    carries, nothing else."""
+    sharding = getattr(row, "sharding", None)
+    if not isinstance(sharding, NamedSharding):
+        sharding = None
+    return _make_buffer_programs(_buffer_rows(mcap), row.shape,
+                                 np.dtype(row.dtype), sharding)
+
+
+def krylov_buffer(mcap: int, row):
+    """The buffer a solve of at most ``mcap`` basis vectors runs in:
+    ``[_buffer_rows(mcap), *row.shape]`` of ``row``'s dtype, zero but for
+    ``row`` in row 0, on ``row``'s device or sharded over its mesh as
+    ``row`` is.  One program's output (see :func:`_make_buffer_programs`);
+    dispatched, not waited for."""
+    return _buffer_programs(mcap, row)[0](row)
+
+
 def lanczos_block(matvec: Callable, *args, **kwargs) -> LanczosResult:
     """Solve-span wrapper over :func:`_lanczos_block_impl` (see there for
     the full contract): the solver call is ONE ``solve`` span, each block
@@ -1588,7 +1651,8 @@ def _lanczos_impl(
     full-sweep run of a stopped block's remainder carries ``redo=True``
     with its own ``steps``, and restarts are on the result.
     Under the root the host loop is named where the device can wait for it:
-    ``lanczos/start`` (start vector and probe apply; the Krylov buffer),
+    ``lanczos/start`` (start vector and probe apply; then the one program
+    that makes the Krylov buffer, dispatched and not waited for),
     then per ``iteration`` ``lanczos/dispatch`` (``built`` says whether the
     block program was new to this call), ``lanczos/wait`` and
     ``lanczos/check`` (the recurrence's copies to the host, the ω tracker,
@@ -1627,7 +1691,8 @@ def _lanczos_impl(
     root.add(steps_counted=0, steps_run=0, probe_applies=0,
              programs_built=0, omega_stops=0, steps_discarded=0)
     # lanczos/start: the start vector and the eager probe apply (and below,
-    # once more, the Krylov buffer)
+    # once more: the start vector normalised and the one program that makes
+    # the Krylov buffer around it)
     with obs_trace.span("lanczos/start", kind="phase"):
         if v0 is None:
             if n is None:
@@ -1673,9 +1738,11 @@ def _lanczos_impl(
     n_reorth = 2 if full_reorth else 1
 
     with obs_trace.span("lanczos/start", kind="phase"):
-        V = jnp.zeros((_buffer_rows(mcap),) + shape, dtype)
         nrm = jnp.sqrt(jnp.real(_vdot(v, v)))
-        V = V.at[0].set((v / nrm.astype(dtype)).astype(dtype))
+        start = (v / nrm.astype(dtype)).astype(dtype)
+        make_buffer, set_row = _buffer_programs(mcap, start)
+        V = make_buffer(start)
+        del start                   # row 0 holds it now
         alph_d = jnp.zeros(mcap, jnp.float64)
         bet_d = jnp.zeros(mcap, jnp.float64)
         # the Krylov buffer is the solver's whole device footprint —
@@ -1688,12 +1755,14 @@ def _lanczos_impl(
             mem_h = obs_memory.track_tree(
                 f"solver/{obs_memory.next_instance('lanczos')}/krylov_basis",
                 (V, alph_d, bet_d), rows=int(_buffer_rows(mcap)))
-        # the buffer allocated, before any block program.  Not waited for:
-        # the probe apply is still in flight, and a wait here would hold
-        # the host's build of the block programs back behind it (0.45 s a
-        # solve at chain_32_symm: PERF.md, PR 37).  The allocator counts a
-        # buffer when its program is dispatched, so the peak is this
-        # span's all the same
+        # the buffer's program dispatched behind the probe apply, before
+        # any block program: in use are what is resident, the probe apply's
+        # vectors and ONE buffer (each chip's share of it on a mesh), with
+        # nothing of a buffer's size owned by nobody.  Not waited for: a
+        # wait here would hold the host's build of the block programs back
+        # behind the probe apply (0.45 s a solve at chain_32_symm: PERF.md,
+        # PR 37).  The allocator counts a buffer when its program is
+        # dispatched, so the sample reads it all the same
         obs_memory.sample_watermark("lanczos/start")
 
     # Block programs compiled lazily: ONE full-sweep runner (dynamic step
@@ -1817,8 +1886,10 @@ def _lanczos_impl(
                 log_debug("lanczos checkpoint basis exceeds max_basis_size; "
                           "starting fresh")
             else:
+                # each row through one program that donates the buffer:
+                # an eager update would copy the whole of it a row
                 for i, row in enumerate(got["V_rows"]):
-                    V = V.at[i].set(row)
+                    V = set_row(V, i, row)
                 na = min(int(got["m"]), mcap)
                 alph_d = alph_d.at[:na].set(
                     jnp.asarray(got["alph"][:na]))

@@ -757,6 +757,54 @@ def test_lanczos_programs_compile(one_chip, tpu_knobs, compiled, program):
         assert all(in_vmem for _, in_vmem in _gather_results(exe))
 
 
+@pytest.mark.parametrize("chips", [1, 4])
+def test_krylov_buffer_programs_compile(topo, one_chip, chips):
+    """The buffer's maker and its row setter (PR 38) at chain_32_symm's
+    sizes, on one chip and over the four-chip mesh: the maker takes one row
+    and puts out the buffer (each chip its quarter, sharded as the row is
+    behind an unsharded row axis); the setter aliases the buffer it is
+    given.  What the compiler holds inside them is written down here
+    because the allocator's ``peak_bytes_in_use`` does not show it: the f64
+    output is combined from two f32 halves (``X64Combine``), a temporary of
+    the buffer's size in the maker and one and a half in the setter, as in
+    every program that writes the buffer."""
+    from distributed_matvec_tpu.solve.lanczos import (
+        _buffer_programs, _buffer_rows)
+
+    rows = _buffer_rows(M_CAP)
+    if chips == 1:
+        row = _shapes(one_chip)((N,))
+        buffer = jax.ShapeDtypeStruct((rows, N), jnp.float64,
+                                      sharding=one_chip)
+    else:
+        _, row, mesh = _distributed_ell_engine(topo)
+        buffer = jax.ShapeDtypeStruct(
+            (rows,) + row.shape, jnp.float64,
+            sharding=NamedSharding(mesh, P(None, *row.sharding.spec)))
+    make, set_row = _buffer_programs(M_CAP, row)
+    held = rows * int(np.prod(row.shape)) * 8 // chips   # a chip's share
+    index = jax.ShapeDtypeStruct((), jnp.int32)
+
+    exe = make.lower(row).compile()
+    m = exe.memory_analysis()
+    assert m.argument_size_in_bytes < 2 * held // rows
+    assert held <= m.output_size_in_bytes < 1.001 * held
+    assert m.alias_size_in_bytes == 0
+    assert m.temp_size_in_bytes < 1.001 * held
+    _fits(exe, "krylov buffer maker")
+    if chips == 4:
+        assert exe.output_shardings == buffer.sharding
+        assert "all-" not in exe.as_text()      # every chip fills its own
+
+    exe = set_row.lower(buffer, index, row).compile()
+    m = exe.memory_analysis()
+    assert held <= m.alias_size_in_bytes == m.output_size_in_bytes
+    assert m.temp_size_in_bytes < 1.6 * held
+    _fits(exe, "krylov buffer row setter")
+    if chips == 4:
+        assert exe.output_shardings == buffer.sharding
+
+
 def test_distributed_ell_apply_compiles_on_four_devices(tpu_knobs, compiled):
     """The hash-sharded ELL apply on a 4-device mesh built from the
     described devices: the emulated-f64 ``all_to_all`` under ``shard_map``
