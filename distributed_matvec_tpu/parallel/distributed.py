@@ -118,7 +118,8 @@ from ..utils.timers import TreeTimer
 from .engine import (SENTINEL_STATE, analyze_bound_apply, apply_diag_jit,
                      attach_traced_counter_check,
                      check_complex_backend, choose_ell_split,
-                     emit_engine_init, gather_coefficients_jit, oom_reraise,
+                     emit_engine_init, gather_coefficients_jit,
+                     gather_row_bytes, oom_reraise,
                      precompile, raise_deferred_failure,
                      record_structure_cache, register_engine_memory,
                      compact_magnitude, unroll_terms_ok, use_pair_complex)
@@ -599,7 +600,7 @@ class DistributedEngine:
                 _t_build = time.perf_counter()
                 with self.timer.scope("build_plan"), \
                         obs_trace.span("engine_init/build_plan",
-                                       kind="build"):
+                                       kind="build") as build_span:
                     try:
                         self._plan_stream(row_provider, compact=False)
                     except Exception as e:
@@ -609,6 +610,7 @@ class DistributedEngine:
                         oom_reraise(e, engine="distributed", mode=mode,
                                     phase="init",
                                     n_states=int(self.n_states))
+                    build_span.add(**self._plan_counts)
                 self._save_structure(structure_cache, soft=soft_save)
                 self._emit_plan_reshard(cache_arg,
                                         time.perf_counter() - _t_build)
@@ -1305,6 +1307,10 @@ class DistributedEngine:
                     self._ell_tail = (self._assemble_sharded(trow_shards),
                                       self._assemble_sharded(tidx_shards),
                                       self._assemble_sharded(tcf_shards))
+                live = max((int(nnz[d].sum()) for d in my_shards), default=0)
+                if multi:
+                    live = int(np.max(mhu.process_allgather(np.int64(live))))
+                self._plan_counts = self._ell_plan_counts(live)
             # the shards' uploads are not waited for
             obs_memory.sample_watermark("plan/pack")
         _mem_h.release()           # stream staging gone; tables resident
@@ -1312,6 +1318,28 @@ class DistributedEngine:
         # the tables resident and waited for
         obs_memory.sample_watermark("plan_upload/distributed",
                                     wait_for=self.structure_arrays())
+
+    def _ell_plan_counts(self, live_entries: int) -> dict:
+        """What one apply of the ELL plan moves on each chip, for the build
+        span and the ``engine_init`` event (every shard has the same padded
+        shapes, so each count is the fullest chip's): ``shard_rows`` (M),
+        ``row_gather_slots`` (the row gathers from ``x`` and the receive
+        buffer: M rows x the main table's width, plus the tail's rows x its
+        width), ``live_entries`` (the fullest shard's stored entries),
+        ``send_values`` (the values the pack gathers into the send buffer,
+        D x C; 0 on one device), ``row_bytes`` (one gathered row in the
+        chip's layout: 16, or 32 for a pair-form vector) and
+        ``value_bytes`` (8, or 16 for a complex value)."""
+        D, M = self.n_devices, self.shard_size
+        slots = M * self._ell_T0
+        if self._ell_tail is not None:
+            width, rows = self._ell_tail[1].shape[1:]
+            slots += width * rows
+        return {"shard_rows": int(M), "row_gather_slots": int(slots),
+                "live_entries": int(live_entries),
+                "send_values": int(D * self.query_capacity) if D > 1 else 0,
+                "row_bytes": gather_row_bytes(3 if self.real else 6),
+                "value_bytes": 8 if self.real else 16}
 
     def _finish_compact_aux(self, n_all_dev) -> None:
         """Derived compact-mode device arrays (recomputed on cache restore).

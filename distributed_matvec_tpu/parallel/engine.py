@@ -612,6 +612,7 @@ def emit_engine_init(eng, engine_kind: str, init_s: Optional[float] = None
          **getattr(eng, "_ell_counts", {}),
          **gather_table_counts(eng),
          **getattr(eng, "_ell_form", {}),
+         **getattr(eng, "_plan_counts", {}),
          **({} if init_s is None else {"init_s": round(init_s, 6)}))
 
 

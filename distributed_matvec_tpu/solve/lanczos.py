@@ -727,6 +727,47 @@ def _vdot(a, b):
     return jnp.sum(a.conj().reshape(-1) * b.reshape(-1))
 
 
+def _row_shape(shape):
+    """A basis row as the solver's programs hold it: flat, or, for a pair
+    vector (a trailing (re, im) axis of 2), its own shape.  A pair vector
+    flattened and put back for the apply is a transpose on a TPU, which
+    lays the (re, im) axis out as sublanes: the compiler then carried the
+    buffer, the window's rows and the vectors in layouts padded 16 to 128
+    times, and at ``chain_32_k1`` on four chips the full-sweep block program
+    needed 16.84 GB of a 15.75 GB chip (PERF.md, PR 39).  Decided by the
+    shape alone, so that a program built from (cap, shape, dtype) outside
+    ``lanczos`` (the benchmark's warm-up) is the one ``lanczos`` builds."""
+    if len(shape) > 1 and shape[-1] == 2:
+        return tuple(shape)
+    return (int(np.prod(shape)),)
+
+
+def _bcast(c, k):
+    """``c`` with ``k`` trailing axes of 1, to scale rows of ``k`` axes."""
+    return c[(...,) + (None,) * k]
+
+
+def _pair_dot(a, b):
+    """⟨a, b⟩ of two pair vectors of one shape: :func:`_vdot`'s form, with
+    no flattening (see :func:`_row_shape`)."""
+    return jnp.sum(a * b)
+
+
+def _J(w):
+    """Multiply-by-i on a pair vector: (re, im) → (−im, re)."""
+    return jnp.stack([-w[..., 1], w[..., 0]], axis=-1)
+
+
+def _project_J(wf, Vb, mask=1.0):
+    """``wf`` less its projections on ``J·Vb`` (the rows of ``Vb`` times i),
+    without forming ``J·Vb``: J is antisymmetric, so ⟨J·v, w⟩ = −⟨v, J·w⟩,
+    and Σ d_r J·v_r = J·Σ d_r v_r, so J turns two vectors and not every row
+    of the block."""
+    k = wf.ndim
+    d = jnp.sum(Vb * _J(wf)[None], axis=tuple(range(1, k + 1))) * mask
+    return wf + _J(jnp.sum(_bcast(d, k) * Vb, axis=0))
+
+
 @jax.jit
 def _combine_rows(S, Vf):
     """``Sᵀ·Vf`` — out[i] = Σ_j S[j, i]·Vf[j] for S [r, l] and the first r
@@ -735,22 +776,25 @@ def _combine_rows(S, Vf):
     reasons :func:`_vdot` gives.  As a ``tensordot`` the 96-row
     chain_32_symm basis became an ``f32[8, 96, 4707969]`` temporary
     (13.5 GB) and the thick restart did not fit a 16 GB chip.  One pass over
-    the [l, n] accumulator per block, not per row."""
+    the [l, n] accumulator per block, not per row.  A row of Vf may have
+    more than one axis (:func:`_row_shape`); out is then [l, *row]."""
     r, l = S.shape
-    n = Vf.shape[1]
+    row = Vf.shape[1:]
+    k = len(row)
 
     def add(Y, Sb, Vb):
-        return Y + jnp.sum(Sb[:, :, None] * Vb[:, None, :], axis=0)
+        return Y + jnp.sum(_bcast(Sb, k) * Vb[:, None], axis=0)
 
     def full_block(j, Y):
         r0 = j * _GS_BLOCK
         zero = jnp.zeros((), r0.dtype)
         return add(Y,
                    jax.lax.dynamic_slice(S, (r0, zero), (_GS_BLOCK, l)),
-                   jax.lax.dynamic_slice(Vf, (r0, zero), (_GS_BLOCK, n)))
+                   jax.lax.dynamic_slice(Vf, (r0,) + (zero,) * k,
+                                         (_GS_BLOCK,) + row))
 
     nfull, rest = divmod(r, _GS_BLOCK)
-    Y = jnp.zeros((l, n), jnp.promote_types(S.dtype, Vf.dtype))
+    Y = jnp.zeros((l,) + row, jnp.promote_types(S.dtype, Vf.dtype))
     if nfull:
         Y = jax.lax.fori_loop(0, nfull, full_block, Y)
     if rest:
@@ -774,21 +818,17 @@ def _make_block_runner(mv, mcap, shape, dtype, n_reorth, pair=False):
     orthogonalizes against J·V as well: ⟨v, w⟩ and ⟨J·v, w⟩ are exactly
     Re and −Im of the complex ⟨z, w⟩, so the J-aware recurrence *is*
     complex-arithmetic Lanczos (each eigenvalue once, no phantom copies) —
-    in pure f64.
+    in pure f64.  Pair rows keep their shape (:func:`_row_shape`).
 
     ``mv(x, operands)`` is a pure function: the engine's matrix tables ride
     in ``operands`` as real jit arguments.  Closing over them instead would
     bake gigabyte-scale constants into this program (see
     ``LocalEngine.bound_matvec``).
     """
-    nflat = int(np.prod(shape))
     nrows = _buffer_rows(mcap)
-
-    def J_rows(A):
-        """Multiply-by-i on flattened pair rows: (re, im) → (−im, re)."""
-        p = A.reshape(A.shape[:-1] + (nflat // 2, 2))
-        return jnp.stack([-p[..., 1], p[..., 0]],
-                         axis=-1).reshape(A.shape)
+    row = _row_shape(shape)
+    k = len(row)
+    dot = _pair_dot if pair else _vdot
 
     @partial(jax.jit, donate_argnums=(0, 1, 2))
     def run_block(V, alph, bet, m0, nsteps, operands):
@@ -799,17 +839,18 @@ def _make_block_runner(mv, mcap, shape, dtype, n_reorth, pair=False):
             # on v5e (no f64 MXU; measured 16.5 vs 2.5 ms for a [48, 4.7M]
             # slab), and the reorth passes dominated the iteration at scale.
             def project(wf, Vb, mask):
-                c = jnp.sum(Vb.conj() * wf[None, :], axis=1) \
-                    * mask.astype(wf.dtype)
-                return wf - jnp.sum(c[:, None] * Vb, axis=0)
+                c = jnp.sum(Vb.conj() * wf[None], axis=tuple(
+                    range(1, k + 1))) * mask.astype(wf.dtype)
+                return wf - jnp.sum(_bcast(c, k) * Vb, axis=0)
 
             def one_block(r0, wf):
                 Vb = jax.lax.dynamic_slice(
-                    Vf, (r0, jnp.zeros((), r0.dtype)), (_GS_BLOCK, nflat))
+                    Vf, (r0,) + (jnp.zeros((), r0.dtype),) * k,
+                    (_GS_BLOCK,) + row)
                 mask = (r0 + jnp.arange(_GS_BLOCK)) <= m
                 wf = project(wf, Vb, mask)
                 if pair:
-                    wf = project(wf, J_rows(Vb), mask)
+                    wf = _project_J(wf, Vb, mask.astype(wf.dtype))
                 return wf
 
             nblk = (m + 1 + _GS_BLOCK - 1) // _GS_BLOCK
@@ -819,20 +860,20 @@ def _make_block_runner(mv, mcap, shape, dtype, n_reorth, pair=False):
         def body(i, carry):
             V, alph, bet = carry
             m = m0 + i
-            Vf = V.reshape(nrows, nflat)
+            Vf = V.reshape((nrows,) + row)
             vm = jax.lax.dynamic_index_in_dim(Vf, m, keepdims=False)
             # named scopes: metadata on the operations (``op_name``), so
             # a device trace tells the phases of an iteration apart
             with jax.named_scope("lanczos/apply"):
                 w = mv(vm.reshape(shape), operands)
             with jax.named_scope("lanczos/recurrence"):
-                a = jnp.real(_vdot(vm, w))
-                wf = w.reshape(nflat)
+                a = jnp.real(dot(vm, w))
+                wf = w.reshape(row)
             with jax.named_scope("lanczos/reorth"):
                 for _ in range(n_reorth):
                     wf = mgs_pass(wf, Vf, m)
             with jax.named_scope("lanczos/recurrence"):
-                b = jnp.sqrt(jnp.real(_vdot(wf, wf)))
+                b = jnp.sqrt(jnp.real(dot(wf, wf)))
                 vnew = (wf / jnp.where(b <= 1e-300, 1.0, b)).astype(dtype)
             with jax.named_scope("lanczos/store"):
                 V = jax.lax.dynamic_update_index_in_dim(
@@ -885,8 +926,10 @@ def _make_window_runner(mv, mcap, shape, dtype, n_reorth, nsteps,
     The ω-gated host loop guarantees the window is enough: whenever the
     accumulated orthogonality estimate reaches √ε, the rest of the block
     runs the full sweep via :func:`_make_block_runner`."""
-    nflat = int(np.prod(shape))
     nrows = _buffer_rows(mcap)
+    row = _row_shape(shape)
+    k = len(row)
+    dot = _pair_dot if pair else _vdot
     # the trailing window: v_m and v_{m-1} (the recurrence pair) plus two
     # more recent rows of slack — PROPACK's local reorthogonalization uses
     # exactly the pair; the ω gate upgrades to full sweeps when locality
@@ -896,29 +939,24 @@ def _make_window_runner(mv, mcap, shape, dtype, n_reorth, nsteps,
     # escalated blocks run the full runner with its n_reorth sweeps
     n_local = max(1, n_reorth - 1)
 
-    def J_rows(A):
-        p = A.reshape(A.shape[:-1] + (nflat // 2, 2))
-        return jnp.stack([-p[..., 1], p[..., 0]],
-                         axis=-1).reshape(A.shape)
-
     @partial(jax.jit, donate_argnums=(0, 1, 2))
     def run_window(V, alph, bet, m0, omega, operands):
         om_curr, om_prev, eps, limit = omega
-        Vf = V.reshape(nrows, nflat)
+        Vf = V.reshape((nrows,) + row)
         r0 = jnp.maximum(m0 - (W_ROWS - 1), 0)
         W = jax.lax.dynamic_slice(
-            Vf, (r0, jnp.zeros((), r0.dtype)), (W_ROWS, nflat))
+            Vf, (r0,) + (jnp.zeros((), r0.dtype),) * k, (W_ROWS,) + row)
         # rows above m0 can be stale (short thick restarts leave old basis
         # rows beyond l) — zero them; zero rows project to nothing
-        W = jnp.where(((r0 + jnp.arange(W_ROWS)) <= m0)[:, None], W, 0)
+        W = jnp.where(_bcast((r0 + jnp.arange(W_ROWS)) <= m0, k), W, 0)
         # ring invariant: v_{m0} sits in the LAST row.  When m0 < W_ROWS-1
         # the clamped slice leaves it at index m0 — roll the (zeroed)
         # stale rows over the top
         W = jnp.roll(W, (W_ROWS - 1) - (m0 - r0), axis=0)
 
         def project(wf, Vb):
-            c = jnp.sum(Vb.conj() * wf[None, :], axis=1)
-            return wf - jnp.sum(c[:, None] * Vb, axis=0)
+            c = jnp.sum(Vb.conj() * wf[None], axis=tuple(range(1, k + 1)))
+            return wf - jnp.sum(_bcast(c, k) * Vb, axis=0)
 
         def work(carry):
             ran, _, W, alph, bet, om, omp = carry
@@ -927,15 +965,15 @@ def _make_window_runner(mv, mcap, shape, dtype, n_reorth, nsteps,
             with jax.named_scope("lanczos/apply"):
                 w = mv(vm.reshape(shape), operands)
             with jax.named_scope("lanczos/recurrence"):
-                a = jnp.real(_vdot(vm, w))
-                wf = w.reshape(nflat)
+                a = jnp.real(dot(vm, w))
+                wf = w.reshape(row)
             with jax.named_scope("lanczos/reorth"):
                 for _ in range(n_local):
                     wf = project(wf, W)
                     if pair:
-                        wf = project(wf, J_rows(W))
+                        wf = _project_J(wf, W)
             with jax.named_scope("lanczos/recurrence"):
-                b = jnp.sqrt(jnp.real(_vdot(wf, wf)))
+                b = jnp.sqrt(jnp.real(dot(wf, wf)))
                 vnew = (wf / jnp.where(b <= 1e-300, 1.0, b)).astype(dtype)
                 W = jnp.concatenate([W[1:], vnew[None]], axis=0)
             with jax.named_scope("lanczos/store"):
@@ -947,7 +985,7 @@ def _make_window_runner(mv, mcap, shape, dtype, n_reorth, nsteps,
             return (ran + 1, ~(worst < limit), W, alph, bet, new, om), vnew
 
         def rest(carry):
-            return carry, jnp.zeros((nflat,), dtype)
+            return carry, jnp.zeros(row, dtype)
 
         (ran, _, _, alph, bet, _, _), Vnew = jax.lax.scan(
             lambda carry, _: jax.lax.cond(carry[1], rest, work, carry),
@@ -955,7 +993,7 @@ def _make_window_runner(mv, mcap, shape, dtype, n_reorth, nsteps,
             None, length=nsteps)
         with jax.named_scope("lanczos/store"):
             Vf = jax.lax.dynamic_update_slice(
-                Vf, Vnew, (m0 + 1, jnp.zeros((), m0.dtype)))
+                Vf, Vnew, (m0 + 1,) + (jnp.zeros((), m0.dtype),) * k)
         return Vf.reshape(V.shape), alph, bet, ran
 
     return run_window
@@ -963,15 +1001,15 @@ def _make_window_runner(mv, mcap, shape, dtype, n_reorth, nsteps,
 
 def _make_restart(mcap, shape, dtype, l):
     """V[:l] ← SᵀV[:m] (kept Ritz vectors), V[l] ← last residual vector."""
-    nflat = int(np.prod(shape))
     nrows = _buffer_rows(mcap)
+    row = _row_shape(shape)
 
     @partial(jax.jit, donate_argnums=(0,))
     def restart(V, S_l):
-        Vf = V.reshape(nrows, nflat)
+        Vf = V.reshape((nrows,) + row)
         v_last = Vf[mcap]
         Y = _combine_rows(S_l.astype(dtype), Vf)
-        Vf = jax.lax.dynamic_update_slice(Vf, Y, (0, 0))
+        Vf = jax.lax.dynamic_update_slice(Vf, Y, (0,) * (len(row) + 1))
         Vf = jax.lax.dynamic_update_index_in_dim(Vf, v_last, l, axis=0)
         return Vf.reshape(V.shape)
 
@@ -2123,16 +2161,17 @@ def _lanczos_impl(
     evecs = None
     if compute_eigenvectors and m:
         with obs_trace.span("lanczos/epilogue", kind="phase"):
-            Vf = V.reshape(_buffer_rows(mcap), -1)
+            Vf = V.reshape((_buffer_rows(mcap),) + _row_shape(shape))
             Sj = jnp.asarray(S[:, :kk].astype(
                 np.complex128
                 if np.issubdtype(np.dtype(dtype), np.complexfloating)
                 else np.float64), dtype=dtype)
             E = _combine_rows(Sj, Vf)              # the first m rows of Vf
             evecs = []
+            dot = _pair_dot if pair else _vdot
             for i in range(kk):
                 e = E[i]
-                enrm = jnp.sqrt(jnp.real(_vdot(e, e)))
+                enrm = jnp.sqrt(jnp.real(dot(e, e)))
                 evecs.append((e / enrm.astype(dtype)).reshape(shape))
             # the combination and the norms are in flight
             obs_memory.sample_watermark("lanczos/epilogue")

@@ -76,6 +76,12 @@ T, T0, T_TAIL, S_TAIL = 32, 20, 12, 249_601
 CHUNK = 1 << 16
 LK_DIR, LK_SHIFT, LK_PROBES = (1 << 24) + 1, 8, 6
 M_CAP = 96                          # apps/diagonalize.py's default Krylov cap
+# chain_32_k1 on four chips (the benchmark's chain_32_k1_x4): the plan's
+# shapes read off the plain reference's matrix under the partition's hash
+# (my CPU run, PR 39) and, where they differ, the chip's (the build span,
+# my chip run, PR 39): M rows a shard, a query capacity C a peer, an ELL
+# table 20 wide and a tail of 124,080 rows 10 wide, pair rows
+K1_X4 = dict(M=2_348_928, C=2_320_800, T0=20, tail=(10, 124_080), pair=True)
 
 
 @pytest.fixture(scope="module")
@@ -229,10 +235,12 @@ def _range_engine(sh, config, pair=False, keep=None):
     return eng, S((n,) + ctail, jnp.float64)
 
 
-def _distributed_ell_engine(topo):
+def _distributed_ell_engine(topo, M=None, C=None, T0=T0, tail=None,
+                            pair=False):
     """A DistributedEngine shell carrying chain_32_symm's hash-sharded ELL
-    shapes on a 4-device mesh built from the described devices, and the
-    ``S(shape, dtype)`` that places an array on it."""
+    shapes (or the ones given: ``K1_X4``) on a 4-device mesh built from the
+    described devices, the ``S(shape, dtype)`` that places a vector on it,
+    and the mesh."""
     from distributed_matvec_tpu.parallel.distributed import DistributedEngine
     from distributed_matvec_tpu.parallel.mesh import SHARD_AXIS
 
@@ -244,21 +252,23 @@ def _distributed_ell_engine(topo):
         return jax.ShapeDtypeStruct(shape, dtype,
                                     sharding=NamedSharding(mesh, spec))
 
-    M = -(-N // D // 1024) * 1024 + 4096    # shard rows incl. hash imbalance
-    C = M // 2                              # per-peer query capacity
-    s_tail = S_TAIL // D + 1024
+    # shard rows incl. hash imbalance, and the per-peer query capacity
+    M = M or -(-N // D // 1024) * 1024 + 4096
+    C = C or M // 2
+    t_tail, s_tail = tail or (T_TAIL, S_TAIL // D + 1024)
+    pt = (2,) if pair else ()               # (re, im) pair rows
     eng = object.__new__(DistributedEngine)
     eng.n_devices, eng.query_capacity, eng.mesh = D, C, mesh
-    eng.pair, eng._dtype, eng._ell_T0 = False, jnp.float64, T0
+    eng.pair, eng._dtype, eng._ell_T0 = pair, jnp.float64, T0
     eng._qin = S((D, D, C), jnp.int32)
     eng._ell_idx = S((D, T0, M), jnp.int32)
-    eng._ell_coeff = S((D, T0, M), jnp.float64)
+    eng._ell_coeff = S((D, T0, M) + pt, jnp.float64)
     eng._diag = S((D, M), jnp.float64)
     eng._ell_tail = (S((D, s_tail), jnp.int32),
-                     S((D, T_TAIL, s_tail), jnp.int32),
-                     S((D, T_TAIL, s_tail), jnp.float64))
+                     S((D, t_tail, s_tail), jnp.int32),
+                     S((D, t_tail, s_tail) + pt, jnp.float64))
     eng._make_ell_matvec()
-    return eng, S((D, M), jnp.float64), mesh
+    return eng, S((D, M) + pt, jnp.float64), mesh
 
 
 def _compile(name, topo):
@@ -816,6 +826,68 @@ def test_distributed_ell_apply_compiles_on_four_devices(tpu_knobs, compiled):
     _fits(exe, "distributed ell apply (per device)")
     _fits(compiled("distributed_window"),
           "distributed lanczos window (per device)")
+
+
+def _fusion_signatures(exe):
+    """The optimised HLO's fusions as a device trace names them
+    (``benchmark/trace_reduce.py::signature``): result and operand shapes,
+    each operand's shape read off the instruction that defines it."""
+    text = exe.as_text()
+    shape_of = dict(re.findall(r"(%[\w.\-]+) = (\w+\[[0-9,]*\])", text))
+    found = []
+    for m in re.finditer(r"= (\w+\[[0-9,]*\])\S* fusion\(([^)]*)\)", text):
+        names = re.sub(r"/\*.*?\*/", "", m.group(2)).split(",")
+        operands = [shape_of[o.strip()] for o in names if o.strip()]
+        found.append(f"fusion {m.group(1)}({','.join(operands)})")
+    return found
+
+
+def test_chain_32_k1_x4_programs_fit_four_chips(topo, tpu_knobs):
+    """``chain_32_k1_x4``'s shard shapes on the described 2x2 mesh: the
+    pair-form distributed apply and the full-sweep block program, the
+    largest of a solve's programs (the J-aware reorthogonalisation over a
+    104-row buffer of ``[4, M, 2]`` pair rows, 3.91 GB a chip), compile and
+    fit a chip with the 0.3 GB that stay resident beside their arguments.
+    The apply's gathers are the ones ``benchmark/dist_gathers.py``
+    classifies: 20 + 10 row gathers from the chip's ``x`` with the
+    exchange's values behind it (M + 4 C rows of six f32 parts) and the
+    pack's two halves (4 C pair values from M rows).  With its pair rows
+    flattened (PR 39's parent) the block program needed 16.84 GB of the
+    15.75 GB the compiler allows a v5e chip, and was refused."""
+    import importlib
+    import sys
+
+    from distributed_matvec_tpu.solve.lanczos import (_buffer_rows,
+                                                      _make_block_runner)
+
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    gathers = importlib.import_module("benchmark.dist_gathers")
+    eng, x, mesh = _distributed_ell_engine(topo, **K1_X4)
+    M, C = K1_X4["M"], K1_X4["C"]
+    exe = jax.jit(eng._apply_fn).lower(x, eng._operands).compile()
+    assert _fits(exe, "pair-form distributed apply") < 3.5e9
+    sigs = _fusion_signatures(exe)
+    rows = [(int(g.group(1)), int(g.group(2))) for g in map(
+        gathers.ROW.match, sigs) if g and int(g.group(3)) == M + 4 * C]
+    packs = [(int(g.group(1)), g.group(2)) for g in map(
+        gathers.PACK.match, sigs) if g and int(g.group(3)) == M]
+    assert sorted(rows) == sorted([(M, 6)] * 20 + [(K1_X4["tail"][1], 6)] * 10)
+    assert packs == [(4 * C, ",2")] * 2
+
+    def mv(v, ops):
+        return eng._apply_fn(v, ops)[0].astype(jnp.float64)
+
+    rep = NamedSharding(mesh, P())
+    V = jax.ShapeDtypeStruct(
+        (_buffer_rows(M_CAP),) + x.shape, jnp.float64,
+        sharding=NamedSharding(mesh, P(None, *x.sharding.spec)))
+    ab = jax.ShapeDtypeStruct((M_CAP,), jnp.float64, sharding=rep)
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=rep)
+    fn = _make_block_runner(mv, M_CAP, x.shape, jnp.float64, 2, pair=True)
+    full = fn.lower(V, ab, ab, i32, i32, eng._operands).compile()
+    assert _fits(full, "pair-form full-sweep block (per device)") \
+        + 0.3e9 < 15.75 * 2 ** 30
 
 
 # what ``jax.named_scope`` leaves in the optimised HLO: every instruction's
