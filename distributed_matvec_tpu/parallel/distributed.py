@@ -26,6 +26,9 @@ Three modes, mirroring :class:`~.engine.LocalEngine`:
   — no u64 hashing, no sort, no searchsorted, no scatter at matvec time.
   This replaces the reference's *dynamic* producer/consumer routing with a
   compile-time communication plan, the way XLA itself handles sharded matmuls.
+  Where the peers read nearly all of each shard (:func:`exchange_gathers`)
+  the plan indexes the all-gathered shards instead: the terms gather from
+  ``all_gather(x_local)``, a table of D·M rows, and no send buffer is packed.
 
 * ``"compact"`` — the ELL routing plan with 4 B/entry sign-tagged indices
   for isotropic real sectors (coefficients derived as ``W·s·n(j)/n(i)`` at
@@ -150,6 +153,16 @@ def _round_up(n: int, b: int) -> int:
 def _pspec(ndim: int) -> P:
     """PartitionSpec splitting axis 0 over the mesh, replicating the rest."""
     return P(SHARD_AXIS, *([None] * (ndim - 1)))
+
+
+def exchange_gathers(D: int, M: int, C: int) -> bool:
+    """The ELL exchange's form, from the plan's own numbers: True where
+    all-gathering the D shards of M rows moves no more values than the
+    packed ``all_to_all`` of C values a peer, ``(D - 1)·M <= D·C``; the
+    same inequality says the all-gathered table (D·M rows) is no longer
+    than the receive-extended one (M + D·C).  One device exchanges
+    nothing."""
+    return D > 1 and (D - 1) * M <= D * C
 
 
 def _close_plan_files(files: dict) -> None:
@@ -514,6 +527,10 @@ class DistributedEngine:
         self._pipeline_req = pipeline_depth
         self.pipeline_depth = 0
         self._plan_remote_unique: Optional[int] = None
+        #: the ELL exchange: "packed" (a send buffer of C values a peer and
+        #: an ``all_to_all``) or "gathered" (the shards all-gathered); set
+        #: by the plan build from :func:`exchange_gathers`, or restored
+        self.exchange_form = "packed"
         self._n_my_shards = sum(
             1 for d in range(D) if self._shard_addressable(d))
 
@@ -1124,36 +1141,41 @@ class DistributedEngine:
             remote_unique = sum(queries[d][p].size for d in my_shards
                                 for p in range(D) if queries[d][p] is not None)
             self._plan_remote_unique = remote_unique
+            gathered = not compact and exchange_gathers(D, M, C)
+            self.exchange_form = "gathered" if gathered else "packed"
             log_debug(f"routing plan: D={D} M={M} T={T} T0={T0} tail={S} "
-                      f"capacity={C} remote_unique(local)={remote_unique}")
+                      f"capacity={C} remote_unique(local)={remote_unique} "
+                      f"exchange={self.exchange_form}")
 
             # qin[d][q] = the local indices peer q reads from this shard
             # (0-padded); sorted-unique order fixed by pass 1b.  queries[q][d]
             # lives on shard q's owner, so in a multi-controller run each
             # source shard's query lists cross processes in ONE bounded
-            # [D, C] allgather round.
-            qin_rows = {d: np.zeros((D, C), np.int32) for d in my_shards}
-            if not multi:
-                for d in my_shards:
-                    for q in range(D):
-                        if q != d:
-                            ql = queries[q][d]
-                            qin_rows[d][q, : ql.size] = ql
-            else:
-                for q in range(D):
-                    buf = np.zeros((D, C), np.int32)
-                    if q in queries:
-                        for dd in range(D):
-                            if dd != q:
-                                ql = queries[q][dd]
-                                buf[dd, : ql.size] = ql
-                    buf = np.sum(mhu.process_allgather(buf), axis=0,
-                                 dtype=np.int32)
+            # [D, C] allgather round.  The gathered form packs nothing.
+            self._qin = None
+            if not gathered:
+                qin_rows = {d: np.zeros((D, C), np.int32) for d in my_shards}
+                if not multi:
                     for d in my_shards:
-                        if d != q:
-                            qin_rows[d][q] = buf[d]
-            qin_shards = [qin_rows.get(d) for d in range(D)]
-            self._qin = self._assemble_sharded(qin_shards)
+                        for q in range(D):
+                            if q != d:
+                                ql = queries[q][d]
+                                qin_rows[d][q, : ql.size] = ql
+                else:
+                    for q in range(D):
+                        buf = np.zeros((D, C), np.int32)
+                        if q in queries:
+                            for dd in range(D):
+                                if dd != q:
+                                    ql = queries[q][dd]
+                                    buf[dd, : ql.size] = ql
+                        buf = np.sum(mhu.process_allgather(buf), axis=0,
+                                     dtype=np.int32)
+                        for d in my_shards:
+                            if d != q:
+                                qin_rows[d][q] = buf[d]
+                qin_shards = [qin_rows.get(d) for d in range(D)]
+                self._qin = self._assemble_sharded(qin_shards)
             obs_memory.sample_watermark("plan/queries")
 
         with obs_trace.span("plan/pack", kind="phase"):
@@ -1191,7 +1213,9 @@ class DistributedEngine:
                 for s, e, n_c, betas, cf, nz in chunks(d):
                     # per-entry destination: local index, or M + p·C + slot
                     # where slot = position in the pass-1b unique-state list
-                    # (binary search — the lists are sorted by construction)
+                    # (binary search — the lists are sorted by construction);
+                    # gathered, the row of the all-gathered shards: d·M + the
+                    # local index, or p·M + the peer's index pass 1b resolved
                     flat_b = betas[nz]
                     owner = shard_index_host(flat_b, D)
                     gflat = np.zeros(flat_b.size, np.int64)
@@ -1200,7 +1224,7 @@ class DistributedEngine:
                     if loc.any():
                         ip = np.searchsorted(a_d, flat_b[loc])
                         np.clip(ip, 0, M - 1, out=ip)
-                        gflat[loc] = ip
+                        gflat[loc] = d * M + ip if gathered else ip
                         if compact:
                             nflat[loc] = n_d[ip]
                     for p in range(D):
@@ -1211,7 +1235,8 @@ class DistributedEngine:
                             continue
                         pos = np.searchsorted(qstate[d][p], flat_b[sel])
                         np.clip(pos, 0, max(qstate[d][p].size - 1, 0), out=pos)
-                        gflat[sel] = M + p * C + pos
+                        gflat[sel] = (p * M + queries[d][p][pos] if gathered
+                                      else M + p * C + pos)
                         if compact:
                             nflat[sel] = qnorm[d][p][pos]
                     g = np.zeros(betas.shape, np.int64)
@@ -1326,20 +1351,34 @@ class DistributedEngine:
         ``row_gather_slots`` (the row gathers from ``x`` and the receive
         buffer: M rows x the main table's width, plus the tail's rows x its
         width), ``live_entries`` (the fullest shard's stored entries),
-        ``send_values`` (the values the pack gathers into the send buffer,
-        D x C; 0 on one device), ``row_bytes`` (one gathered row in the
-        chip's layout: 16, or 32 for a pair-form vector) and
-        ``value_bytes`` (8, or 16 for a complex value)."""
-        D, M = self.n_devices, self.shard_size
+        ``send_values`` and ``pack_values`` (:meth:`_exchange_counts`),
+        ``row_bytes`` (one gathered row in the chip's layout: 16, or 32 for
+        a pair-form vector) and ``value_bytes`` (8, or 16 for a complex
+        value)."""
+        M = self.shard_size
         slots = M * self._ell_T0
         if self._ell_tail is not None:
             width, rows = self._ell_tail[1].shape[1:]
             slots += width * rows
         return {"shard_rows": int(M), "row_gather_slots": int(slots),
                 "live_entries": int(live_entries),
-                "send_values": int(D * self.query_capacity) if D > 1 else 0,
+                **self._exchange_counts(),
                 "row_bytes": gather_row_bytes(3 if self.real else 6),
                 "value_bytes": 8 if self.real else 16}
+
+    def _exchange_counts(self) -> dict:
+        """A chip's exchange in one ELL / compact apply: ``send_values``,
+        the rows the exchange puts in the row-gather table beside the
+        chip's own (D x C packed, (D - 1) x M gathered), so that
+        ``shard_rows + send_values`` is that table's length; and
+        ``pack_values``, the values the pack gathers into the send buffer
+        (D x C packed, 0 gathered).  Both 0 on one device."""
+        D, M, C = self.n_devices, self.shard_size, self.query_capacity
+        if D == 1:
+            return {"send_values": 0, "pack_values": 0}
+        if self.exchange_form == "gathered":
+            return {"send_values": int((D - 1) * M), "pack_values": 0}
+        return {"send_values": int(D * C), "pack_values": int(D * C)}
 
     def _finish_compact_aux(self, n_all_dev) -> None:
         """Derived compact-mode device arrays (recomputed on cache restore).
@@ -1515,11 +1554,11 @@ class DistributedEngine:
                         if jax.process_count() == 1:
                             return self._load_structure_v2(cand)
                         continue   # keep scanning per-rank v3 candidates
-                    for k in ("T0", "C", "W"):
+                    for k in ("T0", "C", "W", "form"):
                         if k in g.attrs:
                             scalars[k] = g.attrs[k]
                     for d in my_shards:
-                        if f"qin_{d}" not in g:
+                        if f"idx_{d}" not in g:
                             continue
                         found_shards.add(d)
                         for k in names:
@@ -1532,9 +1571,16 @@ class DistributedEngine:
         need = {"T0", "C"} | ({"W"} if self.mode == "compact" else set())
         if set(my_shards) - found_shards or need - set(scalars):
             return False
+        # a sidecar written before the form was recorded is packed
+        form = str(scalars.get("form", "packed"))
+        if form not in ("packed", "gathered") or (
+                form == "packed"
+                and any(rows["qin"][d] is None for d in my_shards)):
+            return False
+        self.exchange_form = form
         self._ell_T0 = int(scalars["T0"])
         self.query_capacity = int(scalars["C"])
-        self._qin = put_rows(rows["qin"])
+        self._qin = put_rows(rows["qin"]) if form == "packed" else None
         has_tail = any(r is not None for r in rows["tail_rows"])
         if self.mode == "ell":
             self._ell_idx = put_rows(rows["idx"])
@@ -1565,6 +1611,7 @@ class DistributedEngine:
         if data is None:
             return False
         sh3 = shard_spec(self.mesh, 3)
+        self.exchange_form = "packed"
         self._ell_T0 = int(data["T0"])
         self.query_capacity = int(data["C"])
         self._qin = jax.device_put(jnp.asarray(data["qin"]), sh3)
@@ -1618,13 +1665,15 @@ class DistributedEngine:
         from ..io.hdf5 import save_engine_structure
 
         D = self.n_devices
-        payload = {"T0": self._ell_T0, "C": self.query_capacity}
+        payload = {"T0": self._ell_T0, "C": self.query_capacity,
+                   "form": self.exchange_form}
         if self.mode == "compact":
             payload["W"] = self._c_W
         for d in range(D):
             if not self._shard_addressable(d):
                 continue
-            payload[f"qin_{d}"] = self._shard_piece(self._qin, d)
+            if self._qin is not None:
+                payload[f"qin_{d}"] = self._shard_piece(self._qin, d)
             if self.mode == "ell":
                 payload[f"idx_{d}"] = self._shard_piece(self._ell_idx, d)
                 payload[f"coeff_{d}"] = self._shard_piece(self._ell_coeff, d)
@@ -3479,17 +3528,22 @@ class DistributedEngine:
         use_sg = split_gather_enabled()
         is_pair = self.pair
         nd_base = 2 if is_pair else 1   # ndim of one unbatched local vector
+        gathered = self.exchange_form == "gathered"
 
         def shard_body(x, qin, gidx, coeff, diag, tail):
-            x, qin, gidx, coeff, diag = (
-                a[0] for a in (x, qin, gidx, coeff, diag))
+            x, gidx, coeff, diag = (a[0] for a in (x, gidx, coeff, diag))
             batched = x.ndim == nd_base + 1
             # the named scopes are metadata on the operations (their
             # ``op_name``; a device trace carries it per event): no
             # operation is added, moved or split for them
-            if D > 1:
+            if D > 1 and gathered:
+                with jax.named_scope("apply/exchange"):
+                    # [D·M] + x.shape[1:], shard p's rows at p·M
+                    xx = jax.lax.all_gather(x, SHARD_AXIS, axis=0,
+                                            tiled=True)
+            elif D > 1:
                 with jax.named_scope("apply/pack"):
-                    S = x[qin]                  # [D, C] + x.shape[1:]
+                    S = x[qin[0]]               # [D, C] + x.shape[1:]
                 with jax.named_scope("apply/exchange"):
                     R = jax.lax.all_to_all(S, SHARD_AXIS, 0, 0, tiled=True)
                     xx = jnp.concatenate(
@@ -3540,7 +3594,9 @@ class DistributedEngine:
                 else P()
             f = jax.shard_map(
                 shard_body, mesh=mesh,
-                in_specs=(_pspec(x.ndim), _pspec(qin.ndim), _pspec(gidx.ndim),
+                in_specs=(_pspec(x.ndim),
+                          P() if qin is None else _pspec(qin.ndim),
+                          _pspec(gidx.ndim),
                           _pspec(coeff.ndim), _pspec(diag.ndim), tail_specs),
                 out_specs=_pspec(x.ndim),
             )
@@ -4119,7 +4175,8 @@ class DistributedEngine:
         * ``plan_h2d``   streamed mode's per-apply plan bytes (one full
           stream per ≤4-column group — the k>4 re-stream policy);
         * ``compute``    x gathers per structure entry (+ the send-side
-          ``x[qin]`` gather in ell/compact; the orbit scan in fused);
+          ``x[qin]`` gather in ell/compact where the exchange is packed;
+          the orbit scan in fused);
         * ``exchange``   exactly :meth:`_exchange_nbytes`'s send volume;
         * ``accumulate`` receive-side ``segment_sum`` slots (fused and
           streamed) or the two-level tail scatter rows (ell/compact).
@@ -4141,12 +4198,12 @@ class DistributedEngine:
         c = obs_phases.zero_counts()
         c["exchange"]["bytes"] = xbytes
         if self.mode in ("ell", "compact"):
-            C = self.query_capacity
+            pack = self._exchange_counts()["pack_values"]
             tail = self._ell_tail if self.mode == "ell" else self._c_tail
             cfb = (16 if cplx else 8) if self.mode == "ell" else 4 + 8
             g_tail = int(tail[1].shape[1] * tail[1].shape[2]) if tail else 0
             rows_t = int(tail[0].shape[1]) if tail else 0
-            g = nmy * (self._ell_T0 * M + g_tail + D * C)
+            g = nmy * (self._ell_T0 * M + g_tail + pack)
             c["compute"] = {"bytes": g * (vb * k + cfb), "gathers": g,
                             "flops": g * k * fmul}
             c["accumulate"] = {"bytes": nmy * rows_t * vb * k,
@@ -4207,7 +4264,8 @@ class DistributedEngine:
     def _exchange_nbytes(self, xh) -> int:
         """Estimated per-rank ``all_to_all`` send volume for ONE apply of
         ``xh`` (this rank's addressable shards only).  ELL/compact send
-        exactly the padded [D, C] query payload per shard; fused mode sends
+        exactly the padded [D, C] query payload per shard, or the other
+        D - 1 shards where the exchange is gathered; fused mode sends
         the fixed-capacity state+amplitude buckets per row chunk."""
         tail_elems = 1
         for s in xh.shape[2:]:
@@ -4222,7 +4280,8 @@ class DistributedEngine:
             return 0
         nmy = self._n_my_shards
         if self.mode in ("ell", "compact"):
-            return nmy * D * self.query_capacity * tail_elems * 8
+            return (nmy * self._exchange_counts()["send_values"]
+                    * tail_elems * 8)
         if self.mode in ("streamed", "hybrid"):
             # amplitudes only: the receive side already holds its layout,
             # so the betas no longer travel (half the fused exchange for
@@ -4414,12 +4473,14 @@ class DistributedEngine:
         """The live precomputed plan/structure arrays by name (empty in
         fused mode) — the single enumeration the memory ledger registers
         and :attr:`ell_nbytes` sums, parity-tested per mode.  Includes the
-        static routing plan (``qin``) the apply's ``all_to_all`` gathers
-        from; compact mode's derived norm tables count too (they were
-        silently missing from the hand-maintained total before)."""
+        static routing plan (``qin``) the apply's pack gathers from, where
+        the exchange is packed; compact mode's derived norm tables count
+        too (they were silently missing from the hand-maintained total
+        before)."""
         if self.mode == "ell":
-            out = {"idx": self._ell_idx, "coeff": self._ell_coeff,
-                   "qin": self._qin}
+            out = {"idx": self._ell_idx, "coeff": self._ell_coeff}
+            if self._qin is not None:
+                out["qin"] = self._qin
             if self._ell_tail is not None:
                 rows, t_idx, t_cf = self._ell_tail
                 out.update(tail_rows=rows, tail_idx=t_idx, tail_coeff=t_cf)

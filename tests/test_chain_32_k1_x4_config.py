@@ -115,9 +115,9 @@ def pair_form():
     update_config(complex_pair=prev)
 
 
-def _engine(op):
-    eng = DistributedEngine(op, n_devices=D)
-    assert eng.mode == "ell" and eng.n_devices == D
+def _engine(op, devices=D):
+    eng = DistributedEngine(op, n_devices=devices)
+    assert eng.mode == "ell" and eng.n_devices == devices
     return eng
 
 
@@ -146,6 +146,47 @@ def test_pair_form_distributed_apply_matches_the_reference(
     assert np.max(np.abs(y - want) / tol) <= 1.0
     low = reference.apply_rows(spec, reps, x, rows, np.complex64)
     assert np.max(np.abs(low - want) / tol) > 1e3
+
+
+@pytest.mark.parametrize("key, devices, form", [
+    (PAIR[0], 4, "gathered"), ((16, 0, 1), 4, "gathered"),
+    (PAIR[0], 8, "packed"), ((16, 0, 1), 8, "packed")],
+    ids=["pair_gathered", "real_gathered", "pair_packed", "real_packed"])
+def test_both_exchange_forms_match_local_engine_and_the_reference(
+        key, devices, form, ring, reference, pair_form):
+    """The ELL apply in either form of its exchange, as the plan's own
+    numbers choose it: on four devices the rings' peers read nearly all of
+    each shard and the shards are all-gathered (``pack_values`` 0), on
+    eight the exchange packs C values a peer (``pack_values`` 8 C).  Every
+    row against ``LocalEngine`` and against the plain reference at the
+    configuration's contract, in pair form and in a real sector."""
+    from distributed_matvec_tpu.parallel.engine import LocalEngine
+
+    op, spec, reps, _ = ring(key)
+    eng = _engine(op, devices)
+    assert eng.exchange_form == form
+    counts = eng._plan_counts
+    C = eng.query_capacity
+    assert counts["pack_values"] == (0 if form == "gathered"
+                                     else devices * C)
+    assert counts["send_values"] == (
+        (devices - 1) * eng.shard_size if form == "gathered"
+        else devices * C)
+    rng = np.random.default_rng(40 + devices)
+    x = rng.standard_normal(reps.size)
+    if eng.pair:
+        x = x + 1j * rng.standard_normal(reps.size)
+    x /= np.linalg.norm(x)
+    y = eng.from_hashed(eng.matvec(eng.to_hashed(x)))
+    local = LocalEngine(op)
+    y_local = local.matvec(K.pair_from_complex(x) if local.pair else x)
+    y_local = np.asarray(y_local)
+    if eng.pair:
+        y = K.complex_from_pair(y)
+        y_local = K.complex_from_pair(y_local)
+    want = reference.apply_rows(spec, reps, x, np.arange(reps.size))
+    np.testing.assert_allclose(y, y_local, atol=ATOL, rtol=RTOL)
+    np.testing.assert_allclose(y, want, atol=ATOL, rtol=RTOL)
 
 
 # ---------------------------------------------------------------------------
@@ -222,11 +263,12 @@ def test_the_pair_buffer_is_sharded_a_quarter_a_device(ring, pair_form,
 # (c) the plan's six counts against counts made from the reference's rows
 
 
-def _independent_counts(spec, reps, reference, T, pair):
-    """The six counts from the reference's own rows: a row's live terms are
-    its antiparallel bonds whose target's orbit has a norm; shards by the
-    partition's hash; the split of the table by the engine's rule on that
-    histogram."""
+def _independent_counts(spec, reps, reference, T, pair, D=D):
+    """The seven counts from the reference's own rows: a row's live terms
+    are its antiparallel bonds whose target's orbit has a norm; shards by
+    the partition's hash; the split of the table by the engine's rule on
+    that histogram; the exchange gathered where all-gathering the shards
+    moves no more values than packing C a peer, (D - 1) M <= D C."""
     s = reps.astype(np.uint32)
     owner = shard_index(reps, D)
     per_shard = np.bincount(owner, minlength=D)
@@ -252,32 +294,41 @@ def _independent_counts(spec, reps, reference, T, pair):
     T0, S, Tmax = choose_ell_split(hist, D * M, T, real_rows=reps.size)
     tail_rows = max(int((live[owner == d] > T0).sum()) for d in range(D)) \
         if S else 0
+    gathered = (D - 1) * M <= D * C
     return {"shard_rows": M,
             "row_gather_slots": M * T0 + tail_rows * (Tmax - T0),
             "live_entries": max(int(live[owner == d].sum())
                                 for d in range(D)),
-            "send_values": D * C,
+            "send_values": (D - 1) * M if gathered else D * C,
+            "pack_values": 0 if gathered else D * C,
             "row_bytes": 32 if pair else 16,
             "value_bytes": 16 if pair else 8}
 
 
-@pytest.mark.parametrize("key", PAIR + [(16, 0, 1)],
-                         ids=["pair_16", "pair_20", "real_16"])
-def test_the_plan_counts_equal_independent_counts(key, ring, reference,
-                                                  pair_form):
+@pytest.mark.parametrize("key, devices", [
+    (PAIR[0], 4), (PAIR[1], 4), ((16, 0, 1), 4), (PAIR[0], 8),
+    ((16, 0, 1), 8)],
+    ids=["pair_16", "pair_20", "real_16", "pair_16_8_devices",
+         "real_16_8_devices"])
+def test_the_plan_counts_equal_independent_counts(key, devices, ring,
+                                                  reference, pair_form):
     """``shard_rows``, ``row_gather_slots``, ``live_entries``,
-    ``send_values``, ``row_bytes`` and ``value_bytes`` on the
-    ``engine_init/build_plan`` span and in the ``engine_init`` event, in
-    pair form and in a real sector (k = 0) alike."""
+    ``send_values``, ``pack_values``, ``row_bytes`` and ``value_bytes`` on
+    the ``engine_init/build_plan`` span and in the ``engine_init`` event,
+    in pair form and in a real sector (k = 0) alike; on four devices the
+    rings' peers read nearly all of each shard and the exchange is
+    gathered (nothing packed, ``send_values`` 3 M), on eight it is packed
+    (``send_values`` = ``pack_values`` = 8 C)."""
     from distributed_matvec_tpu import obs
 
     op, spec, reps, _ = ring(key)
     obs.reset_all()
-    eng = _engine(op)
+    eng = _engine(op, devices)
     pair = key[1] != 0
     assert eng.pair == pair
     want = _independent_counts(spec, reps, reference,
-                               op.number_off_diag_terms, pair)
+                               op.number_off_diag_terms, pair, devices)
+    assert (want["pack_values"] == 0) == (devices == 4)
     build = [e for e in obs.events("span")
              if e.get("name") == "engine_init/build_plan"][-1]
     init = obs.events("engine_init")[-1]

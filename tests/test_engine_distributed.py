@@ -313,6 +313,76 @@ def test_distributed_structure_cache(mode, tmp_path, rng):
     assert not e3.structure_restored
 
 
+@pytest.mark.parametrize("D, M, C, gathered", [
+    (4, 1_177_216, 1_162_872, True),    # chain_32_symm on four chips
+    (4, 2_348_928, 2_320_800, True),    # its complex sector, pair form
+    # at the line, 3 M == 4 C, all-gathering moves no more: it is taken
+    (4, 100, 75, True),
+    (4, 100, 74, False),
+    (2, 100, 50, True),
+    (2, 100, 49, False),
+    (8, 1_664, 1_128, False),           # a 16-site ring over eight shards
+    (1, 100, 100, False),               # one device exchanges nothing
+])
+def test_exchange_form_rule(D, M, C, gathered):
+    """The ELL exchange all-gathers the shards exactly where that moves no
+    more values than the packed ``all_to_all``, (D - 1) M <= D C: at the
+    line, on both sides of it, and never on one device."""
+    from distributed_matvec_tpu.parallel.distributed import exchange_gathers
+
+    assert exchange_gathers(D, M, C) is gathered
+
+
+@needs_8
+@pytest.mark.parametrize("ndev, form", [(4, "gathered"), (8, "packed")])
+def test_structure_sidecar_keeps_the_exchange_form(ndev, form, tmp_path,
+                                                   rng):
+    """The sidecar records the exchange's form beside C and a restored
+    engine takes it: the same form, the routing table only where the
+    exchange packs, the apply bit for bit."""
+    import h5py
+
+    op = build_heisenberg(16, 8, None)
+    op.basis.build()
+    x = rng.random(op.basis.number_states) - 0.5
+    cache = str(tmp_path / "c.h5")
+    e1 = DistributedEngine(op, n_devices=ndev, structure_cache=cache)
+    assert not e1.structure_restored and e1.exchange_form == form
+    with h5py.File(e1._structure_sidecar(cache), "r") as f:
+        g = f["engine_structure"]
+        assert str(g.attrs["form"]) == form
+        assert ("qin_0" in g) == (form == "packed")
+    e2 = DistributedEngine(op, n_devices=ndev, structure_cache=cache)
+    assert e2.structure_restored and e2.exchange_form == form
+    assert (e2._qin is None) == (form == "gathered")
+    np.testing.assert_array_equal(e1.matvec_global(x), e2.matvec_global(x))
+
+
+@needs_8
+@pytest.mark.parametrize("ndev, restored", [(8, True), (4, False)])
+def test_a_sidecar_without_a_form_loads_as_packed(ndev, restored, tmp_path,
+                                                  rng):
+    """A sidecar written before the form was recorded is read as packed:
+    one that holds the routing table restores and applies bit for bit; one
+    whose plan was gathered (no routing table) is not restored, and the
+    engine rebuilds it."""
+    import h5py
+
+    op = build_heisenberg(16, 8, None)
+    op.basis.build()
+    x = rng.random(op.basis.number_states) - 0.5
+    cache = str(tmp_path / "c.h5")
+    e1 = DistributedEngine(op, n_devices=ndev, structure_cache=cache)
+    with h5py.File(e1._structure_sidecar(cache), "r+") as f:
+        del f["engine_structure"].attrs["form"]
+    e2 = DistributedEngine(op, n_devices=ndev, structure_cache=cache)
+    assert e2.structure_restored is restored
+    assert e2.exchange_form == e1.exchange_form
+    np.testing.assert_array_equal(e1.matvec_global(x), e2.matvec_global(x))
+    np.testing.assert_allclose(e2.matvec_global(x), op.matvec_host(x),
+                               atol=ATOL, rtol=RTOL)
+
+
 @needs_8
 @pytest.mark.parametrize("mode", ["ell", "compact", "fused"])
 def test_engine_from_shards_all_modes(mode, tmp_path, rng):

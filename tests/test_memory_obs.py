@@ -449,15 +449,23 @@ def test_local_ell_nbytes_parity(clean_obs, mode):
         f"engine/{eng._mem_instance}/structure") == expected
 
 
-@pytest.mark.parametrize("mode", ["ell", "compact", "fused"])
+@pytest.mark.parametrize("mode", ["ell", "ell_gathered", "compact", "fused"])
 def test_distributed_ell_nbytes_parity(clean_obs, mode):
     from distributed_matvec_tpu.parallel.distributed import DistributedEngine
 
-    op = build_heisenberg(10, 5, None, ())
+    # 252 states leave each peer reading under 3/4 of a shard: packed; at
+    # 12,870 it reads nearly all of it and the shards are all-gathered
+    gathered = mode == "ell_gathered"
+    op = build_heisenberg(*((16, 8) if gathered else (10, 5)), None, ())
+    mode = "ell" if gathered else mode
     eng = DistributedEngine(op, n_devices=4, mode=mode, batch_size=64)
     if mode == "ell":
-        expected = (eng._ell_idx.nbytes + eng._ell_coeff.nbytes
-                    + eng._qin.nbytes)
+        assert eng.exchange_form == ("gathered" if gathered else "packed")
+        # the send buffer's query table exists where the exchange packs
+        assert ("qin" in eng.structure_arrays()) == (not gathered)
+        expected = eng._ell_idx.nbytes + eng._ell_coeff.nbytes
+        if not gathered:
+            expected += eng._qin.nbytes
         if eng._ell_tail is not None:
             expected += sum(a.nbytes for a in eng._ell_tail)
     elif mode == "compact":

@@ -82,6 +82,10 @@ M_CAP = 96                          # apps/diagonalize.py's default Krylov cap
 # my chip run, PR 39): M rows a shard, a query capacity C a peer, an ELL
 # table 20 wide and a tail of 124,080 rows 10 wide, pair rows
 K1_X4 = dict(M=2_348_928, C=2_320_800, T0=20, tail=(10, 124_080), pair=True)
+# chain_32_symm on four chips (the benchmark's chain_32_symm_x4): M and C as
+# the chip's plan build gives them (its packed exchange's gather read 4 C =
+# 4,651,488 values from M rows): C / M = 0.988, where the plan all-gathers
+SYMM_X4 = dict(M=1_177_216, C=1_162_872)
 
 
 @pytest.fixture(scope="module")
@@ -240,8 +244,11 @@ def _distributed_ell_engine(topo, M=None, C=None, T0=T0, tail=None,
     """A DistributedEngine shell carrying chain_32_symm's hash-sharded ELL
     shapes (or the ones given: ``K1_X4``) on a 4-device mesh built from the
     described devices, the ``S(shape, dtype)`` that places a vector on it,
-    and the mesh."""
-    from distributed_matvec_tpu.parallel.distributed import DistributedEngine
+    and the mesh.  The exchange takes the form the plan's rule gives for
+    (D, M, C): packed at the default C = M / 2, gathered at the cells' C
+    (98.8% of M)."""
+    from distributed_matvec_tpu.parallel.distributed import (
+        DistributedEngine, exchange_gathers)
     from distributed_matvec_tpu.parallel.mesh import SHARD_AXIS
 
     D = 4
@@ -260,7 +267,9 @@ def _distributed_ell_engine(topo, M=None, C=None, T0=T0, tail=None,
     eng = object.__new__(DistributedEngine)
     eng.n_devices, eng.query_capacity, eng.mesh = D, C, mesh
     eng.pair, eng._dtype, eng._ell_T0 = pair, jnp.float64, T0
-    eng._qin = S((D, D, C), jnp.int32)
+    gathered = exchange_gathers(D, M, C)
+    eng.exchange_form = "gathered" if gathered else "packed"
+    eng._qin = None if gathered else S((D, D, C), jnp.int32)
     eng._ell_idx = S((D, T0, M), jnp.int32)
     eng._ell_coeff = S((D, T0, M) + pt, jnp.float64)
     eng._diag = S((D, M), jnp.float64)
@@ -275,15 +284,21 @@ def _compile(name, topo):
     """One program of the main path, compiled for the described chip(s):
     ``ell_apply``, ``window`` and ``full`` on one chip (``<program>@<key of
     HISTOGRAMS>`` at another histogram than the chain's), ``distributed_apply``
-    and ``distributed_window`` on the 4-device mesh."""
+    (packed), ``distributed_apply_gathered`` (the cells' C / M of 0.988) and
+    ``distributed_window`` on the 4-device mesh."""
     from distributed_matvec_tpu.solve.lanczos import (
         _buffer_rows, _make_block_runner, _make_window_runner)
 
     if name.startswith("distributed"):
         from distributed_matvec_tpu.parallel.mesh import SHARD_AXIS
 
-        eng, x, mesh = _distributed_ell_engine(topo)
-        if name == "distributed_apply":
+        if name == "distributed_apply_gathered":
+            eng, x, mesh = _distributed_ell_engine(topo, **SYMM_X4)
+            assert eng.exchange_form == "gathered"
+        else:
+            eng, x, mesh = _distributed_ell_engine(topo)
+            assert eng.exchange_form == "packed"
+        if name.startswith("distributed_apply"):
             return jax.jit(eng._apply_fn).lower(x, eng._operands).compile()
 
         # the Lanczos window over hashed [D, M] vectors that
@@ -848,10 +863,11 @@ def test_chain_32_k1_x4_programs_fit_four_chips(topo, tpu_knobs):
     largest of a solve's programs (the J-aware reorthogonalisation over a
     104-row buffer of ``[4, M, 2]`` pair rows, 3.91 GB a chip), compile and
     fit a chip with the 0.3 GB that stay resident beside their arguments.
-    The apply's gathers are the ones ``benchmark/dist_gathers.py``
-    classifies: 20 + 10 row gathers from the chip's ``x`` with the
-    exchange's values behind it (M + 4 C rows of six f32 parts) and the
-    pack's two halves (4 C pair values from M rows).  With its pair rows
+    At this C (98.8% of M) the plan all-gathers the shards, and the apply's
+    gathers are the ones ``benchmark/dist_gathers.py`` classifies as row
+    gathers: 20 + 10 from the four shards all-gathered (4 M rows of six f32
+    parts, the chip's own M and the 3 M the exchange put beside them), and
+    no pack.  With its pair rows
     flattened (PR 39's parent) the block program needed 16.84 GB of the
     15.75 GB the compiler allows a v5e chip, and was refused."""
     import importlib
@@ -864,16 +880,21 @@ def test_chain_32_k1_x4_programs_fit_four_chips(topo, tpu_knobs):
         os.path.abspath(__file__))))
     gathers = importlib.import_module("benchmark.dist_gathers")
     eng, x, mesh = _distributed_ell_engine(topo, **K1_X4)
-    M, C = K1_X4["M"], K1_X4["C"]
+    M = K1_X4["M"]
+    assert eng.exchange_form == "gathered"
     exe = jax.jit(eng._apply_fn).lower(x, eng._operands).compile()
-    assert _fits(exe, "pair-form distributed apply") < 3.5e9
+    # 3.62 GB where the packed form held 3.08: with no pack ahead of them
+    # the compiler slices the columns' coefficients and clamps their indices
+    # all before the first row gather (0.55 GB live at once)
+    assert _fits(exe, "pair-form distributed apply") < 3.75e9
+    assert "all-gather" in exe.as_text() and "all-to-all" not in exe.as_text()
     sigs = _fusion_signatures(exe)
     rows = [(int(g.group(1)), int(g.group(2))) for g in map(
-        gathers.ROW.match, sigs) if g and int(g.group(3)) == M + 4 * C]
-    packs = [(int(g.group(1)), g.group(2)) for g in map(
-        gathers.PACK.match, sigs) if g and int(g.group(3)) == M]
+        gathers.ROW.match, sigs) if g and int(g.group(3)) == 4 * M]
+    packs = [sig for sig in sigs if gathers.PACK.match(sig)
+             and int(gathers.PACK.match(sig).group(3)) == M]
     assert sorted(rows) == sorted([(M, 6)] * 20 + [(K1_X4["tail"][1], 6)] * 10)
-    assert packs == [(4 * C, ",2")] * 2
+    assert packs == []
 
     def mv(v, ops):
         return eng._apply_fn(v, ops)[0].astype(jnp.float64)
@@ -906,25 +927,36 @@ _INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s*=\s.*?[\w\-]+\(",
 @pytest.mark.parametrize("program, scopes", [
     ("ell_apply", LOCAL_SCOPES),
     ("distributed_apply", APPLY_SCOPES + EXCHANGE_SCOPES),
+    ("distributed_apply_gathered",
+     APPLY_SCOPES + ["apply/exchange", "apply/tail"]),
     ("window", LANCZOS_SCOPES + ["lanczos/omega"] + LOCAL_SCOPES),
     ("full", LANCZOS_SCOPES + LOCAL_SCOPES),
-], ids=["ell_apply", "distributed_apply", "window", "full"])
+], ids=["ell_apply", "distributed_apply_packed",
+        "distributed_apply_gathered", "window", "full"])
 def test_named_scopes_reach_the_tpu_hlo(topo, tpu_knobs, compiled,
                                         monkeypatch, program, scopes):
     """The TPU-optimised HLO of the programs a cell runs carries every
     named scope in some instruction's ``op_name``, and the scopes are
     metadata only: compiled with ``jax.named_scope`` switched off, each
-    program has the same number of instructions."""
+    program has the same number of instructions.  The distributed apply in
+    both forms of its exchange: packed (a gather named ``apply/pack``, then
+    an ``all-to-all``) and gathered (an ``all-gather``, nothing packed)."""
     text = compiled(program).as_text()
     op_names = re.findall(r'op_name="([^"]*)"', text)
     for scope in scopes:
         assert any(f"/{scope}/" in n for n in op_names), scope
+    lines = text.splitlines()
     if program == "distributed_apply":
         # the gather that fills the send buffer is the pack, by name
         assert any(" gather(" in line and "/apply/pack/" in line
-                   for line in text.splitlines())
+                   for line in lines)
         assert any(" all-to-all(" in line and "/apply/exchange/" in line
-                   for line in text.splitlines())
+                   for line in lines)
+    if program == "distributed_apply_gathered":
+        assert any(" all-gather" in line and "/apply/exchange/" in line
+                   for line in lines)
+        assert not any("/apply/pack/" in n for n in op_names)
+        assert "all-to-all" not in text
     monkeypatch.setattr(jax, "named_scope",
                         lambda name: contextlib.nullcontext())
     bare = _compile(program, topo).as_text()
