@@ -27,8 +27,9 @@ Three modes, mirroring :class:`~.engine.LocalEngine`:
   This replaces the reference's *dynamic* producer/consumer routing with a
   compile-time communication plan, the way XLA itself handles sharded matmuls.
   Where the peers read nearly all of each shard (:func:`exchange_gathers`)
-  the plan indexes the all-gathered shards instead: the terms gather from
-  ``all_gather(x_local)``, a table of D·M rows, and no send buffer is packed.
+  the plan indexes the all-gathered shards instead: every peer receives the
+  whole of ``x_local``, the terms gather from that table of D·M rows, and no
+  send buffer is packed.
 
 * ``"compact"`` — the ELL routing plan with 4 B/entry sign-tagged indices
   for isotropic real sectors (coefficients derived as ``W·s·n(j)/n(i)`` at
@@ -1472,6 +1473,11 @@ class DistributedEngine:
             hash_basis_operator(h, self.operator)
         h.update(f"dist|{self.mode}|{self.pair}|{self.real}"
                  f"|{self.n_devices}|{self.shard_size}|v2".encode())
+        if self.mode == "ell":
+            # the columns index the table of the exchange form the plan
+            # chose, which the sidecar records: one written before it did
+            # misses and is rebuilt under the rule
+            h.update(b"|exchange-form")
         if self.mode in ("streamed", "hybrid"):
             # the plan's dest/exchange layout bakes in the row-chunk size
             # and the per-peer capacity; a knob change must miss, not
@@ -1568,19 +1574,15 @@ class DistributedEngine:
                 from ..utils.artifacts import note_artifact_corrupt
                 note_artifact_corrupt(cand, "structure", e)
                 continue
-        need = {"T0", "C"} | ({"W"} if self.mode == "compact" else set())
+        need = {"T0", "C"} | ({"W"} if self.mode == "compact" else {"form"})
         if set(my_shards) - found_shards or need - set(scalars):
             return False
-        # a sidecar written before the form was recorded is packed
-        form = str(scalars.get("form", "packed"))
-        if form not in ("packed", "gathered") or (
-                form == "packed"
-                and any(rows["qin"][d] is None for d in my_shards)):
-            return False
-        self.exchange_form = form
+        # compact mode always packs
+        self.exchange_form = str(scalars.get("form", "packed"))
         self._ell_T0 = int(scalars["T0"])
         self.query_capacity = int(scalars["C"])
-        self._qin = put_rows(rows["qin"]) if form == "packed" else None
+        self._qin = (put_rows(rows["qin"])
+                     if self.exchange_form == "packed" else None)
         has_tail = any(r is not None for r in rows["tail_rows"])
         if self.mode == "ell":
             self._ell_idx = put_rows(rows["idx"])
@@ -3538,9 +3540,16 @@ class DistributedEngine:
             # operation is added, moved or split for them
             if D > 1 and gathered:
                 with jax.named_scope("apply/exchange"):
-                    # [D·M] + x.shape[1:], shard p's rows at p·M
-                    xx = jax.lax.all_gather(x, SHARD_AXIS, axis=0,
-                                            tiled=True)
+                    # every peer receives the whole shard: [D·M] +
+                    # x.shape[1:], shard p's rows at p·M.  An all_to_all of
+                    # the shard broadcast to the D peers moves what an
+                    # all_gather would, (D - 1)·M values; the all_gather is
+                    # asynchronous, and the compiler then slices every
+                    # column's coefficients while it runs, 1.6 GB more in a
+                    # pair-form block program
+                    B = jnp.broadcast_to(x[None], (D,) + x.shape)
+                    R = jax.lax.all_to_all(B, SHARD_AXIS, 0, 0, tiled=True)
+                    xx = R.reshape((D * x.shape[0],) + x.shape[1:])
             elif D > 1:
                 with jax.named_scope("apply/pack"):
                     S = x[qin[0]]               # [D, C] + x.shape[1:]

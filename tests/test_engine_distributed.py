@@ -359,13 +359,11 @@ def test_structure_sidecar_keeps_the_exchange_form(ndev, form, tmp_path,
 
 
 @needs_8
-@pytest.mark.parametrize("ndev, restored", [(8, True), (4, False)])
-def test_a_sidecar_without_a_form_loads_as_packed(ndev, restored, tmp_path,
-                                                  rng):
-    """A sidecar written before the form was recorded is read as packed:
-    one that holds the routing table restores and applies bit for bit; one
-    whose plan was gathered (no routing table) is not restored, and the
-    engine rebuilds it."""
+@pytest.mark.parametrize("ndev, form", [(4, "gathered"), (8, "packed")])
+def test_a_sidecar_without_a_form_is_rebuilt(ndev, form, tmp_path, rng):
+    """An ELL sidecar that does not record its exchange's form is not
+    restored, in either form: the engine rebuilds the plan under the rule,
+    writes the form, and applies bit for bit as before."""
     import h5py
 
     op = build_heisenberg(16, 8, None)
@@ -373,11 +371,13 @@ def test_a_sidecar_without_a_form_loads_as_packed(ndev, restored, tmp_path,
     x = rng.random(op.basis.number_states) - 0.5
     cache = str(tmp_path / "c.h5")
     e1 = DistributedEngine(op, n_devices=ndev, structure_cache=cache)
-    with h5py.File(e1._structure_sidecar(cache), "r+") as f:
+    sidecar = e1._structure_sidecar(cache)
+    with h5py.File(sidecar, "r+") as f:
         del f["engine_structure"].attrs["form"]
     e2 = DistributedEngine(op, n_devices=ndev, structure_cache=cache)
-    assert e2.structure_restored is restored
-    assert e2.exchange_form == e1.exchange_form
+    assert not e2.structure_restored and e2.exchange_form == form
+    with h5py.File(sidecar, "r") as f:
+        assert str(f["engine_structure"].attrs["form"]) == form
     np.testing.assert_array_equal(e1.matvec_global(x), e2.matvec_global(x))
     np.testing.assert_allclose(e2.matvec_global(x), op.matvec_host(x),
                                atol=ATOL, rtol=RTOL)
