@@ -21,6 +21,7 @@ import numpy as np
 
 from ..enumeration import enumerate_representatives as _enumerate
 from ..enumeration import host as _enum
+from ..obs import trace as obs_trace
 from .symmetry import SymmetryGroup
 
 __all__ = ["SpinBasis", "SpinlessFermionBasis", "SpinfulFermionBasis"]
@@ -260,21 +261,21 @@ class SpinfulFermionBasis(SpinBasis):
         d["number_down"] = self.number_down
         return d
 
+    def product_parts(self) -> Tuple[np.ndarray, np.ndarray]:
+        """(up, down): the sorted sectors of the two species, whose product
+        ``(down << n) | up``, down part first, the basis lists."""
+        n = self.physical_sites
+        return (_enum.all_states(n, self.number_up),
+                _enum.all_states(n, self.number_down))
+
     def build(self, force: bool = False) -> "SpinfulFermionBasis":
         if self._representatives is None or force:
             n = self.physical_sites
-            up = (
-                _enum.all_states(n, self.number_up)
-                if self.number_up is not None
-                else _enum.all_states(n, None)
-            )
-            down = (
-                _enum.all_states(n, self.number_down)
-                if self.number_down is not None
-                else _enum.all_states(n, None)
-            )
-            # cartesian product, ascending: state = (down << n) | up
-            states = (down[:, None] << np.uint64(n)) | up[None, :]
-            self._representatives = states.reshape(-1)
-            self._norms = np.ones(self._representatives.size)
+            with obs_trace.span("enumeration/product", kind="phase") as sp:
+                up, down = self.product_parts()
+                sp.add(up_states=int(up.size), down_states=int(down.size))
+                # cartesian product, ascending: state = (down << n) | up
+                states = (down[:, None] << np.uint64(n)) | up[None, :]
+                self._representatives = states.reshape(-1)
+                self._norms = np.ones(self._representatives.size)
         return self

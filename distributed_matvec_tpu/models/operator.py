@@ -223,6 +223,17 @@ class Operator:
         return self.off_diag_table.num_groups
 
     @property
+    def string_terms(self) -> int:
+        """Off-diagonal term groups with a leg whose sign reads a mode it
+        does not flip: the Jordan-Wigner string of a fermion hop between
+        modes that are not adjacent in bit order (the sign then depends on
+        the state); 0 for a Heisenberg coupling, whose signs read only the
+        sites it flips."""
+        t = self.off_diag_table
+        reaches = (t.s & ~t.x[:, None]) != 0
+        return int(np.any(reaches & (t.v != 0), axis=1).sum())
+
+    @property
     def is_hermitian(self) -> bool:
         by_key = {(t.x, t.s, t.m, t.r): t.v for t in self.terms}
         for t in self.terms:

@@ -123,7 +123,7 @@ from .engine import (SENTINEL_STATE, analyze_bound_apply, apply_diag_jit,
                      attach_traced_counter_check,
                      check_complex_backend, choose_ell_split,
                      emit_engine_init, gather_coefficients_jit,
-                     gather_row_bytes, oom_reraise,
+                     gather_row_bytes, oom_reraise, operator_counts,
                      precompile, raise_deferred_failure,
                      record_structure_cache, register_engine_memory,
                      compact_magnitude, unroll_terms_ok, use_pair_complex)
@@ -618,7 +618,9 @@ class DistributedEngine:
                 _t_build = time.perf_counter()
                 with self.timer.scope("build_plan"), \
                         obs_trace.span("engine_init/build_plan",
-                                       kind="build") as build_span:
+                                       kind="build",
+                                       **operator_counts(self.operator)
+                                       ) as build_span:
                     try:
                         self._plan_stream(row_provider, compact=False)
                     except Exception as e:
@@ -675,7 +677,8 @@ class DistributedEngine:
                 _t_build = time.perf_counter()
                 with self.timer.scope("build_plan"), \
                         obs_trace.span("engine_init/build_plan",
-                                       kind="build"):
+                                       kind="build",
+                                       **operator_counts(self.operator)):
                     try:
                         self._plan_stream(row_provider, compact=True)
                     except Exception as e:
@@ -790,7 +793,9 @@ class DistributedEngine:
                     _t_build = time.perf_counter()
                     with self.timer.scope("build_plan"), \
                             obs_trace.span("engine_init/build_plan",
-                                           kind="build"):
+                                           kind="build",
+                                           **operator_counts(
+                                               self.operator)):
                         try:
                             self._build_stream_plan(row_provider)
                             if mode == "hybrid":

@@ -53,7 +53,8 @@ from ..obs import hlo as obs_hlo
 from ..obs import profile as obs_profile
 from ..obs.events import obs_enabled
 from ..ops import kernels as K
-from ..ops.bits import build_sorted_lookup, state_index_bucketed
+from ..ops.bits import (build_product_lookup, build_sorted_lookup,
+                        state_index_bucketed)
 from ..ops.split_gather import prep_gather, split_gather_enabled, split_parts
 from ..utils.config import get_config
 from ..utils.logging import log_debug
@@ -585,6 +586,16 @@ def gather_table_counts(eng) -> Dict[str, int]:
             "row_bytes": gather_row_bytes(3 if eng.real else 6)}
 
 
+def operator_counts(operator) -> Dict[str, Any]:
+    """What a build span and the ``engine_init`` event say of the operator
+    an engine was built for: ``particle``, the basis's particle type
+    (``spin``, ``spinless_fermion``, ``spinful_fermion``), and
+    ``string_terms`` (:attr:`Operator.string_terms`: the off-diagonal terms
+    whose sign the build reads off each state's Jordan-Wigner string)."""
+    return {"particle": operator.basis.particle_type,
+            "string_terms": operator.string_terms}
+
+
 def emit_engine_init(eng, engine_kind: str, init_s: Optional[float] = None
                      ) -> None:
     """One ``engine_init`` telemetry event carrying the construction split
@@ -602,6 +613,7 @@ def emit_engine_init(eng, engine_kind: str, init_s: Optional[float] = None
          mode=eng.mode,
          n_states=int(eng.n_states),
          pair=bool(eng.pair),
+         **operator_counts(eng.operator),
          basis_restored=bool(getattr(eng, "basis_restored", False)),
          structure_restored=bool(getattr(eng, "structure_restored", False)),
          build_structure_s=round(build_s, 6),
@@ -977,6 +989,25 @@ def compact_magnitudes(operator, sample_size: int = 4096,
     return np.unique(np.abs(amps[amps != 0]))
 
 
+#: the widest part of a product basis whose rank tables the lookup holds
+#: (2^24 entries of 4 B a part)
+PRODUCT_LOOKUP_BITS = 24
+
+
+def basis_lookup(basis):
+    """The state lookup of the build's kernels: ranks of the two parts by
+    table for a spinful-fermion basis, the product of its species' sectors
+    (:func:`~..ops.bits.build_product_lookup`: two gathers a state); the
+    bucketed search of the sorted states otherwise."""
+    parts = getattr(basis, "product_parts", None)
+    if parts is not None and basis.physical_sites <= PRODUCT_LOOKUP_BITS:
+        up, down = parts()
+        # an index is an int32 of the tables, as every column index is
+        if up.size * down.size == basis.number_states < 2 ** 31:
+            return build_product_lookup(up, down, basis.physical_sites)
+    return build_sorted_lookup(basis.representatives, basis.number_bits)
+
+
 def _padded_basis_arrays(reps: np.ndarray, norms: np.ndarray, n_pad: int):
     pad = n_pad - reps.size
     alphas = np.concatenate([reps, np.full(pad, SENTINEL_STATE, np.uint64)])
@@ -1056,14 +1087,14 @@ class LocalEngine:
 
         reps, norms = basis.representatives, basis.norms
         alphas, nrm = _padded_basis_arrays(reps, norms, n_pad)
-        # Bucketed basis lookup (replaces searchsorted — see
-        # ops/bits.build_sorted_lookup): device arrays + static ints.
-        pair, dir_tab, self._lk_shift, self._lk_probes = build_sorted_lookup(
-            reps, basis.number_bits)
+        # Basis lookup (replaces searchsorted — see ops/bits.py): device
+        # arrays + static ints.
+        pair, dir_tab, self._lk_shift, self._lk_probes = basis_lookup(basis)
         with self.timer.scope("transfer"), \
                 obs_trace.span("engine_init/transfer", kind="phase"):
-            self._lk_pair = jnp.asarray(pair)         # [N, 2] u32
-            self._lk_dir = jnp.asarray(dir_tab)       # [2^b + 1] i32
+            # [N, 2] u32 and [2^b + 1] i32, or a product's two rank tables
+            self._lk_pair = jnp.asarray(pair)
+            self._lk_dir = jnp.asarray(dir_tab)
             self._alphas = jnp.asarray(alphas)        # [N_pad]
             self._norms = jnp.asarray(nrm)            # [N_pad]
             self.tables = K.device_tables(operator, pair=self.pair)
@@ -1099,7 +1130,9 @@ class LocalEngine:
             if not self.structure_restored:
                 with self.timer.scope("build_structure"), \
                         obs_trace.span("engine_init/build_structure",
-                                       kind="build") as build_span:
+                                       kind="build",
+                                       **operator_counts(operator)
+                                       ) as build_span:
                     try:
                         self._build_ell()
                     except Exception as e:
@@ -1127,7 +1160,8 @@ class LocalEngine:
             if not self.structure_restored:
                 with self.timer.scope("build_structure"), \
                         obs_trace.span("engine_init/build_structure",
-                                       kind="build"):
+                                       kind="build",
+                                       **operator_counts(operator)):
                     try:
                         self._build_compact()
                     except Exception as e:

@@ -148,11 +148,13 @@ def test_full_yaml_matrix_loads():
         assert cfg.hamiltonian.number_off_diag_terms > 0
 
 
-REPO_COPIES = {  # name: (spins, hamming weight, off-diagonal terms)
+REPO_COPIES = {  # name: (spins or modes, hamming weight, off-diagonal terms)
     "heisenberg_chain_16.yaml": (16, 8, 16),
     "heisenberg_chain_28.yaml": (28, 14, 28),
     "heisenberg_chain_32_symm.yaml": (32, 16, 32),
     "heisenberg_square_5x5.yaml": (25, 13, 50),
+    # 16 sites of spinful fermions, 5 up and 5 down: no one weight
+    "hubbard_square_4x4.yaml": (32, None, 64),
 }
 
 
